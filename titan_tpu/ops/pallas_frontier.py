@@ -140,10 +140,8 @@ def _frontier_round_kernel(cols_ref, undec_ref, more_ref, pay0_ref,
     sel = (pos[:, None] == tgt) & surv[:, None]         # (B, B) one-hot
     slab0 = jnp.where(sel, pay0_ref[...][0][:, None], 0).sum(axis=0)
     slab1 = jnp.where(sel, pay1_ref[...][0][:, None], 0).sum(axis=0)
-    pl.store(pay0_out, (pl.dslice(0, 1), pl.dslice(cur, block)),
-             slab0[None, :])
-    pl.store(pay1_out, (pl.dslice(0, 1), pl.dslice(cur, block)),
-             slab1[None, :])
+    pay0_out[pl.ds(0, 1), pl.ds(cur, block)] = slab0[None, :]
+    pay1_out[pl.ds(0, 1), pl.ds(cur, block)] = slab1[None, :]
     cursor_ref[0] = cur + cnt
     nsur_ref[0, 0] = cur + cnt
 

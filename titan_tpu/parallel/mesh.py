@@ -36,17 +36,11 @@ VERTEX_AXIS = "v"
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """Version-spanning shard_map: ``jax.shard_map`` (new spelling) when
-    present, ``jax.experimental.shard_map`` otherwise. Replication
-    checking is disabled either way (check_vma/check_rep) — the engine
-    kernels return deliberately-replicated pmax'd stats next to sharded
-    state, which the checker rejects."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checking disabled
+    (check_vma) — the engine kernels return deliberately-replicated
+    pmax'd stats next to sharded state, which the checker rejects."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 #: mesh cache: one Mesh object per device count (device order is
