@@ -19,13 +19,8 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    cache = __file__.rsplit("/", 2)[0] + "/.bench_cache/xla"
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-    except Exception:
-        pass
+    from titan_tpu.utils.jitcache import enable_compile_cache
+    enable_compile_cache()
 
     E = 1 << 27                        # 134M gathers per trial
     rng = np.random.default_rng(0)

@@ -20,13 +20,8 @@ def main():
                                            pagerank_dense)
     from titan_tpu.olap.tpu import graph500
 
-    cache = __file__.rsplit("/", 2)[0] + "/.bench_cache/xla"
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-    except Exception:
-        pass
+    from titan_tpu.utils.jitcache import enable_compile_cache
+    enable_compile_cache()
 
     scale = int(sys.argv[1]) if len(sys.argv) > 1 else 26
     lj = int(sys.argv[2]) if len(sys.argv) > 2 else 22

@@ -1,0 +1,169 @@
+"""Ask the chip's compiler, without the chip.
+
+libtpu compiles for a TPU that is described, not attached
+(``topologies.get_topology_desc``), so the kernels ``chip_smoke.py``
+dispatches are compiled here at their real sizes — scale-20 R-MAT,
+n = 2^20, 33.5 M symmetric edges in Q = 4,563,400 chunk columns, K = 8
+— and what the compiler refuses costs no chip time. This is the only
+file that describes the chip. The topology and everything built from it
+live in module-scoped fixtures: only the worker that RUNS this file
+loads libtpu (one process at a time may), every worker collects the
+same tests, and the compiles happen in the test's own process.
+
+A compile that passes here is not a chip run.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+N = 1 << 20            # vertices, scale 20
+E = 2 * 16 * N         # symmetrised R-MAT edges, edge factor 16
+Q = 4_563_400          # chunk columns of that graph (seed 2)
+K = 8                  # fused BFS cohort
+NB = (N + 2 + 7) // 8  # frontier bitmap bytes per job
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """Sharding on one described v5e chip. The persistent compile cache
+    is off around these compiles: tests/conftest.py keeps it on with a
+    zero threshold, and an entry written for a TPU cannot be read back
+    without one (every later run would warn and recompile)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def spec(one_chip):
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+def _compile(fn, *args, **static):
+    """Lower the registered jitted function (jit_once hands out a
+    profile shim; the jit itself is ``__wrapped__``) or jit a plain one."""
+    raw = getattr(fn, "__wrapped__", None) or jax.jit(fn)
+    compiled = raw.lower(*args, **static).compile()
+    assert compiled.memory_analysis() is not None
+    return compiled
+
+
+# -- the engine's combine: the branch only the chip takes ------------------
+
+@pytest.mark.parametrize("combine,dtype", [("sum", jnp.float32),
+                                           ("min", jnp.int32)])
+def test_sorted_segment_combine(spec, combine, dtype):
+    from titan_tpu.ops.segment import sorted_segment_combine
+
+    _compile(functools.partial(sorted_segment_combine, combine=combine),
+             spec((E,), dtype), spec((E,), jnp.int32),
+             spec((N,), jnp.int32), spec((N,), jnp.bool_))
+
+
+def test_pallas_seg_scan(spec):
+    from titan_tpu.ops.pallas_segment import pallas_seg_scan
+
+    text = _compile(functools.partial(pallas_seg_scan, combine="sum"),
+                    spec((E,), jnp.float32),
+                    spec((E,), jnp.bool_)).as_text()
+    assert "tpu_custom_call" in text      # the kernel is in the program
+
+
+# -- what phase 3 of the smoke dispatches (keys and shapes taken from a
+#    devprof'd CPU rehearsal at scale 20: the most-called jit_once keys) ----
+
+def test_batched_bfs_plan(spec):
+    from titan_tpu.models.bfs_hybrid import _batched_plan
+
+    _compile(_batched_plan(), spec((K, N + 1), jnp.int32),
+             spec((K,), jnp.bool_), spec((), jnp.int32),
+             spec((N + 1,), jnp.int32), c_cap=N, n_=N, expand=False)
+
+
+def test_batched_bfs_bottom_up(spec):
+    from titan_tpu.models.bfs_hybrid import _batched_bu
+
+    _compile(_batched_bu(), spec((K, N + 1), jnp.int32),
+             spec((K, NB), jnp.uint8), spec((N,), jnp.int32),
+             spec((N,), jnp.int32), spec((2,), jnp.int32),
+             spec((), jnp.int32), spec((8, Q), jnp.int32),
+             spec((N + 1,), jnp.int32), spec((N + 1,), jnp.int32),
+             spec((1,), jnp.uint8),
+             c_cap=N, n_=N, fuse=8, masked=False, expand=False)
+
+
+def test_frontier_push_list_sssp(spec):
+    from titan_tpu.models.frontier import _push_list
+
+    _compile(_push_list("sssp"), spec((N + 1,), jnp.float32),
+             spec((N + 1,), jnp.float32), spec((N,), jnp.int32),
+             spec((65,), jnp.int32), spec((), jnp.int32),
+             spec((), jnp.float32), spec((8, Q), jnp.int32),
+             spec((N + 1,), jnp.int32), spec((N + 1,), jnp.int32),
+             spec((2,), jnp.float32), spec((1,), jnp.uint8),
+             f_cap=N, p_cap=1 << 22, n_=N, masked=False)
+
+
+@pytest.mark.parametrize("rows", [None, 1], ids=["pagerank", "ppr"])
+def test_pagerank_window(spec, rows):
+    from titan_tpu.models.frontier import _pr_window
+    from titan_tpu.models.pagerank import _ppr_window_batched
+
+    fn = _pr_window() if rows is None else _ppr_window_batched()
+    lead = () if rows is None else (rows,)
+    _compile(fn, spec(lead + (N + 1,), jnp.float32),
+             spec(lead + (N + 1,), jnp.float32), spec((), jnp.int32),
+             spec((8, Q), jnp.int32), spec((Q,), jnp.int32), W=1 << 22)
+
+
+# -- the Pallas frontier kernel: refused (ROADMAP S5 ports it) --------------
+
+@pytest.mark.xfail(strict=True, raises=ValueError,
+                   reason="the chip's compiler refuses frontier_round: "
+                          "ValueError: Shape mismatch in input, indices "
+                          "and output (pallas/mosaic/lowering.py, "
+                          "_gather_lowering_rule — the in-kernel jnp.take "
+                          "gathers); ROADMAP S5's port flips this")
+def test_pallas_frontier_round(spec):
+    from titan_tpu.ops.pallas_frontier import frontier_round
+
+    C = 1 << 17
+    _compile(functools.partial(frontier_round, lanes=4, fill0=N, fill1=0,
+                               interpret=False),
+             spec((C,), jnp.int32), spec((K, C), jnp.bool_),
+             spec((C,), jnp.bool_), spec((C,), jnp.int32),
+             spec((C,), jnp.int32), spec((K, N // 8), jnp.uint8), None,
+             spec((8, Q), jnp.int32))
+
+
+def test_pallas_frontier_opt_in_raises_on_tpu(monkeypatch):
+    """The refusal is reported at the gate, not at job time — and
+    interpret mode cannot be reached on a TPU backend."""
+    from titan_tpu.ops import pallas_frontier as pf
+
+    monkeypatch.setenv("TITAN_TPU_FRONTIER_KERNEL", "pallas")
+    assert pf.frontier_kernel_mode() == "pallas"      # CPU: interpreter
+    assert pf.frontier_interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pf.frontier_interpret() is False
+    with pytest.raises(RuntimeError, match="Shape mismatch in input"):
+        pf.frontier_kernel_mode()

@@ -305,7 +305,7 @@ def test_plane_publishes_device_merged_epoch(graph):
         for attr in ("src", "dst", "indptr_in", "out_degree"):
             assert (getattr(snap1, attr)
                     == getattr(rebuilt, attr)).all(), attr
-        # byte accounting: only delta pages crossed the tunnel
+        # byte accounting: only delta pages went host→device
         up = st["counters"]["upload_bytes"]
         assert 0 < up < snapshot_csr_bytes(rebuilt)
         assert st["compact_device_ms"]["count"] == 1

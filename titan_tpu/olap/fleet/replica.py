@@ -67,6 +67,8 @@ def main(argv: Optional[list] = None) -> None:
         raise SystemExit(2)
     raw = sys.stdin.read() if args[0] == "-" else args[0]
     config = json.loads(raw)
+    from titan_tpu.utils.jitcache import enable_compile_cache
+    enable_compile_cache()
     graph, scheduler, server = build(config)
     server.start()
     host = config.get("host", "127.0.0.1")

@@ -34,15 +34,13 @@ across the plain / batched / sharded callers and the overlay and
 level-mask seams.
 
 Kept behind ``TITAN_TPU_FRONTIER_KERNEL=pallas`` (or the explicit
-``frontier_round`` call) until it wins on-device benchmarks; the
-``bfs_pallas`` bench stage captures the on-chip verdict
-(``pallas_bu_speedup`` in ``bench.py --evidence``). CPU-proxy caveats,
-honestly: interpreter mode emulates the kernel with XLA ops, so CPU
-wall times say NOTHING about the chip; and this first cut keeps
-``dstT`` as a whole-array VMEM input — valid at test shapes and on
-chip-day smoke scales, but the s26 9GB edge image needs the input
-moved to ANY/HBM space with per-block DMA before the heavy-level
-capture (recorded in PERF_NOTES r18).
+``frontier_round`` call) and, as it stands, an interpreter-mode kernel
+only: the chip's compiler refuses it (``TPU_REFUSAL`` below), so the
+opt-in raises on a TPU backend. CPU-proxy caveats, honestly:
+interpreter mode emulates the kernel with XLA ops, so CPU wall times
+say NOTHING about the chip; and this first cut keeps ``dstT`` as a
+whole-array VMEM input — valid at test shapes only; the port (ROADMAP
+S5) moves it to ANY/HBM space with per-block DMA.
 """
 
 from __future__ import annotations
@@ -56,21 +54,44 @@ import numpy as np
 DEFAULT_BLOCK = 1024
 
 
+#: what the chip's compiler says of ``frontier_round`` at
+#: ``interpret=False`` (Mosaic lowering, libtpu 0.0.34 / TPU v5 lite,
+#: K=8, C=2^17, the scale-20 ``dstT``, blocks 1024/512/128 alike;
+#: pinned by tests/test_chip_compile.py). The in-kernel ``jnp.take``
+#: gathers have no Mosaic lowering at these shapes — ROADMAP S5's port
+#: (streamed ``dstT``, aligned stores) replaces them.
+TPU_REFUSAL = ("ValueError: Shape mismatch in input, indices and output "
+               "(jax/_src/pallas/mosaic/lowering.py, "
+               "_gather_lowering_rule)")
+
+
 def frontier_kernel_mode() -> str:
     """``TITAN_TPU_FRONTIER_KERNEL`` — ``xla`` (default: the chain in
-    models/bfs_hybrid.py) or ``pallas`` (this kernel; interpreter mode
-    off-TPU). Raises on junk rather than silently falling back."""
+    models/bfs_hybrid.py) or ``pallas`` (this kernel, interpreter mode,
+    off-TPU only). Raises on junk rather than silently falling back,
+    and raises on a TPU backend: the chip's compiler refuses the kernel
+    (``TPU_REFUSAL``), so the opt-in fails here, at the gate, instead of
+    at job time."""
     mode = os.environ.get("TITAN_TPU_FRONTIER_KERNEL", "xla")
     if mode not in ("xla", "pallas"):
         raise ValueError(
             f"TITAN_TPU_FRONTIER_KERNEL={mode!r}: expected xla|pallas")
+    if mode == "pallas":
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "TITAN_TPU_FRONTIER_KERNEL=pallas on a TPU backend: the "
+                "chip's compiler refuses ops/pallas_frontier."
+                f"frontier_round — {TPU_REFUSAL}")
     return mode
 
 
 def frontier_interpret() -> bool:
-    """Interpreter mode off-TPU: the same flag serves the CPU parity
-    tests and the chip — callers pass this as the kernel's static
-    ``interpret`` argument."""
+    """The kernel's static ``interpret`` argument for the five callers
+    in models/: true off-TPU (the CPU parity tests run the kernel in
+    interpreter mode), never true on a TPU backend — and there
+    ``frontier_kernel_mode`` has already refused the opt-in."""
     import jax
 
     return jax.default_backend() != "tpu"

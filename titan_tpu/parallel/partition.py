@@ -183,6 +183,16 @@ def block_layout(colstart: np.ndarray, degc_all: np.ndarray, n: int,
 # explicit NamedSharding placement (ISSUE 13)
 # ---------------------------------------------------------------------------
 
+def _placeable(a):
+    """Host data stays a numpy array so ``device_put`` ships each shard
+    straight to its own device; going through ``jnp.asarray`` first
+    would land the whole stacked image on the first device and reshard
+    it from there. Arrays already on device pass through."""
+    import jax
+
+    return a if isinstance(a, jax.Array) else np.asarray(a)
+
+
 def place_shards(mesh, *arrays):
     """Commit per-shard arrays (leading dim = num_shards) onto the
     mesh with explicit ``NamedSharding(mesh, P("v", None, ...))`` —
@@ -190,12 +200,11 @@ def place_shards(mesh, *arrays):
     pays a host round trip or a device reshuffle to put shard d's rows
     on device d. Returns the placed arrays in order."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     out = []
     for a in arrays:
-        a = jnp.asarray(a)
+        a = _placeable(a)
         spec = P(VERTEX_AXIS, *([None] * (a.ndim - 1)))
         out.append(jax.device_put(a, NamedSharding(mesh, spec)))
     return out
@@ -204,11 +213,10 @@ def place_shards(mesh, *arrays):
 def place_replicated(mesh, *arrays):
     """Commit arrays fully replicated (``P()``) across the mesh."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     sh = NamedSharding(mesh, P())
-    return [jax.device_put(jnp.asarray(a), sh) for a in arrays]
+    return [jax.device_put(_placeable(a), sh) for a in arrays]
 
 
 # ---------------------------------------------------------------------------
@@ -292,7 +300,6 @@ def place_batched_csr(snap_or_graph, mesh) -> dict:
     cache = g.get("_meshed")
     if cache is not None and cache[0] == mesh:
         return cache[1]
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n = g["n"]
@@ -310,7 +317,7 @@ def place_batched_csr(snap_or_graph, mesh) -> dict:
     devprof.count_h2d("parallel.batched_csr", dstT_h.nbytes)
     placed = dict(g)
     placed["dstT"] = jax.device_put(
-        jnp.asarray(dstT_h), NamedSharding(mesh, P(None, VERTEX_AXIS)))
+        dstT_h, NamedSharding(mesh, P(None, VERTEX_AXIS)))
     placed["colstart"], placed["degc"], placed["deg"] = place_replicated(
         mesh, g["colstart"], g["degc"], g["deg"])
     if (n + 1) % D == 0:

@@ -881,13 +881,15 @@ def main(argv: Optional[list] = None) -> None:
     ``python -m titan_tpu.server --console inmemory``."""
     import sys
     args = list(sys.argv[1:] if argv is None else argv)
-    if args and args[0] == "--console":
-        console(args[1] if len(args) > 1 else "inmemory")
-        return
     if not args:
         print("usage: python -m titan_tpu.server <conf.yaml> | "
               "--console <backend>", file=sys.stderr)
         raise SystemExit(2)
+    from titan_tpu.utils.jitcache import enable_compile_cache
+    enable_compile_cache()
+    if args[0] == "--console":
+        console(args[1] if len(args) > 1 else "inmemory")
+        return
     server = from_yaml(args[0]).start()
     print(f"titan_tpu server listening on {server.host}:{server.port}")
     try:
