@@ -212,8 +212,39 @@ def phase_device(chips: int) -> dict:
               f"exceeds the device's bytes_limit {limit}")
         log(f"phase 0 hbm: DEFAULT_BUDGET_BYTES="
             f"{hbm.DEFAULT_BUDGET_BYTES:.4e} fits bytes_limit={limit}")
+    _probe_host_link()
     return {"platform": dev.platform, "kind": dev.device_kind,
             "count": len(devs)}
+
+
+def _probe_host_link() -> None:
+    """Smoke-grade figures for the host<->device link (ROADMAP S2):
+    medians of a few readings, printed, never compared."""
+    import jax
+    import jax.numpy as jnp
+
+    def median_s(fn, reps):
+        ts = []
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            fn()
+            ts.append(time.perf_counter() - t0)
+        return float(np.median(ts))
+
+    bump = jax.jit(lambda x: x + 1)
+    x = bump(jnp.int32(0))
+    int(x)                                                  # compiled, warm
+    sync = median_s(lambda: int(bump(x)), 20)
+    put = median_s(lambda: jnp.int32(7).block_until_ready(), 20)
+    host = np.zeros(64 << 20, np.uint8)                     # 64 MiB
+    h2d = median_s(lambda: jax.device_put(host).block_until_ready(), 5)
+    # a device array keeps its host copy after the first readback, so
+    # every reading takes a fresh one
+    fresh = [jax.device_put(host).block_until_ready() for _ in range(5)]
+    d2h = median_s(lambda: np.asarray(fresh.pop()), 5)
+    log(f"phase 0 host link: dispatch+scalar readback {sync * 1e3:.3f} ms, "
+        f"scalar put {put * 1e3:.3f} ms, H2D {64 / 1024 / h2d:.2f} GiB/s, "
+        f"D2H {64 / 1024 / d2h:.2f} GiB/s (64 MiB, medians)")
 
 
 def generate(scale: int, seed: int):
@@ -296,7 +327,7 @@ class Client:
         def one(i):
             try:
                 out[i] = self.req(path, payloads[i])
-            except BaseException as e:   # re-raised below, never dropped
+            except Exception as e:       # re-raised below, never dropped
                 errs.append(e)
 
         threads = [threading.Thread(target=one, args=(i,))
@@ -352,8 +383,8 @@ def phase_served(ctx: dict, seed: int) -> None:
 
     # references first: a wrong reference must not cost chip compiles
     t0 = time.time()
-    sym_ptr, sym_idx = csr_structure(np.concatenate([src, dst]),
-                                     np.concatenate([dst, src]), n)
+    s2, d2 = np.concatenate([src, dst]), np.concatenate([dst, src])
+    sym_ptr, sym_idx = csr_structure(s2, d2, n)
     out_ptr, out_idx = csr_structure(src, dst, n)
     rng = np.random.default_rng(seed)
     sym_deg = np.diff(sym_ptr)
@@ -365,7 +396,6 @@ def phase_served(ctx: dict, seed: int) -> None:
     trav_ref = [ref_hops_out_count(out_ptr, out_idx, int(s), 2)
                 for s in trav_src]
     wcc_ref = ref_components(sym_ptr, sym_idx, n)
-    s2, d2 = np.concatenate([src, dst]), np.concatenate([dst, src])
     pr_ref = ref_pagerank(s2, d2, n)
     ppr_reset = np.zeros(n)
     ppr_reset[int(bfs_src[0])] = 1.0
@@ -549,13 +579,11 @@ def phase_sharded(scale: int, seed: int) -> None:
     mesh = vertex_mesh(4)
     log(f"sharded: n={n} sym_edges={int(deg.sum())} mesh="
         f"{[d.id for d in mesh.devices.flat]} sources={sources.tolist()}")
-    placed = False
-    for s in sources:
+    for i, s in enumerate(sources):
         t0 = time.time()
         d4, lv4 = frontier_bfs_hybrid_sharded(snap, int(s), mesh)
         t4 = time.time() - t0
-        if not placed:
-            placed = True
+        if i == 0:          # the first call placed the shards
             used = [(d.memory_stats() or {}).get("bytes_in_use")
                     for d in jax.devices()[:4]]
             log(f"sharded: bytes_in_use per device after placement = {used}")

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from titan_tpu.ops.pallas_frontier import TPU_REFUSAL
+
 N = 1 << 20            # vertices, scale 20
 E = 2 * 16 * N         # symmetrised R-MAT edges, edge factor 16
 Q = 4_563_400          # chunk columns of that graph (seed 2)
@@ -138,11 +140,8 @@ def test_pagerank_window(spec, rows):
 # -- the Pallas frontier kernel: refused (ROADMAP S5 ports it) --------------
 
 @pytest.mark.xfail(strict=True, raises=ValueError,
-                   reason="the chip's compiler refuses frontier_round: "
-                          "ValueError: Shape mismatch in input, indices "
-                          "and output (pallas/mosaic/lowering.py, "
-                          "_gather_lowering_rule — the in-kernel jnp.take "
-                          "gathers); ROADMAP S5's port flips this")
+                   reason="the chip's compiler refuses frontier_round "
+                          f"(ROADMAP S5's port flips this): {TPU_REFUSAL}")
 def test_pallas_frontier_round(spec):
     from titan_tpu.ops.pallas_frontier import frontier_round
 

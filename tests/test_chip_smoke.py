@@ -69,13 +69,15 @@ def test_smoke_without_a_tpu_fails_before_phase_1(capsys):
 
 
 def test_compile_cache_dir_comes_from_the_environment_or_the_checkout(
-        monkeypatch, cache_config):
-    # JAX itself reads JAX_COMPILATION_CACHE_DIR into this config value
+        monkeypatch):
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda key, value: updates.append((key, value)))
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/given/from/outside")
-    jax.config.update("jax_compilation_cache_dir", "/given/from/outside")
-    enable_compile_cache()
-    assert jax.config.jax_compilation_cache_dir == "/given/from/outside"
+    enable_compile_cache()          # JAX reads the variable itself
+    assert "jax_compilation_cache_dir" not in dict(updates)
+    assert "jax_persistent_cache_min_compile_time_secs" in dict(updates)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     enable_compile_cache()
-    assert jax.config.jax_compilation_cache_dir == os.path.join(
+    assert dict(updates)["jax_compilation_cache_dir"] == os.path.join(
         ROOT, ".bench_cache", "xla")
