@@ -1233,6 +1233,7 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
         and "_state_sharding" not in g
     bstep_p = _pallas_batched_bu() if use_pallas else None
     interp = frontier_interpret() if use_pallas else False
+    from titan_tpu.obs.tracing import phase
     from titan_tpu.utils.jitcache import dev_scalar
 
     cap_n = _next_pow2(max(n, 2))
@@ -1243,43 +1244,51 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
                 [a, jnp.full((cap_n - a.shape[0],), n + 1, a.dtype)])
         return a
 
-    if init_dist is None and expand:
-        # hops-mode default seeding: one start vertex per job stamped
-        # at start_level over a zero background (multi-source rows go
-        # through init_dist)
-        dist = jnp.zeros((K, n + 1), jnp.int32) \
-            .at[jnp.arange(K),
-                jnp.asarray(src_arr.astype(np.int32))] \
-            .set(start_level) \
-            .at[:, n].set(INF)
-    elif init_dist is None:
-        dist = jnp.full((K, n + 1), INF, jnp.int32) \
-            .at[jnp.arange(K),
-                jnp.asarray(src_arr.astype(np.int32))].set(0)
-    else:
-        d = np.asarray(init_dist, np.int32)
-        if d.shape != (K, n):
-            raise ValueError(f"init_dist must be [K={K}, n={n}], "
-                             f"got {d.shape}")
-        # col n is the scatter pad slot; it starts (and stays) INF in a
-        # fresh run, so a resumed row re-appends it
-        dist = jnp.concatenate(
-            [jnp.asarray(d), jnp.full((K, 1), INF, jnp.int32)], axis=1)
-    if "_state_sharding" in g:
-        # mesh-placed cohort (parallel/partition.place_batched_csr):
-        # pin the [K, n+1] state to its P(None, "v") placement up front
-        # so the first level doesn't pay a layout decision + reshard
-        import jax
-        dist = jax.device_put(dist, g["_state_sharding"])
-    act_h = np.ones(K, bool)
-    active = jnp.asarray(act_h)
+    with phase("bfs.seed", K=K, n=n, mode=mode):
+        if init_dist is None and expand:
+            # hops-mode default seeding: one start vertex per job
+            # stamped at start_level over a zero background (multi-source
+            # rows go through init_dist)
+            dist = jnp.zeros((K, n + 1), jnp.int32) \
+                .at[jnp.arange(K),
+                    jnp.asarray(src_arr.astype(np.int32))] \
+                .set(start_level) \
+                .at[:, n].set(INF)
+        elif init_dist is None:
+            dist = jnp.full((K, n + 1), INF, jnp.int32) \
+                .at[jnp.arange(K),
+                    jnp.asarray(src_arr.astype(np.int32))].set(0)
+        else:
+            d = np.asarray(init_dist, np.int32)
+            if d.shape != (K, n):
+                raise ValueError(f"init_dist must be [K={K}, n={n}], "
+                                 f"got {d.shape}")
+            # col n is the scatter pad slot; it starts (and stays) INF
+            # in a fresh run, so a resumed row re-appends it
+            dist = jnp.concatenate(
+                [jnp.asarray(d), jnp.full((K, 1), INF, jnp.int32)],
+                axis=1)
+        if "_state_sharding" in g:
+            # mesh-placed cohort (parallel/partition.place_batched_csr):
+            # pin the [K, n+1] state to its P(None, "v") placement up
+            # front so the first level doesn't pay a layout decision +
+            # reshard
+            import jax
+            dist = jax.device_put(dist, g["_state_sharding"])
+        act_h = np.ones(K, bool)
+        active = jnp.asarray(act_h)
     levels = np.zeros(K, np.int32)
     completed = np.zeros(K, bool)
     level = int(start_level)
     while level < max_levels:
-        fbits, cand, stats = bplan(dist, active, dev_scalar(level), degc,
-                                   c_cap=cap_n, n_=n, expand=expand)
-        st = np.asarray(stats)          # ONE sync per level for ALL jobs
+        with phase("bfs.plan", level=level) as ph:
+            fbits, cand, stats = bplan(dist, active, dev_scalar(level),
+                                       degc, c_cap=cap_n, n_=n,
+                                       expand=expand)
+            with ph.sync():
+                st = np.asarray(stats)  # ONE sync per level for ALL jobs
+            ph.set(c_count=int(st[0]), frontier=int(st[1:].sum()),
+                   replan=False)
         nf = st[1:]
         mask_changed = False
         # frontier emptied => that job's BFS is complete
@@ -1310,11 +1319,16 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
             # from the shared candidate list (a completed small-
             # component job would otherwise re-contribute ~n dead
             # candidates to every remaining level)
-            active = jnp.asarray(act_h)
-            fbits, cand, stats = bplan(dist, active, dev_scalar(level),
-                                       degc, c_cap=cap_n, n_=n,
-                                       expand=expand)
-            st = np.asarray(stats)
+            with phase("bfs.plan", level=level) as ph:
+                active = jnp.asarray(act_h)
+                fbits, cand, stats = bplan(dist, active,
+                                           dev_scalar(level), degc,
+                                           c_cap=cap_n, n_=n,
+                                           expand=expand)
+                with ph.sync():
+                    st = np.asarray(stats)
+                ph.set(c_count=int(st[0]), frontier=int(st[1:].sum()),
+                       replan=True)
         if oscat is not None:
             # overlay add-edges expand top-down off the level's final
             # bitmaps — independent of the base candidate sweep below
@@ -1343,33 +1357,42 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
         prog = None
         while c_count > 0 and rounds < BU_CHUNK_ROUNDS:
             c_cap2 = min(_next_pow2(max(c_count, 2)), cap_n)
-            if off is None:
-                cand = pad(cand)
-                off = jnp.zeros((cap_n,), jnp.int32)
-                prog = jnp.asarray([c_count, 0], jnp.int32)
             fuse = BU_CHUNK_ROUNDS - rounds
-            if use_pallas:
-                dist, cand, off, prog = bstep_p(
-                    dist, fbits, cand[:c_cap2], off[:c_cap2], prog,
-                    dev_scalar(level), dstT, colstart, degc, tb_l,
-                    c_cap=c_cap2, n_=n, fuse=fuse, masked=masked_l,
-                    expand=expand, lanes=SPLIT_LANES, interpret=interp)
-            else:
-                dist, cand, off, prog = bstep(
-                    dist, fbits, cand[:c_cap2], off[:c_cap2], prog,
-                    dev_scalar(level), dstT, colstart, degc, tb_l,
-                    c_cap=c_cap2, n_=n, fuse=fuse, masked=masked_l,
-                    expand=expand)
-            cand, off = pad(cand), pad(off)
-            c_count, rem8 = (int(x) for x in np.asarray(prog))
+            with phase("bfs.sweep", level=level, c_cap=c_cap2,
+                       fuse=fuse) as ph:
+                if off is None:
+                    cand = pad(cand)
+                    off = jnp.zeros((cap_n,), jnp.int32)
+                    prog = jnp.asarray([c_count, 0], jnp.int32)
+                if use_pallas:
+                    dist, cand, off, prog = bstep_p(
+                        dist, fbits, cand[:c_cap2], off[:c_cap2], prog,
+                        dev_scalar(level), dstT, colstart, degc, tb_l,
+                        c_cap=c_cap2, n_=n, fuse=fuse, masked=masked_l,
+                        expand=expand, lanes=SPLIT_LANES,
+                        interpret=interp)
+                else:
+                    dist, cand, off, prog = bstep(
+                        dist, fbits, cand[:c_cap2], off[:c_cap2], prog,
+                        dev_scalar(level), dstT, colstart, degc, tb_l,
+                        c_cap=c_cap2, n_=n, fuse=fuse, masked=masked_l,
+                        expand=expand)
+                cand, off = pad(cand), pad(off)
+                with ph.sync():
+                    c_count, rem8 = (int(x) for x in np.asarray(prog))
+                ph.set(c_count=c_count, rem8=rem8)
             rounds += fuse
         if c_count > 0:
             c_cap2 = min(_next_pow2(max(c_count, 2)), cap_n)
             rem_cap = _next_pow2(max(rem8, 2))
-            dist = bex(dist, fbits, cand[:c_cap2], off[:c_cap2], prog,
-                       dev_scalar(level), dstT, colstart, degc, tb_l,
-                       c_cap=c_cap2, p_cap=rem_cap, n_=n, masked=masked_l,
-                       expand=expand)
+            # no sync here: bex's device time falls into the next phase
+            # that reads back (the next level's plan, or the caller's)
+            with phase("bfs.exhaust", level=level, c_cap=c_cap2,
+                       p_cap=rem_cap, **{"async": True}):
+                dist = bex(dist, fbits, cand[:c_cap2], off[:c_cap2],
+                           prog, dev_scalar(level), dstT, colstart, degc,
+                           tb_l, c_cap=c_cap2, p_cap=rem_cap, n_=n,
+                           masked=masked_l, expand=expand)
         level += 1
     # jobs still active at max_levels count as completed-at-cap
     if act_h.any():

@@ -16,6 +16,13 @@ surface:
   bucket compiled; backend compile wall time is attributed through a
   ``jax.monitoring`` duration listener + a thread-local call context
   (eager-op compiles inside a profiled window are attributed too).
+* **Compile spans** (ISSUE 25): the same listener journals ONE
+  ``compile`` span per executable the process builds or loads — a
+  ``jit_once`` kernel or an eager program, inside a profiled call or
+  not — into the tracer current on the thread (``obs/tracing.scope``),
+  else the trace ``compile``: key, static arguments, trace / lower /
+  backend milliseconds, persistent-cache hit / miss / off. Needs the
+  tracer and a profiler both on (the defaults).
 * **Transfer accounting**: the upload/readback seams
   (``engine._device_graph_single``, ``bfs_hybrid.build_chunked_csr``,
   the overlay's delta pages, result readbacks) call
@@ -41,6 +48,7 @@ import threading
 import time
 from typing import Optional
 
+from titan_tpu.obs import tracing
 from titan_tpu.utils import jitcache
 from titan_tpu.utils.metrics import MetricManager
 
@@ -52,25 +60,106 @@ _TLS = threading.local()
 _LISTENER = {"on": False}
 
 
-def _on_jax_event(name: str, duration_s: float, **_kw) -> None:
-    """jax.monitoring duration listener: attribute backend-compile wall
-    time to the profiled call in flight on this thread (if any)."""
-    if not _PROFILERS or not name.endswith("backend_compile_duration"):
+#: the jax.monitoring names read here (jax/_src/dispatch.py, compiler.py)
+_EV_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_EV_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_EV_BACKEND = "/jax/core/compile/backend_compile_duration"
+_EV_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_EV_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_EV_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+def _pending() -> dict:
+    """This thread's executable in the making: JAX reports its trace,
+    its lowering and its cache look-up before the backend compile that
+    closes it."""
+    rec = getattr(_TLS, "pending", None)
+    if rec is None:
+        rec = _TLS.pending = {"trace_s": 0.0, "lower_s": 0.0,
+                              "retrieval_s": 0.0, "cache": "off"}
+    return rec
+
+
+def _on_jax_event(name: str, **_kw) -> None:
+    """jax.monitoring event listener: was the persistent cache asked
+    for the executable in the making, and did it have it."""
+    if not _PROFILERS:
         return
-    ctx = getattr(_TLS, "ctx", None)
+    if name == _EV_CACHE_ASKED:
+        _pending()["cache"] = "miss"
+    elif name == _EV_CACHE_HIT:
+        _pending()["cache"] = "hit"
+
+
+def _on_jax_duration(name: str, duration_s: float, fun_name=None,
+                     **_kw) -> None:
+    """jax.monitoring duration listener. Every backend compile (which
+    in this JAX wraps the persistent cache's look-up, so a load counts)
+    becomes one ``compile`` span, whichever code asked for it — a
+    ``jit_once`` kernel or an eager ``x[:cap]``; inside a profiled call
+    its wall is also attributed to that call, as before."""
+    if not _PROFILERS:
+        return
+    if name == _EV_TRACE:
+        _pending()["trace_s"] += duration_s
+    elif name == _EV_LOWER:
+        _pending()["lower_s"] += duration_s
+    elif name == _EV_RETRIEVAL:
+        _pending()["retrieval_s"] += duration_s
+    elif name == _EV_BACKEND:
+        ctx = getattr(_TLS, "ctx", None)
+        if ctx is not None:
+            ctx["compile_s"] += duration_s
+            ctx["compile_events"] += 1
+        rec, _TLS.pending = _pending(), None
+        _compile_span(rec, duration_s, fun_name, ctx)
+
+
+def _compile_span(rec: dict, backend_s: float, fun_name, ctx) -> None:
+    """Journal one built-or-loaded executable, made after the fact from
+    the listener's durations: under the span current on this thread (the
+    batch and level it stalled), else in the trace ``compile``."""
+    cur = tracing.current_span()
+    if cur is not None:
+        tracer, trace_id, parent = cur
+    else:
+        tracer, trace_id, parent = tracing.current(), "compile", None
+        if tracer is None:
+            return
+    attrs: dict = {}
     if ctx is not None:
-        ctx["compile_s"] += duration_s
-        ctx["compile_events"] += 1
+        # the call's static arguments, as far as the shim can tell them
+        # from arrays: the integer, boolean and string keywords
+        attrs.update((k, v) for k, v in ctx["kwargs"].items()
+                     if isinstance(v, (bool, int, str)))
+        attrs["key"] = ctx["key"]
+    else:
+        name = str(fun_name or "?")
+        if name.startswith("jit(") and name.endswith(")"):
+            name = name[4:-1]
+        attrs["key"] = "eager:" + name
+    attrs.update(trace_ms=round(rec["trace_s"] * 1e3, 3),
+                 lower_ms=round(rec["lower_s"] * 1e3, 3),
+                 backend_ms=round(backend_s * 1e3, 3),
+                 cache=rec["cache"],
+                 thread=threading.current_thread().name)
+    if rec["cache"] == "hit":
+        attrs["retrieval_ms"] = round(rec["retrieval_s"] * 1e3, 3)
+    now = tracer.clock()
+    tracer.event(trace_id, "compile", parent=parent,
+                 t0=now - rec["trace_s"] - rec["lower_s"] - backend_s,
+                 t1=now, **attrs)
 
 
 def _ensure_listener() -> None:
     # jax has no per-listener unregister; register once, gate on
-    # _PROFILERS inside the callback
+    # _PROFILERS inside the callbacks
     if _LISTENER["on"]:
         return
     try:
         from jax import monitoring
-        monitoring.register_event_duration_secs_listener(_on_jax_event)
+        monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        monitoring.register_event_listener(_on_jax_event)
         _LISTENER["on"] = True
     except Exception:
         pass
@@ -85,7 +174,8 @@ def _dispatch(key: str, fn, args, kwargs):
     cache_size = getattr(fn, "_cache_size", None)
     before = cache_size() if cache_size is not None else -1
     prev = getattr(_TLS, "ctx", None)
-    ctx = _TLS.ctx = {"compile_s": 0.0, "compile_events": 0}
+    ctx = _TLS.ctx = {"compile_s": 0.0, "compile_events": 0,
+                      "key": key, "kwargs": kwargs}
     t0 = time.perf_counter()
     try:
         out = fn(*args, **kwargs)

@@ -21,6 +21,18 @@ Design constraints (ISSUE r10):
 
 Thread-safety: journal mutation is lock-guarded; ``Span.end`` mutates
 only the span object (single writer — the layer that started it).
+
+Phases (ISSUE 25): a thread that drives the device makes its batch's
+trace current with :func:`scope`; the layers below open their leaf
+spans with :func:`phase`, which journals the span under the scope AND
+writes a ``jax.profiler.TraceAnnotation`` of the same extent, so a
+device trace taken meanwhile names each idle gap for the phase the host
+was in. Phases are leaves: never nested in one another, and only on
+the thread that dispatches (an enclosing annotation would take every
+gap). :func:`current` is the enabled tracer constructed last — how a
+reader that holds no scheduler (the benchmark's, after the server
+closed) reaches the journal; :meth:`Tracer.window` reads a stretch of
+time out of it.
 """
 
 from __future__ import annotations
@@ -31,6 +43,11 @@ import time
 from collections import OrderedDict
 from contextlib import contextmanager
 from typing import Optional
+
+_TLS = threading.local()
+#: the enabled Tracer constructed last (see :func:`current`)
+_CURRENT: Optional["Tracer"] = None
+_ANNOTATION = None      # jax.profiler.TraceAnnotation, imported on first use
 
 #: per-call ingest/drain bound: one split response (or drain batch) may
 #: splice at most this many remote spans — overflow is counted, never
@@ -179,6 +196,9 @@ class Tracer:
         self._traces: "OrderedDict[str, _Trace]" = OrderedDict()
         self._ids = itertools.count(1)
         self._lock = threading.Lock()
+        if enabled:
+            global _CURRENT
+            _CURRENT = self
 
     # -- write side ----------------------------------------------------------
 
@@ -376,6 +396,18 @@ class Tracer:
             tr = self._traces.get(trace_id)
             return tr.dropped if tr is not None else 0
 
+    def window(self, t0: float, t1: Optional[float] = None) -> list:
+        """A stretch of time: the finished spans, of every trace, that
+        started in ``[t0, t1)`` (no upper bound when ``t1`` is None), as
+        ``Span.to_dict`` dicts that also carry their ``trace`` id, by
+        start. The clock is the tracer's (``time.time`` by default)."""
+        with self._lock:
+            picked = [s for tr in self._traces.values() for s in tr.spans
+                      if s.t_end is not None and s.t_start >= t0
+                      and (t1 is None or s.t_start < t1)]
+        picked.sort(key=lambda s: (s.t_start, s.span_id))
+        return [{**s.to_dict(), "trace": s.trace_id} for s in picked]
+
     def tree(self, trace_id: str) -> Optional[dict]:
         """JSON span tree: ``{"trace", "dropped_spans", "spans":
         [nested]}``; spans whose parent was ring-dropped surface as
@@ -429,6 +461,162 @@ class TraceHandle:
         return self.tracer.event(self.trace_id, name,
                                  parent=self.parent if parent is None
                                  else parent, t0=t0, t1=t1, **attrs)
+
+
+# -- phases: the current trace of a device-driving thread -------------------
+
+def current() -> Optional[Tracer]:
+    """The enabled tracer constructed last in this process, or None —
+    still readable after its scheduler closed (as ``devprof.current()``
+    and ``MetricManager.instance()`` are process-wide)."""
+    return _CURRENT
+
+
+class _Scope:
+    __slots__ = ("tracer", "trace_id", "root", "span")
+
+    def __init__(self, tracer: Tracer, trace_id: str, root):
+        self.tracer = tracer
+        self.trace_id = trace_id
+        self.root = root
+        self.span = root        # the open phase, else the root
+
+
+@contextmanager
+def scope(tracer: Tracer, trace_id: str, root):
+    """Make ``(trace_id, root)`` current on this thread: phases opened
+    below journal as children of ``root``. A disabled tracer makes
+    nothing current."""
+    if not tracer.enabled:
+        yield
+        return
+    prev = getattr(_TLS, "scope", None)
+    _TLS.scope = _Scope(tracer, trace_id, root)
+    try:
+        yield
+    finally:
+        _TLS.scope = prev
+
+
+def current_span() -> Optional[tuple]:
+    """``(tracer, trace_id, span)`` current on this thread — the open
+    phase, else the scope's root — or None outside any scope."""
+    sc = getattr(_TLS, "scope", None)
+    return None if sc is None else (sc.tracer, sc.trace_id, sc.span)
+
+
+class _Sync:
+    """Times one blocking readback into its phase's ``sync_ms``."""
+
+    __slots__ = ("_phase", "_t0")
+
+    def __init__(self, phase: "Phase"):
+        self._phase = phase
+
+    def __enter__(self):
+        self._t0 = self._phase._scope.tracer.clock()
+
+    def __exit__(self, *exc):
+        p = self._phase
+        p._sync_s = (p._sync_s or 0.0) \
+            + p._scope.tracer.clock() - self._t0
+        return False
+
+
+class Phase:
+    """One leaf phase: a span under the thread's scope (when there is
+    one) and a profiler annotation of the same extent. ``end`` may be
+    called early (the ``with`` exit is then a no-op)."""
+
+    __slots__ = ("_scope", "_name", "_label", "_attrs", "_span", "_ann",
+                 "_sync_s")
+
+    def __init__(self, sc: Optional[_Scope], name: str, label: str,
+                 attrs: dict):
+        self._scope = sc
+        self._name = name
+        self._label = label
+        self._attrs = attrs
+        self._span = self._ann = None
+        self._sync_s: Optional[float] = None
+
+    def __enter__(self) -> "Phase":
+        global _ANNOTATION
+        sc = self._scope
+        if sc is not None:
+            self._span = sc.span = sc.tracer.start(
+                sc.trace_id, self._name, parent=sc.root, **self._attrs)
+        if _ANNOTATION is None:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        self._ann = _ANNOTATION(self._label)
+        self._ann.__enter__()
+        return self
+
+    def set(self, **attrs) -> "Phase":
+        if self._span is not None:
+            self._span.set(**attrs)
+        return self
+
+    def sync(self):
+        """Context manager round a blocking readback of this phase."""
+        return _Sync(self) if self._span is not None else NULL_PHASE
+
+    def end(self) -> None:
+        ann, self._ann = self._ann, None
+        if ann is None:
+            return
+        ann.__exit__(None, None, None)
+        sc = self._scope
+        if sc is not None:
+            sc.span = sc.root
+            if self._sync_s is not None:
+                self._span.set(sync_ms=round(self._sync_s * 1e3, 3))
+            sc.tracer.end(self._span)
+
+    def __exit__(self, *exc):
+        self.end()
+        return False
+
+
+class _NullPhase:
+    """What :func:`phase` hands out when no tracer is enabled."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **attrs):
+        return self
+
+    def sync(self):
+        return self
+
+    def end(self) -> None:
+        return None
+
+
+NULL_PHASE = _NullPhase()
+
+
+def phase(name: str, level: Optional[int] = None, **attrs):
+    """Open a leaf phase on this thread (``with phase(...) as ph``).
+    Under a :func:`scope` it is a span ``name`` (``level`` among its
+    attributes) in the scope's trace; with an enabled tracer in the
+    process it is also a profiler annotation ``name`` or ``name L<level>``
+    — nothing that varies per request. With tracing off: two checks and
+    a shared no-op."""
+    sc = getattr(_TLS, "scope", None)
+    if sc is None and _CURRENT is None:
+        return NULL_PHASE
+    if level is None:
+        return Phase(sc, name, name, attrs)
+    attrs["level"] = level
+    return Phase(sc, name, f"{name} L{level}", attrs)
 
 
 def trace_summary(tracer: Optional[Tracer], trace_id: str

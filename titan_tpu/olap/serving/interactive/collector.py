@@ -36,13 +36,14 @@ DEFAULT_MAX_FUSE = 16
 class InteractiveRequest:
     """One caller's blocking request: plan + identity + rendezvous."""
 
-    __slots__ = ("plan", "tenant", "submitted_at", "result", "error",
-                 "wait_ms", "_done")
+    __slots__ = ("plan", "tenant", "submitted_at", "finished_at",
+                 "result", "error", "wait_ms", "_done")
 
     def __init__(self, plan, tenant: str):
         self.plan = plan
         self.tenant = tenant
         self.submitted_at = time.time()
+        self.finished_at: Optional[float] = None
         self.result: Optional[dict] = None
         self.error: Optional[BaseException] = None
         self.wait_ms: float = 0.0
@@ -52,6 +53,7 @@ class InteractiveRequest:
                error: Optional[BaseException] = None) -> None:
         self.result = result
         self.error = error
+        self.finished_at = time.time()
         self._done.set()
 
     def wait(self, timeout: Optional[float]) -> bool:

@@ -15,8 +15,13 @@ the owning ``JobScheduler``'s shared planes:
   ``serving.tenant.rejected`` + ``QuotaExceeded`` → HTTP 429), and the
   fused batch wall is attributed to member tenants split over K;
 * **tracing** — one trace per executed batch (trace id
-  ``traverse-<seq>``, readable at ``GET /trace?job=traverse-<seq>``)
-  with fuse/run spans and the shared device-cost event;
+  ``traverse-<seq>``, readable at ``GET /trace?job=traverse-<seq>``):
+  the ``interactive`` root, one ``member`` a request, and the leaf
+  phases ``admit``, ``bfs.seed`` / ``bfs.plan`` / ``bfs.sweep`` /
+  ``bfs.exhaust`` (the level loop's, through ``obs/tracing.scope``),
+  ``extract`` and ``reply``, each also a profiler annotation; a
+  ``compile`` span wherever an executable was built or loaded
+  (docs/observability.md has the table);
 * **device-cost profiler** — each batch executes inside a profiler
   window; its compile/exec/transfer deltas land on the batch trace.
 
@@ -48,6 +53,7 @@ from typing import Optional
 
 import numpy as np
 
+from titan_tpu.obs.tracing import phase, scope
 from titan_tpu.olap.serving.interactive.collector import (
     DEFAULT_MAX_FUSE, DEFAULT_WINDOW_S, Collector, InteractiveRequest)
 from titan_tpu.olap.serving.interactive.compile import (
@@ -230,10 +236,13 @@ class InteractiveLane:
         err = None
         dispatched = False
         try:
-            if isinstance(members[0].plan, PPRPlan):
-                dispatched = self._run_ppr(members, batch_id)
-            else:
-                dispatched = self._run_traverse(members, batch_id)
+            with scope(sched.tracer, batch_id, trace):
+                if isinstance(members[0].plan, PPRPlan):
+                    dispatched = self._run_ppr(members, batch_id)
+                else:
+                    with phase("admit") as admit:
+                        dispatched = self._run_traverse(
+                            members, batch_id, admit)
         except Exception as e:
             err = e
             raise
@@ -260,9 +269,16 @@ class InteractiveLane:
                             k=len(members),
                             kernel_calls=cost["calls"],
                             compiles=cost["compiles"],
-                            exec_ms=round(cost["exec_s"] * 1e3, 3),
                             h2d_bytes=cost["h2d_bytes"],
                             d2h_bytes=cost["d2h_bytes"])
+                now = time.time()
+                for r in members:
+                    # submitted_at -> finish: starts before the root
+                    # by the request's wait for the lane
+                    sched.tracer.event(
+                        batch_id, "member", parent=trace,
+                        t0=r.submitted_at, t1=r.finished_at or now,
+                        tenant=r.tenant, wait_ms=round(r.wait_ms, 3))
                 sched.tracer.end(trace,
                                  wall_ms=round(wall * 1e3, 3),
                                  **({"error": type(err).__name__}
@@ -274,7 +290,10 @@ class InteractiveLane:
 
     # -- traversal groups ----------------------------------------------------
 
-    def _run_traverse(self, members: list, batch_id: str) -> bool:
+    def _run_traverse(self, members: list, batch_id: str,
+                      admit) -> bool:
+        """``admit`` is the open ``admit`` phase: lease, seeds, ledger,
+        CSR look-up and label masks; ended just before the sweep."""
         from titan_tpu.core.defs import Direction
         from titan_tpu.models.bfs_hybrid import build_chunked_csr
         from titan_tpu.olap.serving.hbm import snapshot_csr_bytes
@@ -375,6 +394,8 @@ class InteractiveLane:
             share = nbytes / len(runnable)
             for r in runnable:
                 sched.tenants.hold_hbm(r.tenant, share)
+            admit.set(k_runnable=len(runnable), nbytes=int(nbytes),
+                      epoch=epoch_info.get("epoch")).end()
             t0 = time.time()
             try:
                 self._sweep(runnable, seeds, g, overlay, snap,
@@ -435,36 +456,42 @@ class InteractiveLane:
         # hop-set extraction stays DEVICE-side: one [Kp] size readback,
         # then a compacted index list per id/values member — never the
         # O(n) dist row (a scale-26 row is a ~270 MB D2H transfer)
-        want = jnp.asarray(np.asarray(depths_p, np.int32) + 1)
-        masks = dist == want[:, None]
-        sizes = np.asarray(masks.sum(axis=1, dtype=jnp.int32))
         from titan_tpu.obs import devprof
-        devprof.count_d2h("interactive.sizes", int(sizes.nbytes))
+        with phase("extract", Kp=Kp) as ph:
+            want = jnp.asarray(np.asarray(depths_p, np.int32) + 1)
+            masks = dist == want[:, None]
+            with ph.sync():
+                sizes = np.asarray(masks.sum(axis=1, dtype=jnp.int32))
+            devprof.count_d2h("interactive.sizes", int(sizes.nbytes))
         exec_ms = (time.time() - t0) * 1e3
-        for k, r in enumerate(runnable):
-            plan: TraversalPlan = r.plan
-            count = int(sizes[k])
-            try:
-                if plan.terminal == "count":
-                    result = count
-                elif count == 0:
-                    result = []
-                else:
-                    cap = min(_next_pow2(max(count, 2)),
-                              _next_pow2(max(n, 2)))
-                    _c, ids_dev = compact_ids(masks[k], cap, n)
-                    hopset = np.asarray(ids_dev)[:count]
-                    devprof.count_d2h("interactive.hopset",
-                                      int(hopset.nbytes))
-                    result = self._terminal(plan, snap, hopset)
-            except FallbackToInterpreter as e:
-                r.finish(error=e)
-                continue
-            r.finish(result={"result": result, "batch": batch_id,
-                             "fused_k": fused_k, "hops": plan.depth,
-                             "wait_ms": round(r.wait_ms, 3),
-                             "exec_ms": round(exec_ms, 3),
-                             "epoch": epoch_info})
+        with phase("reply") as ph:
+            d2h_bytes = 0
+            for k, r in enumerate(runnable):
+                plan: TraversalPlan = r.plan
+                count = int(sizes[k])
+                try:
+                    if plan.terminal == "count":
+                        result = count
+                    elif count == 0:
+                        result = []
+                    else:
+                        cap = min(_next_pow2(max(count, 2)),
+                                  _next_pow2(max(n, 2)))
+                        _c, ids_dev = compact_ids(masks[k], cap, n)
+                        hopset = np.asarray(ids_dev)[:count]
+                        devprof.count_d2h("interactive.hopset",
+                                          int(hopset.nbytes))
+                        d2h_bytes += int(hopset.nbytes)
+                        result = self._terminal(plan, snap, hopset)
+                except FallbackToInterpreter as e:
+                    r.finish(error=e)
+                    continue
+                r.finish(result={"result": result, "batch": batch_id,
+                                 "fused_k": fused_k, "hops": plan.depth,
+                                 "wait_ms": round(r.wait_ms, 3),
+                                 "exec_ms": round(exec_ms, 3),
+                                 "epoch": epoch_info})
+            ph.set(d2h_bytes=d2h_bytes)
 
     def _empty_result(self, plan, batch_id, fused_k, epoch_info) -> dict:
         empty = 0 if plan.terminal == "count" else []

@@ -151,6 +151,7 @@ Server config is a YAML file (gremlin-server.yaml analog):
 from __future__ import annotations
 
 import json
+import math
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Optional
@@ -606,13 +607,37 @@ class GraphServer:
                     from urllib.parse import parse_qs, urlparse
                     q = parse_qs(urlparse(self.path).query)
                     tid = (q.get("job") or [None])[0]
+                    tracer = server.tracer()
+                    if tid is None and "since" in q:
+                        # a stretch of time instead of one trace: every
+                        # finished span that started in [since, until)
+                        try:
+                            since = float(q["since"][0])
+                            until = float(q["until"][0]) \
+                                if "until" in q else None
+                            if not all(math.isfinite(t)
+                                       for t in (since, until)
+                                       if t is not None):
+                                raise ValueError("not finite")
+                        except ValueError:
+                            self._send(400, {"error": "trace?since= "
+                                             "and until= take unix "
+                                             "seconds",
+                                             "type": "BadRequest",
+                                             "retryable": False})
+                            return
+                        self._send(200, {
+                            "since": since, "until": until,
+                            "spans": tracer.window(since, until)
+                            if tracer is not None else []})
+                        return
                     if tid is None:
                         self._send(400, {"error": "trace needs "
-                                                  "?job=<id>",
+                                                  "?job=<id> or "
+                                                  "?since=<unix s>",
                                          "type": "BadRequest",
                                          "retryable": False})
                         return
-                    tracer = server.tracer()
                     tree = tracer.tree(tid) if tracer is not None \
                         else None
                     if tree is None:
