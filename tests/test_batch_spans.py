@@ -57,7 +57,7 @@ def ids(social):
     return out
 
 
-def fused_batch(sched, starts, terminal="count"):
+def fused_batch(sched, starts, terminal="count", direction="both"):
     """Submit ``len(starts)`` 2-hop queries at once until they ran as ONE
     batch; returns the responses in the order of ``starts``."""
     lane = sched.interactive()
@@ -68,7 +68,7 @@ def fused_batch(sched, starts, terminal="count"):
         def go(vid):
             barrier.wait()
             out[vid] = lane.submit(plan_from_wire(
-                {"start": [vid], "dir": "both", "hops": 2,
+                {"start": [vid], "dir": direction, "hops": 2,
                  "terminal": terminal}))
 
         threads = [threading.Thread(target=go, args=(v,)) for v in starts]
@@ -107,6 +107,33 @@ def traced_batch(social, ids):
         yield sched.tracer, res, spans
     finally:
         sched.close()
+
+
+@pytest.fixture(scope="module")
+def pulled_batch():
+    """One warm fused ``out()`` batch: a directed chain's layout holds
+    parents, so every level pulls; vertex 0, whose 80 parents (10
+    chunks) no query reaches, outlasts the eight chunk rounds and goes
+    to ``bfs.exhaust``."""
+    g = titan_tpu.open("inmemory")
+    tx = g.new_transaction()
+    vs = [tx.add_vertex("person", name=f"q{i}") for i in range(90)]
+    for v in vs[10:]:
+        v.add_edge("knows", vs[0])
+    for a, b in zip(vs[1:9], vs[2:10]):
+        a.add_edge("knows", b)
+    tx.commit()
+    starts = sorted(v.id for v in g.traversal().V().to_list())[1:K + 1]
+    g.rollback()
+    sched = JobScheduler(graph=g, autostart=False,
+                         interactive_window_s=0.05)
+    try:
+        fused_batch(sched, starts, direction="out")
+        res = fused_batch(sched, starts, direction="out")
+        yield finished_tree(sched.tracer, res[0]["batch"])
+    finally:
+        sched.close()
+        g.close()
 
 
 # -- the span tree of a batch ------------------------------------------------
@@ -166,9 +193,12 @@ def test_phase_attributes(traced_batch):
         assert s.attrs["replan"] is False
         assert s.attrs["frontier"] >= 1 and s.attrs["c_count"] >= 0
     assert by["bfs.plan"][0].attrs["frontier"] == K     # K single starts
-    for s in by.get("bfs.sweep", []):
-        assert s.attrs["c_cap"] >= 2 and s.attrs["fuse"] >= 1
-        assert {"c_count", "rem8"} <= set(s.attrs)
+    sweeps = by["bfs.sweep"]
+    # `both`, no mask: a level whose frontier's chunks fit the ladder
+    # is one push; K single starts of a chunk or two each do
+    assert (sweeps[0].attrs["level"], sweeps[0].attrs["dir"]) == (1, "td")
+    for s in sweeps:
+        check_sweep(s)
     for s in by.get("bfs.exhaust", []):
         assert s.attrs["async"] is True and "sync_ms" not in s.attrs
     assert by["extract"][0].attrs["Kp"] == K
@@ -178,6 +208,35 @@ def test_phase_attributes(traced_batch):
             assert 0 <= s.attrs["sync_ms"] <= s.duration_ms + 1e-3
     cost = by["device_cost"][0].attrs
     assert "exec_ms" not in cost and cost["kernel_calls"] >= 2
+
+
+def check_sweep(span):
+    """A pushed level carries its rung and what it pushed; a chunk round
+    of a pulled level its candidate cap and what the round left over."""
+    a = span.attrs
+    if a["dir"] == "td":
+        assert 1 <= a["pairs"] <= a["mass"] <= a["p_cap"]
+        assert not {"c_cap", "fuse", "c_count", "rem8"} & set(a)
+    else:
+        assert a["dir"] == "bu"
+        assert a["c_cap"] >= 2 and a["fuse"] >= 1
+        assert {"c_count", "rem8"} <= set(a)
+        assert not {"p_cap", "mass", "pairs"} & set(a)
+
+
+def test_phase_attributes_of_a_pulled_batch(pulled_batch):
+    by = {}
+    for s in pulled_batch:
+        by.setdefault(s.name, []).append(s)
+    sweeps = by["bfs.sweep"]
+    assert {s.attrs["level"] for s in sweeps} == {1, 2}
+    assert {s.attrs["dir"] for s in sweeps} == {"bu"}
+    for s in sweeps:
+        check_sweep(s)
+        assert 0 <= s.attrs["sync_ms"] <= s.duration_ms + 1e-3
+    assert {s.attrs["level"] for s in by["bfs.exhaust"]} <= {1, 2}
+    for s in by["bfs.exhaust"]:
+        assert s.attrs["async"] is True and "sync_ms" not in s.attrs
 
 
 def test_id_terminals_read_back_in_reply(social, ids):

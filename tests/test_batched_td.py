@@ -1,0 +1,344 @@
+"""The batched BFS's top-down (push) step and its direction rule
+(ISSUE 26).
+
+What is pinned here, all on the CPU:
+
+* with the push engaged ``frontier_bfs_batched`` is bit-equal to the
+  run with it held off through the rule's own inputs (a directed
+  layout, a slot mask that masks nothing), in both modes, at K = 1, 3
+  and 16, on a hub graph and a uniform one, and equal to scipy's
+  hop sets for hops 1-3;
+* which levels go which way: a mass above the top rung, a masked level,
+  an ``out()`` chain's layout and a mesh-placed cohort pull;
+* after the lane's first batch on a snapshot, a batch of any size up to
+  ``max_fuse`` and a level on any rung build nothing.
+"""
+
+import threading
+
+import numpy as np
+import pytest
+import scipy.sparse as sp
+
+from titan_tpu.models import bfs_hybrid as bh
+from titan_tpu.obs import devprof
+from titan_tpu.obs.tracing import Tracer, scope
+from titan_tpu.olap.tpu import snapshot as snap_mod
+from titan_tpu.utils.metrics import MetricManager
+
+
+def edges(kind: str, scale: int, seed: int = 3):
+    """Symmetrised edge list: ``hub`` skews one endpoint to the low ids
+    (a few vertices hold most edges), ``uniform`` draws both uniformly."""
+    rng = np.random.default_rng(seed)
+    n = 1 << scale
+    m = n * 8
+    dst = rng.integers(0, n, m)
+    src = (n * rng.random(m) ** 4).astype(np.int64) if kind == "hub" \
+        else rng.integers(0, n, m)
+    keep = src != dst
+    src, dst = src[keep], dst[keep]
+    return n, np.concatenate([src, dst]).astype(np.int32), \
+        np.concatenate([dst, src]).astype(np.int32)
+
+
+@pytest.fixture(scope="module", params=["hub", "uniform"])
+def graph(request):
+    scale = 11 if request.param == "hub" else 10
+    n, src, dst = edges(request.param, scale)
+    snap = snap_mod.from_arrays(n, src, dst)
+    adj = sp.csr_matrix((np.ones(len(src), np.int8), (src, dst)),
+                        shape=(n, n))
+    adj.data[:] = 1
+    return snap, bh.build_chunked_csr(snap), adj
+
+
+def sweep_attrs(run):
+    """``(result, the attributes of the run's bfs.sweep spans)``."""
+    tracer = Tracer()
+    root = tracer.start("t", "interactive")
+    with scope(tracer, "t", root):
+        out = run()
+    tracer.end(root)
+    return out, [s.attrs for s in tracer.spans("t")
+                 if s.name == "bfs.sweep"]
+
+
+def sweeps(run):
+    """``(result, [(level, dir)] of the run's bfs.sweep spans)``."""
+    out, attrs = sweep_attrs(run)
+    return out, [(a["level"], a["dir"]) for a in attrs]
+
+
+def run_kw(mode: str) -> dict:
+    return {"mode": "bfs"} if mode == "bfs" \
+        else {"mode": "hops", "start_level": 1, "max_levels": 4}
+
+
+def light(g, adj, k: int) -> list:
+    """The ``k`` vertices with an edge whose 2-hop frontier weighs the
+    fewest chunks: their fused batches fit the layout's ladder at both
+    levels of a 2-hop query, hubs or not."""
+    degc = np.asarray(g["degc"])[:g["n"]].astype(np.int64)
+    mass2 = (adj > 0).astype(np.int64) @ degc
+    mass2[degc == 0] = np.iinfo(np.int64).max
+    picked = np.argsort(mass2, kind="stable")[:k]
+    assert int(mass2[picked].sum()) <= bh._td_caps(g)[-1]
+    return [int(v) for v in picked]
+
+
+def no_mask(g):
+    """A per-level slot bitmap that masks no slot (byte = chunk column)."""
+    import jax.numpy as jnp
+
+    return jnp.zeros((g["q_total"],), jnp.uint8)
+
+
+# -- same answers whichever way a level went ---------------------------------
+
+@pytest.mark.parametrize("K", [1, 3, 16])
+@pytest.mark.parametrize("mode", ["bfs", "hops"])
+def test_push_bit_equal_to_pull(graph, mode, K):
+    _snap, g, adj = graph
+    n = g["n"]
+    rng = np.random.default_rng(K)
+    srcs = [int(v) for v in rng.choice(
+        np.flatnonzero(np.diff(adj.indptr) > 0), K, replace=False)]
+    kw = run_kw(mode)
+    (dist, levels, done), dirs = sweeps(
+        lambda: bh.frontier_bfs_batched(g, srcs, **kw))
+    assert "td" in {d for _lv, d in dirs}           # the push engaged
+    held = [dict(g, directed=True)]
+    if mode == "hops":
+        held.append(g)
+    for layout in held:
+        masks = None if layout is not g else [no_mask(g)] * 3
+        (dist2, levels2, done2), dirs2 = sweeps(
+            lambda: bh.frontier_bfs_batched(layout, srcs,
+                                            level_masks=masks, **kw))
+        assert {d for _lv, d in dirs2} == {"bu"}    # ... and was held off
+        assert np.array_equal(dist, dist2)
+        assert np.array_equal(levels, levels2)
+        assert np.array_equal(done, done2)
+    if mode == "hops":
+        dist3 = dist
+        # a run of depth h leaves the exact hop-h set (walk semantics)
+        # at dist == h + 1: the non-zeros of e_v^T A^h
+        reach = sp.identity(n, dtype=np.int8, format="csr")[srcs]
+        for h in (1, 2, 3):
+            reach = (reach @ adj).astype(bool).astype(np.int8)
+            dist = dist3 if h == 3 else bh.frontier_bfs_batched(
+                g, srcs, **dict(kw, max_levels=h + 1))[0]
+            assert np.array_equal(dist == h + 1, reach.toarray() > 0)
+    else:
+        ref = sp.csgraph.shortest_path(adj, method="D", unweighted=True,
+                                       indices=srcs)
+        want = np.where(np.isinf(ref), bh.INF, ref).astype(np.int64)
+        assert np.array_equal(dist.astype(np.int64), want)
+
+
+def test_multi_start_rows_take_the_same_step(graph):
+    """The lane's ``init_dist`` seeding: the frontier is read from dist,
+    not from ``sources``."""
+    _snap, g, adj = graph
+    n = g["n"]
+    init = np.zeros((2, n), np.int32)
+    starts = [np.flatnonzero(np.diff(adj.indptr) > 0)[:3],
+              np.flatnonzero(np.diff(adj.indptr) > 0)[5:6]]
+    for k, vs in enumerate(starts):
+        init[k, vs] = 1
+    kw = dict(run_kw("hops"), init_dist=init, max_levels=2)
+    (dist, _l, _c), dirs = sweeps(
+        lambda: bh.frontier_bfs_batched(g, [0, 0], **kw))
+    assert "td" in {d for _lv, d in dirs}
+    dist2, _l, _c = bh.frontier_bfs_batched(dict(g, directed=True),
+                                            [0, 0], **kw)
+    assert np.array_equal(dist, dist2)
+    seed = sp.csr_matrix(init.astype(np.int8))
+    assert np.array_equal(dist == 2, (seed @ adj).toarray() > 0)
+
+
+@pytest.mark.parametrize("mode", ["bfs", "hops"])
+def test_rungs_below_n_columns_list_the_same_pairs(graph, mode,
+                                                   monkeypatch):
+    """Below n columns the step lists the frontier's distinct vertices
+    first and their job memberships second; at n and above it compacts
+    the [K, n] mask at once. Same pairs, same dist."""
+    _snap, g, adj = graph
+    n = g["n"]
+    srcs = [int(v) for v in np.flatnonzero(np.diff(adj.indptr) > 0)[3:8]]
+    srcs += srcs[:2]                    # two jobs share a start
+    kw = run_kw(mode)
+    want = bh.frontier_bfs_batched(dict(g, directed=True), srcs, **kw)
+    top = bh._next_pow2(len(srcs) * g["q_total"])
+    monkeypatch.setattr(bh, "_td_caps", lambda _g: (n // 8, n // 2, top))
+    got, attrs = sweep_attrs(
+        lambda: bh.frontier_bfs_batched(g, srcs, **kw))
+    caps = {a["p_cap"] for a in attrs if a["dir"] == "td"}
+    assert min(caps) <= n // 2 and max(caps) > n    # both ways ran
+    for a, b in zip(want, got):
+        assert np.array_equal(a, b)
+
+
+# -- which levels go which way ----------------------------------------------
+
+def test_the_rule():
+    """``_td_cap`` on its inputs alone: the rung follows the mass; a mass
+    past the top rung, a mask, a directed or mesh-placed layout, flat
+    ids past int32 and a dearer push all pull."""
+    g = {"n": 1 << 20, "q_total": 4_563_401}      # the Kron cell's layout
+    caps = bh._td_caps(g)
+    assert caps == (1 << 12, 1 << 17, 1 << 21)
+    assert caps == bh._td_caps({"n": 1 << 20, "q_total": 4_650_000})
+    big = 1 << 20                                   # candidates: n-wide
+    assert bh._td_cap(g, 16, 0, big, False) == caps[0]
+    assert bh._td_cap(g, 16, caps[0], big, False) == caps[0]
+    assert bh._td_cap(g, 16, caps[0] + 1, big, False) == caps[1]
+    assert bh._td_cap(g, 16, caps[-1], big, False) == caps[-1]
+    assert bh._td_cap(g, 16, caps[-1] + 1, big, False) is None
+    assert bh._td_cap(g, 16, 100, big, True) is None
+    assert bh._td_cap(dict(g, directed=True), 16, 100, big, False) is None
+    assert bh._td_cap(dict(g, _mesh=object()), 16, 100, big,
+                      False) is None
+    assert bh._td_cap(g, 2048, 100, big, False) is None
+    # (e): few candidates left, a heavy frontier — a BFS job's middle
+    few = 1000
+    edge = bh.BU_CHUNK_ROUNDS * few // bh.TD_BU_COST
+    assert bh._td_cap(g, 16, edge, few, False) is not None
+    assert bh._td_cap(g, 16, edge + 1, few, False) is None
+    # the ladder follows the layout: the top rung is the largest power
+    # of two at or below half its chunk columns
+    assert bh._td_caps({"n": 64, "q_total": 300}) == (2, 8, 128)
+    assert bh._td_caps({"n": 1, "q_total": 1}) == (2,)
+    assert bh._td_caps({"n": 1 << 26, "q_total": 290_000_000})[-1] == 1 << 27
+
+
+def test_a_mass_above_the_top_rung_pulls(graph, monkeypatch):
+    _snap, g, adj = graph
+    srcs = [int(v) for v in np.flatnonzero(np.diff(adj.indptr) > 0)[:3]]
+    kw = dict(run_kw("hops"), max_levels=3)
+    # one rung that holds whatever three frontiers weigh
+    monkeypatch.setattr(bh, "_td_caps", lambda _g: (
+        bh._next_pow2(3 * g["q_total"]),))
+    want, dirs = sweeps(lambda: bh.frontier_bfs_batched(g, srcs, **kw))
+    assert {d for _lv, d in dirs} == {"td"}
+    # the ladder cut to one rung that holds L1 and not L2
+    deg = np.diff(adj.indptr)
+    l1 = int(sum(-(-deg[s] // 8) for s in srcs))
+    monkeypatch.setattr(bh, "_td_caps", lambda _g: (bh._next_pow2(l1),))
+    got, dirs = sweeps(lambda: bh.frontier_bfs_batched(g, srcs, **kw))
+    assert dirs[0] == (1, "td")
+    assert {d for lv, d in dirs if lv >= 2} == {"bu"}
+    assert np.array_equal(want[0], got[0])
+
+
+def test_a_masked_level_pulls_and_the_others_push(graph):
+    _snap, g, adj = graph
+    srcs = light(g, adj, 2)
+    _out, dirs = sweeps(lambda: bh.frontier_bfs_batched(
+        g, srcs, level_masks=[no_mask(g), None],
+        **dict(run_kw("hops"), max_levels=3)))
+    assert sorted(set(dirs)) == [(1, "bu"), (2, "td")]
+
+
+def test_an_out_chain_and_a_mesh_placed_cohort_pull(graph):
+    from titan_tpu.olap.serving.interactive.compile import \
+        reversed_chunked_csr
+    from titan_tpu.parallel.mesh import vertex_mesh
+    from titan_tpu.parallel.partition import place_batched_csr
+
+    snap, g, adj = graph
+    srcs = [int(v) for v in np.flatnonzero(np.diff(adj.indptr) > 0)[:2]]
+    rev = reversed_chunked_csr(snap)
+    assert rev["directed"] is True
+    placed = place_batched_csr(g, vertex_mesh(2))
+    assert "_mesh" in placed
+    want = bh.frontier_bfs_batched(g, srcs, **run_kw("hops"))[0]
+    for layout in (rev, placed):
+        (dist, _l, _c), dirs = sweeps(lambda: bh.frontier_bfs_batched(
+            layout, srcs, **run_kw("hops")))
+        assert {d for _lv, d in dirs} == {"bu"}
+        assert np.array_equal(dist, want)   # the graph is symmetric
+
+
+def test_levels_are_counted_by_direction(graph):
+    _snap, g, adj = graph
+    srcs = light(g, adj, 1)
+    kw = dict(run_kw("hops"), max_levels=3)
+    metrics = MetricManager()
+    with devprof.DeviceCostProfiler(metrics=metrics):
+        bh.frontier_bfs_batched(g, srcs, **kw)
+        bh.frontier_bfs_batched(dict(g, directed=True), srcs, **kw)
+    count = {d: metrics.counter("device.bfs.levels",
+                                labels={"dir": d}).count
+             for d in ("td", "bu")}
+    assert count == {"td": 2, "bu": 2}
+
+
+# -- a finite set of shapes, built before the first answer -------------------
+
+def test_after_the_first_batch_no_size_and_no_rung_builds(graph):
+    """One query makes the lane build every padded batch size and every
+    rung for its snapshot; then bursts of every size up to ``max_fuse``
+    and a step on each rung find their executables."""
+    from titan_tpu.olap.serving.interactive import plan_from_wire
+    from titan_tpu.olap.serving.scheduler import JobScheduler
+
+    snap, g, adj = graph
+    # a pushed level builds nothing; a pulled one's caps follow its
+    # counts (ROADMAP S1), so: starts whose fused frontiers fit
+    starts = light(g, adj, 16)
+    prof = devprof.DeviceCostProfiler(metrics=MetricManager())
+    sched = JobScheduler(snapshot=snap, autostart=False, profiler=prof,
+                         interactive_window_s=0.05)
+    prof.install()
+    try:
+        lane = sched.interactive()
+
+        def ask(v, out):
+            out.append(lane.submit(plan_from_wire(
+                {"start": [int(snap.vertex_ids[v])], "dir": "both",
+                 "hops": 2, "terminal": "count"})))
+
+        first: list = []
+        ask(starts[0], first)
+        built = prof.compiles()
+        # every rung at every padded size, and the query's own levels
+        assert prof.kernel_stats()["batched_td"]["calls"] \
+            >= 5 * len(bh._td_caps(g)) + 2
+        # journaled as one ``build`` span that holds the dummy batches'
+        # phases, apart from the query's own
+        spans = sched.tracer.spans(first[0]["batch"])
+        (build,) = [s for s in spans if s.name == "build"]
+        assert build.attrs == {"n": g["n"], "q_total": g["q_total"],
+                               "max_k": 16}
+        inner = [s for s in spans if s.parent_id == build.span_id]
+        assert [s.name for s in inner].count("extract") == 5
+        assert [s.name for s in spans if s.parent_id == spans[0].span_id
+                ].count("extract") == 1
+        # keyed by the layout's shape: the next epoch's layout of the
+        # same shape executes nothing
+        calls = prof.kernel_stats()["batched_td"]["calls"]
+        lane._build_shapes(dict(g))
+        assert prof.kernel_stats()["batched_td"]["calls"] == calls
+        fused = set()
+        for size in (16, 5, 3, 2, 1):
+            out: list = []
+            threads = [threading.Thread(target=ask, args=(v, out))
+                       for v in starts[:size]]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+            assert len(out) == size
+            fused |= {r["fused_k"] for r in out}
+            two_hops = (adj[starts[:size]] @ adj).astype(bool).sum(axis=1)
+            assert sorted(r["result"] for r in out) == sorted(
+                int(c) for c in np.asarray(two_hops).ravel())
+        assert max(fused) > 1                       # some did fuse
+        for K in (1, 2, 4, 8, 16):
+            bh.warm_batched_td(g, K, expand=True)   # every rung again
+        assert prof.compiles() == built
+    finally:
+        prof.uninstall()
+        sched.close()

@@ -113,6 +113,22 @@ def test_batched_bfs_bottom_up(spec):
              c_cap=N, n_=N, fuse=8, masked=False, expand=False)
 
 
+@pytest.mark.parametrize("k,expand", [(16, True), (K, False)],
+                         ids=["lane-hops-16", "jobs-bfs-8"])
+def test_batched_bfs_top_down(spec, k, expand):
+    """Every rung of the push ladder, as the lane (16 fused hops
+    queries) and the job batcher (8 BFS jobs) call it: the benchmark
+    must not be the first to show the chip's compiler a rung."""
+    from titan_tpu.models.bfs_hybrid import _batched_td, _td_caps
+
+    for p_cap in _td_caps({"q_total": Q}):
+        _compile(_batched_td(), spec((k, N + 1), jnp.int32),
+                 spec((k,), jnp.bool_), spec((), jnp.int32),
+                 spec((8, Q), jnp.int32), spec((N + 1,), jnp.int32),
+                 spec((N + 1,), jnp.int32),
+                 p_cap=p_cap, n_=N, expand=expand)
+
+
 def test_frontier_push_list_sssp(spec):
     from titan_tpu.models.frontier import _push_list
 

@@ -83,6 +83,25 @@ HEAD_P_CAP = 1 << 18
 # dispatch finishes every trailing level)
 END_C_CAP = 1 << 21
 END_P_CAP = 1 << 22
+# batched top-down (push) step, frontier_bfs_batched: the ladder of its
+# chunk-column caps (``f_cap`` = ``p_cap``; a pair with no chunk is not
+# listed, so pairs never outnumber chunks), as right shifts of the top
+# rung, which is the largest power of two at or below HALF the layout's
+# chunk columns (``_td_caps``): the ladder scales with the graph. A
+# FIXED ladder, not a power of two of the level's mass: every cap is an
+# executable of its own, and a cap minted inside a served window is a
+# 7-25 s stall (PERF.md 5, PR 25). A level whose frontier chunk mass
+# passes the top rung goes bottom-up. At scale 20 (4.56 M / 4.65 M
+# columns) the rungs are 2^12, 2^17 and 2^21 (CPU count, PR 26, the
+# benchmark's 256 starts a cell): 2^12 holds every Urand level and every
+# L1; 2^17 the median 2-hop Kron query (1,761 chunks a start, 77,627 at
+# p95); 2^21 the heaviest 16-query Kron batch (1.47 M chunks).
+TD_RUNG_SHIFTS = (9, 4, 0)
+# direction rule (e): a level goes top-down while
+#   mass * TD_BU_COST <= BU_CHUNK_ROUNDS * c_count
+# — one pushed chunk column against one candidate-round of the
+# bottom-up sweep. Chip measurement that set it: PERF.md 6, PR 26.
+TD_BU_COST = 1
 
 
 def layout_slot_positions(indptr, deg, n: int):
@@ -838,11 +857,16 @@ def _frontier_of():
 # HBM-resident dstT is read once and tested against all K frontier
 # bitmaps (each n/8 bytes — the cache-resident fast-gather regime). That
 # amortizes the per-round plan floor K-fold (PERF_NOTES "K-way
-# plan-amortization model"). The sweep is bottom-up only (level-
-# synchronous pull over the shared candidate list) — BFS distances are
-# canonical, so dist[k] is bit-equal to a sequential single-source run
-# regardless of direction strategy; per-job direction optimization inside
-# a batch is future work. SYMMETRIC graphs only (module contract above).
+# plan-amortization model"). Each level runs in one of two directions,
+# chosen for the whole batch from the counts the plan reads back
+# (``_td_cap``): bottom-up (level-synchronous pull over the shared
+# candidate list: n-wide rounds whatever the frontier) or top-down
+# (``_batched_td``: a push from the (job, vertex) pairs of the frontier,
+# which costs the frontier's chunks). BFS distances and hop sets are
+# canonical, so dist[k] is bit-equal whichever direction a level took.
+# SYMMETRIC graphs only (module contract above); a layout that says it
+# holds one orientation of a directed graph (``"directed": True``)
+# pulls at every level.
 
 
 def _pack_bits_batched(dist, active, level, n_: int):
@@ -883,7 +907,8 @@ def _batched_plan():
             counts (early-exit decisions), the SHARED candidate list
             (vertices unvisited in ANY active job, deg > 0 — one
             compaction amortized over K), and the per-job frontier
-            bitmaps for the bottom-up hit tests.
+            bitmaps for the bottom-up hit tests. Stats read back:
+            ``[c_count, nf[K], mass[K]]``.
 
             ``expand`` (hops mode, olap/serving/interactive): every
             vertex of an active job is a candidate every level — the
@@ -896,11 +921,16 @@ def _batched_plan():
                                          (dist.shape[0], n_))
             else:
                 unvis = (dist[:, :n_] >= INF) & active[:, None]
-            nf = ((dist[:, :n_] == level) & active[:, None]) \
-                .sum(axis=1).astype(jnp.int32)
+            front = (dist[:, :n_] == level) & active[:, None]
+            nf = front.sum(axis=1).astype(jnp.int32)
+            # the frontier's chunk mass per job: what a push would
+            # touch (the direction rule's input, _td_cap)
+            mass = jnp.where(front, degc[:n_], 0).sum(
+                axis=1, dtype=jnp.int32)
             cand_mask = unvis.any(axis=0) & (degc[:n_] > 0)
             c_count, cand = compact_ids(cand_mask, c_cap, n_ + 1)
-            return fbits, cand, jnp.concatenate([c_count[None], nf])
+            return fbits, cand, jnp.concatenate(
+                [c_count[None], nf, mass])
         return bplan
     return _get("batched_plan", build)
 
@@ -980,6 +1010,107 @@ def _batched_bu():
             return dist, cand, off, jnp.stack([c_count, rem8])
         return bstep
     return _get("batched_bu", build)
+
+
+def _batched_td():
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        @functools.partial(jax.jit,
+                           static_argnames=("p_cap", "n_", "expand"),
+                           donate_argnums=(0,))
+        def btd(dist, active, level, dstT, colstart, degc, p_cap: int,
+                n_: int, expand: bool = False):
+            """One top-down level for all K jobs: the (job, vertex)
+            pairs with ``dist[k, v] == level`` of active jobs (read
+            BEFORE the scatter: a vertex stamped this level must not
+            push this level) are compacted into a ``p_cap`` list, their
+            chunks enumerated into ``p_cap`` columns, each column
+            gathered ONCE from dstT and scattered into its owner's job
+            row: ``min`` of ``level + 1`` (bfs: visited entries are
+            smaller and stay), ``max`` with ``expand`` (hops: the
+            re-stamp contract of bstep). Pad lanes (n + 1) and dead
+            columns (the all-pad sink column) drop. Caller guarantee
+            (_td_cap): the frontier's chunk mass <= p_cap and
+            K * (n + 1) < 2^31. Returns (dist, [pairs, columns])."""
+            K = dist.shape[0]
+            row = n_ + 1
+            # a pair with no chunk pushes nothing; degc[n] = 0 drops
+            # the pad slot too
+            front = (dist == level) & active[:, None] & (degc > 0)[None]
+            if p_cap >= row:
+                f_count, flat = compact_ids(front.ravel(), p_cap, K * row)
+                job, v = flat // row, flat % row
+            else:
+                # a compaction costs its INPUT's width (7 ms a million
+                # on a v5e: 110 ms at K = 16), so below n columns: the
+                # frontier's distinct vertices first (n wide, <= pairs
+                # <= p_cap of them), then their K x p_cap memberships
+                _, verts = compact_ids(front.any(axis=0), p_cap, n_)
+                f_count, flat = compact_ids(
+                    jnp.take(front, verts, axis=1).ravel(), p_cap,
+                    K * p_cap)
+                job, v = flat // p_cap, verts[flat % p_cap]
+            valid = jnp.arange(p_cap) < f_count
+            v = jnp.where(valid, v, n_)
+            cols, p_total, owner = enumerate_chunk_pairs(
+                valid, degc[v], colstart[v], p_cap, dstT.shape[1] - 1,
+                with_owner=True)
+            nbr = jnp.take(dstT, cols, axis=1)           # [8, p_cap]
+            rows = jnp.broadcast_to(job[owner][None, :], nbr.shape)
+            if expand:
+                dist = dist.at[rows, nbr].max(level + 1, mode="drop")
+            else:
+                dist = dist.at[rows, nbr].min(level + 1, mode="drop")
+            return dist, jnp.stack([f_count, p_total])
+        return btd
+    return _get("batched_td", build)
+
+
+def _td_caps(g) -> tuple:
+    """The ladder of one layout, lowest rung first: TD_RUNG_SHIFTS of
+    the largest power of two at or below half its chunk columns."""
+    top = 1 << max((int(g["q_total"]) // 2).bit_length() - 1, 1)
+    return tuple(sorted({max(top >> s, 2) for s in TD_RUNG_SHIFTS}))
+
+
+def _td_cap(g, K: int, mass: int, c_count: int, masked: bool):
+    """The direction rule of a batched level, from what the plan read
+    back and what the layout and masks say: the rung (``p_cap``) a
+    top-down step takes, or None for bottom-up. Top-down when (a) the
+    frontier's chunk mass fits the top rung (and flat (job, vertex)
+    ids fit int32), (b) the layout is its own transpose — not one
+    orientation of a directed graph, whose columns hold parents, not
+    children, (c) no slot bitmap (tombstones, a hop's label mask) is in
+    force this level, (d) the cohort is not mesh-placed, and (e) the
+    push is the cheaper side."""
+    if masked or g.get("directed") or "_mesh" in g:
+        return None
+    if K * (g["n"] + 1) >= (1 << 31):
+        return None
+    if mass * TD_BU_COST > BU_CHUNK_ROUNDS * c_count:
+        return None
+    return next((cap for cap in _td_caps(g) if mass <= cap), None)
+
+
+def warm_batched_td(g, K: int, expand: bool) -> None:
+    """Run every rung of the top-down step once at batch size ``K`` on
+    an empty frontier, so that no level of a later batch of that size
+    builds (or loads) an executable: a level's rung follows its mass,
+    which a warm-up by batch sizes cannot cover."""
+    import jax.numpy as jnp
+
+    from titan_tpu.utils.jitcache import dev_scalar
+
+    btd = _batched_td()
+    dist = jnp.zeros((K, g["n"] + 1), jnp.int32)
+    active = jnp.ones((K,), bool)
+    for cap in _td_caps(g):
+        dist, _ = btd(dist, active, dev_scalar(1), g["dstT"],
+                      g["colstart"], g["degc"], p_cap=cap, n_=g["n"],
+                      expand=expand)
+    dist.block_until_ready()
 
 
 def _pallas_batched_bu():
@@ -1127,8 +1258,10 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
     """Batched multi-source BFS: run K BFS jobs over the SAME graph as
     one device run with [K, n] state. Each job's ``dist`` row is
     bit-equal to ``frontier_bfs_hybrid`` from that source (BFS distances
-    are canonical); the per-level plan and every edge-chunk gather are
-    shared across jobs.
+    are canonical); the per-level plan is shared across jobs, and each
+    level runs bottom-up (every edge-chunk gather shared) or top-down
+    (a push from the frontier's (job, vertex) pairs), as ``_td_cap``
+    chooses from the plan's counts: same ``dist`` either way.
 
     ``on_level(level, frontier_counts)``: optional host callback after
     each level's plan, receiving the per-job frontier sizes (np int32
@@ -1223,6 +1356,7 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
     if len(src_arr) and (src_arr.min() < 0 or src_arr.max() >= n):
         raise IndexError(f"source out of range [0, {n})")
     bplan = _batched_plan()
+    btd = _batched_td()
     bstep = _batched_bu()
     bex = _batched_exhaust()
     from titan_tpu.ops.pallas_frontier import (frontier_interpret,
@@ -1233,6 +1367,7 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
         and "_state_sharding" not in g
     bstep_p = _pallas_batched_bu() if use_pallas else None
     interp = frontier_interpret() if use_pallas else False
+    from titan_tpu.obs import devprof
     from titan_tpu.obs.tracing import phase
     from titan_tpu.utils.jitcache import dev_scalar
 
@@ -1287,9 +1422,9 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
                                        expand=expand)
             with ph.sync():
                 st = np.asarray(stats)  # ONE sync per level for ALL jobs
-            ph.set(c_count=int(st[0]), frontier=int(st[1:].sum()),
+            ph.set(c_count=int(st[0]), frontier=int(st[1:1 + K].sum()),
                    replan=False)
-        nf = st[1:]
+        nf = st[1:1 + K]
         mask_changed = False
         # frontier emptied => that job's BFS is complete
         newly_done = act_h & (nf == 0)
@@ -1327,18 +1462,10 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
                                            expand=expand)
                 with ph.sync():
                     st = np.asarray(stats)
-                ph.set(c_count=int(st[0]), frontier=int(st[1:].sum()),
-                       replan=True)
-        if oscat is not None:
-            # overlay add-edges expand top-down off the level's final
-            # bitmaps — independent of the base candidate sweep below
-            # (both min-scatter level+1, so order is immaterial), and
-            # it must run even when the base candidate list is empty
-            # (vertices reachable only through overlay edges)
-            dist = oscat(dist, fbits, ov.src_dev, ov.dst_dev,
-                         dev_scalar(level), cap=ov.cap, n_=n,
-                         expand=expand)
+                ph.set(c_count=int(st[0]),
+                       frontier=int(st[1:1 + K].sum()), replan=True)
         c_count = int(st[0])
+        mass = int(st[1 + K:].sum(dtype=np.int64))
         # per-level label mask (mixed-label hops chains): this level's
         # slot bitmap rides the SAME tbits seam as overlay tombstones —
         # one static `masked` variant serves both, so no new kernel
@@ -1351,14 +1478,41 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
                 if 0 <= i_lm < len(level_masks) else None
             if lm is not None:
                 tb_l, masked_l = lm, True
-        # chunk rounds over the shared candidate list (bu_more shape)
+        p_cap = _td_cap(g, K, mass, c_count, masked_l)
+        devprof.count_level("bu" if p_cap is None else "td")
+        if p_cap is not None:
+            # top-down: a push from the frontier's (job, vertex) pairs,
+            # BEFORE the overlay pass below — it reads the frontier
+            # from dist, and in hops mode the overlay's max-scatter
+            # may re-stamp a frontier vertex to level + 1
+            with phase("bfs.sweep", level=level, dir="td", p_cap=p_cap,
+                       mass=mass) as ph:
+                dist, pushed = btd(dist, active, dev_scalar(level),
+                                   dstT, colstart, degc, p_cap=p_cap,
+                                   n_=n, expand=expand)
+                with ph.sync():
+                    pairs = int(np.asarray(pushed)[0])
+                ph.set(pairs=pairs)
+            c_count = 0
+        if oscat is not None:
+            # overlay add-edges expand top-down off the level's final
+            # bitmaps — independent of the base sweep in either
+            # direction (all scatter level+1 with the same min / max,
+            # so order is immaterial), and it must run even when the
+            # base candidate list is empty (vertices reachable only
+            # through overlay edges)
+            dist = oscat(dist, fbits, ov.src_dev, ov.dst_dev,
+                         dev_scalar(level), cap=ov.cap, n_=n,
+                         expand=expand)
+        # bottom-up: chunk rounds over the shared candidate list
+        # (bu_more shape)
         off = None
         rounds = 0
         prog = None
         while c_count > 0 and rounds < BU_CHUNK_ROUNDS:
             c_cap2 = min(_next_pow2(max(c_count, 2)), cap_n)
             fuse = BU_CHUNK_ROUNDS - rounds
-            with phase("bfs.sweep", level=level, c_cap=c_cap2,
+            with phase("bfs.sweep", level=level, dir="bu", c_cap=c_cap2,
                        fuse=fuse) as ph:
                 if off is None:
                     cand = pad(cand)
@@ -1400,7 +1554,6 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
         levels[act_h] = level
     out = dist[:, :n]
     if not return_device:
-        from titan_tpu.obs import devprof
         devprof.count_d2h("bfs.dist", out.nbytes)
         out = np.asarray(out)
     return out, levels, completed

@@ -213,6 +213,14 @@ def count_d2h(site: str, nbytes: int) -> None:
             prof.on_xfer("d2h", site, int(nbytes))
 
 
+def count_level(direction: str) -> None:
+    """Count one level of a batched BFS by the direction it took
+    (``"td"`` push or ``"bu"`` pull; models/bfs_hybrid._td_cap)."""
+    for prof in list(_PROFILERS):
+        prof.metrics.counter("device.bfs.levels",
+                             labels={"dir": direction}).inc()
+
+
 def current() -> Optional["DeviceCostProfiler"]:
     """The most recently installed profiler, or None."""
     return _PROFILERS[-1] if _PROFILERS else None

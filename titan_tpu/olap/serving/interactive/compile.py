@@ -385,6 +385,9 @@ def reversed_chunked_csr(snap) -> dict:
             [deg, [0]]).astype(np.int32)),
         "q_total": q_total,
         "n": n,
+        # columns hold a vertex's parents and not its children: the
+        # batched BFS pulls at every level (bfs_hybrid._td_cap)
+        "directed": True,
     }
     snap._hybrid_csr_rev = out
     return out

@@ -65,6 +65,11 @@ from titan_tpu.olap.serving.tenants import (QuotaExceeded,
 _batch_seq = itertools.count(1)
 
 
+def _padded(k: int) -> int:
+    """A batch's row count: the next power of two (1 stays 1)."""
+    return 1 << (k - 1).bit_length()
+
+
 class InteractiveLane:
     """See module doc. One lane per JobScheduler
     (``JobScheduler.interactive()``); independently constructible for
@@ -80,6 +85,9 @@ class InteractiveLane:
         self.max_depth = int(max_depth)
         self.collector = Collector(window_s=window_s, max_fuse=max_fuse)
         self._closed = False
+        # shapes (n, q_total, largest padded batch) whose executables
+        # this lane has built (_build_shapes)
+        self._built: set = set()
         self._worker: Optional[threading.Thread] = None
         if autostart:
             self.start()
@@ -379,6 +387,10 @@ class InteractiveLane:
             g = reversed_chunked_csr(snap) \
                 if direction is Direction.OUT \
                 else build_chunked_csr(snap)
+            if direction is Direction.IN:
+                # the forward columns are an in_() chain's parents,
+                # not its children: no push (bfs_hybrid._td_cap)
+                g = dict(g, directed=True)
             # mixed-label chain (ISSUE 13): per-hop slot bitmaps over
             # the union-label lease — one bitmap per distinct hop label
             # set, threaded through the kernels as per-level masks
@@ -396,6 +408,7 @@ class InteractiveLane:
                 sched.tenants.hold_hbm(r.tenant, share)
             admit.set(k_runnable=len(runnable), nbytes=int(nbytes),
                       epoch=epoch_info.get("epoch")).end()
+            self._build_shapes(g)
             t0 = time.time()
             try:
                 self._sweep(runnable, seeds, g, overlay, snap,
@@ -410,34 +423,68 @@ class InteractiveLane:
                 sched.ledger.unpin(key)
             return True
 
-    def _sweep(self, runnable, seeds, g, overlay, snap, batch_id,
-               fused_k, epoch_info, level_masks=None) -> None:
+    def _build_shapes(self, g) -> None:
+        """Before the first answer against a layout of a new shape: run
+        a single-start hops batch of every padded size the collector
+        can fuse, and every rung of the top-down step at that size. A
+        level's rung follows its frontier's mass and a batch's size
+        follows the arrivals, so no warm-up by traffic covers them, and
+        a shape met first inside a served window stalls it for the
+        build. The executables are keyed by the layout's shape, not by
+        the layout: the next epoch's layout of the same shape builds
+        nothing. Journaled as one ``build`` span that holds the dummy
+        batches' phases and every ``compile`` span. Directed layouts
+        pull at every level (caps of data-dependent counts: not a
+        finite set) and are left as they were."""
+        from titan_tpu.models.bfs_hybrid import warm_batched_td
+        from titan_tpu.obs.tracing import current_span
+
+        sizes = [1 << e for e in range(
+            _padded(self.collector.max_fuse).bit_length())]
+        key = (g["n"], g["q_total"], sizes[-1])
+        if g.get("directed") or key in self._built:
+            return
+
+        def build():
+            for Kp in sizes:
+                self._hops(g, [[0]] * Kp, [1] * Kp)
+                warm_batched_td(g, Kp, expand=True)
+
+        cur = current_span()
+        if cur is None:
+            build()
+        else:
+            tracer, trace_id, root = cur
+            with tracer.span(trace_id, "build", parent=root, n=g["n"],
+                             q_total=g["q_total"],
+                             max_k=sizes[-1]) as span:
+                with scope(tracer, trace_id, span):
+                    build()
+        self._built.add(key)
+
+    def _hops(self, g, seeds, depths_p, overlay=None, level_masks=None):
+        """One fused hops run and its hop-set sizes: ``seeds`` (one
+        list of dense start ids a member) over a batch padded to
+        ``len(depths_p)`` rows. Returns ``(masks [Kp, n] device bool,
+        sizes np int32 [Kp])``: row k's hop set at its own depth."""
         import jax.numpy as jnp
 
-        from titan_tpu.models.bfs import _next_pow2
         from titan_tpu.models.bfs_hybrid import frontier_bfs_batched
-        from titan_tpu.ops.compaction import compact_ids
+        from titan_tpu.obs import devprof
 
         n = g["n"]
-        depths = [r.plan.depth for r in runnable]
-        D = max(depths)
-        K = len(runnable)
-        # pad the batch to its power-of-two capacity bucket so fuse
-        # occupancy never mints a fresh XLA shape; pad rows carry
-        # depth 0 — the level-1 keep mask retires them before any sweep
-        Kp = 1 << max(K - 1, 1).bit_length() if K > 1 else 1
-        depths_p = depths + [0] * (Kp - K)
+        Kp = len(depths_p)
+        D = max(depths_p)
 
         def on_level(level, nf):
             keep = np.asarray([level <= d for d in depths_p])
             return keep if not keep.all() else None
 
-        t0 = time.time()
         if all(len(ds) == 1 for ds in seeds):
             # the common point-query shape (one start vertex): seed on
             # DEVICE through the kernel's sources path — no [Kp, n]
             # host init array, no O(n) H2D per query
-            srcs = [ds[0] for ds in seeds] + [0] * (Kp - K)
+            srcs = [ds[0] for ds in seeds] + [0] * (Kp - len(seeds))
             dist, _levels, _completed = frontier_bfs_batched(
                 g, srcs, max_levels=D + 1, start_level=1,
                 on_level=on_level, overlay=overlay, mode="hops",
@@ -456,13 +503,30 @@ class InteractiveLane:
         # hop-set extraction stays DEVICE-side: one [Kp] size readback,
         # then a compacted index list per id/values member — never the
         # O(n) dist row (a scale-26 row is a ~270 MB D2H transfer)
-        from titan_tpu.obs import devprof
         with phase("extract", Kp=Kp) as ph:
             want = jnp.asarray(np.asarray(depths_p, np.int32) + 1)
             masks = dist == want[:, None]
             with ph.sync():
                 sizes = np.asarray(masks.sum(axis=1, dtype=jnp.int32))
             devprof.count_d2h("interactive.sizes", int(sizes.nbytes))
+        return masks, sizes
+
+    def _sweep(self, runnable, seeds, g, overlay, snap, batch_id,
+               fused_k, epoch_info, level_masks=None) -> None:
+        from titan_tpu.models.bfs import _next_pow2
+        from titan_tpu.obs import devprof
+        from titan_tpu.ops.compaction import compact_ids
+
+        n = g["n"]
+        depths = [r.plan.depth for r in runnable]
+        K = len(runnable)
+        # pad the batch to its power-of-two capacity bucket so fuse
+        # occupancy never mints a fresh XLA shape; pad rows carry
+        # depth 0 — the level-1 keep mask retires them before any sweep
+        depths_p = depths + [0] * (_padded(K) - K)
+        t0 = time.time()
+        masks, sizes = self._hops(g, seeds, depths_p, overlay,
+                                  level_masks)
         exec_ms = (time.time() - t0) * 1e3
         with phase("reply") as ph:
             d2h_bytes = 0
