@@ -1,5 +1,5 @@
-"""The batched BFS's top-down (push) step and its direction rule
-(ISSUE 26).
+"""The batched BFS's top-down (push) step, its direction rule (ISSUE
+26) and what one level's program hands the next (ISSUE 29).
 
 What is pinned here, all on the CPU:
 
@@ -11,7 +11,14 @@ What is pinned here, all on the CPU:
 * which levels go which way: a mass above the top rung, a masked level,
   an ``out()`` chain's layout and a mesh-placed cohort pull;
 * after the lane's first batch on a snapshot, a batch of any size up to
-  ``max_fuse`` and a level on any rung build nothing.
+  ``max_fuse`` and a level on any rung build nothing;
+* the frontier handed forward as a pair list with its statistics (the
+  carried road) gives the same ``dist``, ``levels`` and ``completed`` as
+  listing it from ``dist`` at every level (the scan road) and as the
+  pull: mixed depths, retired jobs, multi-start rows, resumes,
+  checkpoints; what a push hands on IS the next level's frontier and
+  plan; a list past its capacity or its rung is scanned for instead; a
+  single-start 2-hop query is four programs and three readbacks.
 """
 
 import threading
@@ -53,15 +60,21 @@ def graph(request):
     return snap, bh.build_chunked_csr(snap), adj
 
 
-def sweep_attrs(run):
-    """``(result, the attributes of the run's bfs.sweep spans)``."""
+def spans_of(run, *names):
+    """``(result, [(name, attrs)] of the run's spans called ``names``)``."""
     tracer = Tracer()
     root = tracer.start("t", "interactive")
     with scope(tracer, "t", root):
         out = run()
     tracer.end(root)
-    return out, [s.attrs for s in tracer.spans("t")
-                 if s.name == "bfs.sweep"]
+    return out, [(s.name, s.attrs) for s in tracer.spans("t")
+                 if s.name in names]
+
+
+def sweep_attrs(run):
+    """``(result, the attributes of the run's bfs.sweep spans)``."""
+    out, spans = spans_of(run, "bfs.sweep")
+    return out, [attrs for _name, attrs in spans]
 
 
 def sweeps(run):
@@ -269,10 +282,11 @@ def test_levels_are_counted_by_direction(graph):
     with devprof.DeviceCostProfiler(metrics=metrics):
         bh.frontier_bfs_batched(g, srcs, **kw)
         bh.frontier_bfs_batched(dict(g, directed=True), srcs, **kw)
-    count = {d: metrics.counter("device.bfs.levels",
-                                labels={"dir": d}).count
-             for d in ("td", "bu")}
-    assert count == {"td": 2, "bu": 2}
+    count = {(d, road): metrics.counter(
+        "device.bfs.levels", labels={"dir": d, "list": road}).count
+        for d, road in (("td", "carried"), ("td", "scan"), ("bu", "none"))}
+    assert count == {("td", "carried"): 2, ("td", "scan"): 0,
+                     ("bu", "none"): 2}
 
 
 # -- a finite set of shapes, built before the first answer -------------------
@@ -341,4 +355,270 @@ def test_after_the_first_batch_no_size_and_no_rung_builds(graph):
         assert prof.compiles() == built
     finally:
         prof.uninstall()
+        sched.close()
+
+
+# -- what one level's program hands the next (ISSUE 29) ---------------------
+
+@pytest.fixture
+def small_graph_lists(monkeypatch):
+    """The hand-on rule is a measured cost ratio (``TD_DEDUP_COST``): on
+    graphs of a thousand vertices only a rung of 2 columns would list.
+    Here every rung does, so that the rungs these graphs use hand their
+    lists on across several levels (and a list can outgrow its room)."""
+    monkeypatch.setattr(bh, "_td_lists", lambda p_cap, n: True)
+
+
+def test_the_hand_on_rule():
+    """At the benchmark's scale the lowest rung hands on and the two
+    above it do not (PERF.md 6, PR 29: rung 2^17 carried 54.5 ms against
+    19.5 + a 6 ms listing)."""
+    n = 1 << 20
+    assert [bh._td_lists(cap, n) for cap in (1 << 12, 1 << 17, 1 << 21)] \
+        == [True, False, False]
+    assert bh._td_lists(1 << 14, n) and not bh._td_lists(1 << 15, n)
+
+
+def scan_only(monkeypatch, g):
+    """Hold the carried road off from outside: a layout with no host
+    copy of its chunk counts (the seed then hands nothing on) and a push
+    that is never asked for the next level's list. Every level plans and
+    lists its frontier from ``dist``, the road from before the list."""
+    from titan_tpu.utils.jitcache import dev_scalar
+
+    real = bh._batched_td()
+
+    def never_hands_on(dist, pj, pv, count, active, level, _want, *a, **kw):
+        return real(dist, pj, pv, count, active, level, dev_scalar(0),
+                    *a, **kw)
+
+    monkeypatch.setattr(bh, "_batched_td", lambda: never_hands_on)
+    return {k: v for k, v in g.items() if k != "_host"}
+
+
+def same(a, b):
+    for x, y in zip(a, b):
+        assert np.array_equal(x, y)
+
+
+def starts(adj, K, seed):
+    rng = np.random.default_rng(seed)
+    return [int(v) for v in rng.choice(
+        np.flatnonzero(np.diff(adj.indptr) > 0), K, replace=False)]
+
+
+@pytest.mark.parametrize("K", [1, 3, 16])
+@pytest.mark.parametrize("mode", ["bfs", "hops"])
+def test_carried_road_bit_equal_to_scan_and_pull(
+        graph, mode, K, monkeypatch, small_graph_lists):
+    _snap, g, adj = graph
+    srcs = light(g, adj, K)
+    kw = run_kw(mode)
+    got, attrs = sweep_attrs(
+        lambda: bh.frontier_bfs_batched(g, srcs, **kw))
+    roads = [a["list"] for a in attrs if a["dir"] == "td"]
+    # the seed is the L1 list, and L1's push hands L2 its own
+    assert roads[:2] == ["carried", "carried"]
+    pulled = bh.frontier_bfs_batched(dict(g, directed=True), srcs, **kw)
+    same(got, pulled)
+    bare = scan_only(monkeypatch, g)
+    scanned, attrs = sweep_attrs(
+        lambda: bh.frontier_bfs_batched(bare, srcs, **kw))
+    roads = [a["list"] for a in attrs if a["dir"] == "td"]
+    assert roads and set(roads) == {"scan"}
+    same(got, scanned)
+
+
+def test_mixed_depths_mask_without_a_replan(graph, monkeypatch,
+                                            small_graph_lists):
+    """The lane's batch: members of depths 1, 2 and 3 and two pad rows
+    (depth 0) in one run. The keep mask retires rows at levels 1, 2 and
+    3; their pairs stay in the list and are masked by the push; no
+    level re-plans and none scans."""
+    _snap, g, adj = graph
+    depths = [3, 1, 2, 2, 1, 1] + [0] * 10
+    srcs = light(g, adj, 6) + [0] * 10
+
+    def on_level(level, _nf):
+        keep = np.asarray([level <= d for d in depths])
+        return None if keep.all() else keep
+
+    kw = dict(run_kw("hops"), on_level=on_level)
+    got, spans = spans_of(
+        lambda: bh.frontier_bfs_batched(g, srcs, **kw),
+        "bfs.plan", "bfs.sweep")
+    plans = [a for name, a in spans if name == "bfs.plan"]
+    assert [a["level"] for a in plans] == [1, 2, 3]
+    assert all(a["carried"] and not a["replan"] for a in plans)
+    assert [(a["dir"], a["list"]) for name, a in spans
+            if name == "bfs.sweep"] == [("td", "carried")] * 3
+    same(got, bh.frontier_bfs_batched(dict(g, directed=True), srcs, **kw))
+    same(got, bh.frontier_bfs_batched(scan_only(monkeypatch, g), srcs,
+                                      **kw))
+    dist = got[0]
+    reach = sp.identity(g["n"], dtype=np.int8, format="csr")[srcs]
+    for h in (1, 2, 3):
+        reach = (reach @ adj).astype(bool).astype(np.int8)
+        for k, d in enumerate(depths):
+            if d == h:
+                assert np.array_equal(dist[k] == h + 1,
+                                      reach[k].toarray().ravel() > 0)
+
+
+def test_a_multi_start_row_scans_once_then_carries(graph,
+                                                   small_graph_lists):
+    _snap, g, adj = graph
+    n = g["n"]
+    init = np.zeros((2, n), np.int32)
+    live = light(g, adj, 4)
+    init[0, live[:3]] = 1
+    init[1, live[3:]] = 1
+    kw = dict(run_kw("hops"), init_dist=init)
+    got, attrs = sweep_attrs(
+        lambda: bh.frontier_bfs_batched(g, [0, 0], **kw))
+    assert [(a["level"], a.get("list")) for a in attrs][:2] == [
+        (1, "scan"), (2, "carried")]
+    same(got, bh.frontier_bfs_batched(dict(g, directed=True), [0, 0],
+                                      **kw))
+
+
+def test_a_resumed_run_and_a_checkpointed_one(graph, small_graph_lists):
+    """``checkpoint`` sees a complete state at every level whichever
+    road the levels took (``dist`` is the truth, the list a cache of
+    it), and a run resumed from any boundary ends bit-equal."""
+    _snap, g, adj = graph
+    srcs = starts(adj, 3, 11)
+    seen, pulled_seen = [], []
+
+    def keeper(into):
+        return lambda level, dist, active: into.append(
+            (level, np.asarray(dist), active))
+
+    whole = bh.frontier_bfs_batched(g, srcs, checkpoint=keeper(seen))
+    pulled = bh.frontier_bfs_batched(dict(g, directed=True), srcs,
+                                     checkpoint=keeper(pulled_seen))
+    same(whole, pulled)
+    assert len(seen) == len(pulled_seen) >= 3
+    for (lv, dist, act), (lv2, dist2, act2) in zip(seen, pulled_seen):
+        assert lv == lv2 and np.array_equal(act, act2)
+        assert np.array_equal(dist, dist2)
+    for lv, dist, _act in seen[1:3]:
+        resumed, attrs = sweep_attrs(lambda: bh.frontier_bfs_batched(
+            g, srcs, init_dist=dist[:, :g["n"]], start_level=lv))
+        same(whole, resumed)
+        roads = [a["list"] for a in attrs if a["dir"] == "td"]
+        assert not roads or roads[0] == "scan"
+
+
+@pytest.mark.parametrize("K", [1, 3, 16])
+@pytest.mark.parametrize("mode", ["bfs", "hops"])
+def test_what_a_push_hands_on_is_the_next_frontier_and_plan(
+        graph, mode, K, monkeypatch, small_graph_lists):
+    """After every push that was asked for it: the list holds each
+    (job, vertex) of the next frontier exactly once (lanes that reached
+    the same vertex raced on the claim array and one won), and the
+    statistics beside it are what ``bplan`` reads from the state."""
+    from titan_tpu.utils.jitcache import dev_scalar
+
+    _snap, g, adj = graph
+    n = g["n"]
+    real, checked = bh._batched_td(), []
+
+    def spy(dist, pj, pv, count, active, level, want, *a, **kw):
+        out = real(dist, pj, pv, count, active, level, want, *a, **kw)
+        dist, nj, nv, ncount, stats = out
+        stats = np.asarray(stats)
+        if stats[2] < 0:        # not asked, or dearer than looking
+            return out
+        nxt = int(np.asarray(level)) + 1
+        _fbits, _cand, plan = bh._batched_plan()(
+            dist, active, dev_scalar(nxt), g["degc"],
+            c_cap=bh._next_pow2(n), n_=n, expand=kw["expand"])
+        assert np.array_equal(stats[3:], np.asarray(plan))
+        assert stats[2] == int(np.asarray(ncount)) == stats[4:4 + K].sum()
+        front = (np.asarray(dist)[:, :n] == nxt) \
+            & np.asarray(active)[:, None]
+        pairs = sorted(zip(np.asarray(nj)[:stats[2]].tolist(),
+                           np.asarray(nv)[:stats[2]].tolist()))
+        want_pairs = sorted(zip(*(x.tolist() for x in np.nonzero(front))))
+        if stats[2] <= len(nj):
+            assert pairs == want_pairs
+        else:       # past its capacity the list is cut (the loop scans)
+            assert len(set(pairs)) == len(nj) \
+                and set(pairs) <= set(want_pairs)
+        checked.append(nxt)
+        return out
+
+    monkeypatch.setattr(bh, "_batched_td", lambda: spy)
+    bh.frontier_bfs_batched(g, light(g, adj, K), **run_kw(mode))
+    assert len(checked) >= 2
+
+
+@pytest.mark.parametrize("fits", ["neither", "the-ladder-not-the-rung"])
+def test_a_list_past_its_room_is_scanned_for(graph, fits, monkeypatch,
+                                             small_graph_lists):
+    """Sixteen members, fifteen of depth 1: the list L1 hands on holds
+    every member's neighbours, L2's frontier is one member's. Past the
+    list's capacity (the ladder's top) the push says so and hands on
+    the statistics alone; past L2's own rung the list is in hand and
+    not used. Either way L2 lists from dist, with no plan."""
+    _snap, g, adj = graph
+    srcs = light(g, adj, 16)
+    depths = [2] + [1] * 15
+    deg = np.diff(adj.indptr)
+    degc = -(-deg // 8)
+    l1 = int(degc[srcs].sum())
+    l2 = int(degc[adj[srcs[0]].indices].sum())
+    handed = int(sum(deg[s] for s in srcs))      # no duplicate edges?
+    rung2 = bh._next_pow2(l2)
+    top = bh._next_pow2(max(l1, l2))
+    if fits == "the-ladder-not-the-rung":
+        top *= bh._next_pow2(handed)
+    monkeypatch.setattr(bh, "_td_caps", lambda _g: tuple(sorted(
+        {rung2, top})))
+
+    def on_level(level, _nf):
+        keep = np.asarray([level <= d for d in depths])
+        return None if keep.all() else keep
+
+    kw = dict(run_kw("hops"), max_levels=3, on_level=on_level)
+    got, spans = spans_of(
+        lambda: bh.frontier_bfs_batched(g, srcs, **kw),
+        "bfs.plan", "bfs.sweep")
+    sweeps_ = [a for name, a in spans if name == "bfs.sweep"]
+    assert [(a["level"], a["dir"], a["list"]) for a in sweeps_] == [
+        (1, "td", "carried"), (2, "td", "scan")]
+    assert sweeps_[0]["handed"] > sweeps_[1]["p_cap"]
+    assert (sweeps_[0]["handed"] > top) == (fits == "neither")
+    assert [a["carried"] for name, a in spans if name == "bfs.plan"] \
+        == [True, True]
+    same(got, bh.frontier_bfs_batched(dict(g, directed=True), srcs, **kw))
+
+
+def test_a_single_start_two_hop_is_four_programs_three_readbacks(graph):
+    from titan_tpu.olap.serving.scheduler import JobScheduler
+
+    class Xfers:
+        def __init__(self):
+            self.d2h = []
+
+        def record(self, kind, **kw):
+            if kind == "xfer" and kw["dir"] == "d2h":
+                self.d2h.append(kw["site"])
+
+    snap, g, adj = graph
+    (src,) = light(g, adj, 1)
+    sched = JobScheduler(snapshot=snap, autostart=False)
+    try:
+        lane = sched.interactive()
+        lane._hops(g, [[src]], [2])                 # builds
+        seen = Xfers()
+        with devprof.DeviceCostProfiler(metrics=MetricManager(),
+                                        recorder=seen) as prof:
+            _masks, sizes = lane._hops(g, [[src]], [2])
+        assert {k: v["calls"] for k, v in prof.kernel_stats().items()} \
+            == {"batched_seed": 1, "batched_td": 2, "batched_extract": 1}
+        assert seen.d2h == ["bfs.stats", "bfs.stats", "interactive.sizes"]
+        assert int(sizes[0]) == (adj[[src]] @ adj).astype(bool).sum()
+    finally:
         sched.close()

@@ -113,20 +113,47 @@ def test_batched_bfs_bottom_up(spec):
              c_cap=N, n_=N, fuse=8, masked=False, expand=False)
 
 
-@pytest.mark.parametrize("k,expand", [(16, True), (K, False)],
-                         ids=["lane-hops-16", "jobs-bfs-8"])
+@pytest.mark.parametrize("k,expand", [(16, True), (1, True), (K, False)],
+                         ids=["lane-hops-16", "lane-hops-1", "jobs-bfs-8"])
 def test_batched_bfs_top_down(spec, k, expand):
     """Every rung of the push ladder, as the lane (16 fused hops
-    queries) and the job batcher (8 BFS jobs) call it: the benchmark
-    must not be the first to show the chip's compiler a rung."""
-    from titan_tpu.models.bfs_hybrid import _batched_td, _td_caps
+    queries, and the one query of a median batch) and the job batcher
+    (8 BFS jobs) call it: the benchmark must not be the first to show
+    the chip's compiler a rung. Each takes the frontier as a pair list
+    and the lowest rung holds the claim dedup that hands on the next."""
+    from titan_tpu.models.bfs_hybrid import (_batched_td, _td_caps,
+                                             _td_lists)
 
-    for p_cap in _td_caps({"q_total": Q}):
+    caps = _td_caps({"q_total": Q})
+    assert [_td_lists(p_cap, N) for p_cap in caps] == [True, False, False]
+    for p_cap in caps:
         _compile(_batched_td(), spec((k, N + 1), jnp.int32),
-                 spec((k,), jnp.bool_), spec((), jnp.int32),
+                 spec((caps[-1],), jnp.int32), spec((caps[-1],), jnp.int32),
+                 spec((), jnp.int32), spec((k,), jnp.bool_),
+                 spec((), jnp.int32), spec((), jnp.int32),
                  spec((8, Q), jnp.int32), spec((N + 1,), jnp.int32),
-                 spec((N + 1,), jnp.int32),
-                 p_cap=p_cap, n_=N, expand=expand)
+                 spec((N + 1,), jnp.int32), p_cap=p_cap, n_=N,
+                 expand=expand, lists=_td_lists(p_cap, N))
+
+
+@pytest.mark.parametrize("k,expand", [(16, True), (K, False)],
+                         ids=["lane-hops-16", "jobs-bfs-8"])
+def test_batched_bfs_seed_list_extract(spec, k, expand):
+    """The rest of a pushed query's programs: the seed (state, job mask
+    and the start level's pair list in one), the scan road's listing
+    (every rung's branch in one executable) and the lane's extract."""
+    from titan_tpu.models.bfs_hybrid import (_batched_list, _batched_seed,
+                                             _td_caps, hop_extract)
+
+    caps = _td_caps({"q_total": Q})
+    _compile(_batched_seed(), spec((k,), jnp.int32), spec((), jnp.int32),
+             n_=N, cap=caps[-1], expand=expand)
+    _compile(_batched_list(), spec((k, N + 1), jnp.int32),
+             spec((k,), jnp.bool_), spec((), jnp.int32),
+             spec((), jnp.int32), spec((N + 1,), jnp.int32),
+             caps=caps, n_=N)
+    _compile(hop_extract(), spec((k, N + 1), jnp.int32),
+             spec((k,), jnp.int32), n_=N)
 
 
 def test_frontier_push_list_sssp(spec):

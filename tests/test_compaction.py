@@ -259,6 +259,66 @@ def test_op_scan_ban_covers_new_subdirectories_zero_config(tmp_path):
         ("opscan", "titan_tpu/olap/fleet/router.py")}
 
 
+def _wide_ops(jaxpr, width: int) -> list:
+    """``(primitive, elements)`` of every cumsum (its operand) and
+    scatter (its updates) at or above ``width``, sub-programs (cond
+    branches, inner jits) included."""
+    import jax
+
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name.startswith("cum"):
+            size = int(np.prod(eqn.invars[0].aval.shape))
+        elif name.startswith("scatter"):
+            size = int(np.prod(eqn.invars[2].aval.shape))
+        else:
+            size = 0
+        if size >= width:
+            found.append((name, size))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _wide_ops(sub, width)
+    return found
+
+
+@pytest.mark.parametrize("K", [1, 16])
+@pytest.mark.parametrize("expand", [False, True], ids=["bfs", "hops"])
+def test_op_scan_a_push_from_a_list_holds_nothing_n_wide(K, expand):
+    """The op scan of the PROGRAM, not of its source (ISSUE 29): a push
+    that takes its frontier as a pair list and hands on the next one
+    holds no cumsum and no scatter as wide as n — a compaction costs
+    its input's width whatever it finds (7 ms a million on a v5e), and
+    the level loop's n-wide ones were most of a point query. Its widest
+    are the scatter's own 8 x p_cap. The scan road's listing, the
+    control, holds the n-wide compaction this one is rid of."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from titan_tpu.models import bfs_hybrid as bh
+
+    n, q, p_cap = 1 << 12, 9000, 1 << 6
+    caps = (p_cap, 1 << 12)
+    i32, b = jnp.int32, jnp.bool_
+    S = jax.ShapeDtypeStruct
+    scalar = S((), i32)
+    push = jax.make_jaxpr(
+        functools.partial(bh._batched_td().__wrapped__, p_cap=p_cap,
+                          n_=n, expand=expand, lists=True))(
+        S((K, n + 1), i32), S((caps[-1],), i32), S((caps[-1],), i32),
+        scalar, S((K,), b), scalar, scalar, S((8, q), i32),
+        S((n + 1,), i32), S((n + 1,), i32))
+    assert _wide_ops(push.jaxpr, n) == []
+    assert max(size for _name, size in _wide_ops(push.jaxpr, 1)) \
+        == 8 * p_cap
+    listing = jax.make_jaxpr(
+        functools.partial(bh._batched_list().__wrapped__, caps=caps,
+                          n_=n))(
+        S((K, n + 1), i32), S((K,), b), scalar, scalar, S((n + 1,), i32))
+    assert _wide_ops(listing.jaxpr, n)
+
+
 @pytest.mark.parametrize("seed", [3, 11])
 def test_sssp_delta_band_plan_differential(seed):
     """The delta-stepping path now runs through the same banded plan as

@@ -49,8 +49,9 @@ import functools
 import numpy as np
 
 from titan_tpu.models.bfs import INF, _next_pow2
-from titan_tpu.ops.compaction import (claim_dedup, claim_reset,
-                                      compact_ids, scatter_compact)
+from titan_tpu.ops.compaction import (CLAIM_SENTINEL, claim_dedup,
+                                      claim_reset, compact_ids,
+                                      scatter_compact)
 
 # mode-switch thresholds (Beamer-style, tuned on v5e):
 # td->bu when the frontier's (chunked) edge mass exceeds 1/ALPHA of the
@@ -102,6 +103,16 @@ TD_RUNG_SHIFTS = (9, 4, 0)
 # — one pushed chunk column against one candidate-round of the
 # bottom-up sweep. Chip measurement that set it: PERF.md 6, PR 26.
 TD_BU_COST = 1
+# hand-on rule of a pushed level (``_td_lists``): the push dedups its
+# scatter targets into the next level's pair list only while
+#   8 * p_cap * TD_DEDUP_COST < n
+# — a deduped lane (a claim scatter-min and its gather, a degc gather,
+# a two-payload compaction: five random ops) against a vertex of the
+# n-wide listing that would find the next frontier instead (one
+# streaming compaction). Chip measurement that set it: PERF.md 6, PR 29
+# (33 ns a lane on rung 2^17 against 5.5 ns a vertex). At scale 20 the
+# lowest rung hands on and the two above it do not.
+TD_DEDUP_COST = 6
 
 
 def layout_slot_positions(indptr, deg, n: int):
@@ -1012,60 +1023,227 @@ def _batched_bu():
     return _get("batched_bu", build)
 
 
+def _batched_seed():
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        @functools.partial(jax.jit,
+                           static_argnames=("n_", "cap", "expand"))
+        def bseed(src, start, n_: int, cap: int, expand: bool = False):
+            """The whole start of a single-start batch in ONE program:
+            the ``[K, n+1]`` state (bfs: INF with 0 at each job's
+            source; hops: 0 with ``start`` at it, the pad slot INF),
+            the all-true job mask, and the start level's frontier as
+            the pair list the push reads — the sources ARE the list, so
+            nothing is compacted. Returns ``(dist, active, pj, pv,
+            count)``; the list's capacity is ``cap`` (the caller hands
+            it forward only where ``K <= cap``)."""
+            K = src.shape[0]
+            k = jnp.arange(K, dtype=jnp.int32)
+            if expand:
+                dist = jnp.zeros((K, n_ + 1), jnp.int32) \
+                    .at[k, src].set(start).at[:, n_].set(INF)
+            else:
+                dist = jnp.full((K, n_ + 1), INF, jnp.int32) \
+                    .at[k, src].set(0)
+            room = max(cap, K)
+            pj = jnp.zeros((room,), jnp.int32).at[:K].set(k)[:cap]
+            pv = jnp.full((room,), n_, jnp.int32).at[:K].set(src)[:cap]
+            return dist, jnp.ones((K,), bool), pj, pv, jnp.int32(K)
+        return bseed
+    return _get("batched_seed", build)
+
+
+def _batched_list():
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        @functools.partial(jax.jit,
+                           static_argnames=("caps", "n_"))
+        def blist(dist, active, level, rung, degc, caps: tuple, n_: int):
+            """The scan road to a push: list the (job, vertex) pairs
+            with ``dist[k, v] == level`` of active jobs from the state
+            itself — what a level does when the program before it left
+            no list (after a plan, a pull, a resume; past the list's
+            capacity). A pair with no chunk pushes nothing and is not
+            listed (``degc[n] = 0`` drops the pad slot too), so pairs
+            never outnumber the frontier's chunks.
+
+            A compaction costs its INPUT's width (7 ms a million on a
+            v5e: 110 ms at K = 16), so on a rung below n columns: the
+            frontier's distinct vertices first (n wide, <= pairs <=
+            the rung of them), then their K x rung memberships.
+            ``rung`` (device scalar) picks the branch; one executable a
+            K holds every rung's. Returns ``(pj, pv, count)`` at the
+            ladder's top capacity."""
+            K = dist.shape[0]
+            row = n_ + 1
+            cap = caps[-1]
+            front = (dist == level) & active[:, None] & (degc > 0)[None]
+
+            def whole(p_cap):
+                def go(_):
+                    count, ids = compact_ids(front.ravel(), p_cap,
+                                             K * row)
+                    return count, ids // row, ids % row
+                return go
+
+            def split(p_cap):
+                def go(_):
+                    _, verts = compact_ids(front.any(axis=0), p_cap, n_)
+                    count, ids = compact_ids(
+                        jnp.take(front, verts, axis=1).ravel(), p_cap,
+                        K * p_cap)
+                    return count, ids // p_cap, verts[ids % p_cap]
+                return go
+
+            def at_capacity(branch, p_cap):
+                # every op above is the rung wide, not the capacity
+                # (a gather costs its output's width too)
+                def go(_):
+                    count, pj, pv = branch(None)
+                    room = (0, cap - p_cap)
+                    return count, jnp.pad(pj, room), jnp.pad(pv, room)
+                return go
+
+            count, pj, pv = jax.lax.switch(
+                rung, [at_capacity((whole if c >= row else split)(c), c)
+                       for c in caps], None)
+            return pj, pv, count
+        return blist
+    return _get("batched_list", build)
+
+
 def _batched_td():
     def build():
         import jax
         import jax.numpy as jnp
 
         @functools.partial(jax.jit,
-                           static_argnames=("p_cap", "n_", "expand"),
+                           static_argnames=("p_cap", "n_", "expand",
+                                            "lists"),
                            donate_argnums=(0,))
-        def btd(dist, active, level, dstT, colstart, degc, p_cap: int,
-                n_: int, expand: bool = False):
-            """One top-down level for all K jobs: the (job, vertex)
-            pairs with ``dist[k, v] == level`` of active jobs (read
-            BEFORE the scatter: a vertex stamped this level must not
-            push this level) are compacted into a ``p_cap`` list, their
-            chunks enumerated into ``p_cap`` columns, each column
-            gathered ONCE from dstT and scattered into its owner's job
-            row: ``min`` of ``level + 1`` (bfs: visited entries are
-            smaller and stay), ``max`` with ``expand`` (hops: the
-            re-stamp contract of bstep). Pad lanes (n + 1) and dead
-            columns (the all-pad sink column) drop. Caller guarantee
-            (_td_cap): the frontier's chunk mass <= p_cap and
-            K * (n + 1) < 2^31. Returns (dist, [pairs, columns])."""
+        def btd(dist, pj, pv, count, active, level, want, dstT, colstart,
+                degc, p_cap: int, n_: int, expand: bool = False,
+                lists: bool = False):
+            """One top-down level for all K jobs, from the level's
+            frontier as a list of (job, vertex) pairs (``pj``, ``pv``,
+            the first ``count`` live; pairs of jobs retired since the
+            list was made are masked by ``active[job]`` here, so a mask
+            change needs no re-plan): the pairs' chunks are enumerated
+            into ``p_cap`` columns, each column gathered ONCE from dstT
+            and scattered into its owner's job row: ``min`` of ``level
+            + 1`` (bfs: visited entries are smaller and stay), ``max``
+            with ``expand`` (hops: the re-stamp contract of bstep). Pad
+            lanes (n + 1) and dead columns (the all-pad sink column)
+            drop. Caller guarantee (_td_cap): ``count`` and the live
+            pairs' chunk mass <= p_cap, and K * (n + 1) < 2^31.
+
+            NO n-wide compaction: on a rung that ``lists`` (static:
+            ``_td_lists``) and with ``want`` (device scalar: the level
+            after this one will run) the NEXT frontier is deduped
+            from the scatter targets at the scatter's own width — lanes
+            that reached the same (job, vertex) race on a claim array
+            (ops.compaction.claim_dedup; the array is made here, a K x
+            (n + 1) fill at HBM bandwidth, so no claim state outlives
+            the program), in bfs mode only where ``dist`` read INF
+            before the scatter, in hops mode every target (the re-stamp
+            contract) — and the winners are compacted 8 x p_cap wide
+            into the list the next push takes, with the next level's
+            plan statistics beside it (``nf``, ``mass`` from the
+            winners; ``c_count`` an n-wide REDUCTION). A rung where
+            deduping is dearer than looking makes no list, whatever
+            ``want`` says.
+
+            Returns ``(dist, nj, nv, ncount, stats)``; ``stats`` =
+            ``[pairs, columns, ncount, c_count, nf[K], mass[K]]`` with
+            ``ncount`` = -1 where no list was made (it may exceed the
+            capacity: the list is then cut and the caller scans)."""
             K = dist.shape[0]
             row = n_ + 1
-            # a pair with no chunk pushes nothing; degc[n] = 0 drops
-            # the pad slot too
-            front = (dist == level) & active[:, None] & (degc > 0)[None]
-            if p_cap >= row:
-                f_count, flat = compact_ids(front.ravel(), p_cap, K * row)
-                job, v = flat // row, flat % row
-            else:
-                # a compaction costs its INPUT's width (7 ms a million
-                # on a v5e: 110 ms at K = 16), so below n columns: the
-                # frontier's distinct vertices first (n wide, <= pairs
-                # <= p_cap of them), then their K x p_cap memberships
-                _, verts = compact_ids(front.any(axis=0), p_cap, n_)
-                f_count, flat = compact_ids(
-                    jnp.take(front, verts, axis=1).ravel(), p_cap,
-                    K * p_cap)
-                job, v = flat // p_cap, verts[flat % p_cap]
-            valid = jnp.arange(p_cap) < f_count
-            v = jnp.where(valid, v, n_)
+            cap = pj.shape[0]
+            take = min(p_cap, cap)
+            valid = jnp.arange(take) < count
+            job = jnp.where(valid, pj[:take], 0)
+            valid = valid & active[job]
+            v = jnp.where(valid, pv[:take], n_)
             cols, p_total, owner = enumerate_chunk_pairs(
                 valid, degc[v], colstart[v], p_cap, dstT.shape[1] - 1,
                 with_owner=True)
             nbr = jnp.take(dstT, cols, axis=1)           # [8, p_cap]
             rows = jnp.broadcast_to(job[owner][None, :], nbr.shape)
+            pushed = jnp.stack([valid.sum().astype(jnp.int32), p_total])
+            if lists:
+                # the dist gather reads PRE-scatter state: duplicates
+                # of one new vertex all see INF and race on the claim
+                found = nbr < n_
+                if not expand:
+                    found = found & (
+                        dist[rows, jnp.minimum(nbr, n_)] >= INF)
             if expand:
                 dist = dist.at[rows, nbr].max(level + 1, mode="drop")
             else:
                 dist = dist.at[rows, nbr].min(level + 1, mode="drop")
-            return dist, jnp.stack([f_count, p_total])
+            if not lists:
+                none = jnp.zeros((0,), jnp.int32)
+                return dist, none, none, jnp.int32(-1), jnp.concatenate(
+                    [pushed, jnp.full((2 + 2 * K,), -1, jnp.int32)])
+
+            def hand_on(_):
+                key = jnp.where(found, rows * row + nbr, K * row)
+                lane = jnp.arange(8 * p_cap, dtype=jnp.int32) \
+                    .reshape(8, p_cap)
+                _, won = claim_dedup(
+                    jnp.full((K * row,), CLAIM_SENTINEL, jnp.int32),
+                    key, lane)
+                won = won & found
+                degn = degc[jnp.minimum(nbr, n_)]
+                mine = won[None] & (
+                    rows[None] == jnp.arange(K)[:, None, None])
+                nf = mine.sum(axis=(1, 2), dtype=jnp.int32)
+                mass = jnp.where(mine, degn[None], 0).sum(
+                    axis=(1, 2), dtype=jnp.int32)
+                ncount, (nj, nv) = scatter_compact(
+                    won.ravel(), (rows.ravel(), nbr.ravel()), cap,
+                    (0, n_))
+                if expand:
+                    unvis = degc[:n_] > 0
+                else:
+                    unvis = ((dist[:, :n_] >= INF) & active[:, None]) \
+                        .any(axis=0) & (degc[:n_] > 0)
+                c_count = unvis.sum().astype(jnp.int32)
+                return nj, nv, ncount, jnp.concatenate(
+                    [ncount[None], c_count[None], nf, mass])
+
+            def leave(_):
+                return (jnp.zeros((cap,), jnp.int32),
+                        jnp.full((cap,), n_, jnp.int32), jnp.int32(-1),
+                        jnp.full((2 + 2 * K,), -1, jnp.int32))
+
+            nj, nv, ncount, nxt = jax.lax.cond(want != 0, hand_on,
+                                               leave, None)
+            return dist, nj, nv, ncount, jnp.concatenate([pushed, nxt])
         return btd
     return _get("batched_td", build)
+
+
+def hop_extract():
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        @functools.partial(jax.jit, static_argnames=("n_",))
+        def bext(dist, want, n_: int):
+            """The lane's hop sets from the state as the level loop
+            leaves it (``[K, n+1]``, sliced here): row k's set is
+            ``dist[k] == want[k]``. Returns ``(masks [K, n] bool,
+            sizes [K])``."""
+            masks = dist[:, :n_] == want[:, None]
+            return masks, masks.sum(axis=1, dtype=jnp.int32)
+        return bext
+    return _get("batched_extract", build)
 
 
 def _td_caps(g) -> tuple:
@@ -1073,6 +1251,13 @@ def _td_caps(g) -> tuple:
     the largest power of two at or below half its chunk columns."""
     top = 1 << max((int(g["q_total"]) // 2).bit_length() - 1, 1)
     return tuple(sorted({max(top >> s, 2) for s in TD_RUNG_SHIFTS}))
+
+
+def _td_lists(p_cap: int, n: int) -> bool:
+    """Whether a push on rung ``p_cap`` hands the next level its list
+    (and statistics): while deduping its 8 x p_cap lanes is cheaper
+    than listing the next frontier from dist, n wide."""
+    return 8 * p_cap * TD_DEDUP_COST < n
 
 
 def _td_cap(g, K: int, mass: int, c_count: int, masked: bool):
@@ -1094,22 +1279,46 @@ def _td_cap(g, K: int, mass: int, c_count: int, masked: bool):
     return next((cap for cap in _td_caps(g) if mass <= cap), None)
 
 
-def warm_batched_td(g, K: int, expand: bool) -> None:
-    """Run every rung of the top-down step once at batch size ``K`` on
-    an empty frontier, so that no level of a later batch of that size
-    builds (or loads) an executable: a level's rung follows its mass,
-    which a warm-up by batch sizes cannot cover."""
-    import jax.numpy as jnp
+def _seed_stats(g: dict, src, expand: bool):
+    """What ``bplan`` would read back at the start level of a
+    single-start batch, from the layout's host copy of ``degc`` and no
+    readback: ``[c_count, nf[K], mass[K]]``. Every job's frontier is its
+    source; every vertex with an edge is a candidate (hops), or one
+    unvisited in SOME job (bfs: all but the source that every job
+    shares)."""
+    degc = g["_host"]["degc"]
+    nz = g.get("_nz")
+    if nz is None:
+        nz = g["_nz"] = int(np.count_nonzero(degc))
+    mass = degc[src]
+    shared = not expand and bool((src == src[0]).all() and mass[0] > 0)
+    return np.concatenate([[nz - shared], np.ones(len(src)), mass]) \
+        .astype(np.int32)
 
+
+def warm_batched_td(g, K: int, expand: bool) -> None:
+    """Run the plan, the scan road's listing and every rung of the
+    top-down step once at batch size ``K`` on an empty frontier, so that
+    no level of a later batch of that size builds (or loads) an
+    executable: a level's rung follows its mass and its road follows
+    what the level before left, which a warm-up by batch sizes cannot
+    cover."""
     from titan_tpu.utils.jitcache import dev_scalar
 
-    btd = _batched_td()
-    dist = jnp.zeros((K, g["n"] + 1), jnp.int32)
-    active = jnp.ones((K,), bool)
-    for cap in _td_caps(g):
-        dist, _ = btd(dist, active, dev_scalar(1), g["dstT"],
-                      g["colstart"], g["degc"], p_cap=cap, n_=g["n"],
-                      expand=expand)
+    n, degc, caps = g["n"], g["degc"], _td_caps(g)
+    dist, active, *_ = _batched_seed()(
+        np.zeros(K, np.int32), dev_scalar(1), n_=n, cap=caps[-1],
+        expand=expand)
+    level = dev_scalar(2)               # nothing is stamped 2
+    _batched_plan()(dist, active, level, degc,
+                    c_cap=_next_pow2(max(n, 2)), n_=n, expand=expand)
+    pj, pv, count = _batched_list()(dist, active, level, dev_scalar(0),
+                                    degc, caps=caps, n_=n)
+    for cap in caps:
+        dist, *_ = _batched_td()(dist, pj, pv, count, active, level,
+                                 dev_scalar(1), g["dstT"], g["colstart"],
+                                 degc, p_cap=cap, n_=n, expand=expand,
+                                 lists=_td_lists(cap, n))
     dist.block_until_ready()
 
 
@@ -1323,6 +1532,38 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
     non-completed jobs), levels np int32 [K] (the level at which each
     job's frontier emptied), completed np bool [K] (False = deactivated
     early via on_level)."""
+    dist, levels, completed = batched_bfs_state(
+        snap_or_graph, sources, max_levels=max_levels, on_level=on_level,
+        init_dist=init_dist, start_level=start_level,
+        checkpoint=checkpoint, overlay=overlay, mode=mode,
+        level_masks=level_masks)
+    out = dist[:, :dist.shape[1] - 1]
+    if not return_device:
+        from titan_tpu.obs import devprof
+        devprof.count_d2h("bfs.dist", out.nbytes)
+        out = np.asarray(out)
+    return out, levels, completed
+
+
+def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
+                      on_level=None, init_dist=None, start_level: int = 0,
+                      checkpoint=None, overlay=None, mode: str = "bfs",
+                      level_masks=None):
+    """``frontier_bfs_batched``'s level loop, returning the state as
+    the loop leaves it: ``dist`` [K, n+1] on the device, pad slot
+    included, for callers that read it with a program of their own (the
+    interactive lane's ``hop_extract``) and want no slice dispatched
+    in between.
+
+    The loop's state is ``(dist, level)`` and two caches of it that one
+    program hands the next: the level's frontier as a list of (job,
+    vertex) pairs, and the level's plan statistics. The seed of a
+    single-start batch leaves both (the sources are the list) and a
+    pushed level leaves both for the level after it, so a run of pushed
+    levels is one dispatch and one readback a level with no n-wide
+    compaction. A level with nothing in hand (after a pull, a resume, a
+    multi-start seed, under a live overlay, past the list's capacity)
+    plans and lists from ``dist`` as ever; ``dist`` stays the truth."""
     import jax.numpy as jnp
 
     g = snap_or_graph if isinstance(snap_or_graph, dict) \
@@ -1379,30 +1620,35 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
                 [a, jnp.full((cap_n - a.shape[0],), n + 1, a.dtype)])
         return a
 
+    caps = _td_caps(g)
+    # what one program may hand the next (the list, the statistics):
+    # only where a level can push at all, and not under a live overlay,
+    # whose add-edges put vertices in a frontier that no push listed
+    # and whose scatter reads the plan's bitmaps at every level
+    hand_on = ov is None and _td_cap(g, K, 0, 1, False) is not None
+    lst, held, st = None, 0, None
     with phase("bfs.seed", K=K, n=n, mode=mode):
-        if init_dist is None and expand:
-            # hops-mode default seeding: one start vertex per job
-            # stamped at start_level over a zero background (multi-source
-            # rows go through init_dist)
-            dist = jnp.zeros((K, n + 1), jnp.int32) \
-                .at[jnp.arange(K),
-                    jnp.asarray(src_arr.astype(np.int32))] \
-                .set(start_level) \
-                .at[:, n].set(INF)
-        elif init_dist is None:
-            dist = jnp.full((K, n + 1), INF, jnp.int32) \
-                .at[jnp.arange(K),
-                    jnp.asarray(src_arr.astype(np.int32))].set(0)
+        if init_dist is None:
+            dist, active, pj, pv, count = _batched_seed()(
+                src_arr.astype(np.int32), dev_scalar(int(start_level)),
+                n_=n, cap=caps[-1], expand=expand)
+            # the start level's frontier is the sources (bfs: stamped
+            # 0, so only a run from level 0 has one)
+            if hand_on and "_host" in g and K <= caps[-1] \
+                    and (expand or start_level == 0):
+                lst, held = (pj, pv, count), K
+                st = _seed_stats(g, src_arr, expand)
         else:
             d = np.asarray(init_dist, np.int32)
             if d.shape != (K, n):
                 raise ValueError(f"init_dist must be [K={K}, n={n}], "
                                  f"got {d.shape}")
             # col n is the scatter pad slot; it starts (and stays) INF
-            # in a fresh run, so a resumed row re-appends it
-            dist = jnp.concatenate(
-                [jnp.asarray(d), jnp.full((K, 1), INF, jnp.int32)],
-                axis=1)
+            # in a fresh run, so a resumed row re-appends it — on the
+            # host: uploads, and no program to build for a rare road
+            dist = jnp.asarray(np.concatenate(
+                [d, np.full((K, 1), INF, np.int32)], axis=1))
+            active = jnp.asarray(np.ones(K, bool))
         if "_state_sharding" in g:
             # mesh-placed cohort (parallel/partition.place_batched_csr):
             # pin the [K, n+1] state to its P(None, "v") placement up
@@ -1411,19 +1657,35 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
             import jax
             dist = jax.device_put(dist, g["_state_sharding"])
         act_h = np.ones(K, bool)
-        active = jnp.asarray(act_h)
     levels = np.zeros(K, np.int32)
     completed = np.zeros(K, bool)
     level = int(start_level)
-    while level < max_levels:
+
+    def plan(replan: bool):
+        """``bplan`` dispatched to its statistics read back: ONE sync
+        per level for ALL jobs."""
         with phase("bfs.plan", level=level) as ph:
             fbits, cand, stats = bplan(dist, active, dev_scalar(level),
                                        degc, c_cap=cap_n, n_=n,
                                        expand=expand)
             with ph.sync():
-                st = np.asarray(stats)  # ONE sync per level for ALL jobs
+                st = np.asarray(stats)
+            devprof.count_d2h("bfs.stats", st.nbytes)
             ph.set(c_count=int(st[0]), frontier=int(st[1:1 + K].sum()),
-                   replan=False)
+                   replan=replan, carried=False)
+        return fbits, cand, st
+
+    while level < max_levels:
+        # the level's statistics: handed on by the program before (the
+        # span then holds the host's decision alone), else planned
+        planned = st is None
+        if planned:
+            fbits, cand, st = plan(False)
+        else:
+            with phase("bfs.plan", level=level, c_count=int(st[0]),
+                       frontier=int(st[1:1 + K].sum()), replan=False,
+                       carried=True, sync_ms=0.0):
+                pass
         nf = st[1:1 + K]
         mask_changed = False
         # frontier emptied => that job's BFS is complete
@@ -1448,22 +1710,23 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
             # dist, this level's frontier (dist == level) is unswept
             checkpoint(level, dist, act_h.copy())
         if mask_changed:
-            # deactivated jobs (completed OR dropped) must stop
-            # influencing the sweep: re-plan with the new mask — it
-            # zeroes their bitmap rows AND drops their unvisited sets
-            # from the shared candidate list (a completed small-
-            # component job would otherwise re-contribute ~n dead
-            # candidates to every remaining level)
-            with phase("bfs.plan", level=level) as ph:
-                active = jnp.asarray(act_h)
-                fbits, cand, stats = bplan(dist, active,
-                                           dev_scalar(level), degc,
-                                           c_cap=cap_n, n_=n,
-                                           expand=expand)
-                with ph.sync():
-                    st = np.asarray(stats)
-                ph.set(c_count=int(st[0]),
-                       frontier=int(st[1:1 + K].sum()), replan=True)
+            active = jnp.asarray(act_h)
+            if planned or not expand:
+                # deactivated jobs (completed OR dropped) must stop
+                # influencing the sweep: re-plan with the new mask — it
+                # zeroes their bitmap rows AND drops their unvisited
+                # sets from the shared candidate list (a completed
+                # small-component job would otherwise re-contribute ~n
+                # dead candidates to every remaining level)
+                fbits, cand, st = plan(True)
+                planned = True
+            else:
+                # statistics handed on, hops mode: the counts are per
+                # job and every vertex with an edge stays a candidate,
+                # so the new mask is applied here; the push masks the
+                # list's pairs by ``active[job]`` itself
+                st = np.where(np.concatenate([[True], act_h, act_h]),
+                              st, 0)
         c_count = int(st[0])
         mass = int(st[1 + K:].sum(dtype=np.int64))
         # per-level label mask (mixed-label hops chains): this level's
@@ -1479,20 +1742,48 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
             if lm is not None:
                 tb_l, masked_l = lm, True
         p_cap = _td_cap(g, K, mass, c_count, masked_l)
-        devprof.count_level("bu" if p_cap is None else "td")
-        if p_cap is not None:
+        if p_cap is None and not planned:
+            # a pull reads the plan's bitmaps and candidate list: made
+            # only now that one will (the overlay's scatter reads them
+            # too, and under an overlay every level plans)
+            fbits, cand, st = plan(False)
+            c_count = int(st[0])
+        if p_cap is None:
+            devprof.count_level("bu", "none")
+            lst = st = None
+        else:
             # top-down: a push from the frontier's (job, vertex) pairs,
-            # BEFORE the overlay pass below — it reads the frontier
-            # from dist, and in hops mode the overlay's max-scatter
-            # may re-stamp a frontier vertex to level + 1
+            # BEFORE the overlay pass below — in hops mode the
+            # overlay's max-scatter may re-stamp a frontier vertex to
+            # level + 1. The pairs are the list the program before left
+            # where it holds them all within this rung, else listed
+            # from dist (the scan road)
+            road = "carried" if lst is not None and held <= p_cap \
+                else "scan"
+            devprof.count_level("td", road)
+            want = hand_on and level + 1 < max_levels
             with phase("bfs.sweep", level=level, dir="td", p_cap=p_cap,
-                       mass=mass) as ph:
-                dist, pushed = btd(dist, active, dev_scalar(level),
-                                   dstT, colstart, degc, p_cap=p_cap,
-                                   n_=n, expand=expand)
+                       mass=mass, list=road) as ph:
+                if road == "scan":
+                    lst = _batched_list()(
+                        dist, active, dev_scalar(level),
+                        dev_scalar(caps.index(p_cap)), degc, caps=caps,
+                        n_=n)
+                dist, nj, nv, ncount, pushed = btd(
+                    dist, *lst, active, dev_scalar(level),
+                    dev_scalar(int(want)), dstT, colstart, degc,
+                    p_cap=p_cap, n_=n, expand=expand,
+                    lists=_td_lists(p_cap, n))
                 with ph.sync():
-                    pairs = int(np.asarray(pushed)[0])
-                ph.set(pairs=pairs)
+                    got = np.asarray(pushed)
+                devprof.count_d2h("bfs.stats", got.nbytes)
+                ph.set(pairs=int(got[0]), handed=int(got[2]))
+            # what the push left for the level after it: statistics
+            # where it deduped its targets, the list too where that
+            # fits the list's capacity
+            held = int(got[2])
+            st = got[3:] if held >= 0 else None
+            lst = (nj, nv, ncount) if 0 <= held <= caps[-1] else None
             c_count = 0
         if oscat is not None:
             # overlay add-edges expand top-down off the level's final
@@ -1533,7 +1824,9 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
                         expand=expand)
                 cand, off = pad(cand), pad(off)
                 with ph.sync():
-                    c_count, rem8 = (int(x) for x in np.asarray(prog))
+                    left = np.asarray(prog)
+                devprof.count_d2h("bfs.stats", left.nbytes)
+                c_count, rem8 = (int(x) for x in left)
                 ph.set(c_count=c_count, rem8=rem8)
             rounds += fuse
         if c_count > 0:
@@ -1552,11 +1845,7 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
     if act_h.any():
         completed[act_h] = True
         levels[act_h] = level
-    out = dist[:, :n]
-    if not return_device:
-        devprof.count_d2h("bfs.dist", out.nbytes)
-        out = np.asarray(out)
-    return out, levels, completed
+    return dist, levels, completed
 
 
 def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,

@@ -213,12 +213,16 @@ def count_d2h(site: str, nbytes: int) -> None:
             prof.on_xfer("d2h", site, int(nbytes))
 
 
-def count_level(direction: str) -> None:
+def count_level(direction: str, road: str) -> None:
     """Count one level of a batched BFS by the direction it took
-    (``"td"`` push or ``"bu"`` pull; models/bfs_hybrid._td_cap)."""
+    (``"td"`` push or ``"bu"`` pull; models/bfs_hybrid._td_cap) and by
+    where a push had its frontier's pairs from (``"carried"``: the
+    program before left the list; ``"scan"``: listed from dist, n wide;
+    ``"none"`` for a pull)."""
     for prof in list(_PROFILERS):
         prof.metrics.counter("device.bfs.levels",
-                             labels={"dir": direction}).inc()
+                             labels={"dir": direction,
+                                     "list": road}).inc()
 
 
 def current() -> Optional["DeviceCostProfiler"]:

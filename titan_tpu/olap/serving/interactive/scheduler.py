@@ -426,9 +426,11 @@ class InteractiveLane:
     def _build_shapes(self, g) -> None:
         """Before the first answer against a layout of a new shape: run
         a single-start hops batch of every padded size the collector
-        can fuse, and every rung of the top-down step at that size. A
-        level's rung follows its frontier's mass and a batch's size
-        follows the arrivals, so no warm-up by traffic covers them, and
+        can fuse (seed, push, extract), and at that size the plan, the
+        listing and every rung of the top-down step. A level's rung
+        follows its frontier's mass, its road what the level before
+        left it, and a batch's size the arrivals, so no warm-up by
+        traffic covers them, and
         a shape met first inside a served window stalls it for the
         build. The executables are keyed by the layout's shape, not by
         the layout: the next epoch's layout of the same shape builds
@@ -467,9 +469,8 @@ class InteractiveLane:
         list of dense start ids a member) over a batch padded to
         ``len(depths_p)`` rows. Returns ``(masks [Kp, n] device bool,
         sizes np int32 [Kp])``: row k's hop set at its own depth."""
-        import jax.numpy as jnp
-
-        from titan_tpu.models.bfs_hybrid import frontier_bfs_batched
+        from titan_tpu.models.bfs_hybrid import (hop_extract,
+                                                 batched_bfs_state)
         from titan_tpu.obs import devprof
 
         n = g["n"]
@@ -485,29 +486,28 @@ class InteractiveLane:
             # DEVICE through the kernel's sources path — no [Kp, n]
             # host init array, no O(n) H2D per query
             srcs = [ds[0] for ds in seeds] + [0] * (Kp - len(seeds))
-            dist, _levels, _completed = frontier_bfs_batched(
+            dist, _levels, _completed = batched_bfs_state(
                 g, srcs, max_levels=D + 1, start_level=1,
                 on_level=on_level, overlay=overlay, mode="hops",
-                level_masks=level_masks, return_device=True)
+                level_masks=level_masks)
         else:
             # multi-start members (V(id1, id2, ...)): rarer — pay the
             # dense init upload
             init = np.zeros((Kp, n), np.int32)
             for k, ds in enumerate(seeds):
                 init[k, ds] = 1
-            dist, _levels, _completed = frontier_bfs_batched(
+            dist, _levels, _completed = batched_bfs_state(
                 g, [0] * Kp, max_levels=D + 1, start_level=1,
                 init_dist=init, on_level=on_level, overlay=overlay,
-                mode="hops", level_masks=level_masks,
-                return_device=True)
+                mode="hops", level_masks=level_masks)
         # hop-set extraction stays DEVICE-side: one [Kp] size readback,
         # then a compacted index list per id/values member — never the
         # O(n) dist row (a scale-26 row is a ~270 MB D2H transfer)
         with phase("extract", Kp=Kp) as ph:
-            want = jnp.asarray(np.asarray(depths_p, np.int32) + 1)
-            masks = dist == want[:, None]
+            masks, sizes = hop_extract()(
+                dist, np.asarray(depths_p, np.int32) + 1, n_=n)
             with ph.sync():
-                sizes = np.asarray(masks.sum(axis=1, dtype=jnp.int32))
+                sizes = np.asarray(sizes)
             devprof.count_d2h("interactive.sizes", int(sizes.nbytes))
         return masks, sizes
 
