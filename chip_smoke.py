@@ -183,7 +183,6 @@ def phase_device(chips: int) -> dict:
 
     from titan_tpu import native
     from titan_tpu.olap.serving import hbm
-    from titan_tpu.ops import pallas_frontier as pf
     from titan_tpu.utils.jitcache import enable_compile_cache
 
     devs = jax.devices()
@@ -197,15 +196,11 @@ def phase_device(chips: int) -> dict:
     log(f"phase 0 device: jax {jax.__version__} platform={dev.platform} "
         f"kind={dev.device_kind!r} count={len(devs)} bytes_limit={limit} "
         f"compile_cache={jax.config.jax_compilation_cache_dir} "
-        f"native={native.available} "
-        f"frontier_kernel_mode={pf.frontier_kernel_mode()} "
-        f"frontier_interpret={pf.frontier_interpret()}")
+        f"native={native.available}")
     check(native.available,
           "titan_tpu.native did not build/load: the numpy R-MAT generator "
           "yields a different graph for the same seed")
     if dev.platform == "tpu":
-        check(not pf.frontier_interpret(),
-              "frontier_interpret() is true on a TPU backend")
         check(limit is not None, "device reports no memory_stats bytes_limit")
         check(hbm.DEFAULT_BUDGET_BYTES <= limit,
               f"hbm.DEFAULT_BUDGET_BYTES {hbm.DEFAULT_BUDGET_BYTES:.3e} "

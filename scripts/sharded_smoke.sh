@@ -58,26 +58,6 @@ assert calls == sum(disp), (calls, disp)
 assert prof.compiles() == 0, \
     f"warm run minted {prof.compiles()} new compile buckets"
 
-# Pallas frontier-kernel leg (ISSUE 16): the fused bottom-up kernel in
-# interpreter mode through the same sharded path — bit-equal to the
-# single-chip hybrid, the same per-level dispatch profile, and zero new
-# compile buckets once warm (the pallas path registers under its own
-# shx_bu_pallas key, so flag flips never alias stale executables)
-import os
-os.environ["TITAN_TPU_FRONTIER_KERNEL"] = "pallas"
-d_pal, lv_pal = S.frontier_bfs_hybrid_sharded(snap, source, mesh)  # warm
-assert (np.asarray(d_pal) == np.asarray(d_ref)).all() and lv_pal == lv_ref, \
-    "pallas sharded BFS diverged from the single-chip hybrid"
-disp_pal = [p["dispatches"] for p in S.LAST_PROFILE]
-assert disp_pal == disp, (disp_pal, disp)
-prof_pal = DeviceCostProfiler()
-with prof_pal:
-    d_pal2, _ = S.frontier_bfs_hybrid_sharded(snap, source, mesh)
-assert (np.asarray(d_pal2) == np.asarray(d_ref)).all()
-assert prof_pal.compiles() == 0, \
-    f"pallas warm run minted {prof_pal.compiles()} new compile buckets"
-os.environ.pop("TITAN_TPU_FRONTIER_KERNEL", None)
-
 # sparse exchange: path graph — frontier is 1 vertex/level, caps stay tiny
 n = 96
 psnap = snap_mod.from_arrays(
@@ -90,6 +70,5 @@ assert max(S.LAST_EXCHANGE_CAPS) <= 8 < n, S.LAST_EXCHANGE_CAPS
 
 print(f"SHARDED_SMOKE_OK scale={scale} levels={levels} "
       f"dispatches_per_level_max={max(disp)} "
-      f"pallas_leg=bit_equal "
       f"path_exchange_cap_max={max(S.LAST_EXCHANGE_CAPS)}")
 EOF

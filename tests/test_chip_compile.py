@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from titan_tpu.ops.pallas_frontier import TPU_REFUSAL
-
 N = 1 << 20            # vertices, scale 20
 E = 2 * 16 * N         # symmetrised R-MAT edges, edge factor 16
 Q = 4_563_400          # chunk columns of that graph (seed 2)
@@ -179,33 +177,3 @@ def test_pagerank_window(spec, rows):
              spec(lead + (N + 1,), jnp.float32), spec((), jnp.int32),
              spec((8, Q), jnp.int32), spec((Q,), jnp.int32), W=1 << 22)
 
-
-# -- the Pallas frontier kernel: refused (ROADMAP S5 ports it) --------------
-
-@pytest.mark.xfail(strict=True, raises=ValueError,
-                   reason="the chip's compiler refuses frontier_round "
-                          f"(ROADMAP S5's port flips this): {TPU_REFUSAL}")
-def test_pallas_frontier_round(spec):
-    from titan_tpu.ops.pallas_frontier import frontier_round
-
-    C = 1 << 17
-    _compile(functools.partial(frontier_round, lanes=4, fill0=N, fill1=0,
-                               interpret=False),
-             spec((C,), jnp.int32), spec((K, C), jnp.bool_),
-             spec((C,), jnp.bool_), spec((C,), jnp.int32),
-             spec((C,), jnp.int32), spec((K, N // 8), jnp.uint8), None,
-             spec((8, Q), jnp.int32))
-
-
-def test_pallas_frontier_opt_in_raises_on_tpu(monkeypatch):
-    """The refusal is reported at the gate, not at job time — and
-    interpret mode cannot be reached on a TPU backend."""
-    from titan_tpu.ops import pallas_frontier as pf
-
-    monkeypatch.setenv("TITAN_TPU_FRONTIER_KERNEL", "pallas")
-    assert pf.frontier_kernel_mode() == "pallas"      # CPU: interpreter
-    assert pf.frontier_interpret() is True
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert pf.frontier_interpret() is False
-    with pytest.raises(RuntimeError, match="Shape mismatch in input"):
-        pf.frontier_kernel_mode()

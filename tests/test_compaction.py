@@ -183,12 +183,12 @@ def test_op_scan_ban_auto_discovers_the_tree():
     n-wide ``jnp.nonzero`` is banned — every compaction goes through
     ops.compaction. The guard used to be a hand-maintained module list
     with per-directory count pins here that every PR had to bump;
-    it is now graftlint rule R1 (tools/graftlint, scope ``titan_tpu/``
-    + ``bench.py``), which AUTO-DISCOVERS the tree. This test keeps the
+    it is now graftlint rule R1 (tools/graftlint, scope
+    ``titan_tpu/``), which AUTO-DISCOVERS the tree. This test keeps the
     coverage contract explicit: the walk must still reach every
-    previously-pinned directory, and the two reference-model
-    exemptions (bfs.py, bfs_hybrid_fused.py — not round-loop hot
-    paths) must be VISIBLE file-level suppressions, not blind spots."""
+    previously-pinned directory, and the reference-model exemption
+    (bfs.py — not a round-loop hot path) must be a VISIBLE file-level
+    suppression, not a blind spot."""
     import os
     import sys
 
@@ -197,7 +197,7 @@ def test_op_scan_ban_auto_discovers_the_tree():
         sys.path.insert(0, repo)
     from tools.graftlint.engine import Linter
 
-    result = Linter(root=repo).run(["titan_tpu", "bench.py"])
+    result = Linter(root=repo).run(["titan_tpu"])
     assert [f"{f.path}:{f.line}: {f.message}"
             for f in result.unsuppressed
             if f.rule == "opscan"] == []
@@ -207,8 +207,7 @@ def test_op_scan_ban_auto_discovers_the_tree():
     for must in ("titan_tpu/models/frontier.py",
                  "titan_tpu/models/bfs_hybrid.py",
                  "titan_tpu/models/bfs_hybrid_sharded.py",
-                 "titan_tpu/ops/epoch_merge.py",
-                 "bench.py"):
+                 "titan_tpu/ops/epoch_merge.py"):
         assert must in scanned, must
     for pkg in ("titan_tpu/olap/serving/",
                 "titan_tpu/olap/serving/interactive/",
@@ -217,12 +216,10 @@ def test_op_scan_ban_auto_discovers_the_tree():
                 # ISSUE 19: the fleet tier joined with zero config
                 "titan_tpu/olap/fleet/"):
         assert any(p.startswith(pkg) for p in scanned), pkg
-    # the exemptions stay visible: suppressed findings with reasons
+    # the exemption stays visible: suppressed findings with reasons
     exempt = [f for f in result.findings
               if f.rule == "opscan" and f.suppressed == "file"]
-    assert {f.path for f in exempt} == {
-        "titan_tpu/models/bfs.py",
-        "titan_tpu/models/bfs_hybrid_fused.py"}
+    assert {f.path for f in exempt} == {"titan_tpu/models/bfs.py"}
 
 
 def test_op_scan_ban_covers_new_subdirectories_zero_config(tmp_path):

@@ -134,22 +134,13 @@ def test_zero_recompiles_on_warm_smoke_shapes(snap):
         labels={"kernel": "batched_plan"}) > 0
 
 
-def test_pallas_path_zero_recompiles_and_kernel_labels(snap,
-                                                       monkeypatch):
-    """TITAN_TPU_FRONTIER_KERNEL=pallas (ISSUE 16): the Pallas bottom-up
-    wrappers register through jit_once like every XLA kernel, so they
-    carry the same warm-shape contract — one warm pass, then zero new
-    compile buckets — and show up under the device.exec.* {kernel}
-    labels the decision plane reads."""
-    import titan_tpu.models.bfs_hybrid as H
-
-    monkeypatch.setenv("TITAN_TPU_FRONTIER_KERNEL", "pallas")
-    # route the plain driver through the bottom-up chain at smoke scale
-    # (tests/test_pallas_frontier.py idiom)
-    monkeypatch.setattr(H, "SPLIT_LANE_MIN", 2)
-    monkeypatch.setattr(H, "END_C_CAP", 0)
-    monkeypatch.setattr(H, "END_P_CAP", 0)
-    monkeypatch.setattr(H, "HEAD_F_CAP", 1)
+def test_bottom_up_path_zero_recompiles_and_kernel_labels(
+        snap, force_bottom_up):
+    """The bottom-up chain, which the smoke shape never reaches under
+    the default thresholds: its kernels register through jit_once like
+    every other, so they carry the same warm-shape contract — one warm
+    pass, then zero new compile buckets — and show up under the
+    device.exec.* {kernel} labels the decision plane reads."""
     rng = np.random.default_rng(7)
     nz = np.flatnonzero(snap.out_degree > 0)
     s8 = [int(s) for s in rng.choice(nz, size=8, replace=True)]
@@ -162,11 +153,10 @@ def test_pallas_path_zero_recompiles_and_kernel_labels(snap,
         for fn in workloads:
             fn()
         assert prof.compiles() == 0, (
-            f"pallas path recompiled warm: {prof.compile_log()[-3:]}")
+            f"bottom-up path recompiled warm: {prof.compile_log()[-3:]}")
     kernels = prof.kernel_stats()
-    assert "pallas_bu_start" in kernels, sorted(kernels)
-    assert "pallas_batched_bu" in kernels, sorted(kernels)
-    for kern in ("pallas_bu_start", "pallas_batched_bu"):
+    for kern in ("hybrid_bu_startL", "batched_bu"):
+        assert kern in kernels, sorted(kernels)
         assert mm.counter_value("device.exec.calls",
                                 labels={"kernel": kern}) > 0
 

@@ -107,53 +107,3 @@ def test_matches_dense_program():
     res = comp.run(BFS(max_iterations=300), params={"source_dense": 0},
                    snapshot=snap)
     assert np.array_equal(np.asarray(res["dist"]), dist)
-
-
-@pytest.mark.parametrize("seed,shards", [(0, 1), (1, 3), (2, 5), (3, 2)])
-def test_tiled_matches_reference(seed, shards):
-    """Tiled path with tiny tiles/shards so every mechanism fires: multiple
-    vertex-range shards, multiple slices per level (edge-budget AND
-    frontier-count splits), partial last slices."""
-    from titan_tpu.models.bfs import frontier_bfs_tiled
-    rng = np.random.default_rng(seed)
-    n = int(rng.integers(50, 700))
-    e = int(rng.integers(10, n * 6))
-    src = rng.integers(0, n, e).astype(np.int32)
-    dst = rng.integers(0, n, e).astype(np.int32)
-    snap = snap_mod.from_arrays(n, src, dst)
-    s0 = int(src[0])
-    max_shard_edges = max(1, e // shards)
-    dist, levels = frontier_bfs_tiled(
-        snap, s0, f_tile=16, m_tile=64, max_shard_edges=max_shard_edges,
-        k_max=max(64, 4 * n // 16 + 8))
-    ref = np_bfs(n, src, dst, s0)
-    assert np.array_equal(np.where(dist >= (1 << 30), 1 << 30, dist), ref)
-    finite = ref[ref < (1 << 30)]
-    assert levels >= int(finite.max()) if len(finite) else levels == 0
-
-
-def test_tiled_hub_heavier_than_tile():
-    """A hub vertex whose degree exceeds the requested m_tile must not be
-    dropped (the tile auto-grows to 2x max degree)."""
-    from titan_tpu.models.bfs import frontier_bfs_tiled
-    n = 200
-    hub_edges = np.arange(1, 150, dtype=np.int32)
-    src = np.concatenate([np.zeros(len(hub_edges), np.int32),
-                          np.array([150], np.int32)])
-    dst = np.concatenate([hub_edges, np.array([151], np.int32)])
-    snap = snap_mod.from_arrays(n, src, dst)
-    dist, levels = frontier_bfs_tiled(snap, 0, f_tile=8, m_tile=16,
-                                      max_shard_edges=64)
-    ref = np_bfs(n, src, dst, 0)
-    assert np.array_equal(np.where(dist >= (1 << 30), 1 << 30, dist), ref)
-
-
-def test_tiled_chain_many_levels():
-    from titan_tpu.models.bfs import frontier_bfs_tiled
-    n = 300
-    src = np.arange(n - 1, dtype=np.int32)
-    dst = np.arange(1, n, dtype=np.int32)
-    snap = snap_mod.from_arrays(n, src, dst)
-    dist, levels = frontier_bfs_tiled(snap, 0, f_tile=4, m_tile=8,
-                                      max_shard_edges=50)
-    assert levels == n - 1 and dist[-1] == n - 1

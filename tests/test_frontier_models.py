@@ -11,8 +11,10 @@ import pytest
 from titan_tpu.models import bfs_hybrid as H
 from titan_tpu.models import frontier as F
 from titan_tpu.models.bfs import INF, frontier_bfs
+from titan_tpu.obs.devprof import DeviceCostProfiler
 from titan_tpu.olap.tpu import snapshot as snap_mod
 from titan_tpu.olap.tpu.rmat import rmat_edges
+from titan_tpu.utils.metrics import MetricManager
 
 
 def sym_snap(rng, n, m):
@@ -275,16 +277,10 @@ def test_hybrid_max_levels_truncates():
 
 
 @pytest.mark.parametrize("seed", [7, 8])
-def test_hybrid_bfs_split_lane_opener_matches(seed, monkeypatch):
+def test_hybrid_bfs_split_lane_opener_matches(seed, force_bottom_up):
     """Force the split-lane bottom-up opener (4-lane test + lanes-4-7
     refetch) on small graphs and check bit-equality with the plain BFS
     (in production it only engages above 2^21 candidates)."""
-    monkeypatch.setattr(H, "SPLIT_LANE_MIN", 2)
-    # also disable the fused endgame + head fast paths so the bu0a/bu0b
-    # opener actually runs on these tiny graphs
-    monkeypatch.setattr(H, "END_C_CAP", 0)
-    monkeypatch.setattr(H, "END_P_CAP", 0)
-    monkeypatch.setattr(H, "HEAD_F_CAP", 1)
     rng = np.random.default_rng(seed)
     n = int(rng.integers(100, 500))
     snap = sym_snap(rng, n, int(rng.integers(2 * n, 8 * n)))
@@ -294,11 +290,7 @@ def test_hybrid_bfs_split_lane_opener_matches(seed, monkeypatch):
     assert (d_ref == np.asarray(d_hyb)).all()
 
 
-def test_hybrid_bfs_split_lane_rmat(monkeypatch):
-    monkeypatch.setattr(H, "SPLIT_LANE_MIN", 2)
-    monkeypatch.setattr(H, "END_C_CAP", 0)
-    monkeypatch.setattr(H, "END_P_CAP", 0)
-    monkeypatch.setattr(H, "HEAD_F_CAP", 1)
+def test_hybrid_bfs_split_lane_rmat(force_bottom_up):
     src, dst = rmat_edges(11, 8, seed=9)
     n = 1 << 11
     snap = snap_mod.from_arrays(n, np.concatenate([src, dst]),
@@ -309,48 +301,69 @@ def test_hybrid_bfs_split_lane_rmat(monkeypatch):
     assert (d_ref == np.asarray(d_hyb)).all()
 
 
-# ---------------------------------------------------------------- fused BFS
+# ------------------------------------------- graphs the survivors all run
 
-import titan_tpu.models.bfs_hybrid_fused as FU
-
-
-@pytest.mark.parametrize("seed", [4, 5])
-def test_fused_bfs_matches_reference(seed, monkeypatch):
-    """Single-dispatch BFS (device-side mode + bucket switching) is
-    bit-equal to the plain BFS; endgame disabled so the td/bu ladder
-    branches actually execute on CPU-sized graphs."""
-    monkeypatch.setattr(FU, "END_C_CAP", 1)
-    monkeypatch.setattr(FU, "END_P_CAP", 1)
-    rng = np.random.default_rng(seed)
-    n = int(rng.integers(80, 400))
-    snap = sym_snap(rng, n, int(rng.integers(2 * n, 8 * n)))
-    source = int(np.flatnonzero(snap.out_degree > 0)[0])
-    d_ref, _ = frontier_bfs(snap, source)
-    d_f, _ = FU.frontier_bfs_hybrid_fused(snap, source)
-    assert (d_ref == np.asarray(d_f)).all()
+def _path(n):
+    src = np.arange(n - 1, dtype=np.int32)
+    return n, src, src + 1
 
 
-def test_fused_bfs_rmat_and_endgame():
+def _rmat11():
     src, dst = rmat_edges(11, 8, seed=4)
-    n = 1 << 11
+    return 1 << 11, src, dst
+
+
+def _hub():
+    """A hub of 149 leaves (19 chunk columns: more than the bottom-up
+    rounds check before the exhaust sweep) and an edge it cannot
+    reach."""
+    leaves = np.arange(1, 150, dtype=np.int32)
+    return (200, np.concatenate([np.zeros(149, np.int32), [150]]),
+            np.concatenate([leaves, [151]]))
+
+
+#: name -> (edges, source, other starts of a K = 4 batch, max_levels)
+SHARED_GRAPHS = {
+    # many levels, cut short: max_levels is honoured by every driver
+    "path-cut": (lambda: _path(300), 0, [299, 150, 7], 40),
+    # the single-source run reaches the endgame under the default caps
+    "rmat-endgame": (_rmat11, None, [5, 77, 1030], 1000),
+    # from the leaf in the hub's LAST chunk column
+    "hub": (_hub, 149, [0, 150, 42], 1000),
+    "long-chain": (lambda: _path(300), 0, [299, 150, 7], 1000),
+}
+
+
+@pytest.mark.parametrize("runner", ["hybrid", "batched-k1", "batched-k4"])
+@pytest.mark.parametrize("name", list(SHARED_GRAPHS))
+def test_shared_graphs_match_reference(name, runner):
+    make, source, others, max_levels = SHARED_GRAPHS[name]
+    n, src, dst = make()
     snap = snap_mod.from_arrays(n, np.concatenate([src, dst]),
                                 np.concatenate([dst, src]))
-    source = int(np.flatnonzero(snap.out_degree > 0)[0])
-    d_ref, _ = frontier_bfs(snap, source)
-    d_f, _ = FU.frontier_bfs_hybrid_fused(snap, source)
-    assert (d_ref == np.asarray(d_f)).all()
-
-
-def test_fused_bfs_path_graph(monkeypatch):
-    monkeypatch.setattr(FU, "END_C_CAP", 1)
-    monkeypatch.setattr(FU, "END_P_CAP", 1)
-    n = 300
-    src = np.arange(n - 1, dtype=np.int32)
-    snap = snap_mod.from_arrays(n, np.concatenate([src, src + 1]),
-                                np.concatenate([src + 1, src]))
-    d_ref, _ = frontier_bfs(snap, 0)
-    d_f, lv = FU.frontier_bfs_hybrid_fused(snap, 0)
-    assert (d_ref == np.asarray(d_f)).all() and lv >= n - 1
+    if source is None:
+        source = int(np.flatnonzero(snap.out_degree > 0)[0])
+    starts = [source] + (others if runner == "batched-k4" else [])
+    want = np.stack([frontier_bfs(snap, s, max_levels=max_levels)[0]
+                     for s in starts])
+    if runner == "hybrid":
+        with DeviceCostProfiler(metrics=MetricManager()) as prof:
+            dist, levels = H.frontier_bfs_hybrid(snap, source,
+                                                 max_levels=max_levels)
+        got, levels = np.asarray(dist)[None], [levels]
+        if name == "rmat-endgame":
+            assert "hybrid_endgame" in prof.kernel_stats()
+    else:
+        got, levels, completed = H.frontier_bfs_batched(
+            snap, starts, max_levels=max_levels)
+        assert completed.all()
+    assert np.array_equal(got, want)
+    if name == "path-cut":
+        assert (want[0, :41] == np.arange(41)).all() \
+            and (want[0, 41:] >= INF).all()
+        assert max(levels) <= max_levels
+    if name == "long-chain":
+        assert levels[0] >= n - 1
 
 
 def test_sssp_quantile_matches_plain():
@@ -369,44 +382,6 @@ def test_sssp_quantile_matches_plain():
     d_q, r_q = frontier_sssp(snap, source, quantile_mass=64)
     d_p, r_p = frontier_sssp(snap, source, quantile_mass=0)
     assert np.allclose(d_q, d_p, rtol=1e-6)
-
-
-def test_fused_bfs_overflow_falls_back(monkeypatch):
-    """A bu level whose candidate set exceeds the trimmed bucket ladder
-    must set the overflow stat and transparently re-run host-driven —
-    never truncate candidates (wrong distances)."""
-    monkeypatch.setattr(FU, "END_C_CAP", 1)
-    monkeypatch.setattr(FU, "END_P_CAP", 1)
-    # shrink the whole bu ladder (FUSED_BU_MAX alone is floored by the
-    # 2^23 bucket, which covers any CPU-test graph) and rebuild the
-    # cached jit so the tiny ladder actually traces
-    orig_ladders = FU._ladders
-
-    def tiny_ladders(n, total_chunks):
-        td, bu, cap_n, cap_q = orig_ladders(n, total_chunks)
-        return td, [8], cap_n, cap_q
-
-    monkeypatch.setattr(FU, "_ladders", tiny_ladders)
-    from titan_tpu.utils import jitcache
-    monkeypatch.delitem(jitcache._JITS, "hybrid_fused", raising=False)
-    # record that the host-driven fallback actually ran
-    called = []
-    real = H.frontier_bfs_hybrid
-
-    def spy(*a, **kw):
-        called.append(1)
-        return real(*a, **kw)
-
-    monkeypatch.setattr(H, "frontier_bfs_hybrid", spy)
-    src, dst = rmat_edges(11, 8, seed=6)
-    n = 1 << 11
-    snap = snap_mod.from_arrays(n, np.concatenate([src, dst]),
-                                np.concatenate([dst, src]))
-    source = int(np.flatnonzero(snap.out_degree > 0)[0])
-    d_ref, _ = frontier_bfs(snap, source)
-    d_f, _ = FU.frontier_bfs_hybrid_fused(snap, source)
-    assert called, "overflow did not route through the host fallback"
-    assert (d_ref == np.asarray(d_f)).all()
 
 
 def test_sssp_quantile_list_truncation_is_sound(monkeypatch):

@@ -10,10 +10,16 @@ walks the tree. This guard keeps the package tree honest:
   is dead code wearing a live extension;
 * no directory under ``titan_tpu/`` is pycache-only (its only contents,
   recursively, are ``__pycache__`` artifacts) — compiled leftovers must
-  not outlive the source tree that produced them.
+  not outlive the source tree that produced them;
+* no kernel is chosen by an environment variable: nothing under
+  ``titan_tpu/models`` or ``titan_tpu/ops`` reads the environment (one
+  known debt, listed);
+* every path the lint CLI defaults to exists, so a deleted file cannot
+  stay in its scope unnoticed.
 """
 
 import os
+import re
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PKG = os.path.join(_REPO, "titan_tpu")
@@ -51,3 +57,37 @@ def test_no_pycache_only_directories():
         f"pycache-only directories (stale build leftovers): {ghosts} — "
         f"delete them; compiled artifacts must not outlive their "
         f"source")
+
+
+#: ROADMAP D3: TITAN_TPU_SEGMENT_KERNEL (scan | native | pallas) waits
+#: for a cell that runs SSSP / WCC (R3) to settle it
+_ENV_READ_DEBTS = {"titan_tpu/ops/segment.py"}
+
+
+def test_no_kernel_reads_the_environment():
+    readers = set()
+    for sub in ("models", "ops"):
+        for dirpath, dirnames, filenames in os.walk(os.path.join(_PKG, sub)):
+            dirnames[:] = [d for d in dirnames if d != "__pycache__"]
+            for name in filenames:
+                path = os.path.join(dirpath, name)
+                if name.endswith(".py") and re.search(
+                        r"\bos\.(environ|getenv)\b|\bfrom os import",
+                        open(path, encoding="utf-8").read()):
+                    readers.add(os.path.relpath(path, _REPO))
+    assert readers == _ENV_READ_DEBTS, (
+        f"kernel modules that read the environment: "
+        f"{sorted(readers - _ENV_READ_DEBTS)} — which program runs is "
+        f"decided from what the code can observe, not by a variable; "
+        f"settled debts to strike: {sorted(_ENV_READ_DEBTS - readers)}")
+
+
+def test_lint_default_paths_exist():
+    import sys
+    if _REPO not in sys.path:
+        sys.path.insert(0, _REPO)
+    from tools.graftlint.__main__ import DEFAULT_PATHS
+
+    missing = [p for p in DEFAULT_PATHS
+               if not os.path.exists(os.path.join(_REPO, p))]
+    assert DEFAULT_PATHS and not missing, missing

@@ -2,7 +2,7 @@
 
 Two jobs:
 
-* ENFORCEMENT — the full titan_tpu/ + bench.py tree must lint clean
+* ENFORCEMENT — the full titan_tpu/ + tests/ tree must lint clean
   (zero unsuppressed findings) inside the 30 s serial-CPU wall budget.
   This is the tier-1 teeth of the op-scan ban and its sibling
   invariants; the per-directory module-count pins it replaced lived in
@@ -42,7 +42,7 @@ def fixture_result():
 
 @pytest.fixture(scope="module")
 def repo_result():
-    return Linter(root=REPO).run(["titan_tpu", "tests", "bench.py"])
+    return Linter(root=REPO).run(["titan_tpu", "tests"])
 
 
 def _in(result, rel):
@@ -201,16 +201,14 @@ def test_inline_suppressions_and_bare_allow(fixture_result):
 
 
 def test_allow_file_suppresses_reference_models(repo_result):
-    """The two non-round-loop reference models carry file-level
-    suppressions for the op-scan ban — the findings still EXIST (the
+    """The non-round-loop reference model carries a file-level
+    suppression for the op-scan ban — the findings still EXIST (the
     exemption is visible, not invisible) but are suppressed with the
     recorded reason."""
-    for rel in ("titan_tpu/models/bfs.py",
-                "titan_tpu/models/bfs_hybrid_fused.py"):
-        got = _in(repo_result, rel)
-        assert got, f"expected suppressed opscan findings in {rel}"
-        assert all(f.suppressed == SUPPRESSED_FILE for f in got)
-        assert all("not a round-loop hot path" in f.reason for f in got)
+    got = _in(repo_result, "titan_tpu/models/bfs.py")
+    assert got, "expected suppressed opscan findings in models/bfs.py"
+    assert all(f.suppressed == SUPPRESSED_FILE for f in got)
+    assert all("not a round-loop hot path" in f.reason for f in got)
 
 
 # ---------------------------------------------------------------------------
@@ -256,9 +254,9 @@ def test_baseline_grandfathers_then_catches_new(tmp_path):
 def test_baseline_auto_loaded_by_every_surface(tmp_path):
     """The checked-in baseline must bind EVERY enforcement surface the
     same way: a bare Linter(root=...) auto-loads
-    tools/graftlint/baseline.json under its root (the CLI, tier-1
-    tests, and bench's lint_clean line can never disagree about the
-    same tree). Opt out explicitly with baseline=Baseline()."""
+    tools/graftlint/baseline.json under its root (the CLI and the
+    tier-1 tests can never disagree about the same tree). Opt out
+    explicitly with baseline=Baseline()."""
     root = _mktree(tmp_path)
     first = Linter(root=str(root)).run(["titan_tpu"])
     assert len(first.unsuppressed) == 1
@@ -372,7 +370,7 @@ def test_rule_catalog_ids_and_aliases():
 
 def test_full_tree_zero_unsuppressed_findings(repo_result):
     """THE invariant gate (acceptance: `python -m tools.graftlint
-    titan_tpu tests bench.py` exits 0). A finding here means new code
+    titan_tpu tests` exits 0). A finding here means new code
     broke an invariant — fix it or suppress inline WITH a reason."""
     pretty = "\n".join(
         f"{f.path}:{f.line}: [{f.rule}] {f.message}"
@@ -384,21 +382,5 @@ def test_full_tree_zero_unsuppressed_findings(repo_result):
 
 
 def test_full_tree_wall_clock_under_30s(repo_result):
-    """Lint rides tier-1 (870 s serial-CPU budget) — keep it a rounding
-    error."""
+    """Lint rides tier-1 — keep it a rounding error."""
     assert repo_result.wall_s < 30.0, repo_result.wall_s
-
-
-def test_bench_evidence_carries_lint_clean_line():
-    """ROADMAP #5 wiring: chip-day bundles record that the invariants
-    held — a value (clean flag + counts), never silently absent."""
-    import bench
-
-    ev = bench.Evidence.__new__(bench.Evidence)
-    ev.rep = bench.Report.__new__(bench.Report)
-    ev.rep.detail = {}
-    got = ev._lint_clean()
-    assert got["present"] is True
-    val = got["value"]
-    assert val["clean"] is True and val["unsuppressed"] == 0
-    assert val["files"] > 100 and val["suppressed"] >= 11

@@ -162,10 +162,9 @@ def test_sharded_bit_equal_forced_devices_subprocess(ndev):
     """1- and 2-device meshes need their own processes: the forced
     host device count is an XLA init-time flag, and this session is
     pinned to 8 (conftest). Same pattern as the multihost dryrun.
-    Tier-1 budget note: the 1-device case rides the slow tier — the
-    1-device mesh path also runs on every CPU bench (`bfs23_sharded`
-    stage) and in `experiments/sharded_1dev.py`; tier-1 keeps the
-    genuinely-multi-device forced-2 case (8 runs in-process above)."""
+    Tier-1 budget note: the 1-device case rides the slow tier; tier-1
+    keeps the genuinely-multi-device forced-2 case (8 runs in-process
+    above)."""
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"

@@ -284,239 +284,6 @@ def frontier_bfs_sharded(snap, source_dense: int, mesh,
     return np.asarray(dist[:n]), level
 
 
-# ---------------------------------------------------------------------------
-# tiled frontier BFS: fixed-shape slices, device-side planning
-# ---------------------------------------------------------------------------
-#
-# The pow-2 bucket scheme above compiles one kernel per (f_cap, m_cap) pair
-# and pads each level to the next power of two (up to 2x wasted index-op
-# work — the dominant cost, see PERF_NOTES.md). The tiled path instead
-# processes every level as a sequence of FIXED-shape slices (f_tile
-# frontier slots, m_tile edge slots): two jitted functions total, padding
-# bounded by one partial slice per shard per level, and — because slices
-# never cross vertex-range shard boundaries — per-shard LOCAL edge indices
-# stay below 2^31, which is what makes Graph500 scale-26 (2^31 directed
-# edges) runnable on one chip with int32 indices and x64 off.
-
-_TILE_STEP = None
-_TILE_WRAPUP = None
-
-
-def _tile_step():
-    global _TILE_STEP
-    if _TILE_STEP is not None:
-        return _TILE_STEP
-    import functools
-
-    import jax
-
-    @functools.partial(jax.jit,
-                       static_argnames=("f_tile", "m_tile", "n_", "block"),
-                       donate_argnums=(0,))
-    def tile_step(dist, frontier, fb, fcnt, level, base, dst_l, ip_l, deg_l,
-                  f_tile: int, m_tile: int, n_: int, block: int):
-        # frontier: [n_ + f_tile] int32 sorted vertex ids padded with n_;
-        # this slice covers frontier[fb : fb + fcnt], all within the shard
-        # whose vertex block starts at `base`
-        fvert = jax.lax.dynamic_slice(frontier, (fb,), (f_tile,))
-        valid = jnp.arange(f_tile) < fcnt
-        local = jnp.clip(fvert - base, 0, block - 1)
-        degs = jnp.where(valid, deg_l[local], 0)
-        nbr = _expand_neighbors(valid, degs, ip_l[local], dst_l, m_tile, n_)
-        return dist.at[nbr].min(level + 1)
-
-    _TILE_STEP = tile_step
-    return tile_step
-
-
-def _tile_wrapup():
-    global _TILE_WRAPUP
-    if _TILE_WRAPUP is not None:
-        return _TILE_WRAPUP
-    import functools
-
-    import jax
-
-    @functools.partial(
-        jax.jit, static_argnames=("f_tile", "budget", "k_max", "n_",
-                                  "shard_bounds"))
-    def wrapup(dist, level, out_degree, f_tile: int, budget: int,
-               k_max: int, n_: int, shard_bounds: tuple):
-        """After all of level ``level``'s slices: find the next frontier and
-        plan its slices. Returns (frontier, plan, stats) where plan is
-        [num_shards, k_max+1] int32 frontier-index boundaries (slice k of
-        shard d = frontier[plan[d,k] : plan[d,k+1]], stop when it stops
-        advancing) and stats = [nf, m_0, .., m_{S-1}] (per-shard edge
-        totals; int32-safe because each shard holds < 2^31 edges)."""
-        changed = dist[:n_] == level + 1
-        nf = changed.sum().astype(jnp.int32)
-        frontier = jnp.nonzero(changed, size=n_ + f_tile, fill_value=n_)[0] \
-            .astype(jnp.int32)
-        fdeg = jnp.where(changed, out_degree, 0)
-        # global frontier-index prefix: fcp[v] = #frontier vertices <= v
-        fcp = jnp.cumsum(changed.astype(jnp.int32))
-        num_shards = len(shard_bounds) - 1
-        plans = []
-        stats = [nf]
-        for d in range(num_shards):          # static unroll (few shards)
-            lo, hi = shard_bounds[d], shard_bounds[d + 1]
-            inside = (jnp.arange(n_) >= lo) & (jnp.arange(n_) < hi)
-            cumd = jnp.cumsum(jnp.where(inside, fdeg, 0))
-            stats.append(cumd[n_ - 1])
-            f_lo = fcp[lo - 1] if lo > 0 else jnp.int32(0)
-
-            def body(k, state, cumd=cumd, hi=hi):
-                v, plan = state
-                prev_e = jnp.where(v > 0, cumd[jnp.maximum(v - 1, 0)], 0)
-                prev_f = jnp.where(v > 0, fcp[jnp.maximum(v - 1, 0)], 0)
-                nv = jnp.searchsorted(cumd, prev_e + budget, side="right")
-                nv2 = jnp.searchsorted(fcp, prev_f + f_tile, side="right")
-                nv = jnp.minimum(jnp.minimum(nv, nv2), hi).astype(jnp.int32)
-                f_hi = jnp.where(nv > 0, fcp[jnp.maximum(nv - 1, 0)], 0)
-                e_hi = jnp.where(nv > 0, cumd[jnp.maximum(nv - 1, 0)], 0)
-                plan = plan.at[0, k + 1].set(f_hi.astype(jnp.int32))
-                plan = plan.at[1, k + 1].set(e_hi.astype(jnp.int32))
-                return nv, plan
-
-            # plan row 0: frontier-index boundaries; row 1: edge-count
-            # prefix at each boundary (host sizes each slice's kernel)
-            plan0 = jnp.zeros((2, k_max + 1), jnp.int32).at[0, 0].set(f_lo)
-            _, plan = jax.lax.fori_loop(0, k_max, body,
-                                        (jnp.int32(lo), plan0))
-            plans.append(plan)
-        return frontier, jnp.stack(plans), jnp.stack(stats)
-
-    _TILE_WRAPUP = wrapup
-    return wrapup
-
-
-def _shard_out_csr_balanced(snap, max_edges: int):
-    """Vertex-range shards with ≈edge-balanced cuts (each shard's edge count
-    <= max(max_edges, heaviest vertex)), padded to uniform static shapes.
-    Returns (shard_bounds tuple, block, e_max, [(base, dst, ip, deg)])."""
-    import numpy as np
-
-    cache = getattr(snap, "_tiled_shards", None)
-    if cache is not None and cache[0] == max_edges:
-        return cache[1]
-    n = snap.n
-    dst_by_src, indptr_out = snap.out_csr()
-    e_total = int(indptr_out[-1])
-    num = max(1, -(-e_total // max_edges))
-    # cut where the edge prefix crosses k/num of the total
-    cuts = [0]
-    for k in range(1, num):
-        cuts.append(int(np.searchsorted(indptr_out, k * e_total / num)))
-    cuts.append(n)
-    cuts = sorted(set(cuts))
-    bounds = tuple(cuts)
-    num = len(bounds) - 1
-    block = max(1, max(bounds[d + 1] - bounds[d] for d in range(num)))
-    e_max = max(1, max(int(indptr_out[bounds[d + 1]] - indptr_out[bounds[d]])
-                       for d in range(num)))
-    shards = []
-    for d in range(num):
-        lo_v, hi_v = bounds[d], bounds[d + 1]
-        s, e = int(indptr_out[lo_v]), int(indptr_out[hi_v])
-        dst_l = np.full((e_max,), n, np.int32)
-        dst_l[:e - s] = dst_by_src[s:e]
-        ip_l = np.zeros((block + 1,), np.int32)
-        ip = (indptr_out[lo_v:hi_v + 1] - s).astype(np.int32)
-        ip_l[:hi_v - lo_v + 1] = ip
-        ip_l[hi_v - lo_v + 1:] = ip[-1] if len(ip) else 0
-        deg_l = np.zeros((block,), np.int32)
-        deg_l[:hi_v - lo_v] = snap.out_degree[lo_v:hi_v]
-        shards.append((lo_v, jnp.asarray(dst_l), jnp.asarray(ip_l),
-                       jnp.asarray(deg_l)))
-    got = (bounds, block, e_max, shards)
-    snap._tiled_shards = (max_edges, got)
-    return got
-
-
-def frontier_bfs_tiled(snap, source_dense: int, max_levels: int = 1000,
-                       f_tile: int = 1 << 21, m_tile: int = 1 << 27,
-                       max_shard_edges: int = 1 << 30, k_max: int = 96):
-    """Frontier BFS with fixed-shape slices (see block comment above).
-    Works at any scale whose PER-SHARD edge count fits int32 — in
-    particular Graph500 scale-26 (2^31 directed edges) via 2+ shards.
-
-    Returns (dist ndarray [n] int32 with INF for unreachable, levels)."""
-    import numpy as np
-
-    n = snap.n
-    bounds, block, e_max, shards = _shard_out_csr_balanced(
-        snap, max_shard_edges)
-    max_deg = int(snap.out_degree.max()) if n else 0
-    # budget >= max_deg guarantees every slice advances by >= 1 vertex
-    # (a vertex heavier than the budget would otherwise plan empty slices
-    # forever and silently drop the tail of the frontier)
-    m_tile = max(m_tile, 2 * max_deg)
-    m_tile = min(m_tile, max(2 * max_deg, _next_pow2(e_max), 2))
-    budget = max(1, m_tile - max_deg)
-    f_tile = min(f_tile, _next_pow2(n))
-    # enough slice slots that no level can outgrow the plan: a shard's
-    # level needs at most ceil(edges/budget) + ceil(frontier/f_tile)
-    # slices, plus one spare slot that must stay idle (the truncation
-    # check below requires it)
-    k_max = max(k_max,
-                -(-e_max // budget) + -(-block // f_tile) + 2)
-    outdeg_d = getattr(snap, "_dev_outdeg", None)
-    if outdeg_d is None:
-        outdeg_d = jnp.asarray(snap.out_degree.astype(np.int32))
-        snap._dev_outdeg = outdeg_d
-    step = _tile_step()
-    wrap = _tile_wrapup()
-
-    dist = jnp.full((n + 1,), INF, jnp.int32).at[source_dense].set(0)
-    # the source's "level -1 wrapup" plans level 0's slices
-    frontier, plan, stats = wrap(dist, jnp.int32(-1), outdeg_d,
-                                 f_tile=f_tile, budget=budget, k_max=k_max,
-                                 n_=n, shard_bounds=bounds)
-    # per-slice kernel sizing: a light level must not pay the full-tile
-    # shapes, so each slice picks the smallest fitting (f, m) from a short
-    # static ladder (each combination compiles once)
-    f_sizes = sorted({min(1 << 14, f_tile), min(1 << 18, f_tile), f_tile})
-    m_sizes = sorted({min(1 << s, m_tile) for s in (18, 21, 24, 27)}
-                     | {m_tile})
-
-    def pick(sizes, need):
-        for s in sizes:
-            if need <= s:
-                return s
-        return sizes[-1]
-
-    level = 0
-    while level < max_levels:
-        plan_h = np.asarray(plan)
-        stats_h = np.asarray(stats)
-        nf = int(stats_h[0])
-        m_total = sum(int(x) for x in stats_h[1:])
-        if nf == 0 or m_total == 0:
-            break
-        for d, (base, dst_l, ip_l, deg_l) in enumerate(shards):
-            frow, erow = plan_h[d]
-            if frow[k_max] > frow[k_max - 1]:
-                raise RuntimeError(
-                    f"slice plan truncated at k_max={k_max} (shard {d}) — "
-                    f"frontier tail would be silently dropped")
-            for k in range(k_max):
-                fb, fe = int(frow[k]), int(frow[k + 1])
-                if fe <= fb:
-                    break
-                m_slice = int(erow[k + 1]) - int(erow[k])
-                dist = step(dist, frontier, jnp.int32(fb),
-                            jnp.int32(fe - fb), jnp.int32(level),
-                            jnp.int32(base), dst_l, ip_l, deg_l,
-                            f_tile=pick(f_sizes, fe - fb),
-                            m_tile=pick(m_sizes, max(m_slice, 1)),
-                            n_=n, block=block)
-        frontier, plan, stats = wrap(dist, jnp.int32(level), outdeg_d,
-                                     f_tile=f_tile, budget=budget,
-                                     k_max=k_max, n_=n, shard_bounds=bounds)
-        level += 1
-    return np.asarray(dist[:n]), level
-
-
 def frontier_bfs(snap, source_dense: int, max_levels: int = 1000):
     """Host-driven frontier BFS: each level expands ONLY the frontier's
     out-edges, so total index-op work is O(E) for the whole run instead of

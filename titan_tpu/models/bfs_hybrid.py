@@ -677,111 +677,6 @@ def _bu_exhaust():
     return _get("hybrid_ex", build)
 
 
-# --------------------------------------------------------------------------
-# Pallas bottom-up path (TITAN_TPU_FRONTIER_KERNEL=pallas): the fused
-# fetch+test+compact round kernel (ops/pallas_frontier.py) behind the
-# SAME level-step contracts as the XLA chain above — each wrapper is
-# bit-equal to its XLA counterpart (tests/test_pallas_frontier.py pins
-# this in interpreter mode). The exhaust stages (ex/bex) stay XLA in
-# both modes: they are rare straggler sweeps with pair-enumeration
-# shapes the round kernel doesn't model.
-# --------------------------------------------------------------------------
-
-
-def _pallas_bu_start():
-    def build():
-        import jax
-        import jax.numpy as jnp
-
-        from titan_tpu.ops.pallas_frontier import frontier_round
-
-        @functools.partial(jax.jit,
-                           static_argnames=("c_cap", "n_", "lanes",
-                                            "interpret"),
-                           donate_argnums=(0,))
-        def bu0p(dist, level, dstT, colstart, degc, c_cap: int, n_: int,
-                 lanes: int, interpret: bool):
-            """Bottom-up opener on the fused round kernel: candidate
-            build, then ONE kernel pass does the chunk-0 narrow-lane
-            test, the wide refetch for the misses, and the survivor
-            compaction on-chip — replacing bu0 AND the bu0a/bu0b
-            split-lane pair (the lane ladder is in-kernel, so the
-            SPLIT_LANE_MIN host-sized second dispatch never applies)."""
-            q_pad = dstT.shape[1] - 1
-            fbits = _pack_bits(dist, level, n_)
-            unvis = (dist[:n_] >= INF) & (degc[:n_] > 0)
-            c_count, cand = compact_ids(unvis, c_cap, n_)
-
-            alive = jnp.arange(c_cap) < c_count
-            v = jnp.minimum(cand, n_)
-            cols = jnp.where(alive, colstart[v], q_pad)
-            found, cand2, _, nc = frontier_round(
-                cols, alive[None, :], alive & (degc[v] > 1), cand,
-                jnp.ones((c_cap,), jnp.int32), fbits[None, :], None,
-                dstT, lanes=lanes, fill0=n_, fill1=0,
-                interpret=interpret)
-            found0 = found[0]
-            dist = dist.at[jnp.where(found0, v, n_ + 1)].set(
-                level + 1, mode="drop")
-            surv = alive & ~found0 & (degc[v] > 1)
-            rem8 = jnp.where(surv, degc[v] - 1, 0).sum(dtype=jnp.int32)
-            st = jax.lax.cond(
-                nc == 0,
-                lambda _: _level_stats(dist, degc, level, n_),
-                lambda _: jnp.zeros((4,), jnp.int32), None)
-            return dist, fbits, cand2, jnp.stack([nc, rem8]), st
-        return bu0p
-    return _get("pallas_bu_start", build)
-
-
-def _pallas_bu_more():
-    def build():
-        import jax
-        import jax.numpy as jnp
-
-        from titan_tpu.ops.pallas_frontier import frontier_round
-
-        @functools.partial(jax.jit,
-                           static_argnames=("c_cap", "n_", "fuse",
-                                            "lanes", "interpret"),
-                           donate_argnums=(0,))
-        def bup(dist, fbits, cand, off, prog, level, dstT, colstart,
-                degc, c_cap: int, n_: int, fuse: int, lanes: int,
-                interpret: bool):
-            """_bu_more on the fused round kernel: each of the ``fuse``
-            rounds is one kernel pass (narrow fetch, bitmap test, wide
-            refetch for the undecided, on-chip survivor compaction)."""
-            c_count = prog[0]
-            q_pad = dstT.shape[1] - 1
-
-            def round_(state, _):
-                dist, cand, off, c_count = state
-                alive = jnp.arange(c_cap) < c_count
-                v = jnp.minimum(cand, n_)
-                cols = jnp.where(alive, colstart[v] + off, q_pad)
-                found, cand2, off2, nc = frontier_round(
-                    cols, alive[None, :], alive & (off + 1 < degc[v]),
-                    cand, off + 1, fbits[None, :], None, dstT,
-                    lanes=lanes, fill0=n_, fill1=0, interpret=interpret)
-                dist = dist.at[jnp.where(found[0], v, n_ + 1)].set(
-                    level + 1, mode="drop")
-                return (dist, cand2, off2, nc), None
-
-            (dist, cand, off, c_count), _ = jax.lax.scan(
-                round_, (dist, cand, off, c_count), None, length=fuse)
-            alive = jnp.arange(c_cap) < c_count
-            v = jnp.minimum(cand, n_)
-            rem = jnp.where(alive, jnp.maximum(degc[v] - off, 0), 0) \
-                .sum(dtype=jnp.int32)
-            st = jax.lax.cond(
-                c_count == 0,
-                lambda _: _level_stats(dist, degc, level, n_),
-                lambda _: jnp.zeros((4,), jnp.int32), None)
-            return dist, cand, off, jnp.stack([c_count, rem]), st
-        return bup
-    return _get("pallas_bu_more", build)
-
-
 def _endgame():
     def build():
         import jax
@@ -1322,67 +1217,6 @@ def warm_batched_td(g, K: int, expand: bool) -> None:
     dist.block_until_ready()
 
 
-def _pallas_batched_bu():
-    def build():
-        import jax
-        import jax.numpy as jnp
-
-        from titan_tpu.ops.pallas_frontier import frontier_round
-
-        @functools.partial(jax.jit,
-                           static_argnames=("c_cap", "n_", "fuse",
-                                            "masked", "expand", "lanes",
-                                            "interpret"),
-                           donate_argnums=(0,))
-        def bpstep(dist, fbits, cand, off, prog, level, dstT, colstart,
-                   degc, tbits, c_cap: int, n_: int, fuse: int,
-                   masked: bool = False, expand: bool = False,
-                   lanes: int = 2, interpret: bool = False):
-            """_batched_bu on the fused round kernel: one chunk gather
-            per round tested against all K bitmaps on-chip, tombstone /
-            level-mask slots riding the kernel's tbits seam, survivor
-            compaction in-kernel. Same contract as bstep, bit-equal
-            (tests/test_pallas_frontier.py). NOT used for mesh-placed
-            cohorts (GSPMD cannot partition a pallas_call) — the driver
-            keeps those on the XLA kernels."""
-            c_count = prog[0]
-            q_pad = dstT.shape[1] - 1
-            live = (fbits != 0).any(axis=1) if expand else None  # [K]
-
-            def round_(state, _):
-                dist, cand, off, c_count = state
-                alive = jnp.arange(c_cap) < c_count
-                v = jnp.minimum(cand, n_)
-                cols = jnp.where(alive & (off < degc[v]),
-                                 colstart[v] + off, q_pad)
-                if expand:
-                    undec = (dist[:, v] != level + 1) & live[:, None]
-                else:
-                    undec = dist[:, v] >= INF
-                found, cand2, off2, nc = frontier_round(
-                    cols, undec & alive[None, :],
-                    alive & (off + 1 < degc[v]), cand, off + 1, fbits,
-                    tbits if masked else None, dstT, lanes=lanes,
-                    fill0=n_ + 1, fill1=0, interpret=interpret)
-                if expand:
-                    dist = dist.at[:, jnp.where(alive, v, n_ + 1)].max(
-                        jnp.where(found, level + 1, 0), mode="drop")
-                else:
-                    dist = dist.at[:, jnp.where(alive, v, n_ + 1)].min(
-                        jnp.where(found, level + 1, INF), mode="drop")
-                return (dist, cand2, off2, nc), None
-
-            (dist, cand, off, c_count), _ = jax.lax.scan(
-                round_, (dist, cand, off, c_count), None, length=fuse)
-            alive = jnp.arange(c_cap) < c_count
-            v = jnp.minimum(cand, n_)
-            rem8 = jnp.where(alive, jnp.maximum(degc[v] - off, 0), 0) \
-                .sum(dtype=jnp.int32)
-            return dist, cand, off, jnp.stack([c_count, rem8])
-        return bpstep
-    return _get("pallas_batched_bu", build)
-
-
 def _batched_exhaust():
     def build():
         import jax
@@ -1600,14 +1434,6 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
     btd = _batched_td()
     bstep = _batched_bu()
     bex = _batched_exhaust()
-    from titan_tpu.ops.pallas_frontier import (frontier_interpret,
-                                               frontier_kernel_mode)
-    # mesh-placed cohorts stay on the XLA kernels: GSPMD cannot
-    # partition a pallas_call across the "v" axis
-    use_pallas = frontier_kernel_mode() == "pallas" \
-        and "_state_sharding" not in g
-    bstep_p = _pallas_batched_bu() if use_pallas else None
-    interp = frontier_interpret() if use_pallas else False
     from titan_tpu.obs import devprof
     from titan_tpu.obs.tracing import phase
     from titan_tpu.utils.jitcache import dev_scalar
@@ -1809,19 +1635,11 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
                     cand = pad(cand)
                     off = jnp.zeros((cap_n,), jnp.int32)
                     prog = jnp.asarray([c_count, 0], jnp.int32)
-                if use_pallas:
-                    dist, cand, off, prog = bstep_p(
-                        dist, fbits, cand[:c_cap2], off[:c_cap2], prog,
-                        dev_scalar(level), dstT, colstart, degc, tb_l,
-                        c_cap=c_cap2, n_=n, fuse=fuse, masked=masked_l,
-                        expand=expand, lanes=SPLIT_LANES,
-                        interpret=interp)
-                else:
-                    dist, cand, off, prog = bstep(
-                        dist, fbits, cand[:c_cap2], off[:c_cap2], prog,
-                        dev_scalar(level), dstT, colstart, degc, tb_l,
-                        c_cap=c_cap2, n_=n, fuse=fuse, masked=masked_l,
-                        expand=expand)
+                dist, cand, off, prog = bstep(
+                    dist, fbits, cand[:c_cap2], off[:c_cap2], prog,
+                    dev_scalar(level), dstT, colstart, degc, tb_l,
+                    c_cap=c_cap2, n_=n, fuse=fuse, masked=masked_l,
+                    expand=expand)
                 cand, off = pad(cand), pad(off)
                 with ph.sync():
                     left = np.asarray(prog)
@@ -1879,12 +1697,6 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
     ex = _bu_exhaust()
     endgame = _endgame()
     frontier_of = _frontier_of()
-    from titan_tpu.ops.pallas_frontier import (frontier_interpret,
-                                               frontier_kernel_mode)
-    use_pallas = frontier_kernel_mode() == "pallas"
-    bu0p = _pallas_bu_start() if use_pallas else None
-    bup = _pallas_bu_more() if use_pallas else None
-    interp = frontier_interpret() if use_pallas else False
 
     total_chunks = int((g["q_total"] - 1))
     cap_n = _next_pow2(max(n, 2))
@@ -1946,15 +1758,7 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
                 (int(x) for x in np.asarray(st_dev))
         else:
             c_cap = min(_next_pow2(max(n_unvis, 2)), cap_n)
-            if use_pallas:
-                # fused Pallas opener: the lane ladder runs on-chip, so
-                # the SPLIT_LANE_MIN two-dispatch split never applies
-                dist, fbits, cand, prog, st_dev = bu0p(
-                    dist, dev_scalar(level), dstT, colstart, degc,
-                    c_cap=c_cap, n_=n, lanes=SPLIT_LANES,
-                    interpret=interp)
-                nc, rem8 = (int(x) for x in np.asarray(prog))
-            elif c_cap >= SPLIT_LANE_MIN:
+            if c_cap >= SPLIT_LANE_MIN:
                 # split-lane opener: SPLIT_LANES-wide test over
                 # everyone, then the remaining lanes only for the
                 # minority that missed (host-sized)
@@ -1985,17 +1789,10 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
                     cand = pad(cand)
                     off = jnp.ones((cap_n,), jnp.int32)
                 fuse = BU_CHUNK_ROUNDS - rounds
-                if use_pallas:
-                    dist, cand, off, prog, st_dev = bup(
-                        dist, fbits, cand[:c_cap2], off[:c_cap2],
-                        prog, dev_scalar(level), dstT, colstart,
-                        degc, c_cap=c_cap2, n_=n, fuse=fuse,
-                        lanes=SPLIT_LANES, interpret=interp)
-                else:
-                    dist, cand, off, prog, st_dev = bu(
-                        dist, fbits, cand[:c_cap2], off[:c_cap2],
-                        prog, dev_scalar(level), dstT, colstart,
-                        degc, c_cap=c_cap2, n_=n, fuse=fuse)
+                dist, cand, off, prog, st_dev = bu(
+                    dist, fbits, cand[:c_cap2], off[:c_cap2],
+                    prog, dev_scalar(level), dstT, colstart,
+                    degc, c_cap=c_cap2, n_=n, fuse=fuse)
                 cand, off = pad(cand), pad(off)
                 nc, rem8 = (int(x) for x in np.asarray(prog))
                 rounds += fuse

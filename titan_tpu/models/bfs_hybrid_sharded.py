@@ -12,8 +12,7 @@ exchange — measuring ~2.0× over the plain hybrid on a ONE-device mesh
 the overhead was dispatch/merge machinery, not communication.
 
 The ISSUE-13 rebuild fuses each level into ONE dispatch per mode per
-cap bucket, the same way ``bfs_hybrid_fused`` fused the single-chip
-head loop:
+cap bucket:
 
 * **td level** (``shx_td``): frontier list build (replicated
   compaction of ``dist == level`` — the per-level n-scale pass every
@@ -329,35 +328,17 @@ def _bu_level(mesh):
     """One whole bottom-up level, fused: candidate build + chunk-0
     bitmap test + fused chunk rounds + K-stride exhaust (inside a
     replicated survivor-width cond ladder) + sparse exchange + stats.
-    One dispatch per level per (c_cap, found_cap) bucket.
-
-    With ``TITAN_TPU_FRONTIER_KERNEL=pallas`` the chunk-0 test and the
-    fused-round fetch+test+compact run through the Pallas round kernel
-    (ops/pallas_frontier.py) inside the SAME single dispatch — the
-    variant registers under its own mesh_jit name (``shx_bu_pallas``)
-    so a mid-process flag flip never reuses the XLA-compiled kernel
-    and the compile buckets stay honest. The K-stride exhaust
-    while_loop stays XLA in both modes (rare straggler path with
-    pair-stride shapes). Bit-equal either way; the dispatch budget
-    (<= 2 per level with the found_cap retry) is unchanged."""
+    One dispatch per level per (c_cap, found_cap) bucket (<= 2 per
+    level with the found_cap retry)."""
     from jax.sharding import PartitionSpec as P
 
-    from titan_tpu.ops.pallas_frontier import frontier_kernel_mode
     from titan_tpu.parallel.mesh import VERTEX_AXIS, mesh_jit
-
-    mode = frontier_kernel_mode()
 
     def builder(mesh):
         import jax
         import jax.numpy as jnp
 
-        from titan_tpu.models.bfs_hybrid import SPLIT_LANES
-        from titan_tpu.ops.pallas_frontier import (frontier_interpret,
-                                                   frontier_round)
         from titan_tpu.parallel.mesh import shard_map_compat
-
-        use_pallas = mode == "pallas"
-        interp = frontier_interpret() if use_pallas else False
 
         def bu(dist, level, dstT_sh, colstart_sh, degc_sh, degc, lo_sh,
                hi_sh, c_cap: int, found_cap: int, n_: int, b_max: int):
@@ -374,26 +355,13 @@ def _bu_level(mesh):
                 alive = jnp.arange(c_cap) < c_count
                 lv = jnp.clip(cand, 0, b_max - 1)
                 cols = jnp.where(alive, cs_l[lv], q_pad)
-                if use_pallas:
-                    # fused chunk-0: lane-laddered test + survivor
-                    # compaction on-chip (cursor seeded at 1 — chunk 0
-                    # is consumed by this call)
-                    found_k, cand1, off1, nc = frontier_round(
-                        cols, alive[None, :],
-                        alive & (degc_l[lv] > 1), cand,
-                        jnp.ones((c_cap,), jnp.int32), fbits[None, :],
-                        None, dstT_l, lanes=SPLIT_LANES, fill0=b_max,
-                        fill1=0, interpret=interp)
-                    found = found_k[0]
-                else:
-                    parents = jnp.take(dstT_l, jnp.clip(cols, 0, q_pad),
-                                       axis=1)
-                    found = alive & _bit_of(fbits, parents).any(axis=0)
+                parents = jnp.take(dstT_l, jnp.clip(cols, 0, q_pad),
+                                   axis=1)
+                found = alive & _bit_of(fbits, parents).any(axis=0)
                 dist = dist.at[jnp.where(found, lv + lo, n_ + 1)].set(
                     level + 1, mode="drop")
                 surv = alive & ~found & (degc_l[lv] > 1)
-                if not use_pallas:
-                    nc = surv.sum().astype(jnp.int32)
+                nc = surv.sum().astype(jnp.int32)
                 # REPLICATED survivor max: every shard takes the same
                 # ladder branch, so no collective ever sits inside a
                 # cond (a divergent branch with a collective deadlocks
@@ -404,18 +372,10 @@ def _bu_level(mesh):
 
                 def rounds_at(w: int):
                     def go(dist):
-                        if use_pallas:
-                            # the kernel already compacted the chunk-0
-                            # survivors at c_cap width; the first w
-                            # entries ARE scatter_compact's width-w
-                            # result (same stable order, same fills,
-                            # and the ladder guarantees nc_max <= w)
-                            cand_w, off_w = cand1[:w], off1[:w]
-                        else:
-                            _, (cand_w, off_w) = scatter_compact(
-                                surv,
-                                (cand, jnp.ones((c_cap,), jnp.int32)),
-                                w, (b_max, 0))
+                        _, (cand_w, off_w) = scatter_compact(
+                            surv,
+                            (cand, jnp.ones((c_cap,), jnp.int32)),
+                            w, (b_max, 0))
                         ncr = jnp.minimum(nc, w)
 
                         def round_(state, _):
@@ -423,19 +383,6 @@ def _bu_level(mesh):
                             alv = jnp.arange(w) < ncr
                             lvv = jnp.clip(cand, 0, b_max - 1)
                             cls = jnp.where(alv, cs_l[lvv] + off, q_pad)
-                            if use_pallas:
-                                ft_k, cand2, off2, nc2 = frontier_round(
-                                    cls, alv[None, :],
-                                    alv & (off + 1 < degc_l[lvv]),
-                                    cand, off + 1, fbits[None, :],
-                                    None, dstT_l, lanes=SPLIT_LANES,
-                                    fill0=b_max, fill1=0,
-                                    interpret=interp)
-                                ft = ft_k[0]
-                                dist = dist.at[
-                                    jnp.where(ft, lvv + lo, n_ + 1)].set(
-                                    level + 1, mode="drop")
-                                return (dist, cand2, off2, nc2), None
                             par = jnp.take(dstT_l,
                                            jnp.clip(cls, 0, q_pad),
                                            axis=1)
@@ -517,8 +464,7 @@ def _bu_level(mesh):
         return bu
 
     return mesh_jit(
-        "shx_bu" if mode == "xla" else "shx_bu_pallas", mesh, builder,
-        out_specs=(P(), P()),
+        "shx_bu", mesh, builder, out_specs=(P(), P()),
         static_argnames=("c_cap", "found_cap", "n_", "b_max"))
 
 

@@ -3,7 +3,7 @@
 The freshness half of the live plane's cost model: applying a delta to
 the HOST snapshot (``GraphSnapshot.apply_changes``) invalidates every
 device-layout cache and forces the next run to re-upload the full
-chunked CSR (11.6 GB at bfs_heavy scale) host→device.  The
+chunked CSR (11.6 GB for a Twitter-2010-size graph) host→device.  The
 overlay instead keeps the base CSR device arrays UNTOUCHED and layers
 the delta next to them:
 

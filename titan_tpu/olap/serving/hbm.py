@@ -1,15 +1,12 @@
 """Device-memory (HBM) accounting for graph images — admission's ledger.
 
-This is the bench driver's ``_DEV_GRAPHS`` budget logic promoted to a
-library (ISSUE r7: "as a library, not a script-local"): the serving
-scheduler admits jobs against it before building a snapshot's chunked
-CSR on device, and bench.py's stage-shared graph cache is the same
-``DeviceGraphCache`` re-used. The byte model matches what the kernels
-actually upload: the transposed 8-aligned ``dstT`` [8, q_total] int32
-plus three [n+1] int32 side arrays (colstart/degc/deg) —
+The serving scheduler admits jobs against it before building a
+snapshot's chunked CSR on device. The byte model matches what the
+kernels actually upload: the transposed 8-aligned ``dstT`` [8, q_total]
+int32 plus three [n+1] int32 side arrays (colstart/degc/deg) —
 models/bfs_hybrid.build_chunked_csr's exact footprint. Eviction is
-largest-first over unpinned entries (the bench policy); pinned entries
-(graphs under a running batch) are never evicted.
+largest-first over unpinned entries; pinned entries (graphs under a
+running batch) are never evicted.
 """
 
 from __future__ import annotations
@@ -17,8 +14,8 @@ from __future__ import annotations
 import threading
 from typing import Callable, Optional
 
-#: default budget, bench.py's historical 12 GB of a 16 GB v5e HBM
-#: (leaving headroom for kernel state/temporaries)
+#: default budget: 12 GB of a 16 GB v5e HBM (leaving headroom for
+#: kernel state/temporaries)
 DEFAULT_BUDGET_BYTES = 12.0e9
 
 
@@ -26,11 +23,6 @@ def chunked_csr_bytes(n: int, q_total: int) -> int:
     """Device bytes of a chunked CSR: dstT [8, q_total] int32 + 3 x
     [n+1] int32 (colstart/degc/deg)."""
     return q_total * 8 * 4 + 3 * 4 * (n + 1)
-
-
-def graph_bytes(hg: dict) -> int:
-    """Bytes for a host-graph dict (graph500.load_or_build result)."""
-    return chunked_csr_bytes(hg["n"], hg["q_total"])
 
 
 def snapshot_csr_bytes(snap) -> int:
@@ -135,38 +127,3 @@ class HBMLedger:
             self._bytes.pop(key, None)
             self._pins.pop(key, None)
 
-
-class DeviceGraphCache:
-    """Stage-shared device-graph cache (bench.py's ``_DEV_GRAPHS`` as a
-    class): ``get_or_load(key, host_loader, uploader)`` returns
-    ``(host_graph, device_graph, gen_s, upload_s)``, keeping every
-    loaded graph resident and evicting largest-first only when a new
-    graph would overflow the budget."""
-
-    def __init__(self, budget_bytes: float = DEFAULT_BUDGET_BYTES):
-        self._ledger = HBMLedger(budget_bytes, on_evict=self._drop)
-        self._graphs: dict = {}
-        self._lock = threading.Lock()
-
-    def __contains__(self, key) -> bool:
-        return key in self._graphs
-
-    def _drop(self, key) -> None:
-        self._graphs.pop(key, None)
-
-    def get_or_load(self, key, host_loader, uploader):
-        import time as _time
-        with self._lock:
-            got = self._graphs.get(key)
-            if got is not None:
-                return got + (0.0, 0.0)
-            t0 = _time.time()
-            hg = host_loader()
-            gen_s = _time.time() - t0
-            self._ledger.reserve(key, graph_bytes(hg))
-            self._ledger.unpin(key)   # resident-but-evictable
-            t0 = _time.time()
-            g = uploader(hg)
-            upload_s = _time.time() - t0
-            self._graphs[key] = (hg, g)
-            return hg, g, gen_s, upload_s

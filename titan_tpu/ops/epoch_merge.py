@@ -3,8 +3,8 @@
 The live plane's epoch boundary (olap/live/compactor.py) used to be the
 old Titan-style full rebuild in disguise: merge the overlay into the
 base on the HOST (``np.concatenate`` + a full dst-stable sort) and
-re-upload the merged chunked CSR whole — ~11.6 GB of H2D per epoch at
-bfs_heavy scale, which caps sustainable write throughput at whatever
+re-upload the merged chunked CSR whole — ~11.6 GB of H2D per epoch for
+a Twitter-2010-size graph, which caps sustainable write throughput at whatever
 the host→device link will carry. But every input of the merge is ALREADY resident
 in HBM: the base ``dstT`` (models/bfs_hybrid.build_chunked_csr), the
 overlay's COO add-buffer and the tombstone bitmap (olap/live/overlay).

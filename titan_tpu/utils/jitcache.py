@@ -54,7 +54,7 @@ def enable_compile_cache() -> None:
     in the environment JAX reads it itself and no directory is set
     here; otherwise the cache lives at the fixed in-checkout path
     ``<repo>/.bench_cache/xla`` (the path is part of the cache key, so
-    it never moves). Every entry-point process (chip_smoke.py, bench.py,
+    it never moves). Every entry-point process (chip_smoke.py,
     ``python -m titan_tpu.server``, the experiments, the tests) calls
     this before building kernels."""
     import os

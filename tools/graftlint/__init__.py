@@ -11,7 +11,7 @@ Entry points:
 
 * ``python -m tools.graftlint [paths...]`` — the CLI (``scripts/lint.sh``)
 * :class:`tools.graftlint.engine.Linter` — the library API
-  (``tests/test_lint.py``, ``bench.py --evidence``'s ``lint_clean`` line)
+  (``tests/test_lint.py``)
 
 Rule catalog + suppression/baseline workflow: docs/static-analysis.md.
 """

@@ -14,7 +14,7 @@ from tools.graftlint.engine import (DEFAULT_BASELINE_RELPATH, Baseline,
 from tools.graftlint.report import render_json, render_text
 from tools.graftlint.rules import rule_ids
 
-DEFAULT_PATHS = ["titan_tpu", "tests", "bench.py"]
+DEFAULT_PATHS = ["titan_tpu", "tests"]
 DEFAULT_BASELINE = DEFAULT_BASELINE_RELPATH
 
 

@@ -20,17 +20,17 @@ import copy
 
 DEFAULT_CONFIG: dict = {
     # R1 — the op-scan ban (docs/performance.md, ISSUE r6): the whole
-    # package plus bench.py's eager device paths. Everything else
-    # (tests, experiments) may use op-scans as oracles.
+    # package. Everything else (tests, experiments) may use op-scans
+    # as oracles.
     "opscan": {
-        "scope": ["titan_tpu/", "bench.py"],
+        "scope": ["titan_tpu/"],
     },
     # R2 — host syncs inside kernels registered through
     # utils/jitcache.jit_once / parallel/mesh.mesh_jit. The scope is
     # wide; the rule itself only fires inside functions it resolved
     # from a registration call site.
     "host-sync": {
-        "scope": ["titan_tpu/", "bench.py"],
+        "scope": ["titan_tpu/"],
     },
     # R3 — blocking work under the serving/live locks (the PR-10
     # `_requeue` postmortem-write stall).

@@ -292,9 +292,9 @@ _SKIP_DIRS = {"__pycache__", ".git", ".bench_cache", ".pytest_cache",
 
 
 #: the checked-in grandfather list, auto-loaded (root-relative) by
-#: EVERY Linter unless a baseline is passed explicitly — the CLI, the
-#: tier-1 tests, and bench.py's lint_clean line must agree about the
-#: same tree (pass ``baseline=Baseline()`` to opt out)
+#: EVERY Linter unless a baseline is passed explicitly — the CLI and
+#: the tier-1 tests must agree about the same tree (pass
+#: ``baseline=Baseline()`` to opt out)
 DEFAULT_BASELINE_RELPATH = os.path.join("tools", "graftlint",
                                         "baseline.json")
 

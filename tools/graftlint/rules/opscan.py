@@ -11,9 +11,9 @@ Two tiers:
 
 * ``jnp.nonzero`` / ``jnp.flatnonzero`` / ``jnp.argwhere`` are banned
   OUTRIGHT (size= or not) — bounded forms must go through
-  ops.compaction so the contract stays in one place. The two
-  non-round-loop reference models (models/bfs.py,
-  models/bfs_hybrid_fused.py) carry file-level suppressions.
+  ops.compaction so the contract stays in one place. The
+  non-round-loop reference model (models/bfs.py) carries a file-level
+  suppression.
 * the METHOD spellings ``x.nonzero()`` / ``x.flatnonzero()`` are the
   same op-scan wearing an attribute — banned too (the tree's host-side
   idiom is the ``np.nonzero(...)`` function form, which stays legal);
