@@ -1,9 +1,14 @@
-"""Chip probe (PR 26, PR 29): what one level of the batched BFS costs
-in each direction and on each road, on the benchmark's two graphs — the
-measurement behind ``bfs_hybrid.TD_RUNG_SHIFTS`` and ``TD_BU_COST``, and
-behind handing the frontier forward.
+"""Chip probe (PR 26, PR 29, PR 31): what one level of the batched BFS
+costs in each direction and on each road, on the benchmark's two graphs
+— the measurement behind ``bfs_hybrid.TD_RUNG_SHIFTS`` and
+``TD_BU_COST``, and behind handing the frontier forward.
 
     python experiments/batched_td_probe.py [--scale 20]
+        [--rungs 18,19,20] [--push-only]
+
+``--rungs`` keeps the rungs of those powers of two; ``--push-only``
+times ``td-scan`` and ``td-last`` alone, on the executables the rule
+serves with (PR 31: what a rung costs, against its width).
 
 Per graph and batch size K: the top-down step at each rung of the
 ladder with a frontier that fills about 0.8 of the rung (hops mode), on
@@ -51,7 +56,11 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=20)
     ap.add_argument("--graphs", default="gap-kron-s20,gap-urand-s20")
+    ap.add_argument("--rungs", default="",
+                    help="log2 of the rungs to time (default: all)")
+    ap.add_argument("--push-only", action="store_true")
     args = ap.parse_args()
+    rungs = {1 << int(e) for e in args.rungs.split(",") if e}
 
     import jax
     import jax.numpy as jnp
@@ -109,10 +118,13 @@ def main() -> None:
                 np.asarray(bext(dist2, np.full(K, 2, np.int32), n_=n)[1])
 
             for label, fn in (("seed", seed), ("extract", extract)):
-                ms, first = timed(fn)
-                row(K=K, dir=label, ms=round(ms, 2),
-                    first_ms=round(first, 1))
+                if not args.push_only:
+                    ms, first = timed(fn)
+                    row(K=K, dir=label, ms=round(ms, 2),
+                        first_ms=round(first, 1))
             for i, cap in enumerate(caps):
+                if rungs and cap not in rungs:
+                    continue
                 # a frontier of about 0.8 of the rung, split over K jobs
                 take = int(np.searchsorted(csum, 0.8 * cap))
                 init = np.zeros((K, n + 1), np.int32)
@@ -135,18 +147,21 @@ def main() -> None:
 
                 # the dedup on every rung it could run on (fewer lanes
                 # than K x n), whatever _td_lists says: its measurement
-                lists = 8 * cap < K * n
+                lists = bh._td_lists(cap, n) if args.push_only \
+                    else 8 * cap < K * n
                 held = listed()
                 handed = int(push(held, 1)[2])
                 for label, fn in (
                         ("td-scan", lambda: push(listed(), 0)),
                         ("td-carried", lambda: push(held, 1)),
                         ("td-last", lambda: push(held, 0))):
+                    if args.push_only and label == "td-carried":
+                        continue
                     ms, first = timed(fn)
                     row(K=K, dir=label, p_cap=cap, mass=mass,
                         handed=handed, rule=bh._td_lists(cap, n),
                         ms=round(ms, 2), first_ms=round(first, 1))
-                if not lists:
+                if not lists or args.push_only:
                     continue
                 # the claim array of the dedup: made in the program, or
                 # kept between levels and reset at the keys it touched
@@ -166,6 +181,8 @@ def main() -> None:
                     ms, first = timed(fn)
                     row(K=K, dir=label, p_cap=cap, ms=round(ms, 3),
                         first_ms=round(first, 1))
+            if args.push_only:
+                continue
             # one bottom-up level over a 16-vertex-a-job frontier
             init = np.zeros((K, n + 1), np.int32)
             for k in range(K):
@@ -198,6 +215,8 @@ def main() -> None:
                 ms, first = timed(fn)
                 row(K=K, dir=label, c_count=c_count, ms=round(ms, 2),
                     first_ms=round(first, 1))
+        if args.push_only:
+            continue
         # whole BFS runs, the job batcher's mode
         srcs = [int(v) for v in order[:8]]
         for label, layout in (("rule", g), ("held-off",

@@ -12,6 +12,11 @@ What is pinned here, all on the CPU:
   an ``out()`` chain's layout and a mesh-placed cohort pull;
 * after the lane's first batch on a snapshot, a batch of any size up to
   ``max_fuse`` and a level on any rung build nothing;
+* the ladder (ISSUE 31): bottom, middle and top rung where they were,
+  from the middle up a factor of two apart; a level gives the same
+  ``dist`` on every rung that holds it; a query whose second level
+  lands on a rung between the middle and the top answers as the plain
+  reference does and builds nothing;
 * the frontier handed forward as a pair list with its statistics (the
   carried road) gives the same ``dist``, ``levels`` and ``completed`` as
   listing it from ``dist`` at every level (the scan road) and as the
@@ -201,12 +206,17 @@ def test_the_rule():
     ids past int32 and a dearer push all pull."""
     g = {"n": 1 << 20, "q_total": 4_563_401}      # the Kron cell's layout
     caps = bh._td_caps(g)
-    assert caps == (1 << 12, 1 << 17, 1 << 21)
+    assert caps == (1 << 12, 1 << 17, 1 << 18, 1 << 19, 1 << 20, 1 << 21)
     assert caps == bh._td_caps({"n": 1 << 20, "q_total": 4_650_000})
     big = 1 << 20                                   # candidates: n-wide
     assert bh._td_cap(g, 16, 0, big, False) == caps[0]
     assert bh._td_cap(g, 16, caps[0], big, False) == caps[0]
     assert bh._td_cap(g, 16, caps[0] + 1, big, False) == caps[1]
+    # the Kron cell's ten starts of 150,732-193,702 columns and its one
+    # of 892,911 (ISSUE 31): a rung a factor of two above, not the top
+    assert bh._td_cap(g, 1, 150_732, big, False) == 1 << 18
+    assert bh._td_cap(g, 1, 193_702, big, False) == 1 << 18
+    assert bh._td_cap(g, 1, 892_911, big, False) == 1 << 20
     assert bh._td_cap(g, 16, caps[-1], big, False) == caps[-1]
     assert bh._td_cap(g, 16, caps[-1] + 1, big, False) is None
     assert bh._td_cap(g, 16, 100, big, True) is None
@@ -221,9 +231,82 @@ def test_the_rule():
     assert bh._td_cap(g, 16, edge + 1, few, False) is None
     # the ladder follows the layout: the top rung is the largest power
     # of two at or below half its chunk columns
-    assert bh._td_caps({"n": 64, "q_total": 300}) == (2, 8, 128)
+    assert bh._td_caps({"n": 64, "q_total": 300}) == (2, 8, 16, 32, 64, 128)
     assert bh._td_caps({"n": 1, "q_total": 1}) == (2,)
     assert bh._td_caps({"n": 1 << 26, "q_total": 290_000_000})[-1] == 1 << 27
+
+
+@pytest.mark.parametrize("q_total", [
+    1, 5, 40, 300, 2_400, 4_563_401, 4_650_000, 18_600_000, 290_000_000])
+def test_the_ladder(q_total):
+    """The ladder is a function of the layout's chunk columns alone. Its
+    bottom, middle and top rungs stand where PR 26 put them (the top the
+    largest power of two at or below half the columns, the others 2^9
+    and 2^4 below it), so a level that ran on one of them runs the same
+    executable; from the middle up the rungs are a factor of two apart,
+    so no level past the middle pays for more than twice its mass; a toy
+    layout's rungs collapse onto the floor of 2 without duplicates."""
+    caps = bh._td_caps({"q_total": q_total})
+    top = caps[-1]
+    assert top & (top - 1) == 0
+    assert top <= max(q_total // 2, 2) < 2 * top
+    assert caps[0] == max(top >> 9, 2)
+    assert max(top >> 4, 2) in caps
+    assert list(caps) == sorted(set(caps))          # no rung twice
+    upper = [c for c in caps if c >= top >> 4]
+    assert upper == [max(top >> s, 2) for s in (4, 3, 2, 1, 0)][
+        -len(upper):]
+    assert all(b == 2 * a for a, b in zip(upper, upper[1:]))
+    assert [c for c in caps if c < top >> 4] in ([], [caps[0]])
+
+
+@pytest.mark.parametrize("K", [1, 4])
+@pytest.mark.parametrize("mode", ["bfs", "hops"])
+def test_a_level_gives_the_same_on_every_rung_that_holds_it(graph, mode,
+                                                            K):
+    """A rung is a capacity, not an algorithm: one level pushed on every
+    rung that holds its pairs and their chunk mass leaves bit-equal
+    ``dist`` and reads back the same ``pushed`` (pairs, columns)."""
+    from titan_tpu.utils.jitcache import dev_scalar
+
+    _snap, g, adj = graph
+    n, caps = g["n"], bh._td_caps(g)
+    assert len(caps) == 6
+    expand = mode == "hops"
+    srcs = np.asarray(light(g, adj, K), np.int32)
+    start = 1 if expand else 0
+    degc = np.asarray(g["_host"]["degc"])
+    for level, lowest in ((start, 0), (start + 1, 1)):
+        # the start level fits the bottom rung; the level after it is
+        # made to begin at the middle by the lightest starts' mass
+        dist, active, pj, pv, count = bh._batched_seed()(
+            srcs, dev_scalar(start), n_=n, cap=caps[-1], expand=expand)
+        if level > start:
+            dist, *_ = bh._batched_td()(
+                dist, pj, pv, count, active, dev_scalar(start),
+                dev_scalar(0), g["dstT"], g["colstart"], g["degc"],
+                p_cap=caps[-1], n_=n, expand=expand, lists=False)
+            pj, pv, count = bh._batched_list()(
+                dist, active, dev_scalar(level),
+                dev_scalar(len(caps) - 1), g["degc"], caps=caps, n_=n)
+        pairs = int(np.asarray(count))
+        mass = int(degc[np.asarray(pv)[:pairs]].sum())
+        holds = [c for c in caps if max(mass, pairs) <= c]
+        assert len(holds) >= len(caps) - lowest - 1 >= 4
+        before = np.asarray(dist)
+        got = []
+        for cap in holds:
+            out = bh._batched_td()(
+                dist + 0, pj, pv, count, active, dev_scalar(level),
+                dev_scalar(0), g["dstT"], g["colstart"], g["degc"],
+                p_cap=cap, n_=n, expand=expand,
+                lists=bh._td_lists(cap, n))
+            got.append((np.asarray(out[0]), np.asarray(out[4])[:2]))
+        assert got[0][1].tolist() == [pairs, mass]
+        assert not np.array_equal(got[0][0], before)    # it did push
+        for d, pushed in got[1:]:
+            assert np.array_equal(d, got[0][0])
+            assert np.array_equal(pushed, got[0][1])
 
 
 def test_a_mass_above_the_top_rung_pulls(graph, monkeypatch):
@@ -358,6 +441,82 @@ def test_after_the_first_batch_no_size_and_no_rung_builds(graph):
         sched.close()
 
 
+@pytest.fixture(scope="module")
+def served(graph):
+    """A lane that has answered its first query on the module's graph,
+    and so has built every padded batch size and every rung
+    (``_build_shapes``)."""
+    from titan_tpu.olap.serving.interactive import plan_from_wire
+    from titan_tpu.olap.serving.scheduler import JobScheduler
+
+    snap, g, adj = graph
+    prof = devprof.DeviceCostProfiler(metrics=MetricManager())
+    sched = JobScheduler(snapshot=snap, autostart=False, profiler=prof,
+                         interactive_window_s=0.0)
+    prof.install()
+    try:
+        lane = sched.interactive()
+
+        def ask(vs):
+            return lane.submit(plan_from_wire(
+                {"start": [int(snap.vertex_ids[v]) for v in vs],
+                 "dir": "both", "hops": 2, "terminal": "count"}))
+
+        ask(light(g, adj, 1))
+        yield sched, prof, ask
+    finally:
+        prof.uninstall()
+        sched.close()
+
+
+def starts_whose_second_level_weighs(g, adj, low: int, high: int) -> list:
+    """Starts of one 2-hop query whose second level's chunk mass (the
+    chunks of the starts' distinct neighbours) falls in ``(low, high]``:
+    one start where the graph has such a vertex (the carried road, as
+    the benchmark's queries), else the lightest vertices together until
+    they weigh enough (a multi-start row: the scan road)."""
+    degc = np.asarray(g["_host"]["degc"])[:g["n"]].astype(np.int64)
+    reach = (adj > 0).astype(np.int64)
+    mass2 = reach @ degc
+    alone = np.flatnonzero((degc > 0) & (mass2 > low) & (mass2 <= high))
+    if alone.size:
+        return [int(alone[0])]
+    order = [v for v in np.argsort(mass2, kind="stable") if degc[v] > 0]
+    for k in range(2, len(order)):
+        nbrs = np.asarray(reach[order[:k]].sum(axis=0)).ravel() > 0
+        if low < int(degc[nbrs].sum()) <= high:
+            return [int(v) for v in order[:k]]
+    raise AssertionError(f"no starts weigh ({low}, {high}]")
+
+
+@pytest.mark.parametrize("rung", [2, 3, 4])
+def test_a_query_on_a_rung_between_middle_and_top(graph, served, rung):
+    """The rungs ISSUE 31 brought, 2^1 to 2^3 above the middle one: a
+    2-hop query whose second level lands there is pushed on that rung,
+    not on the top one, answers what the plain reference counts, and
+    finds its executables built."""
+    _snap, g, adj = graph
+    sched, prof, ask = served
+    caps = bh._td_caps(g)
+    assert len(caps) == 6
+    vs = starts_whose_second_level_weighs(g, adj, caps[rung - 1],
+                                          caps[rung])
+    built = prof.compiles()
+    resp = ask(vs)
+    assert prof.compiles() == built
+    level2 = [s.attrs for s in sched.tracer.spans(resp["batch"])
+              if s.name == "bfs.sweep" and s.attrs["level"] == 2]
+    assert [(a["dir"], a["p_cap"]) for a in level2] == [("td", caps[rung])]
+    assert caps[rung - 1] < level2[0]["mass"] <= caps[rung]
+    seed = sp.csr_matrix((np.ones(len(vs), np.int64),
+                          (np.zeros(len(vs), np.int64), vs)),
+                         shape=(1, g["n"]))
+    hop = adj.astype(np.int64)
+    want = ((seed @ hop).astype(bool).astype(np.int64) @ hop) \
+        .astype(bool).sum()
+    assert resp["result"] == int(want) > 0
+
+
 # -- what one level's program hands the next (ISSUE 29) ---------------------
 
 @pytest.fixture
@@ -370,12 +529,12 @@ def small_graph_lists(monkeypatch):
 
 
 def test_the_hand_on_rule():
-    """At the benchmark's scale the lowest rung hands on and the two
+    """At the benchmark's scale the lowest rung hands on and the five
     above it do not (PERF.md 6, PR 29: rung 2^17 carried 54.5 ms against
     19.5 + a 6 ms listing)."""
     n = 1 << 20
-    assert [bh._td_lists(cap, n) for cap in (1 << 12, 1 << 17, 1 << 21)] \
-        == [True, False, False]
+    caps = bh._td_caps({"q_total": 4_563_401})
+    assert [bh._td_lists(cap, n) for cap in caps] == [True] + [False] * 5
     assert bh._td_lists(1 << 14, n) and not bh._td_lists(1 << 15, n)
 
 
@@ -438,6 +597,11 @@ def test_mixed_depths_mask_without_a_replan(graph, monkeypatch,
     _snap, g, adj = graph
     depths = [3, 1, 2, 2, 1, 1] + [0] * 10
     srcs = light(g, adj, 6) + [0] * 10
+    # one rung, so that the list L2 hands on (the pairs of three members)
+    # fits the rung of L3 (the mass of one): a list past its rung is
+    # test_a_list_past_its_room_is_scanned_for's
+    top = bh._td_caps(g)[-1]
+    monkeypatch.setattr(bh, "_td_caps", lambda _g: (top,))
 
     def on_level(level, _nf):
         keep = np.asarray([level <= d for d in depths])

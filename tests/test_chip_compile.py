@@ -117,13 +117,16 @@ def test_batched_bfs_top_down(spec, k, expand):
     """Every rung of the push ladder, as the lane (16 fused hops
     queries, and the one query of a median batch) and the job batcher
     (8 BFS jobs) call it: the benchmark must not be the first to show
-    the chip's compiler a rung. Each takes the frontier as a pair list
-    and the lowest rung holds the claim dedup that hands on the next."""
+    the chip's compiler a rung, the four a factor of two apart between
+    the middle and the top among them. Each takes the frontier as a pair
+    list and the lowest rung holds the claim dedup that hands on the
+    next."""
     from titan_tpu.models.bfs_hybrid import (_batched_td, _td_caps,
                                              _td_lists)
 
     caps = _td_caps({"q_total": Q})
-    assert [_td_lists(p_cap, N) for p_cap in caps] == [True, False, False]
+    assert caps == (1 << 12, 1 << 17, 1 << 18, 1 << 19, 1 << 20, 1 << 21)
+    assert [_td_lists(p_cap, N) for p_cap in caps] == [True] + [False] * 5
     for p_cap in caps:
         _compile(_batched_td(), spec((k, N + 1), jnp.int32),
                  spec((caps[-1],), jnp.int32), spec((caps[-1],), jnp.int32),

@@ -92,12 +92,21 @@ END_P_CAP = 1 << 22
 # FIXED ladder, not a power of two of the level's mass: every cap is an
 # executable of its own, and a cap minted inside a served window is a
 # 7-25 s stall (PERF.md 5, PR 25). A level whose frontier chunk mass
-# passes the top rung goes bottom-up. At scale 20 (4.56 M / 4.65 M
-# columns) the rungs are 2^12, 2^17 and 2^21 (CPU count, PR 26, the
-# benchmark's 256 starts a cell): 2^12 holds every Urand level and every
-# L1; 2^17 the median 2-hop Kron query (1,761 chunks a start, 77,627 at
-# p95); 2^21 the heaviest 16-query Kron batch (1.47 M chunks).
-TD_RUNG_SHIFTS = (9, 4, 0)
+# passes the top rung goes bottom-up. A push costs its RUNG, not its
+# frontier (one v5e, K = 1, a list in hand, PERF.md 6, PRs 29 and 31):
+# 2.5 ms on 2^12, 16.9 on 2^17, 31.8 on 2^18, 61.5 on 2^19, 122.4 on
+# 2^20, 248.4 on 2^21, linear in ``p_cap`` from the middle rung up. So
+# from the middle to the top the rungs stand a factor of two apart: no
+# such level pays for more than twice its mass. Below the middle the
+# dearest rounding-up is 14 ms, less than a query's fixed 19, and a
+# rung is five executables (one a padded batch size), so there the gap
+# stays. At scale 20 (4.56 M / 4.65 M columns; CPU count, PR 31, the
+# second level of the benchmark's 256 starts a cell): 2^12 holds every
+# Urand level, every L1 and 165 Kron starts; 2^17 holds 80 (the p95
+# start weighs 77,627 columns); 2^18 ten (150,732-193,702) and 2^20
+# one (892,911), which all paid for 2^21 before; 2^19 none; 2^21 the
+# heaviest 16-query Kron batch (1.47 M chunks).
+TD_RUNG_SHIFTS = (9, 4, 3, 2, 1, 0)
 # direction rule (e): a level goes top-down while
 #   mass * TD_BU_COST <= BU_CHUNK_ROUNDS * c_count
 # — one pushed chunk column against one candidate-round of the
