@@ -169,6 +169,28 @@ def test_frontier_push_list_sssp(spec):
              f_cap=N, p_cap=1 << 22, n_=N, masked=False)
 
 
+# LDBC Graphalytics graph500-22 as the benchmark's generator makes it
+# (benchmark/configs/graphalytics-g500-22.json; CPU count, PR 33): the
+# shapes of the cell g500-22.pr-c2, whose job is these two executables
+N22 = 2_396_390
+Q22 = 17_447_196
+
+
+def test_pagerank_job_at_graph500_22(spec):
+    from titan_tpu.models.frontier import (DENSE_WINDOW, _pr_finish,
+                                           _pr_window)
+
+    win = _compile(_pr_window(), spec((N22 + 1,), jnp.float32),
+                   spec((N22 + 1,), jnp.float32), spec((), jnp.int32),
+                   spec((8, Q22), jnp.int32), spec((Q22,), jnp.int32),
+                   W=DENSE_WINDOW)
+    _compile(_pr_finish(), spec((N22 + 1,), jnp.float32),
+             spec((N22 + 1,), jnp.float32), spec((N22 + 1,), jnp.float32),
+             spec((), jnp.float32), n_=N22)
+    # the window's scratch beside the 0.63 GB image: well inside 16 GB
+    assert win.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
 @pytest.mark.parametrize("rows", [None, 1], ids=["pagerank", "ppr"])
 def test_pagerank_window(spec, rows):
     from titan_tpu.models.frontier import _pr_window

@@ -238,3 +238,90 @@ def test_tenant_wire_quota_429_and_tenant_slo_endpoints(served):
     assert s["slo"] == "flood-avail" and s["tenant"] == "flood"
     assert s["sli"]["ok"] is True
     assert s["windows"]["300s"]["burn_rate"] == 0.0
+
+
+# -- the result plane: GET /jobs/<id>/result/<name> (ISSUE 33) ---------------
+
+def _raw(srv, path):
+    """(status, headers, body bytes) of a GET, errors included."""
+    try:
+        with urllib.request.urlopen(
+                f"http://{srv.host}:{srv.port}{path}", timeout=30) as r:
+            return r.status, r.headers, r.read()
+    except urllib.error.HTTPError as e:
+        return e.code, e.headers, e.read()
+
+
+@pytest.mark.parametrize("kind,name", [("pagerank", "rank"),
+                                       ("sssp", "dist"),
+                                       ("wcc", "labels")])
+def test_result_plane_serves_the_array_bytes(served, kind, name):
+    """One path for every kind: the envelope describes the array, the
+    plane hands out its little-endian bytes, equal to the array the job
+    holds in-process."""
+    _, srv = served
+    code, body = _req(srv, "/jobs",
+                      {"kind": kind, "source_dense": 0, "iterations": 5},
+                      method="POST")
+    assert code == 202
+    final = _poll(srv, body["job"])
+    assert final["status"] == "done", final
+    held = srv.scheduler().get(final["job"]).result[name]
+    assert final["arrays"][name] == {"dtype": held.dtype.name,
+                                     "shape": list(held.shape)}
+    assert name not in final["result"]          # scalars only, as before
+    code, headers, raw = _raw(srv, f"/jobs/{final['job']}/result/{name}")
+    assert code == 200
+    assert headers["Content-Type"] == "application/octet-stream"
+    assert headers["X-Dtype"] == held.dtype.name
+    assert headers["X-Shape"] == ",".join(str(d) for d in held.shape)
+    assert int(headers["Content-Length"]) == held.nbytes == len(raw)
+    got = np.frombuffer(raw, np.dtype(headers["X-Dtype"]).newbyteorder("<"))
+    assert got.tobytes() == np.ascontiguousarray(held).tobytes()
+
+
+def test_result_plane_404_and_409_bodies(served):
+    g, srv = served
+    # unknown job
+    code, _, raw = _raw(srv, "/jobs/nope/result/rank")
+    assert code == 404 and json.loads(raw)["type"] == "NotFound"
+    # a job that is not DONE (paused scheduler: it stays QUEUED)
+    srv._scheduler = JobScheduler(graph=g, metrics=MetricManager(),
+                                  autostart=False)
+    code, body = _req(srv, "/jobs", {"kind": "pagerank", "iterations": 2},
+                      method="POST")
+    assert code == 202
+    jid = body["job"]
+    code, _, raw = _raw(srv, f"/jobs/{jid}/result/rank")
+    err = json.loads(raw)
+    assert code == 409 and err["type"] == "Conflict"
+    assert err["status"] == "queued" and err["retryable"] is True
+    # a cancelled job is terminal and never DONE: 409, not retryable
+    code, body = _req(srv, "/jobs", {"kind": "pagerank"}, method="POST")
+    gone = body["job"]
+    assert _req(srv, f"/jobs/{gone}", method="DELETE")[0] == 200
+    code, _, raw = _raw(srv, f"/jobs/{gone}/result/rank")
+    err = json.loads(raw)
+    assert code == 409 and err["status"] == "cancelled"
+    assert err["retryable"] is False
+    srv._scheduler.start()
+    assert _poll(srv, jid)["status"] == "done"
+    # a name the result does not hold: a scalar is not an array either
+    for name in ("labels", "iterations", ""):
+        code, _, raw = _raw(srv, f"/jobs/{jid}/result/{name}")
+        assert code == 404 and json.loads(raw)["type"] == "NotFound", name
+
+
+def test_to_wire_describes_arrays_and_keeps_scalars():
+    from titan_tpu.olap.api import JobSpec
+    from titan_tpu.olap.serving.jobs import Job
+
+    job = Job(JobSpec(kind="pagerank"))
+    assert "arrays" not in job.to_wire()
+    job.start()
+    job.complete({"iterations": 3, "rank": np.zeros((2, 5), np.float32),
+                  "names": ["a"]})
+    w = job.to_wire()
+    assert w["result"] == {"iterations": 3, "names": ["a"]}
+    assert w["arrays"] == {"rank": {"dtype": "float32", "shape": [2, 5]}}
+    json.dumps(w)                                # JSON-safe

@@ -999,6 +999,9 @@ def pagerank_dense(snap_or_graph, iterations: int = 20,
     bit-equal to, per source row."""
     import jax.numpy as jnp
 
+    from titan_tpu.obs import devprof
+    from titan_tpu.obs.tracing import phase
+
     # an explicitly passed view (the serving lease's, frozen at the
     # job's epoch) overrides the snapshot's latest attached view — the
     # scheduler compacts before leasing for this kind, so its view is
@@ -1023,6 +1026,7 @@ def pagerank_dense(snap_or_graph, iterations: int = 20,
     colowner = _colowner(g)
     total = g["q_total"]
     W = min(DENSE_WINDOW, total)
+    windows = -(-total // W)
     win = _pr_window()
     reset_dev = None
     if reset is not None:
@@ -1043,27 +1047,39 @@ def pagerank_dense(snap_or_graph, iterations: int = 20,
             .at[n].set(0.0)
     contrib = jnp.where(deg > 0, rank / jnp.maximum(deg, 1.0), 0.0)
     it = it0
+    # leaf phases (obs/tracing): spans under the caller's scope — the
+    # job's `run` — and profiler annotations. Nothing here waits for
+    # the device: with tol=None the loop only dispatches, and what it
+    # dispatched drains inside `pr.result`'s one blocking readback
     for it in range(it0 + 1, iterations + 1):
         if on_round is not None and not on_round(it - 1):
             raise RoundInterrupted(it - 1)
-        acc = jnp.zeros((n + 1,), jnp.float32)
-        for w0 in range(0, total, W):
-            # pooled window starts: a fresh scalar put per window costs
-            # a host↔device round trip (64 windows/iteration at scale 26)
-            acc = win(acc, contrib, dev_scalar(w0), dstT, colowner, W=W)
-        if reset_dev is None:
-            rank, contrib, delta = fin(acc, rank, deg,
-                                       jnp.float32(damping), n_=n)
-        else:
-            rank, contrib, delta = fin(acc, rank, reset_dev, deg,
-                                       jnp.float32(damping), n_=n)
+        with phase("pr.sweep", it=it, windows=windows):
+            acc = jnp.zeros((n + 1,), jnp.float32)
+            for w0 in range(0, total, W):
+                # pooled window starts: a fresh scalar put per window
+                # costs a host↔device round trip (64 windows/iteration
+                # at scale 26)
+                acc = win(acc, contrib, dev_scalar(w0), dstT, colowner,
+                          W=W)
+        with phase("pr.finish", it=it):
+            if reset_dev is None:
+                rank, contrib, delta = fin(acc, rank, deg,
+                                           jnp.float32(damping), n_=n)
+            else:
+                rank, contrib, delta = fin(acc, rank, reset_dev, deg,
+                                           jnp.float32(damping), n_=n)
+        devprof.count_pr_iteration()
         if checkpoint is not None:
             checkpoint(it, {"rank": rank})
         if tol is not None and float(delta) < tol:
             break
     out = rank[:n]
     if not return_device:
-        out = np.asarray(out)
+        with phase("pr.result", bytes=int(out.nbytes)) as ph:
+            devprof.count_d2h("pagerank.result", out.nbytes)
+            with ph.sync():
+                out = np.asarray(out)
     return out, it
 
 

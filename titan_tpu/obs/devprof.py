@@ -225,6 +225,13 @@ def count_level(direction: str, road: str) -> None:
                                      "list": road}).inc()
 
 
+def count_pr_iteration() -> None:
+    """Count one iteration of ``frontier.pagerank_dense`` (its window
+    sweeps and its finish dispatched)."""
+    for prof in list(_PROFILERS):
+        prof.metrics.counter("device.pr.iterations").inc()
+
+
 def current() -> Optional["DeviceCostProfiler"]:
     """The most recently installed profiler, or None."""
     return _PROFILERS[-1] if _PROFILERS else None

@@ -21,10 +21,12 @@ distances via ``params['targets']``.
 from __future__ import annotations
 
 import time
+from contextlib import nullcontext
 from typing import Optional
 
 import numpy as np
 
+from titan_tpu.obs.tracing import scope
 from titan_tpu.olap.serving.jobs import Job
 
 #: jobs of these kinds fuse into one batched run when they share a
@@ -648,13 +650,20 @@ class Batcher:
                 resume = None
                 if ck is not None:
                     resume = {"rank": ck.arrays["rank"], "it": ck.round}
-                rank, iters = pagerank_dense(
-                    snap, iterations=int(params.get("iterations", 20)),
-                    damping=float(params.get("damping", 0.85)),
-                    tol=params.get("tol"), on_round=on_round,
-                    checkpoint=ckpt, resume=resume, overlay=overlay)
-                job.complete({"iterations": int(iters),
-                              "rank": np.asarray(rank)})
+                # the sweep's leaf phases (pr.sweep, pr.finish,
+                # pr.result) journal under this job's `run` span; the
+                # readback is counted where it is made
+                # (device.xfer.d2h_bytes{site="pagerank.result"})
+                under_run = nullcontext() if h is None \
+                    else scope(h.tracer, h.trace_id, run_span)
+                with under_run:
+                    rank, iters = pagerank_dense(
+                        snap,
+                        iterations=int(params.get("iterations", 20)),
+                        damping=float(params.get("damping", 0.85)),
+                        tol=params.get("tol"), on_round=on_round,
+                        checkpoint=ckpt, resume=resume, overlay=overlay)
+                job.complete({"iterations": int(iters), "rank": rank})
             elif kind == "wcc":
                 from titan_tpu.models.frontier import frontier_wcc
                 ckpt = None

@@ -35,6 +35,8 @@ import threading
 import time
 from typing import Any, Optional
 
+import numpy as np
+
 from titan_tpu.olap.api import JobSpec
 
 
@@ -231,7 +233,9 @@ class Job:
         return self.finished_at - self.started_at
 
     def to_wire(self) -> dict:
-        """JSON-safe summary (large result arrays omitted)."""
+        """JSON-safe summary: large result arrays are left out of
+        ``result`` and described under ``arrays`` (dtype and shape of
+        each), to be fetched over the result plane."""
         out: dict[str, Any] = {
             "job": self.id,
             "kind": self.spec.kind,
@@ -272,6 +276,11 @@ class Job:
                 k: v for k, v in self.result.items()
                 if isinstance(v, (int, float, str, bool, list, dict))
                 or v is None}
+            arrays = {k: {"dtype": v.dtype.name, "shape": list(v.shape)}
+                      for k, v in self.result.items()
+                      if isinstance(v, np.ndarray)}
+            if arrays:
+                out["arrays"] = arrays
         return out
 
     def __repr__(self) -> str:

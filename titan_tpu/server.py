@@ -68,7 +68,20 @@ Endpoints:
                       {"enabled": false} without a live scheduler
   GET    /jobs/<id> — job status/result/metrics envelope (incl. attempt
                       / checkpoint_round / rounds_replayed / retry_at
-                      for jobs on the recovery plane)
+                      for jobs on the recovery plane). ``result``
+                      holds the scalars; the arrays a DONE job made
+                      (``dist``, ``labels``, ``rank``, ...) are
+                      described under ``arrays``: {"rank": {"dtype":
+                      "float32", "shape": [n]}}
+  GET    /jobs/<id>/result/<name> — the result plane: the array
+                      ``<name>`` of a DONE job as
+                      application/octet-stream, its little-endian
+                      bytes in C order, with ``X-Dtype`` (numpy's
+                      dtype name) and ``X-Shape`` (comma-separated)
+                      headers; one path for every kind. 404 JSON
+                      (type NotFound) for an unknown job or a name the
+                      result does not hold, 409 JSON (type Conflict,
+                      ``status``) while the job is not DONE
   DELETE /jobs/<id> — cancel (queued or retrying: immediate; running:
                       at the next level boundary via the per-job
                       early-exit mask)
@@ -497,6 +510,48 @@ class GraphServer:
                 self.end_headers()
                 self.wfile.write(body)
 
+            def _send_array(self, arr) -> None:
+                """One result array as its little-endian bytes, C order:
+                the buffer goes to the socket as it is, never through a
+                Python list."""
+                import numpy as np
+                arr = np.ascontiguousarray(arr).astype(
+                    arr.dtype.newbyteorder("<"), copy=False)
+                self.send_response(200)
+                self.send_header("Content-Type",
+                                 "application/octet-stream")
+                self.send_header("Content-Length", str(arr.nbytes))
+                self.send_header("X-Dtype", arr.dtype.name)
+                self.send_header("X-Shape",
+                                 ",".join(str(d) for d in arr.shape))
+                self.end_headers()
+                self.wfile.write(memoryview(arr).cast("B"))
+
+            def _send_job_result(self, job_id: str, name: str) -> None:
+                """The result plane: ``GET /jobs/<id>/result/<name>``."""
+                import numpy as np
+
+                from titan_tpu.olap.serving.jobs import JobState
+                job = server.scheduler().get(job_id)
+                if job is None:
+                    self._send(404, {"error": "unknown job",
+                                     "type": "NotFound",
+                                     "retryable": False})
+                elif job.state is not JobState.DONE:
+                    self._send(409, {
+                        "error": f"job {job.id} is "
+                                 f"{job.state.value}, not done",
+                        "type": "Conflict", "status": job.state.value,
+                        "retryable": not job.state.terminal})
+                elif not isinstance(
+                        arr := (job.result or {}).get(name), np.ndarray):
+                    self._send(404, {
+                        "error": f"job {job.id} has no result array "
+                                 f"{name!r}",
+                        "type": "NotFound", "retryable": False})
+                else:
+                    self._send_array(arr)
+
             def _authorized(self) -> bool:
                 if server.auth_token is None:
                     return True
@@ -707,6 +762,11 @@ class GraphServer:
                         self._send(200, {"enabled": False})
                     else:
                         self._send(200, {"enabled": True, **slo})
+                elif self.path.startswith("/jobs/") \
+                        and "/result/" in self.path:
+                    job_id, _, name = \
+                        self.path[len("/jobs/"):].partition("/result/")
+                    self._send_job_result(job_id, name)
                 elif self.path.startswith("/jobs/"):
                     sched = server.scheduler()
                     job = sched.get(self.path[len("/jobs/"):])
