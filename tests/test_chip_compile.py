@@ -191,6 +191,24 @@ def test_pagerank_job_at_graph500_22(spec):
     assert win.memory_analysis().temp_size_in_bytes < 4 << 30
 
 
+def test_pagerank_pull_at_graph500_22(spec):
+    """The served job's iteration since ISSUE 35: the Pallas gather
+    with the 9.6 MB table in VMEM and 8,192 indices a grid step in
+    SMEM, the segment sum, the cut to [n]."""
+    from titan_tpu.models import pagerank_pull as pp
+    from titan_tpu.models.frontier import _pr_result
+
+    q_in = -(-Q22 // pp.PULL_BLOCK) * pp.PULL_BLOCK
+    pull = _compile(pp.pull_step(), spec((N22 + 1,), jnp.float32),
+                    spec((N22 + 1,), jnp.float32),
+                    spec((8 * q_in,), jnp.int32), spec((q_in,), jnp.bool_),
+                    spec((N22,), jnp.int32), spec((N22,), jnp.bool_),
+                    impl="vmem", seg_max=20_413)
+    assert "tpu_custom_call" in pull.as_text()
+    assert pull.memory_analysis().temp_size_in_bytes < 1 << 30
+    _compile(_pr_result(), spec((N22 + 1,), jnp.float32), n_=N22)
+
+
 @pytest.mark.parametrize("rows", [None, 1], ids=["pagerank", "ppr"])
 def test_pagerank_window(spec, rows):
     from titan_tpu.models.frontier import _pr_window

@@ -974,11 +974,17 @@ def pagerank_dense(snap_or_graph, iterations: int = 20,
                    return_device: bool = False, on_round=None,
                    checkpoint=None, resume: dict | None = None,
                    overlay=None, reset=None):
-    """Push-mode PageRank over the chunked CSR via dense window sweeps:
+    """PageRank over the chunked CSR:
     rank' = (1-d)/n + d * sum over in-edges of rank[src]/outdeg[src]
     (semantics match the pull-mode engine program in models/pagerank.py,
     incl. leaking dangling mass). Returns (rank float32 [n], iterations
-    run). ``tol``: early exit when the L1 delta falls below it.
+    run). The uniform iteration on a snapshot is a pull over its
+    in-edges, one program an iteration (``_pagerank_pull``,
+    models/pagerank_pull.py); a caller that hands the out-layout dict
+    has no in-edges to read, and the personalised iteration
+    (``reset``) is pinned to the batched kernel's bits: both push
+    through dense window sweeps, as below.
+    ``tol``: early exit when the L1 delta falls below it.
     ``on_round``: per-iteration veto (RoundInterrupted) — the serving
     layer's cancellation/timeout hook, same contract as
     ``_frontier_run``.
@@ -999,7 +1005,6 @@ def pagerank_dense(snap_or_graph, iterations: int = 20,
     bit-equal to, per source row."""
     import jax.numpy as jnp
 
-    from titan_tpu.obs import devprof
     from titan_tpu.obs.tracing import phase
 
     # an explicitly passed view (the serving lease's, frozen at the
@@ -1018,6 +1023,9 @@ def pagerank_dense(snap_or_graph, iterations: int = 20,
             "pagerank_dense on a live overlay: compact the overlay "
             "first (LiveGraphPlane.compact_if_dirty) — dense window "
             "sweeps have no overlay seam")
+    if reset is None and not isinstance(snap_or_graph, dict):
+        return _pagerank_pull(snap_or_graph, iterations, damping, tol,
+                              return_device, on_round, checkpoint, resume)
     g = snap_or_graph if isinstance(snap_or_graph, dict) \
         else build_chunked_csr(snap_or_graph)
     n = g["n"]
@@ -1046,14 +1054,9 @@ def pagerank_dense(snap_or_graph, iterations: int = 20,
         rank = jnp.full((n + 1,), 1.0 / n, jnp.float32) \
             .at[n].set(0.0)
     contrib = jnp.where(deg > 0, rank / jnp.maximum(deg, 1.0), 0.0)
-    it = it0
-    # leaf phases (obs/tracing): spans under the caller's scope — the
-    # job's `run` — and profiler annotations. Nothing here waits for
-    # the device: with tol=None the loop only dispatches, and what it
-    # dispatched drains inside `pr.result`'s one blocking readback
-    for it in range(it0 + 1, iterations + 1):
-        if on_round is not None and not on_round(it - 1):
-            raise RoundInterrupted(it - 1)
+
+    def step(it, rank):
+        nonlocal contrib
         with phase("pr.sweep", it=it, windows=windows):
             acc = jnp.zeros((n + 1,), jnp.float32)
             for w0 in range(0, total, W):
@@ -1069,18 +1072,100 @@ def pagerank_dense(snap_or_graph, iterations: int = 20,
             else:
                 rank, contrib, delta = fin(acc, rank, reset_dev, deg,
                                            jnp.float32(damping), n_=n)
+        return rank, delta
+
+    rank, it = _pr_loop(rank, it0, iterations, tol, on_round, checkpoint,
+                        step)
+    return _pr_readback(rank[:n], return_device), it
+
+
+def _pr_loop(rank, it0, iterations, tol, on_round, checkpoint, step):
+    """The iterations of ``pagerank_dense``, whichever sweep: ``step(it,
+    rank) -> (rank, delta)`` opens the leaf phases (obs/tracing: spans
+    under the caller's scope — the job's `run` — and profiler
+    annotations). Nothing here waits for the device: with tol=None the
+    loop only dispatches, and what it dispatched drains inside
+    `pr.result`'s one blocking readback."""
+    from titan_tpu.obs import devprof
+
+    it = it0
+    for it in range(it0 + 1, iterations + 1):
+        if on_round is not None and not on_round(it - 1):
+            raise RoundInterrupted(it - 1)
+        rank, delta = step(it, rank)
         devprof.count_pr_iteration()
         if checkpoint is not None:
             checkpoint(it, {"rank": rank})
         if tol is not None and float(delta) < tol:
             break
-    out = rank[:n]
+    return rank, it
+
+
+def _pr_readback(out, return_device: bool):
+    from titan_tpu.obs import devprof
+    from titan_tpu.obs.tracing import phase
+
     if not return_device:
         with phase("pr.result", bytes=int(out.nbytes)) as ph:
             devprof.count_d2h("pagerank.result", out.nbytes)
             with ph.sync():
                 out = np.asarray(out)
-    return out, it
+    return out
+
+
+def _pagerank_pull(snap, iterations, damping, tol, return_device,
+                   on_round, checkpoint, resume):
+    """The uniform iteration on a snapshot: one ``pagerank_pull`` and
+    one ``pagerank_finish`` an iteration (models/pagerank_pull.py), no
+    windows and no eager program — the start vector is made on the
+    host, ``contrib`` is computed from ``rank`` inside the pull (so a
+    resumed run is bit-equal to a straight one), the answer is cut to
+    [n] by ``pagerank_result``."""
+    import jax.numpy as jnp
+
+    from titan_tpu.models import pagerank_pull as pp
+    from titan_tpu.obs import devprof
+    from titan_tpu.obs.tracing import phase
+
+    im = pp.pull_image(snap)
+    n = im["n"]
+    impl = pp.gather_impl(n)
+    blocks = im["q_in"] // pp.PULL_BLOCK
+    pull, fin, cut = pp.pull_step(), _pr_finish(), _pr_result()
+    d = dev_scalar(float(damping), "float32")
+    it0 = 0
+    if resume is not None:
+        rank = jnp.asarray(np.asarray(resume["rank"], np.float32))
+        it0 = int(resume["it"])
+    else:
+        start = np.full(n + 1, 1.0 / n, np.float32)
+        start[n] = 0.0
+        rank = jnp.asarray(start)
+
+    def step(it, rank):
+        with phase("pr.sweep", it=it, windows=blocks, impl=impl):
+            acc = pull(rank, im["deg"], im["idx"], im["first"],
+                       im["last"], im["has"], impl=impl,
+                       seg_max=im["seg_max"])
+        devprof.count_pr_gather(impl, 8 * im["q_in"])
+        with phase("pr.finish", it=it):
+            rank, _contrib, delta = fin(acc, rank, im["deg"], d, n_=n)
+        return rank, delta
+
+    rank, it = _pr_loop(rank, it0, iterations, tol, on_round, checkpoint,
+                        step)
+    return _pr_readback(cut(rank, n_=n), return_device), it
+
+
+def _pr_result():
+    def build():
+        import jax
+
+        @functools.partial(jax.jit, static_argnames=("n_",))
+        def cut(rank, n_: int):
+            return rank[:n_]
+        return cut
+    return jit_once("pagerank_result", build)
 
 
 def _pr_window():

@@ -226,10 +226,20 @@ def count_level(direction: str, road: str) -> None:
 
 
 def count_pr_iteration() -> None:
-    """Count one iteration of ``frontier.pagerank_dense`` (its window
-    sweeps and its finish dispatched)."""
+    """Count one iteration of ``frontier.pagerank_dense`` (its sweep
+    and its finish dispatched)."""
     for prof in list(_PROFILERS):
         prof.metrics.counter("device.pr.iterations").inc()
+
+
+def count_pr_gather(impl: str, lanes: int) -> None:
+    """Count the lanes one iteration of the uniform PageRank pull
+    gathered (8 x the pull image's columns, pad lanes included) by what
+    served them: ``"vmem"`` the Pallas kernel's table, ``"xla"`` XLA's
+    gather (models/pagerank_pull.gather_impl)."""
+    for prof in list(_PROFILERS):
+        prof.metrics.counter("device.pr.gather_lanes",
+                             labels={"impl": impl}).inc(int(lanes))
 
 
 def current() -> Optional["DeviceCostProfiler"]:

@@ -34,6 +34,17 @@ def snapshot_csr_bytes(snap) -> int:
     return chunked_csr_bytes(snap.n, q_total)
 
 
+def snapshot_pull_bytes(snap) -> int:
+    """Predicted device bytes of a snapshot's PageRank pull image
+    (models/pagerank_pull.pull_image: the in-edge ``srcT``, a flag a
+    column, a few words a vertex), sized
+    from the in-degrees BEFORE the build. A ``pagerank`` job reserves it
+    beside the forward image."""
+    from titan_tpu.models.pagerank_pull import (pull_columns,
+                                                pull_image_bytes)
+    return pull_image_bytes(snap.n, pull_columns(snap.indptr_in, snap.n))
+
+
 def meshed_snapshot_csr_bytes(snap, num_devices: int) -> int:
     """PER-DEVICE bytes of a MESH-PLACED chunked CSR (ISSUE 13,
     ``parallel/partition.place_batched_csr``): the ``dstT`` edge image

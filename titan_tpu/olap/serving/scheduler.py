@@ -98,7 +98,8 @@ from titan_tpu.olap.api import JobSpec
 from titan_tpu.olap.serving.batcher import Batcher, batch_key
 from titan_tpu.olap.serving.hbm import (DEFAULT_BUDGET_BYTES,
                                         AdmissionError, HBMLedger,
-                                        snapshot_csr_bytes)
+                                        snapshot_csr_bytes,
+                                        snapshot_pull_bytes)
 from titan_tpu.olap.serving.jobs import Job, JobState
 from titan_tpu.olap.serving.pool import SnapshotPool
 from titan_tpu.olap.serving.tenants import (QuotaExceeded,
@@ -388,14 +389,15 @@ class JobScheduler:
     def _forget_snapshot(self, snap) -> None:
         """Pool close hook: a retired/rebuilt snapshot leaves the HBM
         ledger (and the evictable map) instead of counting as resident
-        forever — including the interactive lane's reversed-orientation
-        layout riding on the same snapshot."""
+        forever — including the layouts riding on the same snapshot
+        (the interactive lane's reversed orientation, PageRank's pull
+        image)."""
         key = id(snap)
         self._evictable.pop(key, None)
         self.ledger.release(key)
-        rev_key = ("interactive-rev", key)
-        self._evictable.pop(rev_key, None)
-        self.ledger.release(rev_key)
+        for rider in (("interactive-rev", key), ("pagerank-pull", key)):
+            self._evictable.pop(rider, None)
+            self.ledger.release(rider)
 
     # -- submission surface --------------------------------------------------
 
@@ -1013,13 +1015,29 @@ class JobScheduler:
                     snap, int(self.mesh.devices.size))
             else:
                 nbytes = snapshot_csr_bytes(snap)
+            # a `pagerank` job reads a second image: the in-edge pull
+            # image of models/pagerank_pull, under a key of its own so
+            # that a snapshot already resident for other kinds is not
+            # taken to hold it
+            images = [(ledger_key, nbytes, snap)]
+            if spec.kind == "pagerank":
+                pull_bytes = snapshot_pull_bytes(snap)
+                images.append((("pagerank-pull", ledger_key), pull_bytes,
+                               (snap, "_pull_csr")))
+                nbytes += pull_bytes
+            held = []
             try:
-                self.ledger.reserve(ledger_key, nbytes)
+                for key, image_bytes, _handle in images:
+                    self.ledger.reserve(key, image_bytes)
+                    held.append(key)
             except AdmissionError as e:
+                for key in held:
+                    self.ledger.unpin(key)
                 for job in group:
                     job.fail(str(e))
                 return
-            self._evictable.setdefault(ledger_key, snap)
+            for key, _bytes, handle in images:
+                self._evictable.setdefault(key, handle)
             # the batch shares one graph image: its ledger bytes are
             # held against each member's tenant (per-K share) for the
             # duration of the run — the live view max_hbm_bytes quotas
@@ -1043,7 +1061,8 @@ class JobScheduler:
                 for job in group:
                     self.tenants.drop_hbm(job.tenant, share)
                 self._attribute(group, wall, nbytes)
-                self.ledger.unpin(ledger_key)
+                for key in held:
+                    self.ledger.unpin(key)
                 if w is not None:
                     self._stitch_device_cost(group, w.close())
                 if self.recorder is not None:

@@ -59,13 +59,17 @@ def segment_metadata(indptr: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return last_idx, seg_has
 
 
-def seg_scan(values, flags, combine: str):
+def seg_scan(values, flags, combine: str, max_len: Optional[int] = None):
     """Inclusive segmented scan (Hillis-Steele): ``flags[i]`` marks the first
     element of a segment; returns per-position running combine within the
-    segment. log₂(E) vectorized passes; everything static-shaped."""
+    segment. log₂(E) vectorized passes; everything static-shaped.
+    ``max_len``: the longest segment, where the caller knows it — the
+    passes stop at log₂ of it."""
     op = _COMBINE_FN[combine]
     ident = combine_identity(combine, values.dtype)
     e = values.shape[0]
+    if max_len is not None:
+        e = min(e, max_len)
     d = 1
     while d < e:
         pv = jnp.concatenate([jnp.full((d,), ident, values.dtype), values[:-d]])
