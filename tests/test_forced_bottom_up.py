@@ -1,9 +1,11 @@
 """The bottom-up (pull) rounds, forced at toy scale, against the plain
 reference.
 
-No benchmark cell pulls a level (PERF.md 7: "unverified by any cell"),
-so the five seams of the bottom-up chunk round are held here, on the
-CPU: the single-source chain (opener, split-lane opener, chunk rounds,
+One benchmark cell pulls a level, through the single-source chain's
+split-lane opener and one chunk round (`g500-24.wcc-c2`, PR 36); the
+exhaust and every batched seam are "unverified by any cell" (PERF.md
+7), so the five seams of the bottom-up chunk round are held here, on
+the CPU: the single-source chain (opener, split-lane opener, chunk rounds,
 exhaust), the batched round, the batched round under a tombstone
 overlay, the batched round under per-level slot masks (hops mode), and
 the sharded level with its dispatch budget. Each is driven down the
@@ -75,6 +77,24 @@ def test_plain_bottom_up_matches_reference(seed, force_bottom_up):
     ran = prof.kernel_stats()
     assert {"hybrid_bu_startL", "hybrid_bu_finish0"} <= set(ran), sorted(ran)
     assert np.array_equal(dist, frontier_bfs(snap, src)[0])
+
+
+@pytest.mark.parametrize("n", [5, 32766, 32767, 70000])
+def test_the_frontier_bitmap_in_planes_reads_back_every_vertex(n):
+    """``_pack_bits`` + ``_fbit_of`` (the single-source family's bitmap,
+    in planes since PR 36) against the mask itself, at every id up to the
+    pad vertex n+1: a plane's last byte, a width of exactly one tile
+    (n + 2 = 8 x FBITS_ALIGN) and the first id past it."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(n)
+    dist = np.where(rng.random(n + 2) < 0.3, 7, 3).astype(np.int32)
+    dist[n:] = 0                        # the pad vertices are never in
+    fbits = H._pack_bits(jnp.asarray(dist), jnp.int32(7), n)
+    assert fbits.shape == (H._fbits_width(n),) and fbits.dtype == jnp.uint8
+    ids = jnp.arange(n + 2, dtype=jnp.int32)[None, :]
+    got = np.asarray(H._fbit_of(fbits, ids)).reshape(-1)
+    assert np.array_equal(got, dist == 7)
 
 
 @pytest.mark.parametrize("seed,K", BATCHES)

@@ -247,14 +247,51 @@ def enumerate_chunk_pairs(valid, counts, colstarts, p_cap: int, q_pad: int,
     return cols, p_total, owner
 
 
+#: the frontier bitmap's plane width is a multiple of this many bytes,
+#: so each plane starts on a tile of the chip's memory layout
+FBITS_ALIGN = 4096
+
+
+def _fbits_width(n_: int) -> int:
+    return -(-(n_ + 2) // (8 * FBITS_ALIGN)) * FBITS_ALIGN
+
+
 def _pack_bits(dist, level, n_: int):
-    """Frontier bitmap: bit v = (dist[v] == level), little-endian within
-    bytes, sized to cover index n_+1 (the pad vertex, always 0)."""
+    """Frontier bitmap over vertices 0 .. n_+1 (the pad vertex, always
+    0), in PLANES: with W = ``_fbits_width(n_)`` bytes, vertex v is bit
+    v // W of byte v % W, so byte w is built from eight CONTIGUOUS
+    slices of the mask — elementwise, nothing moves across lanes. Read
+    it with ``_fbit_of`` alone (``_bit_of`` reads the byte-major layout
+    of the host's tombstone bits).
+
+    Why not ``jnp.packbits``: its ``[nbytes, 8]`` reshape takes the
+    chip's compiler 100 s at n = 8.9 M in every executable that packs,
+    which made a cold replica's first graph500-24 job outlast a 300 s
+    timeout; the same byte-major bits from eight STRIDED slices compile
+    in a second and run 86 ms a pack, where a plane pack runs 2 ms
+    (PERF.md 6, PR 36)."""
     import jax.numpy as jnp
 
-    nbytes = (n_ + 2 + 7) // 8
-    mask = jnp.concatenate([dist == level, jnp.zeros((8,), bool)])
-    return jnp.packbits(mask[:nbytes * 8], bitorder="little")
+    w = _fbits_width(n_)
+    mask = jnp.concatenate(
+        [dist == level, jnp.zeros((8 * w - dist.shape[0],), bool)])
+    planes = mask.reshape(8, w).astype(jnp.uint8)
+    out = planes[0]
+    for k in range(1, 8):
+        out = out | (planes[k] << jnp.uint8(k))
+    return out
+
+
+def _fbit_of(fbits, idx):
+    """Test ``_pack_bits``' bitmap at int32 vertex ids 0 .. n_+1 (any
+    shape). The plane is seven compares, not a division: beside the
+    gather they cost nothing."""
+    import jax.numpy as jnp
+
+    w = fbits.shape[0]
+    plane = sum((idx >= k * w).astype(jnp.int32) for k in range(1, 8))
+    byte = jnp.take(fbits, idx - plane * w)
+    return ((byte >> plane.astype(jnp.uint8)) & jnp.uint8(1)).astype(bool)
 
 
 def _bit_of(fbits, idx):
@@ -419,7 +456,7 @@ def _bu_start():
             v = jnp.minimum(cand, n_)
             cols = jnp.where(alive, colstart[v], q_pad)
             parents = jnp.take(dstT, jnp.clip(cols, 0, q_pad), axis=1)
-            hit = _bit_of(fbits, parents)
+            hit = _fbit_of(fbits, parents)
             found = alive & hit.any(axis=0)
             dist = dist.at[jnp.where(found, v, n_ + 1)].set(
                 level + 1, mode="drop")
@@ -523,7 +560,7 @@ def _bu_startL():
             cols = jnp.where(alive, csf & 0x7FFFFFFF, q_pad)
             parentsL = jnp.take(dstT[:lanes], jnp.clip(cols, 0, q_pad),
                                 axis=1)
-            hitL = _bit_of(fbits, parentsL)
+            hitL = _fbit_of(fbits, parentsL)
             found = alive & hitL.any(axis=0)
             dist = dist.at[jnp.where(found, v, n_ + 1)].set(
                 level + 1, mode="drop")
@@ -574,7 +611,7 @@ def _bu_finish_chunk0():
             cols = jnp.where(alive, colstart[v], q_pad)
             parents_hi = jnp.take(dstT, jnp.clip(cols, 0, q_pad),
                                   axis=1)
-            found = alive & _bit_of(fbits, parents_hi).any(axis=0)
+            found = alive & _fbit_of(fbits, parents_hi).any(axis=0)
             dist = dist.at[jnp.where(found, v, n_ + 1)].set(
                 level + 1, mode="drop")
             surv = alive & ~found & (degc[v] > 1)
@@ -623,7 +660,7 @@ def _bu_more():
                 cols = jnp.where(alive, colstart[v] + off, q_pad)
                 parents = jnp.take(dstT, jnp.clip(cols, 0, q_pad),
                                    axis=1)
-                hit = _bit_of(fbits, parents)
+                hit = _fbit_of(fbits, parents)
                 found = alive & hit.any(axis=0)
                 dist = dist.at[jnp.where(found, v, n_ + 1)].set(
                     level + 1, mode="drop")
@@ -671,7 +708,7 @@ def _bu_exhaust():
                 valid, rem, colstart[v] + off, p_cap, dstT.shape[1] - 1,
                 with_owner=True)
             parents = jnp.take(dstT, cols, axis=1)       # [8, p_cap]
-            hit = _bit_of(fbits, parents).any(axis=0)    # [p_cap]
+            hit = _fbit_of(fbits, parents).any(axis=0)    # [p_cap]
             # per-candidate any-hit: scatter-max of hit through the
             # pair -> candidate mapping
             j = jnp.arange(p_cap, dtype=jnp.int32)
@@ -720,7 +757,7 @@ def _endgame():
                     valid, degc[v], colstart[v], p_cap, q_pad,
                     with_owner=True)
                 parents = jnp.take(dstT, cols, axis=1)
-                hit = _bit_of(fbits, parents).any(axis=0)
+                hit = _fbit_of(fbits, parents).any(axis=0)
                 j = jnp.arange(p_cap, dtype=jnp.int32)
                 found_per = jnp.zeros((c_cap,), jnp.int32) \
                     .at[jnp.where(j < p_total, owner, c_cap - 1)] \
@@ -1718,17 +1755,39 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
                 [a, jnp.full((cap_n - a.shape[0],), n, a.dtype)])
         return a
 
+    from titan_tpu.obs import devprof
+    from titan_tpu.obs.tracing import phase
     from titan_tpu.utils.jitcache import dev_scalar
+
+    def stats_of(ph, dev):
+        # the host step's one blocking readback, timed into its phase
+        with ph.sync():
+            got = np.atleast_1d(np.asarray(dev))
+        devprof.count_d2h("bfs.stats", got.nbytes)
+        return [int(x) for x in got]
+
+    # One `bfs.level` phase a host step (obs/tracing: a leaf span under
+    # the caller's scope and a profiler annotation): `dir` is what the
+    # step ran (`head`: the fused early top-down levels; `td`; `bu`: one
+    # bottom-up level, opener to exhaust; `end`: every trailing level in
+    # one dispatch), beside the caps it ran under and `sync_ms`; a `bu`
+    # step also says what its later programs were sized from (`missed`
+    # the split opener's first lanes, `left` the opener, `exhaust` and
+    # `rem8` the chunk rounds: candidates and their unread chunks).
+    # `device.bfs.levels{dir, list="single"}` counts the LEVELS a step
+    # covered, so a run's counts sum to the `levels` it returns.
 
     # ---- fused head: source + early top-down levels, one readback
     f_cap_h = min(HEAD_F_CAP, cap_n)
     p_cap_h = min(HEAD_P_CAP, _next_pow2(max(total_chunks + n, 2)))
-    dist, frontier, st_dev = head(dev_scalar(source_dense),
-                                  dev_scalar(max_levels), dstT, colstart,
-                                  degc, f_cap=f_cap_h, p_cap=p_cap_h,
-                                  n_=n)
-    f_count, m8_f, m8_unvis, n_unvis, level = \
-        (int(x) for x in np.asarray(st_dev))
+    with phase("bfs.level", level=0, dir="head", f_cap=f_cap_h,
+               p_cap=p_cap_h) as ph:
+        dist, frontier, st_dev = head(
+            dev_scalar(source_dense), dev_scalar(max_levels), dstT,
+            colstart, degc, f_cap=f_cap_h, p_cap=p_cap_h, n_=n)
+        f_count, m8_f, m8_unvis, n_unvis, level = stats_of(ph, st_dev)
+        ph.set(levels=level)
+    devprof.count_level("head", "single", level)
     # head refusal (source mass > p_cap_h) returns its initial state:
     # f_count=1, frontier=[source], level=0 — the main loop just takes over
     frontier = pad(frontier) if f_count <= f_cap_h else None
@@ -1738,86 +1797,102 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
         if n_unvis <= END_C_CAP and m8_unvis <= END_P_CAP:
             c_cap = _next_pow2(max(n_unvis, 2))
             p_cap = _next_pow2(max(m8_unvis, 2))
-            dist, iters = endgame(dist, dev_scalar(level),
-                                  dev_scalar(max_levels), dstT, colstart,
-                                  degc, c_cap=c_cap, p_cap=p_cap, n_=n)
-            # +1: the empty probe level, matching the host loop's count
-            level = min(level + int(np.asarray(iters)) + 1, max_levels)
+            with phase("bfs.level", level=level, dir="end", c_cap=c_cap,
+                       p_cap=p_cap) as ph:
+                dist, iters = endgame(
+                    dist, dev_scalar(level), dev_scalar(max_levels), dstT,
+                    colstart, degc, c_cap=c_cap, p_cap=p_cap, n_=n)
+                # +1: the empty probe level, matching the host loop's
+                # count
+                ran = min(stats_of(ph, iters)[0] + 1, max_levels - level)
+                ph.set(levels=ran)
+            devprof.count_level("end", "single", ran)
+            level += ran
             break
 
         use_bu = m8_f * ALPHA > m8_unvis and f_count > 1
         if not use_bu:
             if m8_f == 0:
                 break
-            if frontier is None:      # after bottom-up / head overflow
-                frontier = pad(frontier_of(dist, dev_scalar(level),
-                                           n_=n))
             f_cap = min(_next_pow2(max(f_count, 2)), cap_n)
             p_cap = min(_next_pow2(max(m8_f, 2)),
                         _next_pow2(max(total_chunks + n, 2)))
-            dist, st_dev = td(
-                dist, frontier[:f_cap], st_dev,
-                dev_scalar(level), dstT, colstart, degc,
-                f_cap=f_cap, p_cap=p_cap, n_=n)
-            # the td kernel no longer builds the next frontier list —
-            # the lazy frontier_of path at the top of this branch
-            # materializes it only if the next level stays top-down
-            frontier = None
-            f_count, m8_f, m8_unvis, n_unvis = \
-                (int(x) for x in np.asarray(st_dev))
+            with phase("bfs.level", level=level, dir="td", f_cap=f_cap,
+                       p_cap=p_cap) as ph:
+                if frontier is None:  # after bottom-up / head overflow
+                    frontier = pad(frontier_of(dist, dev_scalar(level),
+                                               n_=n))
+                dist, st_dev = td(
+                    dist, frontier[:f_cap], st_dev,
+                    dev_scalar(level), dstT, colstart, degc,
+                    f_cap=f_cap, p_cap=p_cap, n_=n)
+                # the td kernel no longer builds the next frontier list
+                # — the lazy frontier_of path at the top of this branch
+                # materializes it only if the next level stays top-down
+                frontier = None
+                f_count, m8_f, m8_unvis, n_unvis = stats_of(ph, st_dev)
+            devprof.count_level("td", "single")
         else:
             c_cap = min(_next_pow2(max(n_unvis, 2)), cap_n)
-            if c_cap >= SPLIT_LANE_MIN:
-                # split-lane opener: SPLIT_LANES-wide test over
-                # everyone, then the remaining lanes only for the
-                # minority that missed (host-sized)
-                dist, fbits, cand, prog, st_dev = bu0a(
-                    dist, dev_scalar(level), dstT,
-                    flagged_colstart(g, SPLIT_LANES), degc,
-                    c_cap=c_cap, n_=n, lanes=SPLIT_LANES)
-                nu = int(np.asarray(prog)[0])
-                if nu > 0:
-                    u_cap = min(_next_pow2(max(nu, 2)), cap_n)
-                    cand = pad(cand)
-                    dist, cand, prog, st_dev = bu0b(
-                        dist, fbits, cand[:u_cap], dev_scalar(level),
-                        dstT, colstart, degc, c_cap=u_cap, n_=n)
-                    nc, rem8 = (int(x) for x in np.asarray(prog))
+            split = c_cap >= SPLIT_LANE_MIN
+            with phase("bfs.level", level=level, dir="bu", c_cap=c_cap,
+                       split=split) as ph:
+                if split:
+                    # split-lane opener: SPLIT_LANES-wide test over
+                    # everyone, then the remaining lanes only for the
+                    # minority that missed (host-sized)
+                    dist, fbits, cand, prog, st_dev = bu0a(
+                        dist, dev_scalar(level), dstT,
+                        flagged_colstart(g, SPLIT_LANES), degc,
+                        c_cap=c_cap, n_=n, lanes=SPLIT_LANES)
+                    nu = stats_of(ph, prog)[0]
+                    ph.set(missed=nu)
+                    if nu > 0:
+                        u_cap = min(_next_pow2(max(nu, 2)), cap_n)
+                        cand = pad(cand)
+                        dist, cand, prog, st_dev = bu0b(
+                            dist, fbits, cand[:u_cap], dev_scalar(level),
+                            dstT, colstart, degc, c_cap=u_cap, n_=n)
+                        nc, rem8 = stats_of(ph, prog)
+                    else:
+                        nc, rem8 = 0, 0
                 else:
-                    nc, rem8 = 0, 0
-            else:
-                dist, fbits, cand, prog, st_dev = bu0(
-                    dist, dev_scalar(level), dstT, colstart, degc,
-                    c_cap=c_cap, n_=n)
-                nc, rem8 = (int(x) for x in np.asarray(prog))
-            rounds = 1
-            off = None
-            while nc > 0 and rounds < BU_CHUNK_ROUNDS:
-                c_cap2 = min(_next_pow2(max(nc, 2)), cap_n)
-                if off is None:
-                    cand = pad(cand)
-                    off = jnp.ones((cap_n,), jnp.int32)
-                fuse = BU_CHUNK_ROUNDS - rounds
-                dist, cand, off, prog, st_dev = bu(
-                    dist, fbits, cand[:c_cap2], off[:c_cap2],
-                    prog, dev_scalar(level), dstT, colstart,
-                    degc, c_cap=c_cap2, n_=n, fuse=fuse)
-                cand, off = pad(cand), pad(off)
-                nc, rem8 = (int(x) for x in np.asarray(prog))
-                rounds += fuse
-            if nc > 0:
-                # exhaustive sweep for the stragglers (stats included)
-                c_cap2 = min(_next_pow2(max(nc, 2)), cap_n)
-                rem_cap = _next_pow2(max(rem8, 2))
-                if off is None:
-                    cand = pad(cand)
-                    off = jnp.ones((cap_n,), jnp.int32)
-                dist, st_dev = ex(dist, fbits, cand[:c_cap2],
-                                  off[:c_cap2], prog, dev_scalar(level),
-                                  dstT, colstart, degc, c_cap=c_cap2,
-                                  p_cap=rem_cap, n_=n)
-            f_count, m8_f, m8_unvis, n_unvis = \
-                (int(x) for x in np.asarray(st_dev))
+                    dist, fbits, cand, prog, st_dev = bu0(
+                        dist, dev_scalar(level), dstT, colstart, degc,
+                        c_cap=c_cap, n_=n)
+                    nc, rem8 = stats_of(ph, prog)
+                ph.set(left=nc)
+                rounds = 1
+                off = None
+                while nc > 0 and rounds < BU_CHUNK_ROUNDS:
+                    c_cap2 = min(_next_pow2(max(nc, 2)), cap_n)
+                    if off is None:
+                        cand = pad(cand)
+                        off = jnp.ones((cap_n,), jnp.int32)
+                    fuse = BU_CHUNK_ROUNDS - rounds
+                    dist, cand, off, prog, st_dev = bu(
+                        dist, fbits, cand[:c_cap2], off[:c_cap2],
+                        prog, dev_scalar(level), dstT, colstart,
+                        degc, c_cap=c_cap2, n_=n, fuse=fuse)
+                    cand, off = pad(cand), pad(off)
+                    nc, rem8 = stats_of(ph, prog)
+                    rounds += fuse
+                ph.set(rounds=rounds, exhaust=nc, rem8=rem8 if nc else 0)
+                if nc > 0:
+                    # exhaustive sweep for the stragglers (stats
+                    # included)
+                    c_cap2 = min(_next_pow2(max(nc, 2)), cap_n)
+                    rem_cap = _next_pow2(max(rem8, 2))
+                    if off is None:
+                        cand = pad(cand)
+                        off = jnp.ones((cap_n,), jnp.int32)
+                    dist, st_dev = ex(dist, fbits, cand[:c_cap2],
+                                      off[:c_cap2], prog,
+                                      dev_scalar(level), dstT, colstart,
+                                      degc, c_cap=c_cap2, p_cap=rem_cap,
+                                      n_=n)
+                f_count, m8_f, m8_unvis, n_unvis = stats_of(ph, st_dev)
+            devprof.count_level("bu", "single")
             frontier = None
         level += 1
     out = dist[:n]

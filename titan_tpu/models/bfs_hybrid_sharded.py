@@ -80,7 +80,7 @@ from __future__ import annotations
 import numpy as np
 
 from titan_tpu.models.bfs import INF, _next_pow2
-from titan_tpu.models.bfs_hybrid import (_bit_of, _pack_bits,
+from titan_tpu.models.bfs_hybrid import (_fbit_of, _pack_bits,
                                          enumerate_chunk_pairs)
 from titan_tpu.ops.compaction import compact_ids, scatter_compact
 
@@ -357,7 +357,7 @@ def _bu_level(mesh):
                 cols = jnp.where(alive, cs_l[lv], q_pad)
                 parents = jnp.take(dstT_l, jnp.clip(cols, 0, q_pad),
                                    axis=1)
-                found = alive & _bit_of(fbits, parents).any(axis=0)
+                found = alive & _fbit_of(fbits, parents).any(axis=0)
                 dist = dist.at[jnp.where(found, lv + lo, n_ + 1)].set(
                     level + 1, mode="drop")
                 surv = alive & ~found & (degc_l[lv] > 1)
@@ -386,7 +386,7 @@ def _bu_level(mesh):
                             par = jnp.take(dstT_l,
                                            jnp.clip(cls, 0, q_pad),
                                            axis=1)
-                            ft = alv & _bit_of(fbits, par).any(axis=0)
+                            ft = alv & _fbit_of(fbits, par).any(axis=0)
                             dist = dist.at[
                                 jnp.where(ft, lvv + lo, n_ + 1)].set(
                                 level + 1, mode="drop")
@@ -424,7 +424,7 @@ def _bu_level(mesh):
                                             q_pad)
                             par = jnp.take(dstT_l, cls.reshape(-1),
                                            axis=1)
-                            hit = _bit_of(fbits, par).any(axis=0) \
+                            hit = _fbit_of(fbits, par).any(axis=0) \
                                 .reshape(w, K)
                             ft = alv & (hit & live).any(axis=1)
                             dist = dist.at[

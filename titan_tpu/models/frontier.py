@@ -37,6 +37,7 @@ output).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import numpy as np
@@ -362,7 +363,8 @@ def _frontier_run(snap_or_graph, val, val_exp, kind: str, wparams,
                   max_rounds: int, delta: float | None = None,
                   quantile_mass: int = 0, on_round=None,
                   checkpoint=None, start_rounds: int = 0,
-                  bucket_end0: float | None = None, overlay=None):
+                  bucket_end0: float | None = None, overlay=None,
+                  sync=contextlib.nullcontext):
     """Expansion-tracked round loop: one plan readback per round
     (_band_plan — compacted in-band list + mass-balanced segment
     bounds, no n-wide nonzero), then one _push_list dispatch per
@@ -383,7 +385,8 @@ def _frontier_run(snap_or_graph, val, val_exp, kind: str, wparams,
     ``quantile_mass`` continues the exact trajectory (the pushes are
     min-scatters, order-independent and exact, so the final arrays are
     bit-equal to an uninterrupted run even if kernel-width choices
-    differ after resume)."""
+    differ after resume). ``sync()`` is entered round each round's one
+    blocking readback (a caller's phase times it: ``Phase.sync``)."""
     import time as _time
 
     import jax.numpy as jnp
@@ -490,7 +493,8 @@ def _frontier_run(snap_or_graph, val, val_exp, kind: str, wparams,
             val, val_exp, degc, be_dev, n_=n, f_cap=qf_cap,
             k_max=SLICE_K_MAX, budget=budget,
             quantile_mass=quantile_mass)
-        st_h = np.asarray(stats)           # ONE sync per round
+        with sync():
+            st_h = np.asarray(stats)       # ONE sync per round
         plan_s = _time.time() - t_plan
         nf, m8 = int(st_h[0]), int(st_h[1])
         if int(st_h[2]):
@@ -592,7 +596,7 @@ class _CohortMember:
 
 def _frontier_cohort(g, members, kind: str, wparams, max_rounds: int,
                      delta: float = 0.0, on_round=None, checkpoint=None,
-                     overlay=None) -> None:
+                     overlay=None, sync=contextlib.nullcontext) -> None:
     """Shared round loop over K per-member ``(val, val_exp)`` states —
     the cohort generalization of ``_frontier_run``. Each round
     dispatches every active member's band plan (the member's OWN static
@@ -619,7 +623,9 @@ def _frontier_cohort(g, members, kind: str, wparams, max_rounds: int,
     ``RoundInterrupted`` that cannot abandon its K-1 batchmates.
     Fresh-start cohorts only: resumed jobs run solo through
     ``frontier_sssp``/``frontier_wcc`` (their round counter differs
-    from any fresh batchmate — the same split the batched BFS makes)."""
+    from any fresh batchmate — the same split the batched BFS makes).
+    ``sync()`` is entered round each blocking plan readback, as in
+    ``_frontier_run``."""
     import jax.numpy as jnp
 
     n = g["n"]
@@ -735,7 +741,8 @@ def _frontier_cohort(g, members, kind: str, wparams, max_rounds: int,
             if not _boundary(m):
                 return
             qf_cap, stats, flist, lbounds, thr_dev = _dispatch(m)
-            st_h = np.asarray(stats)
+            with sync():
+                st_h = np.asarray(stats)
             if _host_step(m, st_h, qf_cap, flist, lbounds,
                           thr_dev) != "replan":
                 return
@@ -759,7 +766,8 @@ def _frontier_cohort(g, members, kind: str, wparams, max_rounds: int,
         if not ready:
             continue
         # THE amortization: K members' round plans in one stacked sync
-        st_all = np.asarray(jnp.stack([d[1] for _m, d in ready]))
+        with sync():
+            st_all = np.asarray(jnp.stack([d[1] for _m, d in ready]))
         replans = []
         for (m, (qf_cap, _stats, flist, lbounds, thr_dev)), st_h \
                 in zip(ready, st_all):
@@ -829,8 +837,14 @@ def frontier_wcc_batched(snap_or_graph, count: int,
     bit-equal to a solo ``frontier_wcc``. ``checkpoint(k, rounds,
     state)`` states carry ``levels`` like the sequential form. Returns
     ``(labels, rounds, stopped)`` with rounds including the shared BFS
-    peel's level count."""
+    peel's level count. Phases as ``frontier_wcc``'s, under the caller's
+    scope: the shared peel and ONE ``wcc.propagate`` over the cohort's
+    round loop (``k`` members; ``rounds`` the longest member's), then a
+    ``wcc.result`` a member."""
     import jax.numpy as jnp
+
+    from titan_tpu.obs import devprof
+    from titan_tpu.obs.tracing import phase
 
     g = snap_or_graph if isinstance(snap_or_graph, dict) \
         else build_chunked_csr(snap_or_graph)
@@ -852,10 +866,7 @@ def frontier_wcc_batched(snap_or_graph, count: int,
             [ids + 1, jnp.full((1,), IINF, jnp.int32)])
         levels = 0
     else:
-        seed_v = int(np.asarray(jnp.argmax(g["deg"][:n])))
-        dist, levels = frontier_bfs_hybrid(g, seed_v, max_levels=n,
-                                           return_device=True)
-        val0, exp0 = _wcc_seed_labels()(dist, n_=n)
+        val0, exp0, levels = _wcc_peel(g)
     ck = None
     if checkpoint is not None:
         def ck(k, rounds, state, _levels=levels):
@@ -868,10 +879,15 @@ def frontier_wcc_batched(snap_or_graph, count: int,
                              jnp.array(exp0, copy=True),
                              int(IINF), 0)
                for k in range(count)]
-    _frontier_cohort(g, members, "wcc", (0.0, 0.0), max_rounds,
-                     on_round=on_round, checkpoint=ck, overlay=overlay)
+    with phase("wcc.propagate", k=count) as ph:
+        _frontier_cohort(g, members, "wcc", (0.0, 0.0), max_rounds,
+                         on_round=on_round, checkpoint=ck,
+                         overlay=overlay, sync=ph.sync)
+        ph.set(rounds=max(m.rounds for m in members))
+    for m in members:
+        devprof.count_wcc_rounds(m.rounds)
     outs = [m.out if return_device or m.out is None
-            else np.asarray(m.out) for m in members]
+            else _wcc_readback(m.out) for m in members]
     return outs, [m.rounds + levels for m in members], \
         [m.stopped for m in members]
 
@@ -943,6 +959,47 @@ def frontier_sssp(snap_or_graph, source_dense: int, min_w: float = 0.0,
     if not return_device:
         out = np.asarray(out)
     return out, rounds
+
+
+def _wcc_peel(g):
+    """The giant component off the top: one direction-optimising BFS
+    from the largest-degree vertex — on power-law graphs it anchors the
+    giant component, so the BFS peels about all the edge mass — then the
+    seed labels. Returns ``(val, val_exp, levels)``. Phases: the BFS's
+    own ``bfs.level``s, then ``wcc.seed`` (``levels``, ``source_deg``;
+    it dispatches, nothing blocks)."""
+    import jax.numpy as jnp
+
+    from titan_tpu.obs.tracing import phase
+
+    n = g["n"]
+    at = jnp.argmax(g["deg"][:n])
+    seed_v, seed_deg = (int(x) for x in
+                        np.asarray(jnp.stack([at, g["deg"][at]])))
+    # max_levels=n: a truncated BFS would freeze the partially-peeled
+    # region as expanded, silently splitting its component's labels
+    dist, levels = frontier_bfs_hybrid(g, seed_v, max_levels=n,
+                                       return_device=True)
+    with phase("wcc.seed", levels=int(levels), source_deg=seed_deg,
+               **{"async": True}):
+        # frontier_bfs_hybrid returns dist[:n]; the seeding jit
+        # re-appends nothing — it only reads [:n_]
+        val, val_exp = _wcc_seed_labels()(dist, n_=n)
+    return val, val_exp, levels
+
+
+def _wcc_readback(out):
+    """The labels on the host: ``wcc.result`` (``bytes``, ``sync_ms``;
+    everything the propagation dispatched after its last plan drains
+    inside it), counted under ``device.xfer.d2h_bytes{site=
+    "wcc.result"}``."""
+    from titan_tpu.obs import devprof
+    from titan_tpu.obs.tracing import phase
+
+    with phase("wcc.result", bytes=int(out.nbytes)) as ph:
+        devprof.count_d2h("wcc.result", out.nbytes)
+        with ph.sync():
+            return np.asarray(out)
 
 
 def _wcc_seed_labels():
@@ -1245,8 +1302,17 @@ def frontier_wcc(snap_or_graph, max_rounds: int = 10_000,
     peel's level count, so a resumed run reports the same total).
     ``resume``: ``{"val", "val_exp", "rounds", "levels"}`` — skips the
     BFS peel entirely and continues label propagation; final labels are
-    bit-equal to an uninterrupted run."""
+    bit-equal to an uninterrupted run.
+
+    Phases (obs/tracing: leaf spans under the caller's scope — a served
+    job's ``run`` — and profiler annotations): the peel's ``bfs.level``s
+    and ``wcc.seed`` (``_wcc_peel``), ``wcc.propagate`` (``rounds``,
+    ``sync_ms``: the plan readback a round), ``wcc.result``
+    (``_wcc_readback``); ``device.wcc.rounds`` counts the rounds."""
     import jax.numpy as jnp
+
+    from titan_tpu.obs import devprof
+    from titan_tpu.obs.tracing import phase
 
     g = snap_or_graph if isinstance(snap_or_graph, dict) \
         else build_chunked_csr(snap_or_graph)
@@ -1277,16 +1343,7 @@ def frontier_wcc(snap_or_graph, max_rounds: int = 10_000,
             [ids + 1, jnp.full((1,), IINF, jnp.int32)])
         levels = 0
     else:
-        # seed at the max-degree vertex — on power-law graphs it anchors
-        # the giant component, so the BFS peels ~all edge mass
-        seed_v = int(np.asarray(jnp.argmax(g["deg"][:n])))
-        # max_levels=n: a truncated BFS would freeze the partially-peeled
-        # region as expanded, silently splitting its component's labels
-        dist, levels = frontier_bfs_hybrid(g, seed_v, max_levels=n,
-                                           return_device=True)
-        # frontier_bfs_hybrid returns dist[:n]; the seeding jit
-        # re-appends nothing — it only reads [:n_]
-        val, val_exp = _wcc_seed_labels()(dist, n_=n)
+        val, val_exp, levels = _wcc_peel(g)
     if checkpoint is not None:
         _ck = checkpoint
 
@@ -1294,11 +1351,17 @@ def frontier_wcc(snap_or_graph, max_rounds: int = 10_000,
             state = dict(state)
             state["levels"] = _levels
             _ck(rounds, state)
-    out, rounds = _frontier_run(g, val, val_exp, "wcc", (0.0, 0.0),
-                                max_rounds, on_round=on_round,
-                                checkpoint=checkpoint,
-                                start_rounds=start_rounds,
-                                overlay=overlay)
+    # one leaf phase over the whole propagation: its rounds keep their
+    # host-stamped `round` events (run_single bridges `_trace_rounds`)
+    with phase("wcc.propagate") as ph:
+        out, rounds = _frontier_run(g, val, val_exp, "wcc", (0.0, 0.0),
+                                    max_rounds, on_round=on_round,
+                                    checkpoint=checkpoint,
+                                    start_rounds=start_rounds,
+                                    overlay=overlay, sync=ph.sync)
+        ran = int(rounds) - start_rounds
+        ph.set(rounds=ran)
+    devprof.count_wcc_rounds(ran)
     if not return_device:
-        out = np.asarray(out)
+        out = _wcc_readback(out)
     return out, rounds + levels

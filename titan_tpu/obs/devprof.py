@@ -213,16 +213,28 @@ def count_d2h(site: str, nbytes: int) -> None:
             prof.on_xfer("d2h", site, int(nbytes))
 
 
-def count_level(direction: str, road: str) -> None:
-    """Count one level of a batched BFS by the direction it took
-    (``"td"`` push or ``"bu"`` pull; models/bfs_hybrid._td_cap) and by
-    where a push had its frontier's pairs from (``"carried"``: the
-    program before left the list; ``"scan"``: listed from dist, n wide;
-    ``"none"`` for a pull)."""
-    for prof in list(_PROFILERS):
-        prof.metrics.counter("device.bfs.levels",
-                             labels={"dir": direction,
-                                     "list": road}).inc()
+def count_level(direction: str, road: str, levels: int = 1) -> None:
+    """Count ``levels`` BFS levels by the direction they took and by
+    where a push had its frontier's pairs from. The batched loop
+    (``frontier_bfs_batched``; models/bfs_hybrid._td_cap): ``"td"`` push
+    or ``"bu"`` pull; ``"carried"``: the program before left the list;
+    ``"scan"``: listed from dist, n wide; ``"none"`` for a pull. The
+    single-source family (``frontier_bfs_hybrid``): road ``"single"``,
+    direction ``"head"`` | ``"td"`` | ``"bu"`` | ``"end"``, the fused
+    head and endgame counting every level their one dispatch ran."""
+    if levels > 0:
+        for prof in list(_PROFILERS):
+            prof.metrics.counter("device.bfs.levels",
+                                 labels={"dir": direction,
+                                         "list": road}).inc(int(levels))
+
+
+def count_wcc_rounds(rounds: int) -> None:
+    """Count the min-label propagation rounds of one WCC run (the
+    rounds ``_frontier_run`` planned after the peel)."""
+    if rounds > 0:
+        for prof in list(_PROFILERS):
+            prof.metrics.counter("device.wcc.rounds").inc(int(rounds))
 
 
 def count_pr_iteration() -> None:

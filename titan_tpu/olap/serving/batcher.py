@@ -21,12 +21,12 @@ distances via ``params['targets']``.
 from __future__ import annotations
 
 import time
-from contextlib import nullcontext
+from contextlib import contextmanager, nullcontext
 from typing import Optional
 
 import numpy as np
 
-from titan_tpu.obs.tracing import scope
+from titan_tpu.obs.tracing import phase, scope
 from titan_tpu.olap.serving.jobs import Job
 
 #: jobs of these kinds fuse into one batched run when they share a
@@ -87,6 +87,34 @@ def _dense_source(snap, params: dict) -> int:
         raise ValueError(f"bad source value: {e}") from e
     raise ValueError("job params need 'source' (vertex id) or "
                      "'source_dense'")
+
+
+def _components(labels: np.ndarray) -> int:
+    """Components of a WCC answer. A label is its component's smallest
+    vertex id, so a component is counted at the one vertex that carries
+    its own id: one pass, where ``np.unique`` sorts all n labels inside
+    the job's ``exec_ms``."""
+    return int((labels == np.arange(labels.shape[0],
+                                    dtype=labels.dtype)).sum())
+
+
+def _under(handle, run_span):
+    """The scope in which a kernel's leaf phases journal as children of
+    the job's ``run`` span (nothing without a trace)."""
+    return nullcontext() if handle is None \
+        else scope(handle.tracer, handle.trace_id, run_span)
+
+
+@contextmanager
+def job_phase(job, name: str, **attrs):
+    """A leaf phase of a job's host work outside its ``run`` (the lease,
+    HBM admission, counting an answer): a span under the job's current
+    ``attempt`` and a profiler annotation, so a device idle gap between
+    two runs carries its name."""
+    h = job.trace
+    with _under(h, h.attempt if h is not None else None):
+        with phase(name, **attrs) as ph:
+            yield ph
 
 
 def _epoch_token(snap, overlay):
@@ -473,10 +501,15 @@ class Batcher:
                     checkpoint=ckpt if wants_ckpt else None,
                     overlay=overlay)
             else:
-                outs, rounds_l, stopped = frontier_wcc_batched(
-                    snap, K, on_round=on_round,
-                    checkpoint=ckpt if wants_ckpt else None,
-                    overlay=overlay)
+                # the cohort's leaf phases (the shared peel's bfs.level
+                # and wcc.seed, wcc.propagate, a wcc.result a member)
+                # journal under its FIRST member's `run` span: one
+                # thread drives the cohort, and at K = 1 that is the job
+                with _under(runnable[0].trace, runs[0]):
+                    outs, rounds_l, stopped = frontier_wcc_batched(
+                        snap, K, on_round=on_round,
+                        checkpoint=ckpt if wants_ckpt else None,
+                        overlay=overlay)
         except Exception as e:
             for i, job in enumerate(runnable):
                 if job.trace is not None:
@@ -494,16 +527,18 @@ class Batcher:
                     job.mark_cancelled()
                 continue
             arr = outs[i]
-            devprof.count_d2h("frontier.result",
-                              getattr(arr, "nbytes", 0))
             if kind == "sssp":
+                devprof.count_d2h("frontier.result",
+                                  getattr(arr, "nbytes", 0))
                 job.complete({"rounds": int(rounds_l[i]),
                               "reached":
                                   int((arr < float(FINF)).sum()),
                               "dist": arr})
-            else:
+            else:       # counted where it was read back: wcc.result
+                with job_phase(job, "wcc.count"):
+                    components = _components(arr)
                 job.complete({"rounds": int(rounds_l[i]),
-                              "components": int(len(np.unique(arr))),
+                              "components": components,
                               "labels": arr})
 
     # -- single execution ---------------------------------------------------
@@ -654,9 +689,7 @@ class Batcher:
                 # pr.result) journal under this job's `run` span; the
                 # readback is counted where it is made
                 # (device.xfer.d2h_bytes{site="pagerank.result"})
-                under_run = nullcontext() if h is None \
-                    else scope(h.tracer, h.trace_id, run_span)
-                with under_run:
+                with _under(h, run_span):
                     rank, iters = pagerank_dense(
                         snap,
                         iterations=int(params.get("iterations", 20)),
@@ -683,15 +716,19 @@ class Batcher:
                               "val_exp": ck.arrays["val_exp"],
                               "rounds": ck.round,
                               "levels": ck.meta.get("levels", 0)}
-                lab, rounds = frontier_wcc(snap, on_round=on_round,
-                                           checkpoint=ckpt, resume=resume,
-                                           overlay=overlay)
-                from titan_tpu.obs import devprof
-                devprof.count_d2h("frontier.result",
-                                  getattr(lab, "nbytes", 0))
-                lab = np.asarray(lab)
+                # the peel's, the propagation's and the readback's leaf
+                # phases (bfs.level, wcc.seed, wcc.propagate, wcc.result)
+                # journal under this job's `run` span; the readback is
+                # counted where it is made
+                # (device.xfer.d2h_bytes{site="wcc.result"})
+                with _under(h, run_span):
+                    lab, rounds = frontier_wcc(
+                        snap, on_round=on_round, checkpoint=ckpt,
+                        resume=resume, overlay=overlay)
+                    with phase("wcc.count"):
+                        components = _components(lab)
                 job.complete({"rounds": int(rounds),
-                              "components": int(len(np.unique(lab))),
+                              "components": components,
                               "labels": lab})
             elif kind == "dense":
                 from titan_tpu.olap.tpu.engine import run_single

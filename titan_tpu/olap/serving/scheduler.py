@@ -95,7 +95,8 @@ from titan_tpu.obs import devprof
 from titan_tpu.obs.flightrec import FlightRecorder
 from titan_tpu.obs.tracing import TraceHandle, Tracer
 from titan_tpu.olap.api import JobSpec
-from titan_tpu.olap.serving.batcher import Batcher, batch_key
+from titan_tpu.olap.serving.batcher import (Batcher, batch_key,
+                                              job_phase)
 from titan_tpu.olap.serving.hbm import (DEFAULT_BUDGET_BYTES,
                                         AdmissionError, HBMLedger,
                                         snapshot_csr_bytes,
@@ -976,76 +977,82 @@ class JobScheduler:
             program = spec.params.get("program")
             if program is not None and hasattr(program, "edge_keys"):
                 edge_keys = tuple(program.edge_keys())
-        try:
-            # dense window sweeps (pagerank / DenseProgram) have no
-            # overlay seam: the live pool folds the overlay into the
-            # base BEFORE leasing for these kinds (the documented
-            # compact-before-run fallback, models/frontier.py)
-            lease = self.pool.acquire(labels=spec.labels,
-                                      edge_keys=edge_keys,
-                                      directed=spec.directed,
-                                      compacted=spec.kind in
-                                      ("pagerank", "dense"))
-        except Exception as e:
-            for job in group:
-                job.fail(f"snapshot: {type(e).__name__}: {e}")
-            return
+        # `job.lease` and `job.admit`: leaf phases under the head job's
+        # attempt, so the host's time between two runs has spans and
+        # the device's idle gap there a name (obs/tracing)
+        with job_phase(head, "job.lease"):
+            try:
+                # dense window sweeps (pagerank / DenseProgram) have no
+                # overlay seam: the live pool folds the overlay into the
+                # base BEFORE leasing for these kinds (the documented
+                # compact-before-run fallback, models/frontier.py)
+                lease = self.pool.acquire(labels=spec.labels,
+                                          edge_keys=edge_keys,
+                                          directed=spec.directed,
+                                          compacted=spec.kind in
+                                          ("pagerank", "dense"))
+            except Exception as e:
+                for job in group:
+                    job.fail(f"snapshot: {type(e).__name__}: {e}")
+                return
         with lease as snap:
             overlay = lease.overlay
             epoch_info = lease.epoch_info \
                 or {"epoch": getattr(snap, "epoch", 0)}
             for job in group:
                 job.ran_epoch = epoch_info
-            ledger_key = id(snap)
-            # mesh-placed cohorts charge the PER-DEVICE share (the
-            # edge image shards over the mesh — hbm.meshed_snapshot_
-            # csr_bytes); only batched BFS runs meshed (single-run
-            # kinds and overlay leases keep the single-device layout).
-            # The predicate is the BATCHER's (Batcher.would_mesh) —
-            # the accounting here and the placement there must answer
-            # from one definition. A snapshot already resident under
-            # the other accounting keeps its first byte count
-            # (reserve() pins existing keys without re-pricing) —
-            # conservative either way.
-            meshed = self.batcher.would_mesh(spec.kind, overlay)
-            if meshed:
-                from titan_tpu.olap.serving.hbm import \
-                    meshed_snapshot_csr_bytes
-                nbytes = meshed_snapshot_csr_bytes(
-                    snap, int(self.mesh.devices.size))
-            else:
-                nbytes = snapshot_csr_bytes(snap)
-            # a `pagerank` job reads a second image: the in-edge pull
-            # image of models/pagerank_pull, under a key of its own so
-            # that a snapshot already resident for other kinds is not
-            # taken to hold it
-            images = [(ledger_key, nbytes, snap)]
-            if spec.kind == "pagerank":
-                pull_bytes = snapshot_pull_bytes(snap)
-                images.append((("pagerank-pull", ledger_key), pull_bytes,
-                               (snap, "_pull_csr")))
-                nbytes += pull_bytes
-            held = []
-            try:
-                for key, image_bytes, _handle in images:
-                    self.ledger.reserve(key, image_bytes)
-                    held.append(key)
-            except AdmissionError as e:
-                for key in held:
-                    self.ledger.unpin(key)
+            with job_phase(head, "job.admit") as admit:
+                ledger_key = id(snap)
+                # mesh-placed cohorts charge the PER-DEVICE share (the
+                # edge image shards over the mesh — hbm.meshed_snapshot_
+                # csr_bytes); only batched BFS runs meshed (single-run
+                # kinds and overlay leases keep the single-device layout).
+                # The predicate is the BATCHER's (Batcher.would_mesh) —
+                # the accounting here and the placement there must answer
+                # from one definition. A snapshot already resident under
+                # the other accounting keeps its first byte count
+                # (reserve() pins existing keys without re-pricing) —
+                # conservative either way.
+                meshed = self.batcher.would_mesh(spec.kind, overlay)
+                if meshed:
+                    from titan_tpu.olap.serving.hbm import \
+                        meshed_snapshot_csr_bytes
+                    nbytes = meshed_snapshot_csr_bytes(
+                        snap, int(self.mesh.devices.size))
+                else:
+                    nbytes = snapshot_csr_bytes(snap)
+                # a `pagerank` job reads a second image: the in-edge pull
+                # image of models/pagerank_pull, under a key of its own so
+                # that a snapshot already resident for other kinds is not
+                # taken to hold it
+                images = [(ledger_key, nbytes, snap)]
+                if spec.kind == "pagerank":
+                    pull_bytes = snapshot_pull_bytes(snap)
+                    images.append((("pagerank-pull", ledger_key), pull_bytes,
+                                   (snap, "_pull_csr")))
+                    nbytes += pull_bytes
+                held = []
+                try:
+                    for key, image_bytes, _handle in images:
+                        self.ledger.reserve(key, image_bytes)
+                        held.append(key)
+                except AdmissionError as e:
+                    for key in held:
+                        self.ledger.unpin(key)
+                    for job in group:
+                        job.fail(str(e))
+                    return
+                for key, _bytes, handle in images:
+                    self._evictable.setdefault(key, handle)
+                # the batch shares one graph image: its ledger bytes are
+                # held against each member's tenant (per-K share) for the
+                # duration of the run — the live view max_hbm_bytes quotas
+                # check against — then released and converted into
+                # byte-seconds attribution
+                share = nbytes / len(group)
                 for job in group:
-                    job.fail(str(e))
-                return
-            for key, _bytes, handle in images:
-                self._evictable.setdefault(key, handle)
-            # the batch shares one graph image: its ledger bytes are
-            # held against each member's tenant (per-K share) for the
-            # duration of the run — the live view max_hbm_bytes quotas
-            # check against — then released and converted into
-            # byte-seconds attribution
-            share = nbytes / len(group)
-            for job in group:
-                self.tenants.hold_hbm(job.tenant, share)
+                    self.tenants.hold_hbm(job.tenant, share)
+                admit.set(bytes=int(nbytes))
             t0 = time.time()
             w = self.profiler.window() if self.profiler is not None \
                 else None
