@@ -209,6 +209,26 @@ def test_pagerank_pull_at_graph500_22(spec):
     _compile(_pr_result(), spec((N22 + 1,), jnp.float32), n_=N22)
 
 
+def test_wcc_propagation_on_the_remainder_at_graph500_24(spec):
+    """What ISSUE 37 added to a WCC job of g500-24.wcc-c2 (n 8,871,268:
+    CPU count, PR 36): the seeding that lists the remainder at n / 8
+    and a round's plan over 2^13 of the list."""
+    from titan_tpu.models import frontier as F
+
+    n = 8_871_268
+    r_cap = F._remainder_cap(n)
+    assert r_cap == 1 << 20
+    state = spec((n + 1,), jnp.int32)
+    _compile(F._wcc_seed_labels(), spec((n,), jnp.int32), state,
+             n_=n, r_cap=r_cap)
+    plan = _compile(F._list_plan("wcc"), state, state, state,
+                    spec((r_cap,), jnp.int32), spec((), jnp.int32),
+                    n_=n, w=1 << 13, k_max=F.SLICE_K_MAX,
+                    budget=(1 << 23) - 50_835)
+    # nothing of a plan over the list is n wide
+    assert plan.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("rows", [None, 1], ids=["pagerank", "ppr"])
 def test_pagerank_window(spec, rows):
     from titan_tpu.models.frontier import _pr_window

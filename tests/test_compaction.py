@@ -125,8 +125,12 @@ def test_claim_dedup_out_of_range_keys_never_win():
     assert np.asarray(claim).tolist() == [CLAIM_SENTINEL] * 3 + [0]
 
 
+@pytest.mark.parametrize("named", [False, True], ids=["positions", "ids"])
 @pytest.mark.parametrize("seed", range(3))
-def test_banded_frontier_matches_oracle(seed):
+def test_banded_frontier_matches_oracle(seed, named):
+    """``named``: the band over a LIST of candidates (``ids``: the
+    vertices the positions stand for, ascending), whose members come
+    out under those names with the masses and bounds of the positions."""
     import jax.numpy as jnp
 
     rng = np.random.default_rng(200 + seed)
@@ -136,12 +140,16 @@ def test_banded_frontier_matches_oracle(seed):
     budget = int(rng.integers(1, 300))
     mask = rng.random(L) < rng.random()
     mass = rng.integers(0, 40, L).astype(np.int32)
+    names = np.sort(rng.choice(10 * L, L, replace=False)).astype(np.int32) \
+        if named else np.arange(L, dtype=np.int32)
+    fill = 10 * L if named else L
     nf, m8, overflow, flist, bounds = banded_frontier(
-        jnp.asarray(mask), jnp.asarray(mass), cap, k_max, budget, L)
+        jnp.asarray(mask), jnp.asarray(mass), cap, k_max, budget, fill,
+        **({"ids": jnp.asarray(names)} if named else {}))
     # oracle: nonzero-compacted list, cumsum + searchsorted bounds
     idx = np.nonzero(mask)[0][:cap]
-    ref_list = np.full((cap,), L, np.int32)
-    ref_list[: len(idx)] = idx
+    ref_list = np.full((cap,), fill, np.int32)
+    ref_list[: len(idx)] = names[idx]
     ref_mass = np.zeros((cap,), np.int64)
     ref_mass[: len(idx)] = mass[idx]
     cmass = np.cumsum(ref_mass)

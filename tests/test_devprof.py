@@ -123,7 +123,7 @@ def test_zero_recompiles_on_warm_smoke_shapes(snap):
     # not just one entry point
     kernels = prof.kernel_stats()
     for expected in ("hybrid_head", "batched_plan",
-                     "frontier_bandplan_sssp", "frontier_bandplan_wcc",
+                     "frontier_bandplan_sssp", "frontier_listplan_wcc",
                      "ops.epoch_merge"):
         assert expected in kernels, (expected, sorted(kernels))
     # ... and landed on the labeled metric families

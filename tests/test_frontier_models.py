@@ -401,3 +401,200 @@ def test_sssp_quantile_list_truncation_is_sound(monkeypatch):
     ref, _ = F.frontier_sssp(snap, source, quantile_mass=0)
     got, rounds = F.frontier_sssp(snap, source, quantile_mass=64)
     assert np.asarray(got) == pytest.approx(np.asarray(ref), rel=1e-6)
+
+
+# -- WCC's propagation plans on what the peel left (ISSUE 37) ---------------
+
+def _sym(n, a, b):
+    a, b = np.asarray(a, np.int32), np.asarray(b, np.int32)
+    return snap_mod.from_arrays(n, np.concatenate([a, b]),
+                                np.concatenate([b, a]))
+
+
+def _kron(isolated: bool):
+    """A Kronecker graph with a giant component and some fifty vertices
+    in small ones; as generated, 2,498 of its 4,096 vertices have no
+    edge; without them, the rest under dense ids."""
+    src, dst = rmat_edges(12, 1, seed=5)
+    if isolated:
+        return _sym(1 << 12, src, dst)
+    ids, inv = np.unique(np.concatenate([src, dst]), return_inverse=True)
+    return _sym(len(ids), inv[:len(src)], inv[len(src):])
+
+
+def _paths():
+    """1,000 paths of three under a relabelling: no giant component."""
+    perm = np.random.default_rng(3).permutation(3000)
+    return _sym(3000, perm[np.r_[0:3000:3, 1:3000:3]],
+                perm[np.r_[1:3000:3, 2:3000:3]])
+
+
+def _ring():
+    return _sym(500, np.arange(500), np.roll(np.arange(500), 1))
+
+
+def _propagation(run, no_list: bool = False):
+    """``run()`` under a tracer and a profiler: (its return, the
+    ``wcc.propagate`` span's attributes, WCC's plans by road). With
+    ``no_list`` the peel hands no list: the n-wide road."""
+    from titan_tpu.obs.tracing import Tracer, scope
+
+    tracer, metrics = Tracer(), MetricManager()
+    root = tracer.start("t", "run")
+    with pytest.MonkeyPatch.context() as patch, \
+            DeviceCostProfiler(metrics=metrics), scope(tracer, "t", root):
+        if no_list:
+            peel = F._wcc_peel
+            patch.setattr(F, "_wcc_peel", lambda g: peel(g)[:3] + (None,))
+        got = run()
+    tracer.end(root)
+    (prop,) = [s for s in tracer.spans("t") if s.name == "wcc.propagate"]
+    plans = {d: metrics.counter("device.wcc.plans",
+                                labels={"domain": d}).count
+             for d in ("list", "n")}
+    return got, prop.attrs, plans
+
+
+@pytest.mark.parametrize("case", ["kron", "kron-isolated", "no-giant",
+                                  "one-component", "cohort-k2"])
+def test_wcc_list_road_equals_the_n_wide_road(case):
+    """Labels AND round counts of the propagation planned on the peel's
+    remainder against the same planned over all n (the peel handing no
+    list); the span and the counter say which road ran, and the loop
+    takes n by itself where the remainder outgrows the list's cap."""
+    snap = {"kron": lambda: _kron(False), "kron-isolated": lambda: _kron(True),
+            "no-giant": _paths, "one-component": _ring,
+            "cohort-k2": lambda: _kron(True)}[case]()
+    n = snap.n
+    if case == "cohort-k2":
+        def run():
+            outs, rounds, stopped = F.frontier_wcc_batched(snap, 2)
+            assert stopped == [None, None]
+            assert (outs[0] == outs[1]).all() and rounds[0] == rounds[1]
+            return outs[0], rounds[0]
+    else:
+        def run():
+            return F.frontier_wcc(snap)
+    (lab, rounds), attrs, plans = _propagation(run)
+    (lab_n, rounds_n), attrs_n, plans_n = _propagation(run, no_list=True)
+    assert lab.dtype == np.int32 and lab.tobytes() == lab_n.tobytes()
+    assert rounds == rounds_n and attrs["rounds"] == attrs_n["rounds"]
+    members = 2 if case == "cohort-k2" else 1
+    # the n-wide road, forced: no list, so no remainder to report
+    assert attrs_n["domain"] == n and "remainder" not in attrs_n
+    assert plans_n == {"list": 0, "n": members * (attrs_n["rounds"] + 1)}
+    # what the peel left: every vertex outside its component with an edge
+    deg = np.asarray(snap.out_degree)
+    giant = lab == lab[np.argmax(deg)]
+    assert attrs["remainder"] == int((~giant & (deg > 0)).sum())
+    if case == "no-giant":
+        assert attrs["remainder"] == 2997 > F._remainder_cap(n)
+        assert attrs["domain"] == n and plans == plans_n
+        return
+    want_w = max(2, 1 << (attrs["remainder"] - 1).bit_length())
+    assert attrs["domain"] == want_w <= F._remainder_cap(n)
+    assert plans == {"list": members * (attrs["rounds"] + 1), "n": 0}
+    if case == "one-component":
+        assert attrs["remainder"] == 0 and attrs["rounds"] == 0
+        assert (lab == 0).all()
+    else:
+        assert attrs["remainder"] > 40 and attrs["rounds"] >= 2
+        # the same labels as a run alone (the cohort's case), and right:
+        # each component named by its smallest vertex
+        import scipy.sparse as sp
+        from scipy.sparse.csgraph import connected_components
+        src, dst = np.asarray(snap.src), np.asarray(snap.dst)
+        _c, comp = connected_components(
+            sp.coo_matrix((np.ones(len(src)), (src, dst)), shape=(n, n)))
+        smallest = np.full(comp.max() + 1, n)
+        np.minimum.at(smallest, comp, np.arange(n))
+        assert (lab == smallest[comp]).all()
+    if case == "cohort-k2":
+        solo, solo_rounds = F.frontier_wcc(snap)
+        assert solo.tobytes() == lab.tobytes() and solo_rounds == rounds
+
+
+@pytest.mark.parametrize("how", ["resume", "overlay"])
+def test_wcc_without_a_peel_plans_over_n(how):
+    """A resumed WCC and one over a live overlay run no peel, so no list
+    is handed over: ``domain`` = n, every plan on the n-wide road, the
+    labels those of a straight run."""
+    from titan_tpu.olap.live.overlay import DeltaOverlay
+
+    snap = _kron(True)
+    n = snap.n
+    ref, ref_rounds = F.frontier_wcc(snap)
+    if how == "resume":
+        caps = {}
+
+        def ck(rounds, state):
+            caps[rounds] = {"val": np.asarray(state["val"]).copy(),
+                            "val_exp": np.asarray(state["val_exp"]).copy(),
+                            "levels": state["levels"], "rounds": rounds}
+        F.frontier_wcc(snap, checkpoint=ck)
+        resume = caps[sorted(caps)[1]]
+
+        def run():
+            return F.frontier_wcc(snap, resume=resume)
+    else:
+        ov = DeltaOverlay(snap, min_cap=256)
+        a, b = (int(v) for v in np.flatnonzero(ref != ref[0])[:2])
+        ov.append_edges(np.asarray([0, a], np.int32),
+                        np.asarray([a, 0], np.int32),
+                        np.zeros(2, np.int32))
+        view = ov.view()
+
+        def run():
+            return F.frontier_wcc(snap, overlay=view)
+    (lab, rounds), attrs, plans = _propagation(run)
+    assert attrs["domain"] == n and "remainder" not in attrs
+    assert plans["list"] == 0 and plans["n"] >= attrs["rounds"] + 1
+    if how == "resume":
+        assert lab.tobytes() == ref.tobytes() and rounds == ref_rounds
+    else:
+        # the added edge joins a's component to vertex 0's
+        want = np.where(ref == ref[a], min(ref[0], ref[a]), ref)
+        want = np.where(ref == ref[0], min(ref[0], ref[a]), want)
+        assert (lab == want).all()
+
+
+def test_sssp_compile_keys_stand_beside_the_list_road():
+    """An SSSP dispatches ``bplan`` and ``pushl`` alone, with the static
+    arguments it had: after a WCC has built the list road's programs the
+    same SSSP compiles nothing, and no list plan is among its kernels."""
+    snap = _kron(True)
+    source = int(np.argmax(np.asarray(snap.out_degree)))
+    with DeviceCostProfiler(metrics=MetricManager()) as prof:
+        ref, ref_rounds = F.frontier_sssp(snap, source)
+        kernels = set(prof.kernel_stats())
+        assert kernels == {"frontier_bandplan_sssp",
+                           "frontier_pushlist_sssp"}
+        F.frontier_wcc(snap)
+        assert "frontier_listplan_wcc" in prof.kernel_stats()
+        before = prof.compiles()
+        got, rounds = F.frontier_sssp(snap, source)
+        assert prof.compiles() == before
+        assert {k for k in prof.kernel_stats()
+                if k.endswith("sssp")} == kernels
+    assert got.tobytes() == ref.tobytes() and rounds == ref_rounds
+
+
+def test_wcc_list_road_vetoes_and_checkpoints_every_round():
+    """The list changes what a round plans on, not the loop: ``on_round``
+    is asked and ``checkpoint`` handed the whole state at every round
+    boundary, the last plan's included, and a veto stops the run there."""
+    snap = _kron(True)
+    asked, saved = [], []
+
+    def run():
+        return F.frontier_wcc(
+            snap, on_round=lambda r: asked.append(r) or True,
+            checkpoint=lambda r, st: saved.append((r, sorted(st))))
+    (_lab, rounds), attrs, plans = _propagation(run)
+    assert plans["n"] == 0 and attrs["rounds"] >= 2
+    assert asked == list(range(attrs["rounds"] + 1))
+    assert saved == [(r, ["bucket_end", "levels", "quantile_mass", "val",
+                          "val_exp"]) for r in asked]
+    with pytest.raises(F.RoundInterrupted) as stop:
+        F.frontier_wcc(snap, on_round=lambda r: r < 1)
+    assert stop.value.rounds == 1

@@ -27,7 +27,8 @@ off.
   one shot (on power-law graphs that is ~all edge mass), then min-label
   propagation runs only over the leftover components' tiny edge mass.
   A component is a closed set — no edge crosses the peeled boundary —
-  so the two phases compose exactly.
+  so the two phases compose exactly, and the propagation's rounds plan
+  on the LIST of what the peel left, never on all n (``_list_plan``).
 
 All state stays on device with one small plan readback per round
 (large D2H readbacks are the cost to avoid); the graph dict is
@@ -109,6 +110,18 @@ def _colowner(g):
         co = jnp.concatenate([owner, jnp.full((1,), n, jnp.int32)])
         g["colowner"] = co
     return co
+
+
+def _plan_stats(nf, m8, overflow, pmin):
+    """A plan's one readback: ``[nf, m8, overflow, pmin]`` as int32
+    (a float32 ``pmin`` travels as its bits)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jnp.concatenate(
+        [jnp.stack([nf, m8, overflow]),
+         jax.lax.bitcast_convert_type(pmin, jnp.int32)[None]
+         if pmin.dtype == jnp.float32 else pmin[None]])
 
 
 def _band_plan(kind: str):
@@ -199,11 +212,8 @@ def _band_plan(kind: str):
             # their minimum tells the host where the next bucket starts
             pending = changed & ~inb
             pmin = jnp.min(jnp.where(pending, val[:n_], big_))
-            stats = jnp.concatenate(
-                [jnp.stack([nf, m8, overflow]),
-                 jax.lax.bitcast_convert_type(pmin, jnp.int32)[None]
-                 if val.dtype == jnp.float32 else pmin[None]])
-            return stats, flist, lb, jnp.asarray(thr, val.dtype)
+            return _plan_stats(nf, m8, overflow, pmin), flist, lb, \
+                jnp.asarray(thr, val.dtype)
         return bplan
     return jit_once(f"frontier_bandplan_{kind}", build)
 
@@ -211,6 +221,45 @@ def _band_plan(kind: str):
 # fixed in-band list width for the merged band plan (one compile
 # bucket; truncation is sound — see _band_plan)
 QUANT_LIST_CAP = 1 << 23
+
+
+def _list_plan(kind: str):
+    """``_band_plan``'s round plan over a LIST of candidates instead of
+    all n — the min-label rounds behind a peel, whose every improved
+    vertex lies in the peel's remainder (``_wcc_seed_labels``). ``rlist``
+    holds the candidates' ids ascending with fill ``n_``; its first
+    ``w`` entries (a static width covering them all) are planned:
+    ``val``, ``val_exp`` and ``degc`` are gathered at list width and
+    ``banded_frontier`` compacts at list width, so a round costs the
+    remainder's size and not the graph's. Threshold modes: the caller's
+    ``bucket_end`` only (no quantile band: int-valued kinds never take
+    one). Returns what ``bplan`` returns — the same members in the same
+    ascending order, so the same masses, segment bounds and ``pmin`` —
+    with ``flist`` [w] in place of [f_cap]: ``_push_list`` reads either."""
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        from titan_tpu.ops.compaction import banded_frontier
+
+        @functools.partial(jax.jit,
+                           static_argnames=("n_", "w", "k_max", "budget"))
+        def lplan(val, val_exp, degc, rlist, bucket_end, n_: int, w: int,
+                  k_max: int, budget: int):
+            cand = rlist[:w]
+            v = jnp.minimum(cand, n_)
+            valv, mass = val[v], degc[v]
+            changed = (cand < n_) & (valv < val_exp[v]) & (mass > 0)
+            big_ = jnp.asarray(FINF if val.dtype == jnp.float32
+                               else IINF, val.dtype)
+            thr = jnp.asarray(bucket_end, val.dtype)
+            inb = changed & (valv < thr)
+            nf, m8, overflow, flist, lb = banded_frontier(
+                inb, mass, w, k_max, budget, n_, ids=cand)
+            pmin = jnp.min(jnp.where(changed & ~inb, valv, big_))
+            return _plan_stats(nf, m8, overflow, pmin), flist, lb, thr
+        return lplan
+    return jit_once(f"frontier_listplan_{kind}", build)
 
 
 def _push_list(kind: str):
@@ -347,6 +396,60 @@ def _max_degc(g) -> int:
 QUANTILE_MASS_DEFAULT = 1 << 24
 
 
+def _round_planner(kind: str, g, budget: int, remainder=None):
+    """The round's plan as both round loops dispatch it: ``plan(val,
+    val_exp, be_dev, quantile_mass) -> (qf_cap, stats, flist, lbounds,
+    thr_dev)``, ``qf_cap`` the width of ``flist``.
+
+    Without ``remainder``: ``_band_plan`` over all n. List width:
+    quantile mode caps at QUANT_LIST_CAP (the band carries
+    ~quantile_mass chunks, so members are bounded and truncation only
+    defers); plain/delta modes must cover EVERY improved vertex in one
+    round when possible (a dense WCC round lists up to n members —
+    capping it at 2^23 would multiply round count by n/2^23, each
+    paying the plan sync), so they list at full w_max width — per-round
+    coverage is then bounded by nseg exactly like the r5 vertex-range
+    path (64 x budget chunks). Computed per round: a quantile->plain
+    escalation flips it (one extra plan compile, rare fp corner).
+
+    With ``remainder = (rlist, w)`` (a peel's: ``_wcc_domain``):
+    ``_list_plan`` over the list's first ``w`` entries, every round —
+    the same members in the same order at the list's cost.
+
+    WCC's plans are counted by the road they took
+    (``device.wcc.plans{domain}``)."""
+    from titan_tpu.obs import devprof
+
+    n, degc = g["n"], g["degc"]
+    # the in-band list never usefully exceeds the vertex count: cap its
+    # width at the largest power of two that fits the state arrays
+    w_max = 1 << ((n + 1).bit_length() - 1)
+    if remainder is None:
+        bplan = _band_plan(kind)
+
+        def plan(val, val_exp, be_dev, quantile_mass):
+            qf_cap = min(QUANT_LIST_CAP, w_max) if quantile_mass \
+                else w_max
+            if kind == "wcc":
+                devprof.count_wcc_plan("n")
+            return (qf_cap,) + bplan(
+                val, val_exp, degc, be_dev, n_=n, f_cap=qf_cap,
+                k_max=SLICE_K_MAX, budget=budget,
+                quantile_mass=quantile_mass)
+    else:
+        rlist, w = remainder
+        lplan = _list_plan(kind)
+
+        def plan(val, val_exp, be_dev, quantile_mass):
+            assert not quantile_mass, "a list plan has no quantile band"
+            if kind == "wcc":
+                devprof.count_wcc_plan("list")
+            return (w,) + lplan(
+                val, val_exp, degc, rlist, be_dev, n_=n, w=w,
+                k_max=SLICE_K_MAX, budget=budget)
+    return plan
+
+
 class RoundInterrupted(Exception):
     """Raised out of ``_frontier_run`` when the caller's ``on_round``
     callback vetoes continuing — the serving layer's cancellation /
@@ -364,10 +467,11 @@ def _frontier_run(snap_or_graph, val, val_exp, kind: str, wparams,
                   quantile_mass: int = 0, on_round=None,
                   checkpoint=None, start_rounds: int = 0,
                   bucket_end0: float | None = None, overlay=None,
-                  sync=contextlib.nullcontext):
+                  sync=contextlib.nullcontext, remainder=None):
     """Expansion-tracked round loop: one plan readback per round
     (_band_plan — compacted in-band list + mass-balanced segment
-    bounds, no n-wide nonzero), then one _push_list dispatch per
+    bounds, no n-wide nonzero; over ``remainder``'s list where a peel
+    hands one: ``_round_planner``), then one _push_list dispatch per
     ~budget chunks of listed mass. With ``delta``, rounds expand only
     the current distance bucket (one-sided) and the bucket advances to
     the minimum pending value when it drains — delta-stepping. With
@@ -395,7 +499,6 @@ def _frontier_run(snap_or_graph, val, val_exp, kind: str, wparams,
         else build_chunked_csr(snap_or_graph)
     n = g["n"]
     dstT, colstart, degc = g["dstT"], g["colstart"], g["degc"]
-    plan = _band_plan(kind)
     pushl = _push_list(kind)
     # live-overlay expansion seam (olap/live): tombstoned base slots
     # are masked out of every push; overlay add-edges relax after each
@@ -412,9 +515,6 @@ def _frontier_run(snap_or_graph, val, val_exp, kind: str, wparams,
     max_dc = _max_degc(g)
     is_f32 = val.dtype == jnp.float32
     big = float(FINF) if is_f32 else int(IINF)
-    # the in-band list never usefully exceeds the vertex count: cap its
-    # width at the largest power of two that fits the state arrays
-    w_max = 1 << ((n + 1).bit_length() - 1)
     # a segment carries up to budget + max_dc chunks (one vertex of
     # overshoot), so budget == 2^k would push p_cap to 2^(k+1) and HALF
     # of every big segment's lanes would be padding — shave max_dc off
@@ -427,6 +527,7 @@ def _frontier_run(snap_or_graph, val, val_exp, kind: str, wparams,
     else:                       # degenerate hub: conservative old scheme
         budget = SLICE_BUDGET_CHUNKS
         p_full = _next_pow2(max(budget + max_dc, 2))
+    plan = _round_planner(kind, g, budget, remainder)
 
     wp = jnp.asarray(np.asarray(wparams, np.float32))
     tbits = ov.tomb_dev if masked else jnp.zeros((1,), jnp.uint8)
@@ -472,27 +573,13 @@ def _frontier_run(snap_or_graph, val, val_exp, kind: str, wparams,
             checkpoint(rounds, {"val": val, "val_exp": val_exp,
                                 "bucket_end": bucket_end,
                                 "quantile_mass": quantile_mass})
-        # list width: quantile mode caps at QUANT_LIST_CAP (the band
-        # carries ~quantile_mass chunks, so members are bounded and
-        # truncation only defers); plain/delta modes must cover EVERY
-        # improved vertex in one round when possible (a dense WCC round
-        # lists up to n members — capping it at 2^23 would multiply
-        # round count by n/2^23, each paying the plan sync), so they
-        # list at full w_max width — per-round coverage is then bounded
-        # by nseg exactly like the r5 vertex-range path (64 x budget
-        # chunks). Computed per round: a quantile->plain escalation
-        # flips it (one extra plan compile, rare fp corner).
-        qf_cap = min(QUANT_LIST_CAP, w_max) if quantile_mass else w_max
         if drain:
             # drain the queued pushes first so the plan sync below
             # measures the plan alone, not their completion
             val.block_until_ready()
         t_plan = _time.time()
-        be_dev = dev_scalar(bucket_end, dtname)
-        stats, flist, lbounds, thr_dev = plan(
-            val, val_exp, degc, be_dev, n_=n, f_cap=qf_cap,
-            k_max=SLICE_K_MAX, budget=budget,
-            quantile_mass=quantile_mass)
+        qf_cap, stats, flist, lbounds, thr_dev = plan(
+            val, val_exp, dev_scalar(bucket_end, dtname), quantile_mass)
         with sync():
             st_h = np.asarray(stats)       # ONE sync per round
         plan_s = _time.time() - t_plan
@@ -596,7 +683,8 @@ class _CohortMember:
 
 def _frontier_cohort(g, members, kind: str, wparams, max_rounds: int,
                      delta: float = 0.0, on_round=None, checkpoint=None,
-                     overlay=None, sync=contextlib.nullcontext) -> None:
+                     overlay=None, sync=contextlib.nullcontext,
+                     remainder=None) -> None:
     """Shared round loop over K per-member ``(val, val_exp)`` states —
     the cohort generalization of ``_frontier_run``. Each round
     dispatches every active member's band plan (the member's OWN static
@@ -625,12 +713,12 @@ def _frontier_cohort(g, members, kind: str, wparams, max_rounds: int,
     ``frontier_sssp``/``frontier_wcc`` (their round counter differs
     from any fresh batchmate — the same split the batched BFS makes).
     ``sync()`` is entered round each blocking plan readback, as in
-    ``_frontier_run``."""
+    ``_frontier_run``. ``remainder``: the peel's list, as there — ONE
+    list for the cohort, read by every member's plan and never donated."""
     import jax.numpy as jnp
 
     n = g["n"]
     dstT, colstart, degc = g["dstT"], g["colstart"], g["degc"]
-    plan = _band_plan(kind)
     pushl = _push_list(kind)
     ov = overlay
     if ov is not None and ov.empty:
@@ -642,7 +730,6 @@ def _frontier_cohort(g, members, kind: str, wparams, max_rounds: int,
     is_f32 = members[0].val.dtype == jnp.float32
     big = float(FINF) if is_f32 else int(IINF)
     dtname = "float32" if is_f32 else "int32"
-    w_max = 1 << ((n + 1).bit_length() - 1)
     target = _next_pow2(max(SLICE_BUDGET_CHUNKS, 2))
     if max_dc <= target // 2:
         budget = target - max_dc
@@ -650,6 +737,7 @@ def _frontier_cohort(g, members, kind: str, wparams, max_rounds: int,
     else:
         budget = SLICE_BUDGET_CHUNKS
         p_full = _next_pow2(max(budget + max_dc, 2))
+    plan = _round_planner(kind, g, budget, remainder)
     wp = jnp.asarray(np.asarray(wparams, np.float32))
     tbits = ov.tomb_dev if masked else jnp.zeros((1,), jnp.uint8)
 
@@ -677,13 +765,8 @@ def _frontier_cohort(g, members, kind: str, wparams, max_rounds: int,
         return True
 
     def _dispatch(m):
-        qf_cap = min(QUANT_LIST_CAP, w_max) if m.quantile_mass else w_max
-        be_dev = dev_scalar(m.bucket_end, dtname)
-        stats, flist, lbounds, thr_dev = plan(
-            m.val, m.val_exp, degc, be_dev, n_=n, f_cap=qf_cap,
-            k_max=SLICE_K_MAX, budget=budget,
-            quantile_mass=m.quantile_mass)
-        return qf_cap, stats, flist, lbounds, thr_dev
+        return plan(m.val, m.val_exp, dev_scalar(m.bucket_end, dtname),
+                    m.quantile_mass)
 
     def _host_step(m, st_h, qf_cap, flist, lbounds, thr_dev) -> str:
         """One member's host-side round logic over its synced stats —
@@ -833,7 +916,8 @@ def frontier_wcc_batched(snap_or_graph, count: int,
     and seed labels are computed ONCE and copied per member; members
     then differ only in their serving-layer hooks (per-job veto,
     checkpoint cadence, fault injection) while sharing the round loop's
-    single stacked plan sync. Each member's labels and round count are
+    single stacked plan sync and, behind a peel, its ONE remainder list
+    (``_wcc_domain``). Each member's labels and round count are
     bit-equal to a solo ``frontier_wcc``. ``checkpoint(k, rounds,
     state)`` states carry ``levels`` like the sequential form. Returns
     ``(labels, rounds, stopped)`` with rounds including the shared BFS
@@ -864,9 +948,9 @@ def frontier_wcc_batched(snap_or_graph, count: int,
         val0 = jnp.concatenate([ids, jnp.full((1,), IINF, jnp.int32)])
         exp0 = jnp.concatenate(
             [ids + 1, jnp.full((1,), IINF, jnp.int32)])
-        levels = 0
+        levels, rem = 0, None
     else:
-        val0, exp0, levels = _wcc_peel(g)
+        val0, exp0, levels, rem = _wcc_peel(g)
     ck = None
     if checkpoint is not None:
         def ck(k, rounds, state, _levels=levels):
@@ -882,7 +966,8 @@ def frontier_wcc_batched(snap_or_graph, count: int,
     with phase("wcc.propagate", k=count) as ph:
         _frontier_cohort(g, members, "wcc", (0.0, 0.0), max_rounds,
                          on_round=on_round, checkpoint=ck,
-                         overlay=overlay, sync=ph.sync)
+                         overlay=overlay, sync=ph.sync,
+                         remainder=_wcc_domain(n, rem, ph))
         ph.set(rounds=max(m.rounds for m in members))
     for m in members:
         devprof.count_wcc_rounds(m.rounds)
@@ -961,13 +1046,40 @@ def frontier_sssp(snap_or_graph, source_dense: int, min_w: float = 0.0,
     return out, rounds
 
 
+# A peel's remainder is listed, and the rounds behind it planned on the
+# list, up to n / LIST_PLAN_RATIO vertices; past that the rounds plan
+# over all n, as they do without a peel. A list plan gathers three
+# values an entry where the n-wide plan reads them in order, so it
+# costs 70 ns an entry against 16.5 ns a vertex (v5e, n = 8,871,268,
+# experiments/wcc_listplan_probe.py, PR 37: the n-wide plan 146.2 ms; a
+# FULL list of 2^13 1.7 ms, 2^18 16.2, 2^20 = n / 8.5 70.8, 2^21 =
+# n / 4.2 150.5, 2^23 581.9): the two cross at n / 4.2, and the list
+# has its own n-wide compaction to pay back first (54 ms in the
+# seeding). At n / 8 the dearest list plan is half the n-wide one.
+LIST_PLAN_RATIO = 8
+
+
+def _remainder_cap(n: int) -> int:
+    """The static width ``_wcc_seed_labels`` lists the remainder at:
+    the largest power of two within n / LIST_PLAN_RATIO — from the
+    graph's size alone, so one seeding executable a graph whatever the
+    peel left."""
+    return max(2, 1 << (max(n // LIST_PLAN_RATIO, 1).bit_length() - 1))
+
+
 def _wcc_peel(g):
     """The giant component off the top: one direction-optimising BFS
     from the largest-degree vertex — on power-law graphs it anchors the
     giant component, so the BFS peels about all the edge mass — then the
-    seed labels. Returns ``(val, val_exp, levels)``. Phases: the BFS's
-    own ``bfs.level``s, then ``wcc.seed`` (``levels``, ``source_deg``;
-    it dispatches, nothing blocks)."""
+    seed labels and the REMAINDER: the vertices the BFS did not reach
+    that have an edge, listed once, ids ascending, at ``_remainder_cap``.
+    The BFS ran to exhaustion, so no edge leaves the reached set: every
+    vertex a propagation round can improve is on that list, from the
+    first plan to the one that finds nothing. Returns ``(val, val_exp,
+    levels, (rlist, count))``, the last two on the device (``count`` may
+    exceed the cap: ``_wcc_domain`` decides). Phases: the BFS's own
+    ``bfs.level``s, then ``wcc.seed`` (``levels``, ``source_deg``,
+    ``r_cap``; it dispatches, nothing blocks)."""
     import jax.numpy as jnp
 
     from titan_tpu.obs.tracing import phase
@@ -980,12 +1092,38 @@ def _wcc_peel(g):
     # region as expanded, silently splitting its component's labels
     dist, levels = frontier_bfs_hybrid(g, seed_v, max_levels=n,
                                        return_device=True)
+    r_cap = _remainder_cap(n)
     with phase("wcc.seed", levels=int(levels), source_deg=seed_deg,
-               **{"async": True}):
+               r_cap=r_cap, **{"async": True}):
         # frontier_bfs_hybrid returns dist[:n]; the seeding jit
         # re-appends nothing — it only reads [:n_]
-        val, val_exp = _wcc_seed_labels()(dist, n_=n)
-    return val, val_exp, levels
+        val, val_exp, rlist, count = _wcc_seed_labels()(
+            dist, g["degc"], n_=n, r_cap=r_cap)
+    return val, val_exp, levels, (rlist, count)
+
+
+def _wcc_domain(n: int, rem, ph):
+    """What the propagation plans on, decided from what it can observe:
+    the peel's remainder ``rem = (rlist, count)`` where its count — read
+    back here, once, inside ``wcc.propagate`` (``ph``), so the phase
+    holds the seeding's n-wide compaction — fits the list's cap: then
+    ``(rlist, w)``, ``w`` the count's power of two, for ``_frontier_run``
+    / ``_frontier_cohort``. None (all n, today's ``bplan``) where no peel
+    ran (``rem`` None: a live overlay, a resumed run) or the remainder
+    outgrew the cap (no giant component). ``ph`` gets ``domain`` (the
+    width planned on) and, after a peel, ``remainder`` (the count)."""
+    if rem is None:
+        ph.set(domain=n)
+        return None
+    rlist, count = rem
+    with ph.sync():
+        count = int(np.asarray(count))
+    if count > rlist.shape[0]:
+        ph.set(domain=n, remainder=count)
+        return None
+    w = _next_pow2(max(count, 2))
+    ph.set(domain=w, remainder=count)
+    return rlist, w
 
 
 def _wcc_readback(out):
@@ -1007,12 +1145,18 @@ def _wcc_seed_labels():
         import jax
         import jax.numpy as jnp
 
-        @functools.partial(jax.jit, static_argnames=("n_",))
-        def seed(dist, n_: int):
+        from titan_tpu.ops.compaction import compact_ids
+
+        @functools.partial(jax.jit, static_argnames=("n_", "r_cap"))
+        def seed(dist, degc, n_: int, r_cap: int):
             """Label arrays from a finished BFS: the reached component
             collapses to its minimum vertex id (already expanded — a
             closed component never pushes again); the rest start at
-            their own id, improved-state so round 1 expands them."""
+            their own id, improved-state so round 1 expands them. And
+            the remainder, in the one pass that reads ``dist`` over n:
+            the unreached vertices that have an edge (one without
+            pushes nothing and is reached by nothing), compacted to
+            ``r_cap`` ids ascending, fill ``n_``, with their count."""
             ids = jnp.arange(n_, dtype=jnp.int32)
             reached = dist[:n_] < INF
             rmin = jnp.min(jnp.where(reached, ids, IINF))
@@ -1021,7 +1165,9 @@ def _wcc_seed_labels():
             exp = jnp.concatenate(
                 [jnp.where(reached, lab, lab + 1),
                  jnp.full((1,), IINF, jnp.int32)])
-            return val, exp
+            count, rlist = compact_ids(~reached & (degc[:n_] > 0),
+                                       r_cap, n_)
+            return val, exp, rlist, count
         return seed
     return jit_once("wcc_seed_labels", build)
 
@@ -1307,8 +1453,11 @@ def frontier_wcc(snap_or_graph, max_rounds: int = 10_000,
     Phases (obs/tracing: leaf spans under the caller's scope — a served
     job's ``run`` — and profiler annotations): the peel's ``bfs.level``s
     and ``wcc.seed`` (``_wcc_peel``), ``wcc.propagate`` (``rounds``,
-    ``sync_ms``: the plan readback a round), ``wcc.result``
-    (``_wcc_readback``); ``device.wcc.rounds`` counts the rounds."""
+    ``sync_ms``: the plan readback a round; ``domain``, ``remainder``:
+    what the rounds planned on, ``_wcc_domain`` — the peel's remainder
+    where it is small beside n, all n without a peel), ``wcc.result``
+    (``_wcc_readback``); ``device.wcc.rounds`` counts the rounds,
+    ``device.wcc.plans{domain}`` their plans by road."""
     import jax.numpy as jnp
 
     from titan_tpu.obs import devprof
@@ -1324,7 +1473,7 @@ def frontier_wcc(snap_or_graph, max_rounds: int = 10_000,
     if n == 0:
         out = jnp.zeros((0,), jnp.int32)
         return (out if return_device else np.asarray(out)), 0
-    start_rounds = 0
+    start_rounds, rem = 0, None
     if resume is not None:
         val = jnp.asarray(resume["val"], jnp.int32)
         val_exp = jnp.asarray(resume["val_exp"], jnp.int32)
@@ -1343,7 +1492,7 @@ def frontier_wcc(snap_or_graph, max_rounds: int = 10_000,
             [ids + 1, jnp.full((1,), IINF, jnp.int32)])
         levels = 0
     else:
-        val, val_exp, levels = _wcc_peel(g)
+        val, val_exp, levels, rem = _wcc_peel(g)
     if checkpoint is not None:
         _ck = checkpoint
 
@@ -1358,7 +1507,8 @@ def frontier_wcc(snap_or_graph, max_rounds: int = 10_000,
                                     max_rounds, on_round=on_round,
                                     checkpoint=checkpoint,
                                     start_rounds=start_rounds,
-                                    overlay=overlay, sync=ph.sync)
+                                    overlay=overlay, sync=ph.sync,
+                                    remainder=_wcc_domain(n, rem, ph))
         ran = int(rounds) - start_rounds
         ph.set(rounds=ran)
     devprof.count_wcc_rounds(ran)

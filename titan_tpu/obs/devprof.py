@@ -237,6 +237,16 @@ def count_wcc_rounds(rounds: int) -> None:
             prof.metrics.counter("device.wcc.rounds").inc(int(rounds))
 
 
+def count_wcc_plan(domain: str) -> None:
+    """Count one round plan of a WCC run by the road it took:
+    ``"list"`` over the peel's remainder (``frontier._list_plan``),
+    ``"n"`` over every vertex (``_band_plan``: no peel, or a remainder
+    past the list's cap)."""
+    for prof in list(_PROFILERS):
+        prof.metrics.counter("device.wcc.plans",
+                             labels={"domain": domain}).inc()
+
+
 def count_pr_iteration() -> None:
     """Count one iteration of ``frontier.pagerank_dense`` (its sweep
     and its finish dispatched)."""

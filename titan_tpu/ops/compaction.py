@@ -121,14 +121,16 @@ def claim_reset(claim, keys, sentinel: int = CLAIM_SENTINEL):
 
 
 def banded_frontier(mask, mass, cap: int, k_max: int, budget: int,
-                    fill):
+                    fill, ids=None):
     """Band extraction for priority-batched schedulers: compact the
     member ids AND their per-member masses in one shared-index double
     scatter (no cap-wide ``mass[list]`` re-gather), then cut the listed
     mass into ~``budget``-sized segments.
 
     ``mask`` [L] selects the band, ``mass`` [L] is each item's weight
-    (chunks) read contiguously. Returns ``(nf, m8, overflow, flist,
+    (chunks) read contiguously; ``ids`` [L] names the items (ascending;
+    default: their positions — a band over a LIST of candidates hands
+    the list itself, so the members come out as vertex ids). Returns ``(nf, m8, overflow, flist,
     bounds)``: ``nf`` listed members (min(count, cap)), ``m8`` their
     total mass (int32, clamped), ``overflow`` nonzero iff the mass
     cumsum wrapped int32 (accumulation runs in int64 when x64 is
@@ -143,7 +145,8 @@ def banded_frontier(mask, mass, cap: int, k_max: int, budget: int,
     import jax
     import jax.numpy as jnp
 
-    ids = jnp.arange(mask.shape[0], dtype=jnp.int32)
+    if ids is None:
+        ids = jnp.arange(mask.shape[0], dtype=jnp.int32)
     count, (flist, mlist) = scatter_compact(
         mask, (ids, mass), cap, (fill, 0))
     nf = jnp.minimum(count, cap)
