@@ -1,0 +1,20 @@
+"""Scheduler and batcher: milliseconds of a job during which none of its
+programs was on the device, median over the window's jobs, from the
+journal: the job's extent (its ``job.lease`` started -> its last span
+ended) less the union of its ``kernel`` intervals (``kernel_spans.py``).
+It prints that idle time by the leaf phase that covers it first
+(``job.admit``, ``wcc.count``, ``pr.result`` ...; what no phase covers is
+the links between them). Nothing where the program writes no such
+spans."""
+
+import kernel_spans
+import stats
+
+
+def read(record: dict):
+    all_jobs = kernel_spans.read_jobs(record)
+    if all_jobs is None:
+        return None
+    for line in kernel_spans.describe_idle(all_jobs):
+        print(line, flush=True)
+    return stats.median([kernel_spans.idle_ms(j) for j in all_jobs])

@@ -140,8 +140,14 @@ def test_trace_handle_parent_switching_and_summary():
     s = trace_summary(tr, "j")
     assert s["queue_ms"] == 4.0
     assert s["fuse_ms"] == 1.0
-    assert s["device_ms"] == 250.0
+    # the wall of `run` is the host's; the device's time is read from
+    # the trace's stamped `kernel` spans and absent where it has none
+    assert s["run_ms"] == 250.0
+    assert "device_ms" not in s
     assert s["rounds"] == 3
+    for ms in (40.0, 2.5):
+        h.event("kernel", parent=run, key="k", device_ms=ms)
+    assert trace_summary(tr, "j")["device_ms"] == 42.5
 
 
 def test_tracer_thread_safe_under_concurrent_writes():

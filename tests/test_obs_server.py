@@ -244,7 +244,7 @@ def test_retried_batched_bfs_trace_tree_over_http(served, tmp_path):
 
     # the wire digest agrees with the tree
     assert final["trace"]["queue_ms"] >= 0
-    assert final["trace"]["device_ms"] > 0
+    assert final["trace"]["run_ms"] > 0
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Device-cost profiler: compile / dispatch / transfer telemetry.
 
 The layer that actually decides latency on a TPU — XLA compilations,
-per-kernel device wall time, H2D/D2H traffic — was invisible outside
+per-kernel device time, H2D/D2H traffic — was invisible outside
 hand-run benches (ISSUE 10). This module makes it a first-class metric
 surface:
 
@@ -23,6 +23,20 @@ surface:
   else the trace ``compile``: key, static arguments, trace / lower /
   backend milliseconds, persistent-cache hit / miss / off. Needs the
   tracer and a profiler both on (the defaults).
+* **Kernel spans** (ISSUE 38): every call through ``_dispatch`` hands
+  the smallest array of its output to ONE watcher thread, which takes
+  the calls in dispatch order, blocks until that array is ready and
+  stamps the moment. One chip runs its programs in dispatch order, so
+  ``device_ms = ready - max(dispatched, previous ready)`` is the
+  program's time on the device and ``queued_ms = max(previous ready -
+  dispatched, 0)`` the time it waited behind the program before it.
+  The stamp feeds ``device.exec.ms{kernel}`` and, under a tracer's
+  scope, ONE ``kernel`` span under the span current where the call was
+  dispatched. Nothing is added on the dispatching
+  thread but a queue put; an output that was donated or deleted before
+  the watcher reached it is ``stamped: false``
+  (``device.exec.unstamped{kernel}``) and its time falls to the next
+  stamped program.
 * **Transfer accounting**: the upload/readback seams
   (``engine._device_graph_single``, ``bfs_hybrid.build_chunked_csr``,
   the overlay's delta pages, result readbacks) call
@@ -44,6 +58,8 @@ profiling on or off (pinned by tests/test_devprof.py, alongside the
 
 from __future__ import annotations
 
+import logging
+import queue
 import threading
 import time
 from typing import Optional
@@ -51,6 +67,8 @@ from typing import Optional
 from titan_tpu.obs import tracing
 from titan_tpu.utils import jitcache
 from titan_tpu.utils.metrics import MetricManager
+
+log = logging.getLogger(__name__)
 
 #: installed profilers, in install order (process-wide — kernel caches
 #: are process-wide; tier-1 runs serially so tests stay deterministic)
@@ -115,6 +133,13 @@ def _on_jax_duration(name: str, duration_s: float, fun_name=None,
         _compile_span(rec, duration_s, fun_name, ctx)
 
 
+def _statics(kwargs: dict) -> dict:
+    """A call's static arguments, as far as the shim can tell them from
+    arrays: the integer, boolean and string keywords."""
+    return {k: v for k, v in kwargs.items()
+            if isinstance(v, (bool, int, str))}
+
+
 def _compile_span(rec: dict, backend_s: float, fun_name, ctx) -> None:
     """Journal one built-or-loaded executable, made after the fact from
     the listener's durations: under the span current on this thread (the
@@ -128,10 +153,7 @@ def _compile_span(rec: dict, backend_s: float, fun_name, ctx) -> None:
             return
     attrs: dict = {}
     if ctx is not None:
-        # the call's static arguments, as far as the shim can tell them
-        # from arrays: the integer, boolean and string keywords
-        attrs.update((k, v) for k, v in ctx["kwargs"].items()
-                     if isinstance(v, (bool, int, str)))
+        attrs.update(_statics(ctx["kwargs"]))
         attrs["key"] = ctx["key"]
     else:
         name = str(fun_name or "?")
@@ -165,10 +187,160 @@ def _ensure_listener() -> None:
         pass
 
 
+class _Watcher:
+    """The one thread that stamps when each dispatched program's output
+    became ready on the device (process-wide, as the device's order
+    is). It holds the smallest array of an output until that array is
+    ready, and nothing after."""
+
+    #: calls waiting for their stamp; a full queue drops the stamp
+    #: (counted), it never blocks a dispatch
+    MAX_PENDING = 4096
+
+    def __init__(self):
+        self._q: queue.Queue = queue.Queue(self.MAX_PENDING)
+        self._thread: Optional[threading.Thread] = None
+        self._lock = threading.Lock()
+        self._prev_ready = 0.0
+        # dispatch time of the first unstamped program since the last
+        # stamp: the next stamped program's interval starts no later
+        self._carry: Optional[float] = None
+
+    @property
+    def alive(self) -> bool:
+        t = self._thread
+        return t is not None and t.is_alive()
+
+    def submit(self, key: str, fn, kwargs: dict, out, wall_s: float
+               ) -> None:
+        """Dispatching thread: queue one call for its stamp."""
+        where = tracing.current_span()
+        clock = where[0].clock if where is not None else time.time
+        attrs = dict(_statics(kwargs), key=key,
+                     fn=getattr(fn, "__name__", key),
+                     dispatch_ms=round(wall_s * 1e3, 3))
+        profs = list(_PROFILERS)
+        try:
+            self._q.put_nowait((_smallest_array(out),
+                                (attrs, where, clock, clock(), profs)))
+        except queue.Full:
+            for prof in profs:
+                prof.on_stamp(key, 0.0, False)
+            return
+        if not self.alive:
+            self._start()
+
+    def _start(self) -> None:
+        with self._lock:
+            if not self.alive:
+                self._thread = threading.Thread(
+                    target=self._run, name="devprof-watcher", daemon=True)
+                self._thread.start()
+
+    def _run(self) -> None:
+        while True:
+            item = self._q.get()
+            if item is None:
+                return
+            if isinstance(item, threading.Event):
+                item.set()
+                continue
+            leaf, info = item
+            del item
+            stamped = False
+            if leaf is not None:
+                try:
+                    leaf.block_until_ready()
+                    stamped = True
+                except Exception:   # donated or deleted since (a
+                    pass            # RuntimeError), or it failed
+                del leaf        # the moment it is stamped
+            try:
+                self._stamp(info, stamped)
+            except Exception:   # the watcher outlives a stamp it lost
+                log.exception("devprof: stamp of %s lost", info[0]["key"])
+
+    def _stamp(self, info: tuple, stamped: bool) -> None:
+        attrs, where, clock, dispatched, profs = info
+        prev = self._prev_ready
+        if stamped:
+            ready = clock()
+            carry, self._carry = self._carry, None
+            start = min(max(prev, dispatched if carry is None else carry),
+                        ready)
+            self._prev_ready = max(ready, prev)
+        else:
+            # an instant where it was dispatched; the next stamped
+            # program's interval reaches back to here
+            start = ready = dispatched
+            if self._carry is None:
+                self._carry = dispatched
+        device_s = ready - start
+        for prof in profs:
+            prof.on_stamp(attrs["key"], device_s, stamped)
+        if where is not None:
+            tracer, trace_id, parent = where
+            tracer.event(trace_id, "kernel", parent=parent, t0=start,
+                         t1=ready, **attrs,
+                         device_ms=round(device_s * 1e3, 3),
+                         queued_ms=round(
+                             max(prev - dispatched, 0.0) * 1e3, 3),
+                         stamped=stamped)
+
+    def drain(self, timeout: float) -> bool:
+        """Wait until every call queued so far has its stamp."""
+        if not self.alive:
+            return True
+        done = threading.Event()
+        try:
+            self._q.put(done, timeout=timeout)
+        except queue.Full:
+            return False
+        return done.wait(timeout)
+
+    def stop(self, timeout: float) -> None:
+        """Stamp what is queued, then end the thread (the last profiler
+        left; the next profiled call starts another)."""
+        t = self._thread
+        if t is None or not t.is_alive():
+            return
+        try:
+            self._q.put(None, timeout=timeout)
+        except queue.Full:
+            return
+        t.join(timeout)
+
+
+def _smallest_array(out):
+    """The smallest device array among an output's leaves (None where
+    it has none): the one the watcher blocks on and holds meanwhile."""
+    import jax
+    best = None
+    for leaf in jax.tree_util.tree_leaves(out):
+        # (a call made while an outer jit traces returns tracers)
+        if isinstance(leaf, jax.Array) \
+                and not isinstance(leaf, jax.core.Tracer) \
+                and (best is None or leaf.size < best.size):
+            best = leaf
+    return best
+
+
+_WATCHER = _Watcher()
+
+
+def drain(timeout: float = 5.0) -> bool:
+    """Wait until every profiled call dispatched so far has its stamp
+    (its ``kernel`` span journaled, its ``device.exec.ms`` counted):
+    what a reader of the journal calls first. False on a timeout."""
+    return _WATCHER.drain(timeout)
+
+
 def _dispatch(key: str, fn, args, kwargs):
     """The jitcache profile dispatch: measure once, fan out to every
     installed profiler. ``fn`` is the RAW jitted function (its
-    ``_cache_size`` delta detects a per-shape-bucket compile)."""
+    ``_cache_size`` delta detects a per-shape-bucket compile). The
+    call's wall is the host's dispatch; the device's time comes from
+    the watcher's stamp."""
     if not _PROFILERS:
         return fn(*args, **kwargs)
     cache_size = getattr(fn, "_cache_size", None)
@@ -187,6 +359,7 @@ def _dispatch(key: str, fn, args, kwargs):
         for prof in list(_PROFILERS):
             prof.on_call(key, wall, compiled, ctx["compile_s"],
                          ctx["compile_events"])
+    _WATCHER.submit(key, fn, kwargs, out, wall)
     return out
 
 
@@ -272,10 +445,12 @@ def current() -> Optional["DeviceCostProfiler"]:
 class DeviceCostProfiler:
     """Process-wide device-cost accounting into a metrics registry.
 
-    Per profiled call: ``device.exec.calls`` / ``device.exec.ms``
-    (labeled ``{kernel}``); a compile (new static shape bucket) counts
-    on ``device.compile.count`` + ``device.compile.ms``, a warm call on
-    ``device.compile.cache_hits``. Transfer seams land on
+    Per profiled call: ``device.exec.calls`` (labeled ``{kernel}``); a
+    compile (new static shape bucket) counts on ``device.compile.count``
+    + ``device.compile.ms``, a warm call on
+    ``device.compile.cache_hits``. Per stamp of the watcher:
+    ``device.exec.ms`` (the program's time on the device) or
+    ``device.exec.unstamped``. Transfer seams land on
     ``device.xfer.h2d_bytes`` / ``device.xfer.d2h_bytes`` (labeled
     ``{site}``). A bounded ``compile_log`` keeps the recent compile
     events for postmortem bundles, and ``window()`` captures totals
@@ -311,8 +486,11 @@ class DeviceCostProfiler:
         with _INSTALL_LOCK:
             if self in _PROFILERS:
                 _PROFILERS.remove(self)
-            if not _PROFILERS:
+            last = not _PROFILERS
+            if last:
                 jitcache.set_profile_dispatch(None)
+        if last:
+            _WATCHER.stop(5.0)
 
     def __enter__(self) -> "DeviceCostProfiler":
         return self.install()
@@ -330,8 +508,6 @@ class DeviceCostProfiler:
                 compile_s: float, compile_events: int) -> None:
         m = self.metrics
         m.counter("device.exec.calls", labels={"kernel": key}).inc()
-        m.histogram("device.exec.ms",
-                    labels={"kernel": key}).update(wall_s * 1e3)
         if compiled:
             m.counter("device.compile.count",
                       labels={"kernel": key}).inc()
@@ -346,12 +522,10 @@ class DeviceCostProfiler:
                       "compile_s": 0.0, "compile_events": 0,
                       "exec_s": 0.0})
             k["calls"] += 1
-            k["exec_s"] += wall_s
             k["compile_s"] += compile_s
             k["compile_events"] += compile_events
             t = self._totals
             t["calls"] += 1
-            t["exec_s"] += wall_s
             t["compile_s"] += compile_s
             if compiled:
                 k["compiles"] += 1
@@ -368,9 +542,26 @@ class DeviceCostProfiler:
         rec = self.recorder
         if rec is not None:
             rec.record("device", kernel=key,
-                       ms=round(wall_s * 1e3, 3), compiled=compiled,
+                       dispatch_ms=round(wall_s * 1e3, 3),
+                       compiled=compiled,
                        **({"compile_ms": round(compile_s * 1e3, 3)}
                           if compiled else {}))
+
+    def on_stamp(self, key: str, device_s: float, stamped: bool) -> None:
+        """Watcher thread: one profiled call's time on the device, or
+        that it could not be stamped."""
+        if not stamped:
+            self.metrics.counter("device.exec.unstamped",
+                                 labels={"kernel": key}).inc()
+            return
+        self.metrics.histogram("device.exec.ms",
+                               labels={"kernel": key}).update(
+                                   device_s * 1e3)
+        with self._lock:
+            k = self._kernels.get(key)
+            if k is not None:
+                k["exec_s"] += device_s
+            self._totals["exec_s"] += device_s
 
     def on_xfer(self, direction: str, site: str, nbytes: int) -> None:
         name = "device.xfer.h2d_bytes" if direction == "h2d" \
@@ -386,7 +577,8 @@ class DeviceCostProfiler:
 
     def kernel_stats(self) -> dict:
         """Per-kernel accumulated stats (calls / compiles / cache hits /
-        compile + exec seconds), keyed by jit_once key."""
+        compile seconds; ``exec_s``: stamped seconds on the device, as
+        far as the watcher has come), keyed by jit_once key."""
         with self._lock:
             return {k: dict(v) for k, v in sorted(self._kernels.items())}
 
@@ -406,8 +598,8 @@ class DeviceCostProfiler:
             return [dict(e) for e in self._compile_log]
 
     def stats(self) -> dict:
-        """Process totals: calls / compiles / cache hits, compile and
-        exec wall seconds, H2D/D2H bytes."""
+        """Process totals: calls / compiles / cache hits, compile wall
+        and stamped device seconds (``exec_s``), H2D/D2H bytes."""
         with self._lock:
             out = dict(self._totals)
         out["compile_s"] = round(out["compile_s"], 6)

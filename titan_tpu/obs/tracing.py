@@ -7,7 +7,9 @@ Design constraints (ISSUE r10):
 
 * **host-only** — spans are plain host timestamps taken at seams that
   already exist (round-boundary callbacks, checkpoint hooks); nothing
-  here adds device collectives or syncs inside jitted code;
+  here adds device collectives or syncs inside jitted code. The one
+  span that times the device, ``kernel``, is made after the fact by
+  ``obs/devprof``'s watcher thread (ISSUE 38), off the serving threads;
 * **bounded** — each trace is a ring buffer of ``max_spans`` spans
   (oldest non-root spans drop first, counted in ``dropped_spans``) and
   the tracer holds at most ``max_traces`` traces (oldest evicted), so a
@@ -622,9 +624,10 @@ def phase(name: str, level: Optional[int] = None, **attrs):
 def trace_summary(tracer: Optional[Tracer], trace_id: str
                   ) -> Optional[dict]:
     """The ``GET /jobs`` digest of a job's trace: where the time went
-    (queue / fuse / device) plus the round count — computed from the
-    journal, None when the trace doesn't exist (tracing disabled /
-    evicted)."""
+    (queue / fuse / run, the host's walls; ``device_ms``: the stamped
+    device time of the trace's ``kernel`` spans, where it has any) plus
+    the round count — computed from the journal, None when the trace
+    doesn't exist (tracing disabled / evicted)."""
     if tracer is None:
         return None
     spans = tracer.spans(trace_id)
@@ -632,8 +635,7 @@ def trace_summary(tracer: Optional[Tracer], trace_id: str
         return None
     out: dict = {"spans": len(spans)}
     rounds = 0
-    device_ms = 0.0
-    have_device = False
+    run_ms = device_ms = None
     for s in spans:
         d = s.duration_ms
         if s.name == "queue" and d is not None:
@@ -641,11 +643,14 @@ def trace_summary(tracer: Optional[Tracer], trace_id: str
         elif s.name == "fuse" and d is not None:
             out["fuse_ms"] = round(d, 3)
         elif s.name == "run" and d is not None:
-            device_ms += d
-            have_device = True
+            run_ms = (run_ms or 0.0) + d
+        elif s.name == "kernel":
+            device_ms = (device_ms or 0.0) + s.attrs["device_ms"]
         elif s.name == "round":
             rounds += 1
-    if have_device:
+    if run_ms is not None:
+        out["run_ms"] = round(run_ms, 3)
+    if device_ms is not None:
         out["device_ms"] = round(device_ms, 3)
     out["rounds"] = rounds
     return out

@@ -44,10 +44,11 @@ Metrics (utils/metrics.MetricManager):
 Device-cost observability (titan_tpu/obs/devprof + flightrec, ISSUE
 10): the scheduler installs a process-wide DeviceCostProfiler by
 default (``profiling=False`` / TITAN_TPU_PROFILING=0 removes it) —
-XLA compiles per static shape bucket, per-kernel device wall and
-H2D/D2H bytes land on the ``device.*`` families, and each executed
-batch's device cost is stitched into its jobs' traces as a
-``device_cost`` span (split over K, like the device-seconds
+XLA compiles per static shape bucket, per-kernel device time
+(stamped by the profiler's watcher thread, which also journals one
+``kernel`` span a call) and H2D/D2H bytes land on the ``device.*``
+families, and each executed batch's device cost is stitched into its
+jobs' traces as a ``device_cost`` span (split over K, like the device-seconds
 accounting). ``flight_dir=`` (or TITAN_TPU_FLIGHT_DIR) attaches a
 FlightRecorder: a bounded ring journals spans / device events /
 counter deltas, and a job that entered execution and ended FAILED /
@@ -167,7 +168,7 @@ class JobScheduler:
             self.tracer.tap = self.recorder.span_tap
         # device-cost profiler (obs/devprof): process-wide interception
         # of the jit entry points (jitcache shim + engine seams) —
-        # compile-per-bucket, per-kernel device wall, H2D/D2H bytes as
+        # compile-per-bucket, per-kernel device time, H2D/D2H bytes as
         # device.* metric families; default ON, one flag removes it
         self.profiler = None
         self._own_profiler = False
@@ -369,6 +370,9 @@ class JobScheduler:
             self.slo.detach_gauges()
         if self.controller is not None:
             self.controller.detach_gauges()
+        # every program dispatched so far gets its stamp, so a reader of
+        # the journal after close() sees every `kernel` span
+        devprof.drain()
         # detach OUR process-wide profiler (a caller-provided one stays
         # the caller's to uninstall)
         if self._own_profiler and self.profiler is not None:
@@ -591,9 +595,9 @@ class JobScheduler:
         return self.slo.evaluate() if self.slo is not None else None
 
     def trace_summary(self, job_id: str) -> Optional[dict]:
-        """Per-job trace digest (queue_ms / fuse_ms / device_ms /
-        rounds) for the ``GET /jobs`` envelope; None when tracing is
-        disabled or the trace was evicted."""
+        """Per-job trace digest (queue_ms / fuse_ms / run_ms /
+        device_ms / rounds) for the ``GET /jobs`` envelope; None when
+        tracing is disabled or the trace was evicted."""
         from titan_tpu.obs.tracing import trace_summary
         return trace_summary(self.tracer, job_id)
 
@@ -893,12 +897,13 @@ class JobScheduler:
     def _stitch_device_cost(self, group: list[Job], cost: dict) -> None:
         """Per-job device-cost attribution (obs/devprof, ISSUE 10):
         the executed batch's profiler window — kernel calls, compiles,
-        compile/exec wall, H2D/D2H bytes — lands on each member's trace
+        compile wall, H2D/D2H bytes — lands on each member's trace
         as a ``device_cost`` event, with the divisible costs split
         evenly over the K fused jobs exactly like the device-seconds
         accounting (the whole point of fusion is that a job's share IS
         total/K). Compile and call counts stay batch-wide: a compile is
-        shared, not divisible."""
+        shared, not divisible. The device's time is not here: it is the
+        trace's ``kernel`` spans, stamped after the window closed."""
         if not cost["calls"]:
             return
         k = len(group)
@@ -911,7 +916,6 @@ class JobScheduler:
                     compiles=cost["compiles"],
                     compile_ms_share=round(cost["compile_s"] * 1e3 / k,
                                            3),
-                    exec_ms_share=round(cost["exec_s"] * 1e3 / k, 3),
                     h2d_bytes_share=cost["h2d_bytes"] // k,
                     d2h_bytes_share=cost["d2h_bytes"] // k)
 

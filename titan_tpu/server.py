@@ -143,7 +143,9 @@ Endpoints:
                       Tracer.ingest, marked ``remote``/``instance``).
                       Each ``GET /jobs``
                       entry also carries a ``trace`` digest
-                      (queue_ms / fuse_ms / device_ms / rounds).
+                      (queue_ms / fuse_ms / run_ms / device_ms /
+                      rounds; device_ms: the stamped device time
+                      of the trace's kernel spans).
                       docs/observability.md documents the span model.
   GET /trace/export?job=<id> — drain the trace's COMPLETED spans
                       exactly once as wire dicts, framed with
