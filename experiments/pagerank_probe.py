@@ -38,6 +38,7 @@ def gather_probe(im: dict, n: int) -> dict:
     import numpy as np
 
     from titan_tpu.models import pagerank_pull as pp
+    from titan_tpu.ops import vmem_gather as vg
     from titan_tpu.ops.segment import seg_scan
 
     def median_ms(fn, *args):
@@ -49,18 +50,18 @@ def gather_probe(im: dict, n: int) -> dict:
             ts.append((time.perf_counter() - t0) * 1e3)
         return float(np.median(ts))
 
-    rows = pp.table_rows(n)
+    rows = vg.table_rows(n)
     flat = np.zeros(rows * 128, np.float32)
     flat[:n] = np.random.default_rng(7).random(n, np.float32) / n
     table = jnp.asarray(flat.reshape(rows, 128))
     lanes = 8 * im["q_in"]
-    out: dict = {"lanes": lanes, "impl": pp.gather_impl(n)}
+    out: dict = {"lanes": lanes, "impl": vg.gather_impl(n)}
     xla = jax.jit(pp._colsum_xla)
     out["xla_ms"] = median_ms(xla, im["idx"], table)
     out["xla_lanes_per_s"] = lanes / out["xla_ms"] * 1e3
     want = xla(im["idx"], table)
     if out["impl"] == "vmem":
-        vmem = jax.jit(pp._colsum_vmem)
+        vmem = jax.jit(vg.colsum_vmem)
         out["vmem_ms"] = median_ms(vmem, im["idx"], table)
         out["vmem_lanes_per_s"] = lanes / out["vmem_ms"] * 1e3
         got = vmem(im["idx"], table)

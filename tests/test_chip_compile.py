@@ -197,8 +197,9 @@ def test_pagerank_pull_at_graph500_22(spec):
     SMEM, the segment sum, the cut to [n]."""
     from titan_tpu.models import pagerank_pull as pp
     from titan_tpu.models.frontier import _pr_result
+    from titan_tpu.ops.vmem_gather import padded_columns
 
-    q_in = -(-Q22 // pp.PULL_BLOCK) * pp.PULL_BLOCK
+    q_in = padded_columns(Q22)
     pull = _compile(pp.pull_step(), spec((N22 + 1,), jnp.float32),
                     spec((N22 + 1,), jnp.float32),
                     spec((8 * q_in,), jnp.int32), spec((q_in,), jnp.bool_),
@@ -227,6 +228,30 @@ def test_wcc_propagation_on_the_remainder_at_graph500_24(spec):
                     budget=(1 << 23) - 50_835)
     # nothing of a plan over the list is n wide
     assert plan.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_dense_opener_at_graph500_24(spec):
+    """What ISSUE 39 put on g500-24.wcc-c2's one pulled level: the
+    split-lane opener n wide over the leading-lane image, its frontier
+    test the 35.5 MB 0/1 table in VMEM; and the image's builder."""
+    from titan_tpu.models import bfs_hybrid as H
+    from titan_tpu.ops import vmem_gather as vg
+
+    n, q, lanes = 8_871_268, 70_278_271, H.SPLIT_LANES
+    c_cap = 1 << 24
+    assert vg.table_rows(n) * 512 <= vg.VMEM_TABLE_MAX
+    width = vg.padded_columns(n + 1)
+    state = spec((n + 1,), jnp.int32)
+    bu0a = _compile(H._bu_startL(), state, spec((), jnp.int32),
+                    spec((lanes * width,), jnp.int32), state, state,
+                    c_cap=c_cap, n_=n, lanes=lanes, impl="vmem")
+    assert "tpu_custom_call" in bu0a.as_text()
+    # nothing of it is a list of 2^24 but the untested handed on (the
+    # list of the candidates it replaced kept 0.8 GB of temporaries)
+    assert bu0a.memory_analysis().temp_size_in_bytes < 128 << 20
+    lead = _compile(H._lead_image(), spec((8, q), jnp.int32), state, state,
+                    lanes=lanes)
+    assert lead.memory_analysis().output_size_in_bytes == lanes * width * 4
 
 
 @pytest.mark.parametrize("rows", [None, 1], ids=["pagerank", "ppr"])

@@ -241,7 +241,7 @@ WCC_KEYS = {
     ("bfs.level", "head"): {"hybrid_head"},
     ("bfs.level", "td"): {"hybrid_td", "hybrid_frontier_of"},
     ("bfs.level", "bu"): {"hybrid_bu_start", "hybrid_bu_startL",
-                          "hybrid_csflag", "hybrid_bu_finish0",
+                          "hybrid_lead", "hybrid_bu_finish0",
                           "hybrid_bu_more", "hybrid_ex"},
     ("bfs.level", "end"): {"hybrid_endgame"},
     ("wcc.seed", None): {"wcc_seed_labels"},
@@ -367,4 +367,4 @@ def test_the_docs_naming_table_is_the_codes():
         shim = jitcache._JITS.get(key)
         if shim is not None:
             assert shim.__name__ == doc[key], key
-    assert {"pagerank_pull", "hybrid_csflag"} <= set(doc)
+    assert {"pagerank_pull", "hybrid_lead"} <= set(doc)

@@ -18,6 +18,7 @@ from titan_tpu.models.frontier import pagerank_dense
 from titan_tpu.olap.serving.hbm import (snapshot_csr_bytes,
                                         snapshot_pull_bytes)
 from titan_tpu.olap.tpu import snapshot as snap_mod
+from titan_tpu.ops import vmem_gather as vg
 
 ITERATIONS, DAMPING = 10, 0.85
 IMPLS = ("xla", "vmem")
@@ -27,8 +28,8 @@ IMPLS = ("xla", "vmem")
 def kernel_in_the_interpreter(monkeypatch):
     """The CPU has no Mosaic: wherever a test asks for the kernel
     (``impl="vmem"``), Pallas's interpreter runs it."""
-    monkeypatch.setattr(pp, "_colsum_vmem", functools.partial(
-        pp._colsum_vmem, interpret=True))
+    monkeypatch.setattr(vg, "colsum_vmem", functools.partial(
+        vg.colsum_vmem, interpret=True))
 
 
 def pagerank64(n, src, dst, iterations, damping):
@@ -85,7 +86,7 @@ def graph(request):
 
 
 def run(snap, monkeypatch, impl, **kw):
-    monkeypatch.setattr(pp, "gather_impl", lambda n: impl)
+    monkeypatch.setattr(vg, "gather_impl", lambda n: impl)
     return pagerank_dense(snap, iterations=ITERATIONS, damping=DAMPING,
                           **kw)
 
@@ -110,11 +111,11 @@ def test_the_hub_straddles_blocks_and_the_cover_is_padded():
     im = pp.pull_image(snap)
     deg_in = np.diff(snap.indptr_in)
     real = int((-(-deg_in // 8)).sum()) + 1
-    assert real % pp.PULL_BLOCK != 0            # case (c) of ISSUE 35
-    assert im["q_in"] % pp.PULL_BLOCK == 0 and \
-        0 < im["q_in"] - real < pp.PULL_BLOCK
+    assert real % vg.BLOCK != 0            # case (c) of ISSUE 35
+    assert im["q_in"] % vg.BLOCK == 0 and \
+        0 < im["q_in"] - real < vg.BLOCK
     assert im["q_in"] == pp.pull_columns(snap.indptr_in, n)
-    assert im["seg_max"] == -(-deg_in.max() // 8) > 2 * pp.PULL_BLOCK
+    assert im["seg_max"] == -(-deg_in.max() // 8) > 2 * vg.BLOCK
     # cached on the snapshot, dropped with the other layouts
     assert pp.pull_image(snap) is im
     snap._invalidate_layout_caches()
@@ -180,7 +181,7 @@ def test_who_keeps_the_window_sweep(monkeypatch):
     def never(_n):
         raise AssertionError("the pull was chosen")
 
-    monkeypatch.setattr(pp, "gather_impl", never)
+    monkeypatch.setattr(vg, "gather_impl", never)
     n, src, dst = simple_undirected(36, n=1 << 9, m=1 << 12)
     snap = snap_mod.from_arrays(n, src, dst)
     by_dict, _ = pagerank_dense(build_chunked_csr(snap), iterations=3)
@@ -188,7 +189,7 @@ def test_who_keeps_the_window_sweep(monkeypatch):
     reset[5] = 1.0
     pagerank_dense(snap, iterations=3, reset=reset)
     assert not hasattr(snap, "_pull_csr")
-    monkeypatch.setattr(pp, "gather_impl", lambda _n: "xla")
+    monkeypatch.setattr(vg, "gather_impl", lambda _n: "xla")
     pulled, _ = pagerank_dense(snap, iterations=3)
     assert np.abs(pulled - by_dict).max() <= 1e-6 * by_dict.max()
 
@@ -199,16 +200,17 @@ def test_what_chooses_the_gather(monkeypatch):
 
     import jax
 
-    assert pp.gather_impl(2_396_390) == "xla"           # tier 1: the CPU
+    assert vg.gather_impl(2_396_390) == "xla"           # tier 1: the CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert pp.gather_impl(2_396_390) == "vmem"          # 9.6 MB
-    assert pp.gather_impl(1 << 26) == "xla"             # scale 26: 268 MB
-    edge = pp.VMEM_TABLE_MAX // 4 - 2
-    assert pp.gather_impl(edge) == "vmem"
-    assert pp.gather_impl(edge + 1) == "xla"
-    assert list(inspect.signature(pp.gather_impl).parameters) == ["n"]
-    src = inspect.getsource(pp)
-    assert "os.environ" not in src and "getenv" not in src
+    assert vg.gather_impl(2_396_390) == "vmem"          # 9.6 MB
+    assert vg.gather_impl(1 << 26) == "xla"             # scale 26: 268 MB
+    edge = vg.VMEM_TABLE_MAX // 4 - 2
+    assert vg.gather_impl(edge) == "vmem"
+    assert vg.gather_impl(edge + 1) == "xla"
+    assert list(inspect.signature(vg.gather_impl).parameters) == ["n"]
+    for mod in (pp, vg):
+        src = inspect.getsource(mod)
+        assert "os.environ" not in src and "getenv" not in src
 
 
 # -- what the program reports ----------------------------------------------
@@ -257,7 +259,7 @@ def test_the_counter_and_the_sweeps_attributes():
     assert len(by_name["pr.result"]) == 1
     for s in by_name["pr.sweep"]:
         assert s.parent_id == run_span.span_id
-        assert s.attrs["windows"] == q_in // pp.PULL_BLOCK
+        assert s.attrs["windows"] == q_in // vg.BLOCK
         assert s.attrs["impl"] == "xla"
 
 

@@ -14,6 +14,11 @@ array — are 5-50x cheaper per edge. Kernel design rules:
 * the bottom-up hit test reads a per-level FRONTIER BITMAP (n/8 bytes —
   8.4MB at scale 26, the fast-gather regime) instead of the 4-byte dist
   array (268MB, the slow regime): measured 1.9x on the hit test;
+* a heavy level's first lanes are tested on the VERTEX SET, n-wide in
+  vertex order over the leading-lane image, not on a list of the
+  candidates (``_bu_startL``): a list there is the vertex set written
+  out again, and every operation on it a random one (graph500-24's
+  heavy level: 947 ms on the list, 116 ms n-wide: PERF.md 6, PR 39);
 * work that is usually wasted runs under ``lax.cond``: survivor
   compaction only when survivors exist, the level-end wrap only when the
   level is already decided (at scale 26's heavy level ALL 27M candidates
@@ -73,7 +78,10 @@ BU_CHUNK_ROUNDS = 8
 # fewer leading lanes win: measured scale-26 BFS 7.72s (lanes=2) vs
 # 8.51s (lanes=4) vs 11.5s (r4 4-lane two-gather opener). Below
 # SPLIT_LANE_MIN candidates the extra dispatch+readback outweighs the
-# gather saving.
+# gather saving (tuned while the first lanes ran over a list of the
+# candidates; since PR 39 they run n-wide, _bu_startL, at a cost that
+# does not fall with the candidates: at graph500-24 and 2^21 slots
+# 95 ms against that list's 198 ms, PERF.md 6).
 SPLIT_LANES = 2
 SPLIT_LANE_MIN = 1 << 21
 # head loop caps: early top-down levels fused into one dispatch while the
@@ -483,34 +491,44 @@ def _bu_start():
     return _get("hybrid_bu_start", build)
 
 
-def flagged_colstart(g, lanes: int):
-    """Per-graph cache: ``colstart | (deg <= lanes) << 31`` — the opener
-    needs both ``colstart[v]`` and the "could later lanes still hit?"
-    predicate per candidate; packing the predicate into colstart's free
-    sign bit (colstart < 2^31 by the chunked-CSR int32 contract) lets
-    ONE array carry both through the opener's shared-index scatter
-    compaction (historically: two separate 33M-candidate gathers into
-    268MB tables measured ~1.9s at scale 26; the packed array first
-    halved that, and the scatter formulation in _bu_startL now avoids
-    the per-candidate gather entirely — this array is read
-    CONTIGUOUSLY there). Built once per graph per lane width (one
-    n-scale elementwise pass) and cached in the graph dict."""
-    import jax.numpy as jnp
+def _lead_image():
+    def build():
+        import jax
+        import jax.numpy as jnp
 
-    key = f"_csflag{lanes}"
+        from titan_tpu.ops.vmem_gather import padded_columns
+
+        @functools.partial(jax.jit, static_argnames=("lanes",))
+        def lead(dstT, colstart, deg, lanes: int):
+            n1 = colstart.shape[0]                          # n + 1
+            first = jnp.take(dstT[:lanes], colstart, axis=1)
+            k = jnp.arange(lanes, dtype=jnp.int32)[:, None]
+            first = jnp.where(deg[None, :] > k, first, n1)
+            width = padded_columns(n1)
+            return jnp.pad(first, ((0, 0), (0, width - n1)),
+                           constant_values=n1).reshape(-1)
+        return lead
+    return _get("hybrid_lead", build)
+
+
+def leading_lanes(g, lanes: int):
+    """Per-graph cache: the LEADING-LANE image the split-lane opener
+    reads, ``lead[k, v] = dstT[k, colstart[v]]`` for k < ``lanes`` and
+    v = 0 .. n, the pad vertex n + 1 where ``deg[v] <= k`` (a vertex
+    without an edge shares its column with the next vertex, so the
+    degree masks it; the sink has none) and in the columns past n that
+    round the width up to whole blocks (``vmem_gather.padded_columns``).
+    Stored FLAT, row after row: the layout the table-in-VMEM gather
+    reads, and one reshape from what XLA's gather reads. With it a
+    vertex's first lanes are a contiguous read in vertex order. Built
+    once a graph and lane width (ONE random gather, ``lanes`` x (n + 1)
+    reads) and kept in the graph dict: 2 x 4 x 8.87 M = 71 MB at
+    graph500-24."""
+    key = f"_lead{lanes}"
     got = g.get(key)
     if got is None:
-        def build():
-            import jax
-
-            @functools.partial(jax.jit, static_argnames=("lanes",))
-            def pack(colstart, deg, lanes: int):
-                flag = (deg <= lanes).astype(jnp.int32) << 31
-                return colstart | flag
-            return pack
-        got = _get("hybrid_csflag", build)(g["colstart"], g["deg"],
-                                           lanes=lanes)
-        g[key] = got
+        got = g[key] = _lead_image()(g["dstT"], g["colstart"], g["deg"],
+                                     lanes=lanes)
     return got
 
 
@@ -519,57 +537,60 @@ def _bu_startL():
         import jax
         import jax.numpy as jnp
 
-        @functools.partial(jax.jit,
-                           static_argnames=("c_cap", "n_", "lanes"),
-                           donate_argnums=(0,))
-        def bu0a(dist, level, dstT, csflag, degc, c_cap: int, n_: int,
-                 lanes: int):
-            """Split-lane bottom-up opener: candidate build + a
-            ``lanes``-wide chunk-0 bitmap test (the leading-lane slice
-            ``dstT[:lanes]`` fuses into the gather — no copy, see
-            experiments/lane_split_probe.py). ``csflag`` is
-            flagged_colstart(g, lanes): column and deg <= lanes
-            predicate in one int32, read CONTIGUOUSLY and compacted
-            alongside the candidate list by the shared-index double
-            scatter below — no per-candidate table gather at all.
-            Candidates that miss the tested lanes AND have deg > lanes
-            are compacted as UNTESTED (their remaining lanes may still
-            hit — _bu_finish_chunk0 decides them at a host-sized cap);
-            deg <= lanes misses are decided (pad lanes never hit).
-            Level-end stats under lax.cond when no untested remain
-            (then no bu_more survivors can exist either, since
-            degc > 1 implies deg > 8)."""
-            q_pad = dstT.shape[1] - 1
-            fbits = _pack_bits(dist, level, n_)
-            unvis = (dist[:n_] >= INF) & (degc[:n_] > 0)
-            # candidate build as a shared-index DOUBLE scatter
-            # (ops.compaction.scatter_compact): the list compaction and
-            # the per-candidate csflag fetch land in one fused pass
-            # (XLA fuses scatters with identical indices), replacing
-            # nonzero + a 268MB-table gather — measured 1.76s -> 1.07s
-            # at the scale-26 heavy level. csflag is read CONTIGUOUSLY
-            # (elementwise), which is what makes the gather-free
-            # formulation possible.
-            c_count, (cand, csf) = scatter_compact(
-                unvis, (jnp.arange(n_, dtype=jnp.int32), csflag[:n_]),
-                c_cap, (n_, 0))
+        from titan_tpu.ops.vmem_gather import as_table, colsum_vmem
 
-            alive = jnp.arange(c_cap) < c_count
-            v = jnp.minimum(cand, n_)
-            small = csf < 0                      # deg <= lanes
-            cols = jnp.where(alive, csf & 0x7FFFFFFF, q_pad)
-            parentsL = jnp.take(dstT[:lanes], jnp.clip(cols, 0, q_pad),
-                                axis=1)
-            hitL = _fbit_of(fbits, parentsL)
-            found = alive & hitL.any(axis=0)
-            dist = dist.at[jnp.where(found, v, n_ + 1)].set(
-                level + 1, mode="drop")
-            untested = alive & ~found & ~small
+        @functools.partial(jax.jit,
+                           static_argnames=("c_cap", "n_", "lanes",
+                                            "impl"),
+                           donate_argnums=(0,))
+        def bu0a(dist, level, lead, deg, degc, c_cap: int, n_: int,
+                 lanes: int, impl: str):
+            """Split-lane bottom-up opener: a ``lanes``-wide chunk-0
+            bitmap test of every candidate (unvisited, with an edge),
+            run on the VERTEX SET: n-wide, in vertex order, over
+            ``lead`` = leading_lanes(g, lanes). No list going in: the
+            lanes are a contiguous read, ``dist`` is written
+            elementwise, and the one compaction is that of the
+            UNTESTED — candidates that miss the tested lanes AND have
+            deg > lanes, ids ascending, at most ``c_cap`` of them
+            (their remaining lanes may still hit: _bu_finish_chunk0
+            decides them at a host-sized cap); deg <= lanes misses are
+            decided (pad lanes never hit). Level-end stats under
+            lax.cond when no untested remain (then no bu_more survivors
+            can exist either, since degc > 1 implies deg > 8).
+
+            Its cost is n's, whatever the candidates' count. Testing a
+            LIST of the candidates instead (an n-wide compaction, then
+            a slot a column gather and a bitmap gather a lane, a
+            scatter into dist and a second compaction) lost at every
+            point measured on the chip (experiments/bu_dense_probe.py,
+            graph500-24's heavy level, n = 8.87 M, 2 lanes, ms a call,
+            the candidates thinned by hand to 2^24, 2^23, 2^22, 2^21
+            slots: 946.8, 547.0, 330.1, 198.3 against 223.4, 220.0,
+            223.5, 203.2 under ``impl="xla"`` and 115.7, 115.6, 115.7,
+            95.3 under ``"vmem"``: PERF.md 6, PR 39); below 2^21
+            candidates ``bu0`` opens.
+
+            ``impl`` says what serves the frontier test's random reads
+            (``vmem_gather.gather_impl``: the backend and the table's
+            size): ``"xla"`` the byte gather of ``_fbit_of``,
+            ``"vmem"`` the frontier as a 0/1 table in VMEM under the
+            Pallas gather."""
+            fbits = _pack_bits(dist, level, n_)
+            unvis = (dist >= INF) & (degc > 0)              # [n + 1]
+            if impl == "vmem":
+                table = as_table((dist == level).astype(jnp.float32))
+                hit = colsum_vmem(lead, table, rows=lanes)[:n_ + 1] > 0
+            else:
+                hit = _fbit_of(fbits, lead).reshape(lanes, -1) \
+                    .any(axis=0)[:n_ + 1]
+            found = unvis & hit
+            dist = jnp.where(found, level + 1, dist)
+            untested = unvis & ~found & (deg > lanes)
             nu = untested.sum().astype(jnp.int32)
 
             def compact(_):
-                return scatter_compact(untested, (cand,), c_cap,
-                                       (n_,))[1][0]
+                return compact_ids(untested, c_cap, n_)[1]
 
             def no_compact(_):
                 return jnp.full((c_cap,), n_, jnp.int32)
@@ -1756,6 +1777,7 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
         return a
 
     from titan_tpu.obs import devprof
+    from titan_tpu.ops import vmem_gather
     from titan_tpu.obs.tracing import phase
     from titan_tpu.utils.jitcache import dev_scalar
 
@@ -1773,9 +1795,12 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
     # one dispatch), beside the caps it ran under and `sync_ms`; a `bu`
     # step also says what its later programs were sized from (`missed`
     # the split opener's first lanes, `left` the opener, `exhaust` and
-    # `rem8` the chunk rounds: candidates and their unread chunks).
+    # `rem8` the chunk rounds: candidates and their unread chunks) and
+    # which `opener` it took (`dense`: the split opener, n-wide over the
+    # leading-lane image; `plain`: `bu0`, every lane at once).
     # `device.bfs.levels{dir, list="single"}` counts the LEVELS a step
-    # covered, so a run's counts sum to the `levels` it returns.
+    # covered, so a run's counts sum to the `levels` it returns;
+    # `device.bfs.opener{impl}` the pulled levels by their opener.
 
     # ---- fused head: source + early top-down levels, one readback
     f_cap_h = min(HEAD_F_CAP, cap_n)
@@ -1835,16 +1860,19 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
         else:
             c_cap = min(_next_pow2(max(n_unvis, 2)), cap_n)
             split = c_cap >= SPLIT_LANE_MIN
+            opener = "dense" if split else "plain"
             with phase("bfs.level", level=level, dir="bu", c_cap=c_cap,
-                       split=split) as ph:
+                       split=split, opener=opener) as ph:
                 if split:
                     # split-lane opener: SPLIT_LANES-wide test over
-                    # everyone, then the remaining lanes only for the
-                    # minority that missed (host-sized)
+                    # everyone (n-wide over the leading-lane image),
+                    # then the remaining lanes only for the minority
+                    # that missed (host-sized)
                     dist, fbits, cand, prog, st_dev = bu0a(
-                        dist, dev_scalar(level), dstT,
-                        flagged_colstart(g, SPLIT_LANES), degc,
-                        c_cap=c_cap, n_=n, lanes=SPLIT_LANES)
+                        dist, dev_scalar(level),
+                        leading_lanes(g, SPLIT_LANES), g["deg"], degc,
+                        c_cap=c_cap, n_=n, lanes=SPLIT_LANES,
+                        impl=vmem_gather.gather_impl(n))
                     nu = stats_of(ph, prog)[0]
                     ph.set(missed=nu)
                     if nu > 0:
@@ -1893,6 +1921,7 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
                                       n_=n)
                 f_count, m8_f, m8_unvis, n_unvis = stats_of(ph, st_dev)
             devprof.count_level("bu", "single")
+            devprof.count_opener(opener)
             frontier = None
         level += 1
     out = dist[:n]

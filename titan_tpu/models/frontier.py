@@ -1329,11 +1329,12 @@ def _pagerank_pull(snap, iterations, damping, tol, return_device,
     from titan_tpu.models import pagerank_pull as pp
     from titan_tpu.obs import devprof
     from titan_tpu.obs.tracing import phase
+    from titan_tpu.ops import vmem_gather
 
     im = pp.pull_image(snap)
     n = im["n"]
-    impl = pp.gather_impl(n)
-    blocks = im["q_in"] // pp.PULL_BLOCK
+    impl = vmem_gather.gather_impl(n)
+    blocks = im["q_in"] // vmem_gather.BLOCK
     pull, fin, cut = pp.pull_step(), _pr_finish(), _pr_result()
     d = dev_scalar(float(damping), "float32")
     it0 = 0

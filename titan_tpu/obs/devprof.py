@@ -402,6 +402,17 @@ def count_level(direction: str, road: str, levels: int = 1) -> None:
                                          "list": road}).inc(int(levels))
 
 
+def count_opener(impl: str) -> None:
+    """Count one pulled level of the single-source family by its
+    opener: ``"dense"`` the split-lane opener (``hybrid_bu_startL``:
+    the first lanes n-wide over the leading-lane image), ``"plain"``
+    the opener that tests every lane at once over the candidates' list
+    (under ``bfs_hybrid.SPLIT_LANE_MIN`` candidates)."""
+    for prof in list(_PROFILERS):
+        prof.metrics.counter("device.bfs.opener",
+                             labels={"impl": impl}).inc()
+
+
 def count_wcc_rounds(rounds: int) -> None:
     """Count the min-label propagation rounds of one WCC run (the
     rounds ``_frontier_run`` planned after the peel)."""
@@ -431,7 +442,7 @@ def count_pr_gather(impl: str, lanes: int) -> None:
     """Count the lanes one iteration of the uniform PageRank pull
     gathered (8 x the pull image's columns, pad lanes included) by what
     served them: ``"vmem"`` the Pallas kernel's table, ``"xla"`` XLA's
-    gather (models/pagerank_pull.gather_impl)."""
+    gather (ops/vmem_gather.gather_impl)."""
     for prof in list(_PROFILERS):
         prof.metrics.counter("device.pr.gather_lanes",
                              labels={"impl": impl}).inc(int(lanes))
