@@ -210,6 +210,37 @@ def test_pagerank_pull_at_graph500_22(spec):
     _compile(_pr_result(), spec((N22 + 1,), jnp.float32), n_=N22)
 
 
+def test_cdlp_round_at_graph500_22(spec):
+    """A round of the served CDLP job (ISSUE 40) over the same image:
+    the Pallas gather a lane at a time (rows = 1) with the labels as a
+    float32 table in VMEM, the sort of 139.6 M (owner, label) pairs, the
+    vote. What the rounds keep on the device at once is what admission
+    reserves for them (``models/cdlp.work_bytes``)."""
+    from titan_tpu.models import cdlp as C
+    from titan_tpu.ops.vmem_gather import padded_columns
+
+    q_in = padded_columns(Q22)
+    lanes = spec((8 * q_in,), jnp.int32)
+    labels = spec((N22,), jnp.int32)
+    gather = _compile(C._gather(), labels, lanes, impl="vmem", n_=N22)
+    assert "tpu_custom_call" in gather.as_text()
+    sort = _compile(C._sort(), spec((q_in,), jnp.bool_), lanes)
+    # two operands: an unstable sort carries no positions beside them
+    assert sort.memory_analysis().output_size_in_bytes \
+        // (4 * 8 * q_in) == 2
+    vote = _compile(C._vote(), lanes, lanes, labels, labels,
+                    spec((N22,), jnp.bool_), seg_max=20_413, n_=N22)
+    # the widest program: the sorted pair and its temporaries, with the
+    # gathered lanes that may outlive the sort, inside what is reserved
+    for program in (gather, sort, vote):
+        m = program.memory_analysis()
+        held = m.argument_size_in_bytes + m.output_size_in_bytes \
+            + m.temp_size_in_bytes
+        assert held + 4 * 8 * q_in <= C.work_bytes(N22, q_in) + 4 * 8 * q_in
+    m = vote.memory_analysis()
+    assert m.temp_size_in_bytes < 6 * 4 * 8 * q_in
+
+
 def test_wcc_propagation_on_the_remainder_at_graph500_24(spec):
     """What ISSUE 37 added to a WCC job of g500-24.wcc-c2 (n 8,871,268:
     CPU count, PR 36): the seeding that lists the remainder at n / 8

@@ -448,6 +448,17 @@ def count_pr_gather(impl: str, lanes: int) -> None:
                              labels={"impl": impl}).inc(int(lanes))
 
 
+def count_cdlp_round(impl: str, lanes: int) -> None:
+    """Count one round of ``models/cdlp.cdlp`` (its gather, sort and
+    vote dispatched) and the lanes it gathered (8 x the pull image's
+    columns, pad lanes included) by what served them, ``"vmem"`` or
+    ``"xla"`` (ops/vmem_gather.gather_impl)."""
+    for prof in list(_PROFILERS):
+        prof.metrics.counter("device.cdlp.rounds").inc()
+        prof.metrics.counter("device.cdlp.lanes",
+                             labels={"impl": impl}).inc(int(lanes))
+
+
 def current() -> Optional["DeviceCostProfiler"]:
     """The most recently installed profiler, or None."""
     return _PROFILERS[-1] if _PROFILERS else None

@@ -257,7 +257,9 @@ class JobSpec:
 
     ``kind``: 'bfs' (batchable — same-snapshot BFS jobs fuse into ONE
     [K, n] multi-source device run), 'sssp' | 'pagerank' | 'wcc'
-    (frontier kernels, executed singly), 'dense' (a DenseProgram
+    (frontier kernels, executed singly), 'cdlp' (label propagation's
+    most-frequent-label vote, ``params['iterations']`` synchronous
+    rounds, executed singly), 'dense' (a DenseProgram
     instance under ``params['program']``), or 'callable'
     (``params['fn']`` — the host computer's async delegation hook).
 

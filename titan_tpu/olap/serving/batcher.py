@@ -98,6 +98,14 @@ def _components(labels: np.ndarray) -> int:
                                     dtype=labels.dtype)).sum())
 
 
+def _communities(labels: np.ndarray) -> int:
+    """Distinct labels of a CDLP answer. A label is a vertex id, so each
+    is marked where it points: one pass, no sort."""
+    seen = np.zeros(labels.shape[0], bool)
+    seen[labels] = True
+    return int(seen.sum())
+
+
 def _under(handle, run_span):
     """The scope in which a kernel's leaf phases journal as children of
     the job's ``run`` span (nothing without a trace)."""
@@ -730,6 +738,36 @@ class Batcher:
                 job.complete({"rounds": int(rounds),
                               "components": components,
                               "labels": lab})
+            elif kind == "cdlp":
+                from titan_tpu.models.cdlp import cdlp
+                ckpt = None
+                if wants_ckpt:
+                    def ckpt(it, state):
+                        if rec.due(it):
+                            rec.save(it,
+                                     {"labels":
+                                          np.asarray(state["labels"])},
+                                     kind="cdlp",
+                                     meta={"epoch": epoch})
+                resume = None
+                if ck is not None:
+                    resume = {"labels": ck.arrays["labels"],
+                              "it": ck.round}
+                # the rounds' and the readback's leaf phases (cdlp.round,
+                # cdlp.result) journal under this job's `run` span; the
+                # readback is counted where it is made
+                # (device.xfer.d2h_bytes{site="cdlp.result"})
+                with _under(h, run_span):
+                    labels, iters = cdlp(
+                        snap,
+                        iterations=int(params.get("iterations", 10)),
+                        on_round=on_round, checkpoint=ckpt,
+                        resume=resume, overlay=overlay)
+                    with phase("cdlp.count"):
+                        communities = _communities(labels)
+                job.complete({"iterations": int(iters),
+                              "communities": communities,
+                              "labels": labels})
             elif kind == "dense":
                 from titan_tpu.olap.tpu.engine import run_single
                 program = params.pop("program")

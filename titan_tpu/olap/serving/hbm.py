@@ -45,6 +45,16 @@ def snapshot_pull_bytes(snap) -> int:
     return pull_image_bytes(snap.n, pull_columns(snap.indptr_in, snap.n))
 
 
+def snapshot_cdlp_bytes(snap) -> int:
+    """Predicted device bytes a ``cdlp`` job's rounds work on beside the
+    pull image (models/cdlp.work_bytes: the gathered labels, the sort's
+    operands and the vote's temporaries, each as wide as the image's
+    lanes), sized from the in-degrees like the image itself."""
+    from titan_tpu.models.cdlp import work_bytes
+    from titan_tpu.models.pagerank_pull import pull_columns
+    return work_bytes(snap.n, pull_columns(snap.indptr_in, snap.n))
+
+
 def meshed_snapshot_csr_bytes(snap, num_devices: int) -> int:
     """PER-DEVICE bytes of a MESH-PLACED chunked CSR (ISSUE 13,
     ``parallel/partition.place_batched_csr``): the ``dstT`` edge image

@@ -100,6 +100,7 @@ from titan_tpu.olap.serving.batcher import (Batcher, batch_key,
                                               job_phase)
 from titan_tpu.olap.serving.hbm import (DEFAULT_BUDGET_BYTES,
                                         AdmissionError, HBMLedger,
+                                        snapshot_cdlp_bytes,
                                         snapshot_csr_bytes,
                                         snapshot_pull_bytes)
 from titan_tpu.olap.serving.jobs import Job, JobState
@@ -111,7 +112,7 @@ from titan_tpu.utils.metrics import MetricManager
 
 #: job kinds that execute against a pooled snapshot (everything except
 #: host 'callable' delegations)
-_SNAPSHOT_KINDS = ("bfs", "sssp", "pagerank", "wcc", "dense")
+_SNAPSHOT_KINDS = ("bfs", "sssp", "pagerank", "wcc", "cdlp", "dense")
 
 _KNOWN_KINDS = _SNAPSHOT_KINDS + ("callable",)
 
@@ -390,6 +391,15 @@ class JobScheduler:
                 delattr(obj, attr)
         elif snap is not None and hasattr(snap, "_hybrid_csr"):
             delattr(snap, "_hybrid_csr")
+
+    def _let_go(self, held, work_key) -> None:
+        """End of a run's hold on its ledger entries: images stay
+        resident but evictable, a job's working set leaves."""
+        for key in held:
+            if key == work_key:
+                self.ledger.release(key)
+            else:
+                self.ledger.unpin(key)
 
     def _forget_snapshot(self, snap) -> None:
         """Pool close hook: a retired/rebuilt snapshot leaves the HBM
@@ -986,15 +996,16 @@ class JobScheduler:
         # the device's idle gap there a name (obs/tracing)
         with job_phase(head, "job.lease"):
             try:
-                # dense window sweeps (pagerank / DenseProgram) have no
-                # overlay seam: the live pool folds the overlay into the
-                # base BEFORE leasing for these kinds (the documented
+                # dense window sweeps (pagerank / DenseProgram) and the
+                # pull image (pagerank, cdlp) have no overlay seam: the
+                # live pool folds the overlay into the base BEFORE
+                # leasing for these kinds (the documented
                 # compact-before-run fallback, models/frontier.py)
                 lease = self.pool.acquire(labels=spec.labels,
                                           edge_keys=edge_keys,
                                           directed=spec.directed,
                                           compacted=spec.kind in
-                                          ("pagerank", "dense"))
+                                          ("pagerank", "cdlp", "dense"))
             except Exception as e:
                 for job in group:
                     job.fail(f"snapshot: {type(e).__name__}: {e}")
@@ -1025,29 +1036,39 @@ class JobScheduler:
                         snap, int(self.mesh.devices.size))
                 else:
                     nbytes = snapshot_csr_bytes(snap)
-                # a `pagerank` job reads a second image: the in-edge pull
-                # image of models/pagerank_pull, under a key of its own so
-                # that a snapshot already resident for other kinds is not
-                # taken to hold it
+                # a `pagerank` or `cdlp` job reads a second image: the
+                # in-edge pull image of models/pagerank_pull, under a key
+                # of its own so that a snapshot already resident for other
+                # kinds is not taken to hold it. A `cdlp` job's rounds
+                # besides work on several times that image (the sort's
+                # operands, the vote's temporaries: models/cdlp.work_bytes):
+                # reserved for the run under a key with nothing to evict,
+                # and released, not left resident, behind it
                 images = [(ledger_key, nbytes, snap)]
-                if spec.kind == "pagerank":
+                if spec.kind in ("pagerank", "cdlp"):
                     pull_bytes = snapshot_pull_bytes(snap)
                     images.append((("pagerank-pull", ledger_key), pull_bytes,
                                    (snap, "_pull_csr")))
                     nbytes += pull_bytes
+                work_key = None
+                if spec.kind == "cdlp":
+                    work_key = ("cdlp-work", ledger_key)
+                    work_bytes = snapshot_cdlp_bytes(snap)
+                    images.append((work_key, work_bytes, None))
+                    nbytes += work_bytes
                 held = []
                 try:
                     for key, image_bytes, _handle in images:
                         self.ledger.reserve(key, image_bytes)
                         held.append(key)
                 except AdmissionError as e:
-                    for key in held:
-                        self.ledger.unpin(key)
+                    self._let_go(held, work_key)
                     for job in group:
                         job.fail(str(e))
                     return
                 for key, _bytes, handle in images:
-                    self._evictable.setdefault(key, handle)
+                    if handle is not None:
+                        self._evictable.setdefault(key, handle)
                 # the batch shares one graph image: its ledger bytes are
                 # held against each member's tenant (per-K share) for the
                 # duration of the run — the live view max_hbm_bytes quotas
@@ -1072,8 +1093,7 @@ class JobScheduler:
                 for job in group:
                     self.tenants.drop_hbm(job.tenant, share)
                 self._attribute(group, wall, nbytes)
-                for key in held:
-                    self.ledger.unpin(key)
+                self._let_go(held, work_key)
                 if w is not None:
                     self._stitch_device_cost(group, w.close())
                 if self.recorder is not None:

@@ -19,6 +19,7 @@ only argument-passed benchmarks are real):
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -78,6 +79,107 @@ def seg_scan(values, flags, combine: str, max_len: Optional[int] = None):
         flags = flags | pf
         d <<= 1
     return values
+
+
+#: elements a row of the two-level scans below: a vector register's lanes
+_ROW = 128
+
+
+def _back(x, d: int, fill, axis: int):
+    """``x`` moved ``d`` places on along ``axis``, ``fill`` behind it."""
+    pad = list(x.shape)
+    pad[axis] = d
+    kept = jax.lax.slice_in_dim(x, 0, x.shape[axis] - d, axis=axis)
+    return jnp.concatenate([jnp.full(pad, fill, x.dtype), kept], axis=axis)
+
+
+def running_max(x):
+    """Inclusive running maximum of ``x`` (integers, none below 0):
+    Hillis-Steele passes, a long array on two levels (inside rows of 128,
+    then over the rows' last elements), which costs 7 passes over it.
+    ``lax.cummax`` ran three times as long over 1.4e8 elements, and the
+    chip's compiler takes 40 s to build one of any size (PERF.md 6, PR
+    40)."""
+    e = x.shape[0]
+    two_level = e % _ROW == 0 and e > _ROW
+    rows = x.reshape(e // _ROW, _ROW) if two_level else x[None, :]
+    d = 1
+    while d < rows.shape[1]:
+        rows = jnp.maximum(rows, _back(rows, d, 0, 1))
+        d <<= 1
+    if two_level:
+        carried = _back(running_max(rows[:, -1]), 1, 0, 0)
+        rows = jnp.maximum(rows, carried[:, None])
+    return rows.reshape(e)
+
+
+def _first_max_passes(score, payload, flags, axis: int, length: int):
+    """Hillis-Steele passes of the segmented first-max along ``axis``, as
+    many as a segment of ``length`` elements needs. Returns (score,
+    payload, flags): ``flags`` then says whether a segment began at or
+    before the position, as far back as the passes looked."""
+    ident = combine_identity("max", score.dtype)
+    back = functools.partial(_back, axis=axis)
+    d = 1
+    while d < min(score.shape[axis], length):
+        ps, pp = back(score, d, ident), back(payload, d, 0)
+        take = ~flags & (ps >= score)
+        score = jnp.where(take, ps, score)
+        payload = jnp.where(take, pp, payload)
+        flags = flags | back(flags, d, False)
+        d <<= 1
+    return score, payload, flags
+
+
+def seg_first_max(score, payload, flags, max_len: Optional[int] = None):
+    """Inclusive segmented scan of the FIRST largest ``score`` so far in
+    the segment, with the ``payload`` that stands beside it (a tie keeps
+    the earlier element): the segmented arg-max, ``seg_scan``'s passes
+    over a pair. Returns (score, payload) per position; a segment's
+    answer stands at its last element. ``max_len``: the longest segment,
+    where the caller knows it.
+
+    A long array is scanned on two levels, which costs 7 passes over it
+    and not log2(``max_len``): inside rows of 128 elements; then over the
+    rows' last elements (a row in which a segment begins hands nothing
+    of the rows before it on); then every element before its row's
+    first segment start takes what the rows before carried in."""
+    e = score.shape[0]
+    length = e if max_len is None else min(e, max_len)
+    if e % _ROW or e <= _ROW:
+        return _first_max_passes(score, payload, flags, 0, length)[:2]
+    rows = e // _ROW
+    s, p, f = _first_max_passes(
+        score.reshape(rows, _ROW), payload.reshape(rows, _ROW),
+        flags.reshape(rows, _ROW), 1, length)
+    cs, cp, _cf = _first_max_passes(s[:, -1], p[:, -1], f[:, -1], 0,
+                                    -(-length // _ROW) + 1)
+    cs = _back(cs, 1, combine_identity("max", score.dtype), 0)
+    cp = _back(cp, 1, 0, 0)
+    take = ~f & (cs[:, None] >= s)
+    return (jnp.where(take, cs[:, None], s).reshape(e),
+            jnp.where(take, cp[:, None], p).reshape(e))
+
+
+def mode_vote(owner, label, pad, max_len: Optional[int] = None):
+    """The most-frequent-label vote, the one combiner here that is no
+    semiring: a multiset's mode cannot be folded element by element, so
+    the caller hands the (owner, label) pairs SORTED by owner, then
+    label, and equal labels stand in runs. A run's length is known at
+    its last element (its position less the run's first, carried there
+    by ``running_max``); ``seg_first_max`` over an owner's runs keeps the
+    longest, and of equally long runs the first, which is the smallest
+    label. Elements labelled ``pad`` do not vote. Returns int32 per
+    position: at an owner's LAST element, the smallest of its most
+    frequent labels (``pad`` where nothing voted)."""
+    first = owner != _back(owner, 1, -1, 0)
+    start = first | (label != _back(label, 1, -1, 0))
+    pos = jnp.arange(label.shape[0], dtype=jnp.int32)
+    run0 = running_max(jnp.where(start, pos, 0))
+    ends = jnp.concatenate([start[1:], jnp.ones((1,), bool)])
+    count = jnp.where(ends & (label != pad), pos - run0 + 1, 0)
+    _score, best = seg_first_max(count, label, first, max_len=max_len)
+    return best
 
 
 def sorted_segment_combine(values, seg_ids, last_idx, seg_has, combine: str):

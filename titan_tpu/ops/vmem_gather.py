@@ -10,10 +10,12 @@ rows are summed pairwise and the MXU sums each of a tile's 128 rows
 into its lane. XLA's gather serves such reads an element at a time
 (124 M lanes/s on a v5e against this kernel's 713 M: PERF.md 6, PR 35).
 
-Two callers: the uniform PageRank pull (``models/pagerank_pull.py``:
-``contrib`` over the eight in-edges of a column) and the dense
-bottom-up opener (``models/bfs_hybrid.py``: the frontier as a 0/1 table
-over each vertex's leading lanes). ``gather_impl`` says whether the
+Three callers: the uniform PageRank pull (``models/pagerank_pull.py``:
+``contrib`` over the eight in-edges of a column), the dense bottom-up
+opener (``models/bfs_hybrid.py``: the frontier as a 0/1 table over each
+vertex's leading lanes) and CDLP's rounds (``models/cdlp.py``: at
+``rows=1`` a column is one index and its sum the gathered value itself,
+the labels as float32). ``gather_impl`` says whether the
 kernel can serve a table of n vertices — the backend and the table's
 size, what the code can observe — never a flag, an argument or the
 environment.
