@@ -333,16 +333,18 @@ def _metric_shape(m):
 
 
 def test_shadow_mode_is_byte_identical_to_off():
-    snap = _snap()
+    # a snapshot each: the first admission of a snapshot prices it
+    # (serving.hbm.sizing_passes), whichever scheduler that is
+    snap, snap_sh = _snap(), _snap()
     m_off, m_sh = MetricManager(), MetricManager()
     s_off = JobScheduler(snapshot=snap, metrics=m_off, autostart=False,
                          profiling=False, max_batch=8, autotune="off")
-    s_sh = JobScheduler(snapshot=snap, metrics=m_sh, autostart=False,
+    s_sh = JobScheduler(snapshot=snap_sh, metrics=m_sh, autostart=False,
                         profiling=False, max_batch=8,
                         autotune="shadow", autotune_tick_s=3600.0)
     try:
         jobs_off = _run_jobs(s_off, snap)
-        jobs_sh = _run_jobs(s_sh, snap)
+        jobs_sh = _run_jobs(s_sh, snap_sh)
         # a full-occupancy batch ran: the shadow controller DECIDES...
         entries = s_sh.controller.tick(force=True)
         assert [(e["rule"], e["old"], e["new"]) for e in entries] == \

@@ -164,6 +164,10 @@ def test_double_buffer_reserves_next_epoch_beside_current():
     assert ledger.resident_bytes() > before
     ledger.release(id(snap))           # pool retire path
     assert ledger.resident_bytes() == snapshot_csr_bytes(merged)
+    # admission asks a built image for its own column count (ISSUE 41):
+    # the merged image's is the formula over the merged degrees
+    assert merged._hybrid_csr["q_total"] \
+        == int((-(-merged.out_degree.astype("int64") // 8)).sum()) + 1
     # the new entry is resident-but-evictable: a job's reserve pins it
     ledger.reserve(id(merged), snapshot_csr_bytes(merged))
     assert ledger.pinned_bytes() == snapshot_csr_bytes(merged)

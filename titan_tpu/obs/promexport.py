@@ -62,6 +62,8 @@ HELP = {
         "device bytes of graph images on the HBM ledger",
     "serving.hbm.pinned_bytes":
         "ledger bytes pinned under running batches",
+    "serving.hbm.sizing_passes":
+        "passes over a degree array that pricing a snapshot's images ran",
     "serving.pool.snapshots": "snapshots resident in the serving pool",
     "serving.slo.burn_rate":
         "error-budget burn rate per objective and window",

@@ -275,7 +275,9 @@ class EpochCompactor:
             # re-uploads the whole image — charge the epoch for it so
             # upload_bytes reflects what the boundary commits over
             # the host→device link either way
-            from titan_tpu.olap.serving.hbm import snapshot_csr_bytes
+            from titan_tpu.olap.serving.hbm import (price,
+                                                    snapshot_csr_bytes)
+            price(merged, ("out",), metrics)
             metrics.counter("serving.live.upload_bytes").inc(
                 snapshot_csr_bytes(merged))
         self.host_merges += 1

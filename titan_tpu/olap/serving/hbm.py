@@ -1,12 +1,28 @@
 """Device-memory (HBM) accounting for graph images — admission's ledger.
 
 The serving scheduler admits jobs against it before building a
-snapshot's chunked CSR on device. The byte model matches what the
-kernels actually upload: the transposed 8-aligned ``dstT`` [8, q_total]
-int32 plus three [n+1] int32 side arrays (colstart/degc/deg) —
-models/bfs_hybrid.build_chunked_csr's exact footprint. Eviction is
-largest-first over unpinned entries; pinned entries (graphs under a
-running batch) are never evicted.
+snapshot's images on device. The ledger holds, each under its own key:
+the forward chunked CSR every kind reads (``id(snap)``; byte model:
+the transposed 8-aligned ``dstT`` [8, q_total] int32 plus three [n+1]
+int32 side arrays (colstart/degc/deg) —
+models/bfs_hybrid.build_chunked_csr's exact footprint), the in-edge
+pull image a ``pagerank`` or ``cdlp`` job reads beside it
+(``("pagerank-pull", id(snap))``, models/pagerank_pull.pull_image), a
+``cdlp`` run's working set (``("cdlp-work", id(snap))``, reserved for
+the run and released behind it, models/cdlp.work_bytes) and the
+interactive lane's reversed layout for ``out()``
+(``("interactive-rev", id(snap))``). Eviction is largest-first over
+unpinned entries; pinned entries (graphs under a running batch) are
+never evicted.
+
+Every size derives from ``n`` and two column counts, sum(ceil(deg/8))
++ 1 over the out-degrees (``"out"``) and over the in-degrees
+(``"in"``). Each is one pass over a degree array, paid once a
+snapshot: ``_columns`` keeps it ON the snapshot (``_q_out``,
+``_q_in``), and ``GraphSnapshot._invalidate_layout_caches`` drops it
+with the layouts it sizes, so a refreshed or mutated snapshot is
+re-priced before its next reservation. ``price`` tells an admission how
+many passes it paid (0 on a priced snapshot).
 """
 
 from __future__ import annotations
@@ -14,9 +30,65 @@ from __future__ import annotations
 import threading
 from typing import Callable, Optional
 
+import numpy as np
+
 #: default budget: 12 GB of a 16 GB v5e HBM (leaving headroom for
 #: kernel state/temporaries)
 DEFAULT_BUDGET_BYTES = 12.0e9
+
+#: counter of passes over a degree array that pricing ran, by
+#: ``{image="out"|"in"}``
+SIZING_PASSES = "serving.hbm.sizing_passes"
+
+#: image -> (the attribute its column count is kept under, the built
+#: layout that carries the same count as ``q_total``)
+_KEPT = {"out": ("_q_out", "_hybrid_csr"),
+         "in": ("_q_in", "_hybrid_csr_rev")}
+
+
+def _columns(snap, image: str, metrics=None) -> tuple:
+    """``(columns, passes paid)`` of a snapshot's forward (``"out"``) or
+    reversed (``"in"``) chunked layout, computable BEFORE any build
+    (admission must not pay the upload to learn it doesn't fit):
+    sum(ceil(deg/8)) + 1 pad column. The one place that reads a degree
+    array: once a snapshot, then the kept integer; a layout already
+    built is asked for its own count instead. A pass is counted on
+    ``metrics`` (the process-wide registry without one). Two threads
+    that price one snapshot at once may each run the pass: both count
+    it, and both keep the same integer."""
+    attr, built = _KEPT[image]
+    q = getattr(snap, attr, None)
+    if q is not None:
+        return q, 0
+    layout = getattr(snap, built, None)
+    if layout is not None:
+        q, paid = int(layout["q_total"]), 0
+    else:
+        deg = snap.out_degree if image == "out" \
+            else np.diff(snap.indptr_in[:snap.n + 1])
+        q = int((-(-deg.astype(np.int64) // 8)).sum()) + 1
+        paid = 1
+        if metrics is None:
+            from titan_tpu.utils.metrics import MetricManager
+            metrics = MetricManager.instance()
+        metrics.counter(SIZING_PASSES, labels={"image": image}).inc()
+    setattr(snap, attr, q)
+    return q, paid
+
+
+def price(snap, images, metrics=None) -> int:
+    """Make sure the column counts of ``images`` (``"out"``, ``"in"``)
+    are kept on ``snap``, so that the byte functions below read two
+    integers: returns the passes over a degree array this admission
+    paid for it, 0 on a priced snapshot."""
+    return sum(_columns(snap, image, metrics)[1] for image in images)
+
+
+def _pull_columns(snap) -> int:
+    """Columns of the pull image (models/pagerank_pull.pull_columns):
+    the reversed layout's, rounded up to a whole block."""
+    from titan_tpu.ops.vmem_gather import padded_columns
+    return padded_columns(_columns(snap, "in")[0])
 
 
 def chunked_csr_bytes(n: int, q_total: int) -> int:
@@ -26,12 +98,17 @@ def chunked_csr_bytes(n: int, q_total: int) -> int:
 
 
 def snapshot_csr_bytes(snap) -> int:
-    """Predicted device bytes for a GraphSnapshot's chunked CSR,
-    computable BEFORE the build (admission must not pay the upload to
-    learn it doesn't fit): q_total = sum(ceil(deg/8)) + 1 pad column."""
-    deg = snap.out_degree
-    q_total = int((-(-deg.astype("int64") // 8)).sum()) + 1
-    return chunked_csr_bytes(snap.n, q_total)
+    """Predicted device bytes for a GraphSnapshot's chunked CSR, from
+    its kept ``"out"`` column count."""
+    return chunked_csr_bytes(snap.n, _columns(snap, "out")[0])
+
+
+def snapshot_rev_csr_bytes(snap) -> int:
+    """Predicted device bytes of the REVERSED chunked CSR (the
+    interactive lane's ``out()`` orientation,
+    interactive/compile.reversed_chunked_csr), from the kept ``"in"``
+    column count."""
+    return chunked_csr_bytes(snap.n, _columns(snap, "in")[0])
 
 
 def snapshot_pull_bytes(snap) -> int:
@@ -40,9 +117,8 @@ def snapshot_pull_bytes(snap) -> int:
     column, a few words a vertex), sized
     from the in-degrees BEFORE the build. A ``pagerank`` job reserves it
     beside the forward image."""
-    from titan_tpu.models.pagerank_pull import (pull_columns,
-                                                pull_image_bytes)
-    return pull_image_bytes(snap.n, pull_columns(snap.indptr_in, snap.n))
+    from titan_tpu.models.pagerank_pull import pull_image_bytes
+    return pull_image_bytes(snap.n, _pull_columns(snap))
 
 
 def snapshot_cdlp_bytes(snap) -> int:
@@ -51,8 +127,7 @@ def snapshot_cdlp_bytes(snap) -> int:
     operands and the vote's temporaries, each as wide as the image's
     lanes), sized from the in-degrees like the image itself."""
     from titan_tpu.models.cdlp import work_bytes
-    from titan_tpu.models.pagerank_pull import pull_columns
-    return work_bytes(snap.n, pull_columns(snap.indptr_in, snap.n))
+    return work_bytes(snap.n, _pull_columns(snap))
 
 
 def meshed_snapshot_csr_bytes(snap, num_devices: int) -> int:

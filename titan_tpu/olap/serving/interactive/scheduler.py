@@ -304,7 +304,9 @@ class InteractiveLane:
         CSR look-up and label masks; ended just before the sweep."""
         from titan_tpu.core.defs import Direction
         from titan_tpu.models.bfs_hybrid import build_chunked_csr
-        from titan_tpu.olap.serving.hbm import snapshot_csr_bytes
+        from titan_tpu.olap.serving.hbm import (AdmissionError, price,
+                                                snapshot_csr_bytes,
+                                                snapshot_rev_csr_bytes)
 
         sched = self.sched
         plan0: TraversalPlan = members[0].plan
@@ -360,21 +362,21 @@ class InteractiveLane:
             # HBM admission FIRST, build second (the heavy queue's
             # order): the layout this run reads is sized host-side —
             # forward graph image for in_/both, the REVERSED layout
-            # (the only resident one) for out(), its q_total a cheap
-            # O(n) cumsum over in-degrees — and reserved BEFORE any
-            # device bytes move, so the ledger can evict or refuse
-            # while refusal is still free. An AdmissionError fails the
-            # group; the finally unpins exactly what was reserved
-            from titan_tpu.olap.serving.hbm import (AdmissionError,
-                                                    chunked_csr_bytes)
+            # (the only resident one) for out(), each from the column
+            # count kept on the snapshot (one pass over a degree array
+            # the first batch of a snapshot, none after) — and reserved
+            # BEFORE any device bytes move, so the ledger can evict or
+            # refuse while refusal is still free. An AdmissionError
+            # fails the group; the finally unpins exactly what was
+            # reserved
             if direction is Direction.OUT:
                 key = ("interactive-rev", id(snap))
-                deg_in = np.diff(snap.indptr_in)
-                q_rev = int((-(-deg_in // 8)).sum()) + 1
-                nbytes = chunked_csr_bytes(snap.n, q_rev)
+                passes = price(snap, ("in",), self._metrics)
+                nbytes = snapshot_rev_csr_bytes(snap)
                 handle = (snap, "_hybrid_csr_rev")
             else:
                 key = id(snap)
+                passes = price(snap, ("out",), self._metrics)
                 nbytes = snapshot_csr_bytes(snap)
                 handle = snap
             try:
@@ -407,6 +409,7 @@ class InteractiveLane:
             for r in runnable:
                 sched.tenants.hold_hbm(r.tenant, share)
             admit.set(k_runnable=len(runnable), nbytes=int(nbytes),
+                      sizing_passes=passes,
                       epoch=epoch_info.get("epoch")).end()
             self._build_shapes(g)
             t0 = time.time()
@@ -601,7 +604,8 @@ class InteractiveLane:
     def _run_ppr(self, members: list, batch_id: str) -> bool:
         from titan_tpu.models.pagerank import (
             pagerank_personalized_batched, top_k_per_user)
-        from titan_tpu.olap.serving.hbm import snapshot_csr_bytes
+        from titan_tpu.olap.serving.hbm import (AdmissionError, price,
+                                                snapshot_csr_bytes)
 
         sched = self.sched
         plan0: PPRPlan = members[0].plan
@@ -625,8 +629,8 @@ class InteractiveLane:
                         f"unknown ppr source {r.plan.source!r}: {e}"))
             if not runnable:
                 return False
-            from titan_tpu.olap.serving.hbm import AdmissionError
             key = id(snap)
+            price(snap, ("out",), self._metrics)
             nbytes = snapshot_csr_bytes(snap)
             try:
                 sched.ledger.reserve(key, nbytes)

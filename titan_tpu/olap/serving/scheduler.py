@@ -40,6 +40,9 @@ Metrics (utils/metrics.MetricManager):
   serving.tenant.{rejected,throttled}  (quota admissions, by tenant)
   serving.hbm.{resident_bytes,pinned_bytes} + serving.pool.snapshots
                                  (callback gauges over the ledger/pool)
+  serving.hbm.sizing_passes      (passes over a degree array that pricing
+                                  a snapshot's images ran, {image}: one or
+                                  two a snapshot, 0 a job on a priced one)
 
 Device-cost observability (titan_tpu/obs/devprof + flightrec, ISSUE
 10): the scheduler installs a process-wide DeviceCostProfiler by
@@ -99,7 +102,7 @@ from titan_tpu.olap.api import JobSpec
 from titan_tpu.olap.serving.batcher import (Batcher, batch_key,
                                               job_phase)
 from titan_tpu.olap.serving.hbm import (DEFAULT_BUDGET_BYTES,
-                                        AdmissionError, HBMLedger,
+                                        AdmissionError, HBMLedger, price,
                                         snapshot_cdlp_bytes,
                                         snapshot_csr_bytes,
                                         snapshot_pull_bytes)
@@ -1018,6 +1021,12 @@ class JobScheduler:
                 job.ran_epoch = epoch_info
             with job_phase(head, "job.admit") as admit:
                 ledger_key = id(snap)
+                # every size below reads the column counts kept on the
+                # snapshot; a snapshot's first admission pays the pass
+                # over a degree array that each count takes, here
+                pulls = spec.kind in ("pagerank", "cdlp")
+                passes = price(snap, ("out", "in") if pulls else ("out",),
+                               self._metrics)
                 # mesh-placed cohorts charge the PER-DEVICE share (the
                 # edge image shards over the mesh — hbm.meshed_snapshot_
                 # csr_bytes); only batched BFS runs meshed (single-run
@@ -1045,7 +1054,7 @@ class JobScheduler:
                 # reserved for the run under a key with nothing to evict,
                 # and released, not left resident, behind it
                 images = [(ledger_key, nbytes, snap)]
-                if spec.kind in ("pagerank", "cdlp"):
+                if pulls:
                     pull_bytes = snapshot_pull_bytes(snap)
                     images.append((("pagerank-pull", ledger_key), pull_bytes,
                                    (snap, "_pull_csr")))
@@ -1077,7 +1086,7 @@ class JobScheduler:
                 share = nbytes / len(group)
                 for job in group:
                     self.tenants.hold_hbm(job.tenant, share)
-                admit.set(bytes=int(nbytes))
+                admit.set(bytes=int(nbytes), sizing_passes=passes)
             t0 = time.time()
             w = self.profiler.window() if self.profiler is not None \
                 else None

@@ -337,12 +337,15 @@ class GraphSnapshot:
 
     def _invalidate_layout_caches(self) -> None:
         """Drop every derived layout / device-array cache the model
-        kernels lazily attach (they rebuild from the refreshed arrays).
+        kernels lazily attach (they rebuild from the refreshed arrays),
+        and with them the column counts admission prices those layouts
+        by (``_q_out``, ``_q_in``: olap/serving/hbm keeps them here).
         The dense vertex-property columns are NOT cleared here — they
         stay aligned across edge-only merges; apply_changes clears them
         on property mutations (by key) and vertex-set changes (all)."""
         for attr in ("_out_csr", "_out_csr_order", "_hybrid_csr",
-                     "_hybrid_csr_rev", "_pull_csr", "_frontier_shards",
+                     "_hybrid_csr_rev", "_pull_csr", "_q_out", "_q_in",
+                     "_frontier_shards",
                      "_dev_frontier_sh", "_tiled_shards", "_dev_outdeg",
                      "_dev_frontier"):
             if hasattr(self, attr):
