@@ -241,6 +241,76 @@ def test_cdlp_round_at_graph500_22(spec):
     assert m.temp_size_in_bytes < 6 * 4 * 8 * q_in
 
 
+def test_lcc_programs_at_graph500_22(spec):
+    """The programs of the served LCC job (ISSUE 42) at graph500-22's
+    shapes (n 2,396,390; 17,447,936 columns; 16,384 hubs: a table of 512
+    words a row; 22.1 M low-low edges padded to 22 chunks; the tail's
+    rows of 128 and its widest and most populous classes: CPU counts,
+    PR 42): new to this compiler are ``lax.population_count``, a gather
+    of 512-word rows and the AND-reduce over them. The gathers stay
+    gathers (a row a lane, not a dynamic-slice a word), a dispatch's
+    temporaries stay inside what admission reserves for them, and none
+    builds for over a minute (build times printed)."""
+    import time
+
+    from titan_tpu.models import lcc as L
+    from titan_tpu.ops.vmem_gather import padded_columns
+
+    q = padded_columns(Q22)
+    words = L.hub_words(L.HUBS)
+    table = spec((N22 + 2, words), jnp.uint32)
+    lanes = spec((8, q), jnp.int32)
+    at = spec((), jnp.int32)
+    rows = spec((2_028_000, L.TAIL_ROW), jnp.int32)
+    blocks = [((858_112, 8), 1024), ((63_488, 128), 64), ((1_008, 192), 42)]
+    credits = tuple(
+        (spec(shape, jnp.int32), spec(shape, jnp.int32))
+        for (b, d), _per in blocks for shape in ((b, d), (b,)))
+    programs = {
+        "lcc_pass": lambda: _compile(
+            L._pass(), table, lanes, spec((q,), jnp.int32),
+            spec((8, q), jnp.bool_), at, chunk=L.PASS_CHUNK,
+            tile=L.PASS_TILE),
+        "lcc_colsum": lambda: _compile(
+            L._colsum(), table, spec((2, 22 * L.COL_CHUNK), jnp.int32),
+            at, chunk=L.COL_CHUNK, tile=L.COL_TILE),
+        "lcc_finish": lambda: _compile(
+            L._finish(),
+            (spec((L.PASS_CHUNK,), jnp.int32),) * 17, spec((q,), jnp.bool_),
+            spec((N22,), jnp.int32), spec((N22,), jnp.bool_),
+            spec((L.HUBS,), jnp.int32),
+            (spec((32 * words,), jnp.int32),) * 22,
+            spec((N22,), jnp.int32), credits, seg_max=20_413,
+            trim=17 * L.PASS_CHUNK - q),
+    }
+    for (b, d), per in blocks:
+        programs[f"lcc_tail d={d}"] = functools.partial(
+            _compile, L._tail(), rows, spec((b, d), jnp.int32),
+            spec((b, d), jnp.int32), per=per)
+    work = L.work_bytes(N22, q, L.HUBS)
+    for name, build in programs.items():
+        t0 = time.time()
+        program = build()
+        took = time.time() - t0
+        m = program.memory_analysis()
+        print(f"{name}: built in {took:.1f} s, temporaries "
+              f"{m.temp_size_in_bytes >> 20} MiB")
+        assert took < 60, (name, took)
+        assert m.temp_size_in_bytes + m.output_size_in_bytes < work, name
+        text = program.as_text()
+        if name != "lcc_finish":
+            assert " gather(" in text, name
+    text = programs["lcc_pass"]().as_text()
+    assert "u32[8192,512]" in text          # a tile's rows, whole
+    assert "popcnt" in text or "population" in text
+    assert "cummax" not in text
+    # what admission prices holds what the build read on the chip (its
+    # `lcc.image` span's bytes, PR 42), with under a fifth to spare
+    assert L.table_bytes(N22, L.HUBS) == 4_907_810_816
+    assert 6_707_200_208 < L.image_bytes(N22, q, L.HUBS) \
+        < 1.2 * 6_707_200_208
+
+
 def test_wcc_propagation_on_the_remainder_at_graph500_24(spec):
     """What ISSUE 37 added to a WCC job of g500-24.wcc-c2 (n 8,871,268:
     CPU count, PR 36): the seeding that lists the remainder at n / 8

@@ -381,7 +381,7 @@ def test_the_default_is_ten_rounds_and_an_unknown_kind_is_refused():
         assert env["status"] == "done" and \
             env["result"]["iterations"] == 10
         with pytest.raises(ValueError, match="cdlp"):
-            served.sched.submit(JobSpec(kind="lcc"))
+            served.sched.submit(JobSpec(kind="triangles"))
     finally:
         served.close()
 

@@ -459,6 +459,22 @@ def count_cdlp_round(impl: str, lanes: int) -> None:
                              labels={"impl": impl}).inc(int(lanes))
 
 
+def count_lcc(part: str, edges: int, wedges: Optional[int] = None
+              ) -> None:
+    """Count one part of ``models/lcc.lcc`` dispatched: ``"hub"`` (a
+    level's pass and column sums; ``edges``: the lanes it read, pad
+    lanes included) or ``"tail"`` (``edges``: the low graph's, once
+    each; ``wedges``: the oriented wedges its compares decide)."""
+    for prof in list(_PROFILERS):
+        prof.metrics.counter("device.lcc.edges",
+                             labels={"part": part}).inc(int(edges))
+        if part == "hub":
+            prof.metrics.counter("device.lcc.levels").inc()
+        if wedges is not None:
+            prof.metrics.counter("device.lcc.wedges",
+                                 labels={"part": part}).inc(int(wedges))
+
+
 def current() -> Optional["DeviceCostProfiler"]:
     """The most recently installed profiler, or None."""
     return _PROFILERS[-1] if _PROFILERS else None

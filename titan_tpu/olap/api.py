@@ -259,7 +259,9 @@ class JobSpec:
     [K, n] multi-source device run), 'sssp' | 'pagerank' | 'wcc'
     (frontier kernels, executed singly), 'cdlp' (label propagation's
     most-frequent-label vote, ``params['iterations']`` synchronous
-    rounds, executed singly), 'dense' (a DenseProgram
+    rounds, executed singly), 'lcc' (the local clustering coefficient
+    of an undirected snapshot from exact triangle counts, no parameter,
+    executed singly), 'dense' (a DenseProgram
     instance under ``params['program']``), or 'callable'
     (``params['fn']`` — the host computer's async delegation hook).
 

@@ -768,6 +768,22 @@ class Batcher:
                 job.complete({"iterations": int(iters),
                               "communities": communities,
                               "labels": labels})
+            elif kind == "lcc":
+                from titan_tpu.models.lcc import lcc
+                # no checkpoint: a retried job starts over, the image
+                # still resident. The parts' leaf phases (lcc.image,
+                # lcc.hub, lcc.tail, lcc.result) journal under this
+                # job's `run` span; the readback is counted where it
+                # is made (device.xfer.d2h_bytes{site="lcc.result"})
+                with _under(h, run_span):
+                    counts, coeff = lcc(snap, on_round=on_round,
+                                        overlay=overlay)
+                    with phase("lcc.count"):
+                        # every triangle stands at its three vertices
+                        triangles = int(counts.sum(dtype=np.int64)) // 3
+                job.complete({"triangles": triangles,
+                              "lcc": coeff,
+                              "triangle_counts": counts})
             elif kind == "dense":
                 from titan_tpu.olap.tpu.engine import run_single
                 program = params.pop("program")

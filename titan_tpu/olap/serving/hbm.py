@@ -9,8 +9,12 @@ models/bfs_hybrid.build_chunked_csr's exact footprint), the in-edge
 pull image a ``pagerank`` or ``cdlp`` job reads beside it
 (``("pagerank-pull", id(snap))``, models/pagerank_pull.pull_image), a
 ``cdlp`` run's working set (``("cdlp-work", id(snap))``, reserved for
-the run and released behind it, models/cdlp.work_bytes) and the
-interactive lane's reversed layout for ``out()``
+the run and released behind it, models/cdlp.work_bytes), an ``lcc``
+job's hub bit table with what is built beside it (``("lcc-image",
+id(snap))``, models/lcc.lcc_image: resident and evictable like the
+other images, eight times the pull image at graph500-22) and its
+working set (``("lcc-work", id(snap))``, released behind the run) and
+the interactive lane's reversed layout for ``out()``
 (``("interactive-rev", id(snap))``). Eviction is largest-first over
 unpinned entries; pinned entries (graphs under a running batch) are
 never evicted.
@@ -128,6 +132,22 @@ def snapshot_cdlp_bytes(snap) -> int:
     lanes), sized from the in-degrees like the image itself."""
     from titan_tpu.models.cdlp import work_bytes
     return work_bytes(snap.n, _pull_columns(snap))
+
+
+def snapshot_lcc_bytes(snap) -> int:
+    """Predicted device bytes of what an ``lcc`` job keeps resident
+    beside the pull image (models/lcc.image_bytes: the hub bit table and
+    what is built with it), from ``n``, the kept ``"in"`` column count
+    and the module's hub count: no pass over a degree array."""
+    from titan_tpu.models import lcc
+    return lcc.image_bytes(snap.n, _pull_columns(snap), lcc.HUBS)
+
+
+def snapshot_lcc_work_bytes(snap) -> int:
+    """Predicted device bytes an ``lcc`` job's tiles and finish work on
+    (models/lcc.work_bytes), from the same integers."""
+    from titan_tpu.models import lcc
+    return lcc.work_bytes(snap.n, _pull_columns(snap), lcc.HUBS)
 
 
 def meshed_snapshot_csr_bytes(snap, num_devices: int) -> int:

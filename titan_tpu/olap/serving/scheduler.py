@@ -105,6 +105,8 @@ from titan_tpu.olap.serving.hbm import (DEFAULT_BUDGET_BYTES,
                                         AdmissionError, HBMLedger, price,
                                         snapshot_cdlp_bytes,
                                         snapshot_csr_bytes,
+                                        snapshot_lcc_bytes,
+                                        snapshot_lcc_work_bytes,
                                         snapshot_pull_bytes)
 from titan_tpu.olap.serving.jobs import Job, JobState
 from titan_tpu.olap.serving.pool import SnapshotPool
@@ -115,7 +117,22 @@ from titan_tpu.utils.metrics import MetricManager
 
 #: job kinds that execute against a pooled snapshot (everything except
 #: host 'callable' delegations)
-_SNAPSHOT_KINDS = ("bfs", "sssp", "pagerank", "wcc", "cdlp", "dense")
+_SNAPSHOT_KINDS = ("bfs", "sssp", "pagerank", "wcc", "cdlp", "lcc",
+                   "dense")
+
+#: kinds that read the in-edge pull image of models/pagerank_pull (no
+#: overlay seam: leased compacted)
+_PULL_KINDS = ("pagerank", "cdlp", "lcc")
+
+#: kind -> (ledger key, bytes from the snapshot, the snapshot attribute
+#: an eviction drops) of an image the kind keeps resident beside the
+#: pull image
+_KIND_IMAGE = {"lcc": ("lcc-image", snapshot_lcc_bytes, "_lcc_csr")}
+
+#: kind -> (ledger key, bytes from the snapshot) of the working set a
+#: run of the kind holds and releases
+_KIND_WORK = {"cdlp": ("cdlp-work", snapshot_cdlp_bytes),
+              "lcc": ("lcc-work", snapshot_lcc_work_bytes)}
 
 _KNOWN_KINDS = _SNAPSHOT_KINDS + ("callable",)
 
@@ -413,7 +430,8 @@ class JobScheduler:
         key = id(snap)
         self._evictable.pop(key, None)
         self.ledger.release(key)
-        for rider in (("interactive-rev", key), ("pagerank-pull", key)):
+        for rider in (("interactive-rev", key), ("pagerank-pull", key),
+                      ("lcc-image", key)):
             self._evictable.pop(rider, None)
             self.ledger.release(rider)
 
@@ -436,6 +454,15 @@ class JobScheduler:
                 labels={"kind": "unknown", "tenant": tenant}).inc()
             raise ValueError(f"unknown job kind {spec.kind!r} "
                              f"(known: {', '.join(_KNOWN_KINDS)})")
+        if spec.kind == "lcc" and spec.directed:
+            self._metrics.counter(
+                "serving.jobs.rejected",
+                labels={"kind": spec.kind, "tenant": tenant}).inc()
+            raise ValueError(
+                "lcc on a directed snapshot: the specification's "
+                "directed form (in- and out-neighbours together, a pair "
+                "counted in each direction it is an edge) is not "
+                "implemented; submit with directed=false")
         faults = spec.params.get("faults") \
             if isinstance(spec.params, dict) else None
         if faults is not None:
@@ -1000,7 +1027,7 @@ class JobScheduler:
         with job_phase(head, "job.lease"):
             try:
                 # dense window sweeps (pagerank / DenseProgram) and the
-                # pull image (pagerank, cdlp) have no overlay seam: the
+                # pull image (pagerank, cdlp, lcc) have no overlay seam: the
                 # live pool folds the overlay into the base BEFORE
                 # leasing for these kinds (the documented
                 # compact-before-run fallback, models/frontier.py)
@@ -1008,7 +1035,7 @@ class JobScheduler:
                                           edge_keys=edge_keys,
                                           directed=spec.directed,
                                           compacted=spec.kind in
-                                          ("pagerank", "cdlp", "dense"))
+                                          _PULL_KINDS + ("dense",))
             except Exception as e:
                 for job in group:
                     job.fail(f"snapshot: {type(e).__name__}: {e}")
@@ -1024,7 +1051,7 @@ class JobScheduler:
                 # every size below reads the column counts kept on the
                 # snapshot; a snapshot's first admission pays the pass
                 # over a degree array that each count takes, here
-                pulls = spec.kind in ("pagerank", "cdlp")
+                pulls = spec.kind in _PULL_KINDS
                 passes = price(snap, ("out", "in") if pulls else ("out",),
                                self._metrics)
                 # mesh-placed cohorts charge the PER-DEVICE share (the
@@ -1045,26 +1072,32 @@ class JobScheduler:
                         snap, int(self.mesh.devices.size))
                 else:
                     nbytes = snapshot_csr_bytes(snap)
-                # a `pagerank` or `cdlp` job reads a second image: the
-                # in-edge pull image of models/pagerank_pull, under a key
-                # of its own so that a snapshot already resident for other
-                # kinds is not taken to hold it. A `cdlp` job's rounds
-                # besides work on several times that image (the sort's
-                # operands, the vote's temporaries: models/cdlp.work_bytes):
-                # reserved for the run under a key with nothing to evict,
-                # and released, not left resident, behind it
+                # a `pagerank`, `cdlp` or `lcc` job reads a second image:
+                # the in-edge pull image of models/pagerank_pull, under a
+                # key of its own so that a snapshot already resident for
+                # other kinds is not taken to hold it. An `lcc` job keeps a
+                # third beside it, its hub bit table (_KIND_IMAGE), resident
+                # and evictable like the others. A `cdlp` or `lcc` job
+                # besides works on more than it keeps (_KIND_WORK: the
+                # sort's operands and the vote's temporaries; the tiles'
+                # gathered rows): reserved for the run under a key with
+                # nothing to evict, and released, not left resident,
+                # behind it
                 images = [(ledger_key, nbytes, snap)]
                 if pulls:
-                    pull_bytes = snapshot_pull_bytes(snap)
-                    images.append((("pagerank-pull", ledger_key), pull_bytes,
+                    images.append((("pagerank-pull", ledger_key),
+                                   snapshot_pull_bytes(snap),
                                    (snap, "_pull_csr")))
-                    nbytes += pull_bytes
+                if spec.kind in _KIND_IMAGE:
+                    name, sized, attr = _KIND_IMAGE[spec.kind]
+                    images.append(((name, ledger_key), sized(snap),
+                                   (snap, attr)))
                 work_key = None
-                if spec.kind == "cdlp":
-                    work_key = ("cdlp-work", ledger_key)
-                    work_bytes = snapshot_cdlp_bytes(snap)
-                    images.append((work_key, work_bytes, None))
-                    nbytes += work_bytes
+                if spec.kind in _KIND_WORK:
+                    name, sized = _KIND_WORK[spec.kind]
+                    work_key = (name, ledger_key)
+                    images.append((work_key, sized(snap), None))
+                nbytes = sum(image[1] for image in images)
                 held = []
                 try:
                     for key, image_bytes, _handle in images:

@@ -344,7 +344,8 @@ class GraphSnapshot:
         stay aligned across edge-only merges; apply_changes clears them
         on property mutations (by key) and vertex-set changes (all)."""
         for attr in ("_out_csr", "_out_csr_order", "_hybrid_csr",
-                     "_hybrid_csr_rev", "_pull_csr", "_q_out", "_q_in",
+                     "_hybrid_csr_rev", "_pull_csr", "_lcc_csr", "_q_out",
+                     "_q_in",
                      "_frontier_shards",
                      "_dev_frontier_sh", "_tiled_shards", "_dev_outdeg",
                      "_dev_frontier"):

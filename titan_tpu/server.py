@@ -52,7 +52,11 @@ Endpoints:
                       propagation: {"kind": "cdlp", "iterations": 10},
                       synchronous rounds; result ``iterations``,
                       ``communities`` and the array ``labels`` int32
-                      [n]), dense.
+                      [n]), lcc (LDBC Graphalytics' local clustering
+                      coefficient: {"kind": "lcc"}, no parameter, an
+                      undirected snapshot only; result ``triangles``
+                      and the arrays ``lcc`` float32 [n] and
+                      ``triangle_counts`` int32 [n]), dense.
                       Same-snapshot BFS jobs fuse into one batched
                       [K, n] device run; max_retries/checkpoint_every
                       opt into the recovery plane (olap/recovery —
