@@ -432,8 +432,13 @@ def phase_served(ctx: dict, seed: int) -> None:
             lab, return_counts=True)[1].max()))
         check(got == wcc_ref, f"wcc (components, largest) {got} != "
                               f"reference {wcc_ref}")
+        tests = {impl: prof.metrics.counter_value(
+            "device.bfs.frontier_test", {"prog": "end", "impl": impl})
+            for impl in ("vmem", "xla")}
+        check(sum(tests.values()) > 0, "the wcc peel ran no endgame")
         log(f"phase 3 wcc: components={got[0]} largest={got[1]} exact, "
-            f"exec_ms={body.get('exec_ms')}")
+            f"exec_ms={body.get('exec_ms')}, end's frontier test impl="
+            + "+".join(f"{impl} x{k}" for impl, k in tests.items() if k))
 
         body = client.wait_done(others["pagerank"])
         rank = np.asarray(sched.get(others["pagerank"]).result["rank"],

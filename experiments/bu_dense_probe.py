@@ -47,7 +47,7 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 
 def traced_ops(fn, top: int = 14) -> dict:
     """One call of ``fn`` under the profiler: the device's busy seconds
-    and its operations by own time."""
+    and its operations by own time, each with the times it ran."""
     import jax
 
     import trace_reduce
@@ -64,6 +64,7 @@ def traced_ops(fn, top: int = 14) -> dict:
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
     ops: dict = {}
+    calls: dict = {}
     busy = 0.0
     for plane, lines in planes:
         lines = dict(lines)
@@ -73,8 +74,10 @@ def traced_ops(fn, top: int = 14) -> dict:
                 (s, e) for s, e, _n in events)) / 1e6
             for name, t in trace_reduce.self_times(events).items():
                 ops[name] = ops.get(name, 0.0) + t / 1e6
+            for _s, _e, name in events:
+                calls[name] = calls.get(name, 0) + 1
     return {"busy_ms": round(busy, 3),
-            "ops_ms": [[n, round(t, 3)] for n, t in sorted(
+            "ops_ms": [[n, round(t, 3), calls[n]] for n, t in sorted(
                 ops.items(), key=lambda kv: -kv[1])[:top]]}
 
 

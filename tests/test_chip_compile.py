@@ -355,6 +355,36 @@ def test_dense_opener_at_graph500_24(spec):
     assert lead.memory_analysis().output_size_in_bytes == lanes * width * 4
 
 
+@pytest.mark.parametrize("prog", ["end", "bu0b"])
+def test_frontier_test_in_vmem_at_graph500_24(spec, prog):
+    """What ISSUE 43 put on the two list-wide programs of a
+    g500-24.wcc-c2 job (one call each, on 2^20 columns): their frontier
+    test reads the 35.5 MB 0/1 table in VMEM through the Pallas gather
+    at eight rows a column (in ``end`` inside the ``while_loop``'s
+    body), and XLA's gather of 8 x 2^20 single bytes, 68 ms a test on
+    the chip (PERF.md 5), is in neither."""
+    from titan_tpu.models import bfs_hybrid as H
+
+    n, q, cap = 8_871_268, 70_278_271, 1 << 20
+    assert H._frontier_road("vmem", cap) == "vmem"
+    state = spec((n + 1,), jnp.int32)
+    at = spec((), jnp.int32)
+    image = spec((8, q), jnp.int32)
+    if prog == "end":
+        program = _compile(H._endgame(), state, at, at, image, state,
+                           state, c_cap=cap, p_cap=cap, n_=n, impl="vmem")
+    else:
+        program = _compile(H._bu_finish_chunk0(), state,
+                           spec((H._fbits_width(n),), jnp.uint8),
+                           spec((cap,), jnp.int32), at, image, state,
+                           state, c_cap=cap, n_=n, impl="vmem")
+    text = program.as_text()
+    assert "tpu_custom_call" in text
+    assert "u8[8388608]" not in text and "u8[8,1048576]" not in text
+    # lists of 2^20 and the table, nothing as wide as the image
+    assert program.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize("rows", [None, 1], ids=["pagerank", "ppr"])
 def test_pagerank_window(spec, rows):
     from titan_tpu.models.frontier import _pr_window

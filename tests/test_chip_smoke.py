@@ -48,6 +48,8 @@ def test_smoke_passes_under_cpu_rehearsal(monkeypatch, capsys, cache_config):
                   "phase 3 traverse", "phase 4 second pass"):
         assert phase in body
     assert "pass2 compiles=0" in body and "batch_k=8" in body
+    # the WCC job's peel says what served its endgame's frontier test
+    assert "end's frontier test impl=xla x1" in body
 
 
 def test_smoke_failed_comparison_exits_without_ok_line(monkeypatch, capsys,

@@ -26,6 +26,11 @@ from titan_tpu.utils import jitcache
 from titan_tpu.utils.metrics import MetricManager
 
 LANES = [1, 2, 4]
+#: the programs that test parents against the frontier, by their key
+#: (``bfs_hybrid._frontier_test`` serves every one)
+FRONTIER_TEST_KEYS = ("hybrid_bu_start", "hybrid_bu_startL",
+                      "hybrid_bu_finish0", "hybrid_bu_more", "hybrid_ex",
+                      "hybrid_endgame")
 
 
 def _sym(n, src, dst):
@@ -192,9 +197,11 @@ def kernel_in_the_interpreter(monkeypatch):
     interpreter's kernel is not kept under the key for other tests."""
     monkeypatch.setattr(vg, "colsum_vmem", functools.partial(
         vg.colsum_vmem, interpret=True))
-    jitcache._JITS.pop("hybrid_bu_startL", None)
+    for key in FRONTIER_TEST_KEYS:
+        jitcache._JITS.pop(key, None)
     yield
-    jitcache._JITS.pop("hybrid_bu_startL", None)
+    for key in FRONTIER_TEST_KEYS:
+        jitcache._JITS.pop(key, None)
 
 
 # -- the image ---------------------------------------------------------------
@@ -263,7 +270,9 @@ def test_the_opener_at_a_share_of_the_vertices(lanes, share):
 
 # -- whole runs --------------------------------------------------------------
 
-def _run_with_openers(run):
+def traced(run):
+    """``run()`` under a profiler and a scope of their own: its result,
+    the journal's spans, the registry, the profiler's kernel table."""
     tracer = Tracer()
     mm = MetricManager()
     root = tracer.start("t", "run")
@@ -273,7 +282,11 @@ def _run_with_openers(run):
         assert devprof.drain(10.0)
         stats = prof.kernel_stats()
     tracer.end(root)
-    spans = tracer.spans("t")
+    return out, tracer.spans("t"), mm, stats
+
+
+def _run_with_openers(run):
+    out, spans, mm, stats = traced(run)
     openers = [s.attrs["opener"] for s in spans
                if s.name == "bfs.level" and s.attrs.get("dir") == "bu"]
     impls = [s.attrs.get("impl") for s in spans if s.name == "kernel"

@@ -302,6 +302,55 @@ def _fbit_of(fbits, idx):
     return ((byte >> plane.astype(jnp.uint8)) & jnp.uint8(1)).astype(bool)
 
 
+def _frontier_road(impl: str, columns: int) -> str:
+    """What serves the bottom-up frontier test of a block ``columns``
+    wide under ``impl`` (``vmem_gather.gather_impl``: the backend and
+    the table's size): ``"vmem"`` where the kernel can take the table
+    and the block is whole grid steps, else ``"xla"``. The host loop
+    hands each program this road as its static ``impl``, so a
+    ``kernel`` span says what served its test."""
+    from titan_tpu.ops.vmem_gather import BLOCK
+
+    if impl == "vmem" and columns % BLOCK == 0:
+        return "vmem"
+    return "xla"
+
+
+def _frontier_test(dist, level, n_: int, impl: str, columns: int,
+                   fbits=None):
+    """The bottom-up frontier test of the single-source family, written
+    once: returns ``hit(parents)`` for blocks ``parents`` int32
+    [rows, columns] of vertex ids 0 .. n_+1 (row after row as
+    ``jnp.take(dstT, cols, axis=1)`` gives them; n_ the sink and n_+1
+    the pad, neither ever on a frontier), giving bool [columns]: does
+    any of a column's ``rows`` parents sit on the frontier ``dist ==
+    level``. The frontier's image is made here, once a call of this
+    function (before a program's rounds, not inside them):
+
+    - ``"vmem"`` (``_frontier_road``): the frontier as a 0/1 float32
+      table in VMEM under the Pallas gather of ``ops/vmem_gather.py``
+      (a column's ``rows`` values sum exactly in float32; ``rows`` a
+      power of two). XLA's gather serves these reads an element at a
+      time: 8 x 2^20 of them 68.8 ms on a v5e against 12.3 ms here,
+      and the table wins from one grid step up (0.067 against 0.059 ms
+      at 1,024 columns, the table's one pass 0.10 ms where the
+      bitmap's is 1.05: experiments/endgame_probe.py, PERF.md 6, PR
+      43);
+    - ``"xla"``: ``_fbit_of`` over the plane bitmap ``fbits`` (the
+      level's opener hands it on; packed here where there is none)."""
+    import jax.numpy as jnp
+
+    from titan_tpu.ops import vmem_gather
+
+    if _frontier_road(impl, columns) == "vmem":
+        table = vmem_gather.as_table((dist == level).astype(jnp.float32))
+        return lambda parents: vmem_gather.colsum_vmem(
+            parents.reshape(-1), table, rows=parents.shape[0]) > 0
+    if fbits is None:
+        fbits = _pack_bits(dist, level, n_)
+    return lambda parents: _fbit_of(fbits, parents).any(axis=0)
+
+
 def _bit_of(fbits, idx):
     """Test bitmap bits at int32 indices (any shape)."""
     import jax.numpy as jnp
@@ -444,9 +493,10 @@ def _bu_start():
         import jax.numpy as jnp
 
         @functools.partial(jax.jit,
-                           static_argnames=("c_cap", "n_"),
+                           static_argnames=("c_cap", "n_", "impl"),
                            donate_argnums=(0,))
-        def bu0(dist, level, dstT, colstart, degc, c_cap: int, n_: int):
+        def bu0(dist, level, dstT, colstart, degc, c_cap: int, n_: int,
+                impl: str):
             """Bottom-up level opener, fully fused: build the candidate
             list from dist (the old separate all_unvis dispatch), check
             chunk 0 of every candidate against the frontier BITMAP, then
@@ -464,17 +514,19 @@ def _bu_start():
             v = jnp.minimum(cand, n_)
             cols = jnp.where(alive, colstart[v], q_pad)
             parents = jnp.take(dstT, jnp.clip(cols, 0, q_pad), axis=1)
-            hit = _fbit_of(fbits, parents)
-            found = alive & hit.any(axis=0)
+            found = alive & _frontier_test(dist, level, n_, impl, c_cap,
+                                           fbits)(parents)
             dist = dist.at[jnp.where(found, v, n_ + 1)].set(
                 level + 1, mode="drop")
-            surv = alive & ~found & (degc[v] > 1)
+            # gathered once: a branch of lax.cond would gather it again
+            chunks = degc[v]
+            surv = alive & ~found & (chunks > 1)
             nc = surv.sum().astype(jnp.int32)
 
             def compact(_):
                 _, (cand2,) = scatter_compact(surv, (cand,), c_cap,
                                               (n_,))
-                rem8 = jnp.where(surv, degc[v] - 1, 0) \
+                rem8 = jnp.where(surv, chunks - 1, 0) \
                     .sum(dtype=jnp.int32)
                 return cand2, rem8
 
@@ -537,8 +589,6 @@ def _bu_startL():
         import jax
         import jax.numpy as jnp
 
-        from titan_tpu.ops.vmem_gather import as_table, colsum_vmem
-
         @functools.partial(jax.jit,
                            static_argnames=("c_cap", "n_", "lanes",
                                             "impl"),
@@ -572,18 +622,12 @@ def _bu_startL():
             candidates ``bu0`` opens.
 
             ``impl`` says what serves the frontier test's random reads
-            (``vmem_gather.gather_impl``: the backend and the table's
-            size): ``"xla"`` the byte gather of ``_fbit_of``,
-            ``"vmem"`` the frontier as a 0/1 table in VMEM under the
-            Pallas gather."""
+            (``_frontier_test``)."""
             fbits = _pack_bits(dist, level, n_)
             unvis = (dist >= INF) & (degc > 0)              # [n + 1]
-            if impl == "vmem":
-                table = as_table((dist == level).astype(jnp.float32))
-                hit = colsum_vmem(lead, table, rows=lanes)[:n_ + 1] > 0
-            else:
-                hit = _fbit_of(fbits, lead).reshape(lanes, -1) \
-                    .any(axis=0)[:n_ + 1]
+            first = lead.reshape(lanes, -1)
+            hit = _frontier_test(dist, level, n_, impl, first.shape[1],
+                                 fbits)(first)[:n_ + 1]
             found = unvis & hit
             dist = jnp.where(found, level + 1, dist)
             untested = unvis & ~found & (deg > lanes)
@@ -611,10 +655,10 @@ def _bu_finish_chunk0():
         import jax.numpy as jnp
 
         @functools.partial(jax.jit,
-                           static_argnames=("c_cap", "n_"),
+                           static_argnames=("c_cap", "n_", "impl"),
                            donate_argnums=(0,))
         def bu0b(dist, fbits, cand, level, dstT, colstart, degc,
-                 c_cap: int, n_: int):
+                 c_cap: int, n_: int, impl: str):
             """Finish chunk 0 for the split-lane opener's untested
             candidates: fetch the FULL chunk (all 8 lanes — an
             offset row slice like ``dstT[lo:]`` does NOT fuse into the
@@ -624,7 +668,10 @@ def _bu_finish_chunk0():
             already-tested lanes re-test as guaranteed misses at a few
             percent extra lane work on a small cap), scatter the hits,
             compact the full-chunk-0 misses with degc > 1 for the
-            bu_more rounds (off starts at 1 — chunk 0 is consumed)."""
+            bu_more rounds (off starts at 1 — chunk 0 is consumed).
+            ``bu0a`` wrote only ``level + 1`` into ``dist``, so ``dist
+            == level`` is still the frontier ``fbits`` was packed
+            from."""
             q_pad = dstT.shape[1] - 1
             c_count = (cand < n_).sum().astype(jnp.int32)
             alive = jnp.arange(c_cap) < c_count
@@ -632,16 +679,19 @@ def _bu_finish_chunk0():
             cols = jnp.where(alive, colstart[v], q_pad)
             parents_hi = jnp.take(dstT, jnp.clip(cols, 0, q_pad),
                                   axis=1)
-            found = alive & _fbit_of(fbits, parents_hi).any(axis=0)
+            found = alive & _frontier_test(dist, level, n_, impl, c_cap,
+                                           fbits)(parents_hi)
             dist = dist.at[jnp.where(found, v, n_ + 1)].set(
                 level + 1, mode="drop")
-            surv = alive & ~found & (degc[v] > 1)
+            # gathered once: a branch of lax.cond would gather it again
+            chunks = degc[v]
+            surv = alive & ~found & (chunks > 1)
             nc = surv.sum().astype(jnp.int32)
 
             def compact(_):
                 _, (cand2,) = scatter_compact(surv, (cand,), c_cap,
                                               (n_,))
-                rem8 = jnp.where(surv, degc[v] - 1, 0) \
+                rem8 = jnp.where(surv, chunks - 1, 0) \
                     .sum(dtype=jnp.int32)
                 return cand2, rem8
 
@@ -664,15 +714,18 @@ def _bu_more():
         import jax.numpy as jnp
 
         @functools.partial(jax.jit,
-                           static_argnames=("c_cap", "n_", "fuse"),
+                           static_argnames=("c_cap", "n_", "fuse",
+                                            "impl"),
                            donate_argnums=(0,))
         def bu(dist, fbits, cand, off, prog, level, dstT, colstart,
-               degc, c_cap: int, n_: int, fuse: int):
+               degc, c_cap: int, n_: int, fuse: int, impl: str):
             """``fuse`` chunk-check rounds over the compacted survivor
-            list (bitmap hit test), with the level-end stats under
+            list (frontier hit test), with the level-end stats under
             lax.cond when the survivors die out inside."""
             c_count = prog[0]      # survivor count from the DEVICE
             q_pad = dstT.shape[1] - 1      # progress vector (no put)
+            # the rounds write level + 1 alone: one image serves them all
+            hit_of = _frontier_test(dist, level, n_, impl, c_cap, fbits)
 
             def round_(state, _):
                 dist, cand, off, c_count = state
@@ -681,8 +734,7 @@ def _bu_more():
                 cols = jnp.where(alive, colstart[v] + off, q_pad)
                 parents = jnp.take(dstT, jnp.clip(cols, 0, q_pad),
                                    axis=1)
-                hit = _fbit_of(fbits, parents)
-                found = alive & hit.any(axis=0)
+                found = alive & hit_of(parents)
                 dist = dist.at[jnp.where(found, v, n_ + 1)].set(
                     level + 1, mode="drop")
                 surv = alive & ~found & (off + 1 < degc[v])
@@ -714,10 +766,11 @@ def _bu_exhaust():
         import jax.numpy as jnp
 
         @functools.partial(jax.jit,
-                           static_argnames=("c_cap", "p_cap", "n_"),
+                           static_argnames=("c_cap", "p_cap", "n_",
+                                            "impl"),
                            donate_argnums=(0,))
         def ex(dist, fbits, cand, off, prog, level, dstT, colstart,
-               degc, c_cap: int, p_cap: int, n_: int):
+               degc, c_cap: int, p_cap: int, n_: int, impl: str):
             """One masked sweep over ALL remaining chunks of the surviving
             candidates (rare: frontier-less hubs / small components), then
             the level-end stats (always needed here)."""
@@ -729,7 +782,8 @@ def _bu_exhaust():
                 valid, rem, colstart[v] + off, p_cap, dstT.shape[1] - 1,
                 with_owner=True)
             parents = jnp.take(dstT, cols, axis=1)       # [8, p_cap]
-            hit = _fbit_of(fbits, parents).any(axis=0)    # [p_cap]
+            hit = _frontier_test(dist, level, n_, impl, p_cap,
+                                 fbits)(parents)          # [p_cap]
             # per-candidate any-hit: scatter-max of hit through the
             # pair -> candidate mapping
             j = jnp.arange(p_cap, dtype=jnp.int32)
@@ -750,10 +804,11 @@ def _endgame():
         import jax.numpy as jnp
 
         @functools.partial(jax.jit,
-                           static_argnames=("c_cap", "p_cap", "n_"),
+                           static_argnames=("c_cap", "p_cap", "n_",
+                                            "impl"),
                            donate_argnums=(0,))
         def end(dist, level0, max_lv, dstT, colstart, degc, c_cap: int,
-                p_cap: int, n_: int):
+                p_cap: int, n_: int, impl: str):
             """Finish the BFS: run EVERY remaining level in one dispatch.
             Each iteration is a full bottom-up level over the (shrinking)
             unvisited set — candidate count and chunk mass are bounded by
@@ -771,14 +826,16 @@ def _endgame():
 
             def body(s):
                 dist, cand, c_count, level, _, iters = s
-                fbits = _pack_bits(dist, level, n_)
                 valid = jnp.arange(c_cap) < c_count
                 v = jnp.minimum(cand, n_)
                 cols, p_total, owner = enumerate_chunk_pairs(
                     valid, degc[v], colstart[v], p_cap, q_pad,
                     with_owner=True)
                 parents = jnp.take(dstT, cols, axis=1)
-                hit = _fbit_of(fbits, parents).any(axis=0)
+                # the frontier's image is this level's own: made in
+                # the body, an n-wide elementwise pass
+                hit = _frontier_test(dist, level, n_, impl,
+                                     p_cap)(parents)
                 j = jnp.arange(p_cap, dtype=jnp.int32)
                 found_per = jnp.zeros((c_cap,), jnp.int32) \
                     .at[jnp.where(j < p_total, owner, c_cap - 1)] \
@@ -1800,7 +1857,16 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
     # leading-lane image; `plain`: `bu0`, every lane at once).
     # `device.bfs.levels{dir, list="single"}` counts the LEVELS a step
     # covered, so a run's counts sum to the `levels` it returns;
-    # `device.bfs.opener{impl}` the pulled levels by their opener.
+    # `device.bfs.opener{impl}` the pulled levels by their opener;
+    # `device.bfs.frontier_test{prog, impl}` the calls of the programs
+    # that test parents against the frontier, by what served the test.
+    graph_impl = vmem_gather.gather_impl(n)
+
+    def served_by(prog: str, columns: int) -> str:
+        # the program's static `impl`: the road of its frontier test
+        impl = _frontier_road(graph_impl, columns)
+        devprof.count_frontier_test(prog, impl)
+        return impl
 
     # ---- fused head: source + early top-down levels, one readback
     f_cap_h = min(HEAD_F_CAP, cap_n)
@@ -1826,7 +1892,8 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
                        p_cap=p_cap) as ph:
                 dist, iters = endgame(
                     dist, dev_scalar(level), dev_scalar(max_levels), dstT,
-                    colstart, degc, c_cap=c_cap, p_cap=p_cap, n_=n)
+                    colstart, degc, c_cap=c_cap, p_cap=p_cap, n_=n,
+                    impl=served_by("end", p_cap))
                 # +1: the empty probe level, matching the host loop's
                 # count
                 ran = min(stats_of(ph, iters)[0] + 1, max_levels - level)
@@ -1872,7 +1939,8 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
                         dist, dev_scalar(level),
                         leading_lanes(g, SPLIT_LANES), g["deg"], degc,
                         c_cap=c_cap, n_=n, lanes=SPLIT_LANES,
-                        impl=vmem_gather.gather_impl(n))
+                        impl=served_by(
+                            "bu0a", vmem_gather.padded_columns(n + 1)))
                     nu = stats_of(ph, prog)[0]
                     ph.set(missed=nu)
                     if nu > 0:
@@ -1880,14 +1948,15 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
                         cand = pad(cand)
                         dist, cand, prog, st_dev = bu0b(
                             dist, fbits, cand[:u_cap], dev_scalar(level),
-                            dstT, colstart, degc, c_cap=u_cap, n_=n)
+                            dstT, colstart, degc, c_cap=u_cap, n_=n,
+                            impl=served_by("bu0b", u_cap))
                         nc, rem8 = stats_of(ph, prog)
                     else:
                         nc, rem8 = 0, 0
                 else:
                     dist, fbits, cand, prog, st_dev = bu0(
                         dist, dev_scalar(level), dstT, colstart, degc,
-                        c_cap=c_cap, n_=n)
+                        c_cap=c_cap, n_=n, impl=served_by("bu0", c_cap))
                     nc, rem8 = stats_of(ph, prog)
                 ph.set(left=nc)
                 rounds = 1
@@ -1901,7 +1970,8 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
                     dist, cand, off, prog, st_dev = bu(
                         dist, fbits, cand[:c_cap2], off[:c_cap2],
                         prog, dev_scalar(level), dstT, colstart,
-                        degc, c_cap=c_cap2, n_=n, fuse=fuse)
+                        degc, c_cap=c_cap2, n_=n, fuse=fuse,
+                        impl=served_by("bu", c_cap2))
                     cand, off = pad(cand), pad(off)
                     nc, rem8 = stats_of(ph, prog)
                     rounds += fuse
@@ -1918,7 +1988,7 @@ def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,
                                       off[:c_cap2], prog,
                                       dev_scalar(level), dstT, colstart,
                                       degc, c_cap=c_cap2, p_cap=rem_cap,
-                                      n_=n)
+                                      n_=n, impl=served_by("ex", rem_cap))
                 f_count, m8_f, m8_unvis, n_unvis = stats_of(ph, st_dev)
             devprof.count_level("bu", "single")
             devprof.count_opener(opener)

@@ -413,6 +413,17 @@ def count_opener(impl: str) -> None:
                              labels={"impl": impl}).inc()
 
 
+def count_frontier_test(prog: str, impl: str) -> None:
+    """Count one call of a single-source program that tests parents
+    against the frontier (``prog``: the jitted function, ``bu0a``,
+    ``bu0b``, ``bu``, ``ex``, ``bu0``, ``end``) by what served the
+    test's random reads (``bfs_hybrid._frontier_road``): ``"vmem"`` the
+    frontier as a table in VMEM, ``"xla"`` the bitmap's byte gather."""
+    for prof in list(_PROFILERS):
+        prof.metrics.counter("device.bfs.frontier_test",
+                             labels={"prog": prog, "impl": impl}).inc()
+
+
 def count_wcc_rounds(rounds: int) -> None:
     """Count the min-label propagation rounds of one WCC run (the
     rounds ``_frontier_run`` planned after the peel)."""
