@@ -1,7 +1,7 @@
-"""Admission prices a snapshot's images from two integers kept on the
+"""Admission prices a snapshot's images from integers kept on the
 snapshot (ISSUE 41, ``olap/serving/hbm.py``): the column counts of the
-forward and the reversed chunked layout, one pass over a degree array
-each, once a snapshot. They equal the formulas computed afresh and the
+forward and the reversed chunked layout and (ISSUE 44) the lanes of
+CDLP's row image, one pass over a degree array each, once a snapshot. They equal the formulas computed afresh and the
 counts the built images carry; every byte function returns what it
 returned when it read the degree arrays a call; whatever changes the
 arrays drops the kept counts with the layouts; and a scheduler's jobs
@@ -26,17 +26,47 @@ from titan_tpu.olap.tpu import snapshot as snap_mod
 from titan_tpu.utils.metrics import MetricManager
 
 PASSES = hbm.SIZING_PASSES
+IMAGES = ("out", "in", "cdlp")
 
 
 def passes(metrics) -> tuple:
-    """``(out, in)`` passes counted on ``metrics`` so far."""
+    """``(out, in, cdlp)`` passes counted on ``metrics`` so far."""
     return tuple(metrics.counter_value(PASSES, {"image": image})
-                 for image in ("out", "in"))
+                 for image in IMAGES)
 
 
 def columns_afresh(deg) -> int:
     """The formula admission computed a call: sum(ceil(deg/8)) + 1."""
     return int((-(-np.asarray(deg).astype("int64") // 8)).sum()) + 1
+
+
+def lanes_afresh(deg, n: int) -> int:
+    """Lanes of CDLP's row image, packed a vertex at a time: whole
+    vertices by decreasing column count, next-fit for those of two
+    columns and more, the one-column vertices into what is free and then
+    into rows of their own; a class a whole number of 1,024-lane blocks
+    (the toys' keys have bits to spare: one word a lane)."""
+    cols = sorted((int(-(-d // 8)) for d in deg if d), reverse=True)
+    bits = 32 - (n + 1).bit_length()
+    small = min(1024, 1 << bits)
+    wide = max(small, 1 << max(cols[0] - 1, 0).bit_length()) if cols \
+        else small
+    assert wide // small <= 1 << bits
+    lanes = 0
+    for width, mine, least in (
+            (small, [c for c in cols if c <= small], 1),
+            (wide, [c for c in cols if c > small], 0)):
+        free = []
+        for c in mine:
+            if c > 1:
+                if not free or free[-1] < c:
+                    free.append(width)
+                free[-1] -= c
+        left = max(mine.count(1) - sum(free), 0)
+        rows = max(len(free) + -(-left // width), least)
+        whole = max(1, 1024 // (8 * width))
+        lanes += -(-rows // whole) * whole * 8 * width
+    return lanes
 
 
 def bytes_afresh(snap, num_devices: int = 8) -> dict:
@@ -46,13 +76,15 @@ def bytes_afresh(snap, num_devices: int = 8) -> dict:
     q_out = columns_afresh(snap.out_degree)
     q_rev = columns_afresh(np.diff(snap.indptr_in))
     q_pull = pp.pull_columns(snap.indptr_in, n)
+    lanes = lanes_afresh(np.diff(snap.indptr_in), n)
     csr = q_out * 8 * 4 + 3 * 4 * (n + 1)
     vert = 3 * 4 * (n + 1)
     return {
         "csr": csr,
         "rev": q_rev * 8 * 4 + 3 * 4 * (n + 1),
         "pull": pp.pull_image_bytes(n, q_pull),
-        "cdlp": C.work_bytes(n, q_pull),
+        "cdlp_image": lanes * 8 + 5 * n,
+        "cdlp": C.work_bytes(n, lanes),
         "meshed": int(vert + -(-(csr - vert) // num_devices)),
     }
 
@@ -62,6 +94,7 @@ def bytes_kept(snap, num_devices: int = 8) -> dict:
         "csr": hbm.snapshot_csr_bytes(snap),
         "rev": hbm.snapshot_rev_csr_bytes(snap),
         "pull": hbm.snapshot_pull_bytes(snap),
+        "cdlp_image": hbm.snapshot_cdlp_image_bytes(snap),
         "cdlp": hbm.snapshot_cdlp_bytes(snap),
         "meshed": hbm.meshed_snapshot_csr_bytes(snap, num_devices),
     }
@@ -107,23 +140,30 @@ def test_kept_counts_equal_the_formulas_and_the_images(seed, directed,
     if built_first:
         # an image already built is asked for its own count: no pass
         g, rev = build_chunked_csr(snap), reversed_chunked_csr(snap)
-        assert hbm.price(snap, ("out", "in"), metrics) == 0
-        assert passes(metrics) == (0, 0)
+        rows = C.cdlp_image(snap)
+        assert hbm.price(snap, IMAGES, metrics) == 0
+        assert passes(metrics) == (0, 0, 0)
     else:
         # admission prices BEFORE any build: a pass an image, once
-        assert hbm.price(snap, ("out", "in"), metrics) == 2
+        assert hbm.price(snap, IMAGES, metrics) == 3
         assert not hasattr(snap, "_hybrid_csr")
-        assert passes(metrics) == (1, 1)
+        assert not hasattr(snap, "_cdlp_csr")
+        assert passes(metrics) == (1, 1, 1)
         g, rev = build_chunked_csr(snap), reversed_chunked_csr(snap)
+        rows = C.cdlp_image(snap)
     assert snap._q_out == columns_afresh(snap.out_degree) == g["q_total"]
     assert snap._q_in == columns_afresh(deg_in) == rev["q_total"]
+    assert snap._cdlp_lanes == lanes_afresh(deg_in, n) == rows["lanes"] \
+        == rows["idx"].shape[0] == rows["key_hi"].shape[0]
+    # the plan the pricing made is the build's, and goes with it
+    assert not hasattr(snap, "_cdlp_plan")
     assert pp.pull_image(snap)["q_in"] \
         == pp.pull_columns(snap.indptr_in, n) \
         == hbm._pull_columns(snap)
     assert bytes_kept(snap) == want
     # priced: no later call reads a degree array
-    assert hbm.price(snap, ("out", "in"), metrics) == 0
-    assert passes(metrics) == ((0, 0) if built_first else (1, 1))
+    assert hbm.price(snap, IMAGES, metrics) == 0
+    assert passes(metrics) == ((0, 0, 0) if built_first else (1, 1, 1))
 
 
 def test_a_pass_with_no_registry_counts_on_the_process_wide_one():
@@ -155,7 +195,7 @@ def test_threads_that_price_one_snapshot_at_once_agree():
 
             def go():
                 barrier.wait(30)
-                paid = hbm.price(snap, ("out", "in"), metrics)
+                paid = hbm.price(snap, IMAGES, metrics)
                 got.append((paid, bytes_kept(snap)))
 
             threads = [threading.Thread(target=go) for _ in range(16)]
@@ -166,8 +206,8 @@ def test_threads_that_price_one_snapshot_at_once_agree():
             assert not any(t.is_alive() for t in threads)
             assert [b for _paid, b in got] == [want] * 16
             paid = sum(p for p, _b in got)
-            assert 2 <= paid <= 32 and sum(passes(metrics)) == paid
-            assert hbm.price(snap, ("out", "in"), metrics) == 0
+            assert 3 <= paid <= 48 and sum(passes(metrics)) == paid
+            assert hbm.price(snap, IMAGES, metrics) == 0
     finally:
         sys.setswitchinterval(interval)
 
@@ -222,7 +262,7 @@ def _add_and_remove_vertices(g):
 def test_a_changed_snapshot_is_priced_again(graph, road, directed):
     snap = snap_mod.build(graph, directed=directed)
     metrics = MetricManager()
-    assert hbm.price(snap, ("out", "in"), metrics) == 2
+    assert hbm.price(snap, IMAGES, metrics) == 3
     before = bytes_kept(snap)
     assert before == bytes_afresh(snap)
     if road == "edge-adds":
@@ -239,25 +279,26 @@ def test_a_changed_snapshot_is_priced_again(graph, road, directed):
     else:
         _add_edges(graph)
         snap.rebuild_in_place()
-    assert not hasattr(snap, "_q_out") and not hasattr(snap, "_q_in")
+    assert not any(hasattr(snap, kept) for kept in
+                   ("_q_out", "_q_in", "_cdlp_lanes", "_cdlp_plan"))
     fresh = snap_mod.build(graph, directed=directed)
-    assert hbm.price(snap, ("out", "in"), metrics) == 2
-    assert passes(metrics) == (2, 2)
+    assert hbm.price(snap, IMAGES, metrics) == 3
+    assert passes(metrics) == (2, 2, 2)
     assert bytes_kept(snap) == bytes_afresh(fresh) == bytes_kept(fresh)
     assert bytes_kept(snap) != before
-    assert hbm.price(snap, ("out", "in"), metrics) == 0
+    assert hbm.price(snap, IMAGES, metrics) == 0
 
 
 def test_apply_changes_with_nothing_to_apply_keeps_the_price(graph):
     """A property mutation changes no degree: the counts stay."""
     snap = snap_mod.build(graph)
     metrics = MetricManager()
-    hbm.price(snap, ("out", "in"), metrics)
+    hbm.price(snap, IMAGES, metrics)
     tx = graph.new_transaction()
     _named(tx)[2].property("name", "v02")
     tx.commit()
     snap.refresh()
-    assert hbm.price(snap, ("out", "in"), metrics) == 0
+    assert hbm.price(snap, IMAGES, metrics) == 0
     assert bytes_kept(snap) == bytes_afresh(snap)
 
 
@@ -289,23 +330,25 @@ def test_jobs_on_a_priced_snapshot_pay_no_pass(graph):
                              ("cdlp", {"iterations": 2})):
             attrs = _run(sched, kind, **params)
             # the snapshot's first job of all prices the forward image,
-            # its first job that pulls the reversed one; nobody else
-            first = {"wcc": 1, "pagerank": 1}.get(kind, 0) \
-                if kind not in seen else 0
+            # its first job that pulls the reversed one, its first cdlp
+            # job the rows of its own image; nobody else
+            first = 0 if kind in seen else 1
             assert attrs["sizing_passes"] == first, (kind, attrs)
             assert attrs["bytes"] == seen.setdefault(kind, attrs["bytes"])
-        assert passes(metrics) == (1, 1)
+        assert passes(metrics) == (1, 1, 1)
         # a mutation between two jobs: the pool refreshes the snapshot
         # in place, the next job pays exactly what it needs
         _add_edges(graph)
         attrs = _run(sched, "wcc")
-        assert attrs["sizing_passes"] == 1 and passes(metrics) == (2, 1)
+        assert attrs["sizing_passes"] == 1 and passes(metrics) == (2, 1, 1)
         assert attrs["bytes"] != seen["wcc"]
         assert _run(sched, "wcc")["sizing_passes"] == 0
+        # a cdlp job does not pull: the reversed count is pagerank's to pay
         attrs = _run(sched, "cdlp", iterations=2)
-        assert attrs["sizing_passes"] == 1 and passes(metrics) == (2, 2)
-        assert _run(sched, "pagerank", iterations=2)["sizing_passes"] == 0
-        assert passes(metrics) == (2, 2)
+        assert attrs["sizing_passes"] == 1 and passes(metrics) == (2, 1, 2)
+        assert _run(sched, "pagerank", iterations=2)["sizing_passes"] == 1
+        assert _run(sched, "cdlp", iterations=2)["sizing_passes"] == 0
+        assert passes(metrics) == (2, 2, 2)
     finally:
         sched.close()
 
@@ -319,8 +362,9 @@ def test_job_bytes_are_the_sums_of_the_byte_functions():
         assert _run(sched, "wcc")["bytes"] == want["csr"]
         assert _run(sched, "pagerank", iterations=2)["bytes"] \
             == want["csr"] + want["pull"]
+        # its own image and the rounds' working set, no pull image
         assert _run(sched, "cdlp", iterations=2)["bytes"] \
-            == want["csr"] + want["pull"] + want["cdlp"]
+            == want["csr"] + want["cdlp_image"] + want["cdlp"]
     finally:
         sched.close()
 
@@ -351,20 +395,20 @@ def test_lane_batches_on_a_priced_snapshot_pay_no_pass(graph):
         # both() leases the symmetrized snapshot, out() and in() the
         # directed one: two snapshots, each priced an image at a time
         first = traverse("both")
-        assert first["sizing_passes"] == 1 and passes(metrics) == (1, 0)
+        assert first["sizing_passes"] == 1 and passes(metrics) == (1, 0, 0)
         again = traverse("both")
         assert again["sizing_passes"] == 0
         assert again["nbytes"] == first["nbytes"]
         out = traverse("out")
-        assert out["sizing_passes"] == 1 and passes(metrics) == (1, 1)
+        assert out["sizing_passes"] == 1 and passes(metrics) == (1, 1, 0)
         assert traverse("out") == dict(out, sizing_passes=0)
         into = traverse("in")
-        assert into["sizing_passes"] == 1 and passes(metrics) == (2, 1)
+        assert into["sizing_passes"] == 1 and passes(metrics) == (2, 1, 0)
         assert traverse("in")["sizing_passes"] == 0
         # ppr reads the symmetrized snapshot's forward image: priced
         res = lane.submit(PPRPlan(source=ids[1], iterations=3, top_k=3))
         assert res["iterations"] == 3
-        assert passes(metrics) == (2, 1)
+        assert passes(metrics) == (2, 1, 0)
     finally:
         sched.close()
 
@@ -380,6 +424,6 @@ def test_the_lanes_first_ppr_batch_prices_its_snapshot(graph):
         tx.rollback()
         for _ in range(2):
             lane.submit(PPRPlan(source=ids[1], iterations=3, top_k=3))
-            assert passes(metrics) == (1, 0)
+            assert passes(metrics) == (1, 0, 0)
     finally:
         sched.close()

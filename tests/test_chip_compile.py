@@ -211,34 +211,46 @@ def test_pagerank_pull_at_graph500_22(spec):
 
 
 def test_cdlp_round_at_graph500_22(spec):
-    """A round of the served CDLP job (ISSUE 40) over the same image:
-    the Pallas gather a lane at a time (rows = 1) with the labels as a
-    float32 table in VMEM, the sort of 139.6 M (owner, label) pairs, the
-    vote. What the rounds keep on the device at once is what admission
-    reserves for them (``models/cdlp.work_bytes``)."""
+    """A round of the served CDLP job (ISSUE 40; the row image: ISSUE
+    44) at graph500-22's shapes (CPU counts, PR 44: 14,420 small rows of
+    1,024 columns and 85 wide rows of 32,768; 140,410,880 lanes): the
+    Pallas gather a lane at a time (rows = 1) with the labels as a
+    float32 table in VMEM, the sort along the rows of ONE uint32 word a
+    lane (10 bits of owner above 22 of label), the vote. What the
+    rounds keep on the device at once is what admission reserves for
+    them (``models/cdlp.work_bytes``)."""
     from titan_tpu.models import cdlp as C
-    from titan_tpu.ops.vmem_gather import padded_columns
 
-    q_in = padded_columns(Q22)
-    lanes = spec((8 * q_in,), jnp.int32)
+    classes = ((14_420, 1024), (85, 32_768))
+    wide = sum(r * 8 * w for r, w in classes)
+    lanes = spec((wide,), jnp.int32)
     labels = spec((N22,), jnp.int32)
     gather = _compile(C._gather(), labels, lanes, impl="vmem", n_=N22)
     assert "tpu_custom_call" in gather.as_text()
-    sort = _compile(C._sort(), spec((q_in,), jnp.bool_), lanes)
-    # two operands: an unstable sort carries no positions beside them
-    assert sort.memory_analysis().output_size_in_bytes \
-        // (4 * 8 * q_in) == 2
+    statics = C.sort_statics({"classes": classes, "keys": 1,
+                              "label_bits": 22, "pad_share": 0.0862})
+    sort = _compile(C._sort(), spec((wide,), jnp.uint32), lanes, **statics)
+    text = sort.as_text()
+    # one operand a sort, a sort a class, and the word split again by
+    # arithmetic: no gather
+    assert text.count(" sort(") == 2 and " gather(" not in text
+    assert sort.memory_analysis().output_size_in_bytes // (4 * wide) == 2
     vote = _compile(C._vote(), lanes, lanes, labels, labels,
-                    spec((N22,), jnp.bool_), seg_max=20_413, n_=N22)
+                    spec((N22,), jnp.bool_), max_len=8 * 32_768, n_=N22)
     # the widest program: the sorted pair and its temporaries, with the
     # gathered lanes that may outlive the sort, inside what is reserved
-    for program in (gather, sort, vote):
+    held = {}
+    for name, program in (("gather", gather), ("sort", sort),
+                          ("vote", vote)):
         m = program.memory_analysis()
-        held = m.argument_size_in_bytes + m.output_size_in_bytes \
+        held[name] = m.argument_size_in_bytes + m.output_size_in_bytes \
             + m.temp_size_in_bytes
-        assert held + 4 * 8 * q_in <= C.work_bytes(N22, q_in) + 4 * 8 * q_in
+    print({k: round(v / (4 * wide), 2) for k, v in held.items()})
+    # the image's half of the sort's arguments is not the rounds' to hold
+    assert held["sort"] - 4 * wide + 4 * wide <= C.work_bytes(N22, wide)
+    assert held["vote"] + 4 * wide <= C.work_bytes(N22, wide) + 4 * wide
     m = vote.memory_analysis()
-    assert m.temp_size_in_bytes < 6 * 4 * 8 * q_in
+    assert m.temp_size_in_bytes < 6 * 4 * wide
 
 
 def test_lcc_programs_at_graph500_22(spec):

@@ -1,14 +1,18 @@
-"""CDLP (ISSUE 40): LDBC Graphalytics' community detection by label
-propagation as a served job, on the CPU. The program (``models/cdlp.py``
-over PageRank's pull image, the vote of ``ops/segment.py``) against the
+"""CDLP (ISSUE 40; the row image: ISSUE 44): LDBC Graphalytics'
+community detection by label propagation as a served job, on the CPU.
+The program (``models/cdlp.py`` over a lane image of its own, packed in
+rows that hold whole vertices and sorted a row at a time; the vote of
+``ops/segment.py``) against the
 benchmark's plain reference (``benchmark/reference/cdlp.py``: the
 specification's equations in numpy, nothing of ``titan_tpu`` in it) AND
 against a ``collections.Counter`` count a vertex at a time, so that the
 reference is itself checked: every label, exactly. Shapes worked by hand
 (a tie, a vertex of degree 1, a star, two cliques joined by an edge, a
 vertex whose every neighbour carries another label, a pair that flips
-every round), seeded random graphs with a hub that straddles blocks, 0, 1
-and 10 rounds; the Pallas gather in Pallas's interpreter; then the served
+every round), seeded random graphs with a hub that takes the wide class,
+0, 1 and 10 rounds; the row image's invariants and the sort's two forms
+under forced widths; the Pallas gather in Pallas's interpreter; then the
+served
 path: ``POST /jobs`` -> result plane, spans and counters, timeout and
 cancel at a round's boundary, resume from a checkpoint bit-equal,
 admission of the rounds' working set.
@@ -26,9 +30,9 @@ import numpy as np
 import pytest
 
 from titan_tpu.models import cdlp as C
-from titan_tpu.models import pagerank_pull as pp
 from titan_tpu.olap.api import JobSpec
 from titan_tpu.olap.serving.hbm import (snapshot_cdlp_bytes,
+                                        snapshot_cdlp_image_bytes,
                                         snapshot_csr_bytes,
                                         snapshot_pull_bytes)
 from titan_tpu.olap.serving.scheduler import JobScheduler
@@ -169,22 +173,181 @@ def test_program_reference_and_counter_agree(reference, graph, rounds):
         assert np.array_equal(got[~has], np.flatnonzero(~has))
 
 
-def test_the_hub_straddles_blocks_and_pad_lanes_do_not_vote(graph):
+def test_the_hub_takes_the_wide_class_and_pad_lanes_do_not_vote(graph):
     name, n, _src, _dst, snap = graph
-    im = pp.pull_image(snap)
-    assert im["q_in"] % vg.BLOCK == 0
-    idx = np.asarray(im["idx"]).reshape(8, -1)
+    im = C.cdlp_image(snap)
+    (rows, small), (wrows, wide) = im["classes"]
+    assert small == C.SMALL_MAX and im["keys"] == 1
+    assert im["lanes"] == 8 * (rows * small + wrows * wide)
+    assert (rows * 8 * small) % vg.BLOCK == 0 == (wrows * 8 * wide) % vg.BLOCK
+    idx = np.asarray(im["idx"])
     pads = int((idx == n + 1).sum())
     assert pads > 0 and pads == idx.size - len(snap.src)
+    assert im["pad_share"] == pytest.approx(pads / idx.size)
     if name == "hub":
-        assert im["seg_max"] > vg.BLOCK     # one vertex, several blocks
-        assert im["q_in"] >= 3 * vg.BLOCK
+        # one vertex outgrows a small row: a wide row of its own class,
+        # the next power of two above its columns, several blocks long
+        assert wrows == 1 and wide == 2048 > small
+        assert 8 * wide >= 3 * vg.BLOCK
+    else:
+        assert wrows == 0 and wide == small
     # the pad's label is its own id, above every vertex's: it sorts
     # behind a vertex's labels and its run does not count
     lanes = np.asarray(C._gather()(np.arange(n, dtype=np.int32),
                                    im["idx"], impl="xla", n_=n))
-    assert (lanes.reshape(8, -1)[idx == n + 1] == n + 1).all()
-    assert (lanes.reshape(8, -1)[idx <= n] < n).all()
+    assert (lanes[idx == n + 1] == n + 1).all()
+    assert (lanes[idx <= n] < n).all()
+
+
+# -- the row image -------------------------------------------------------------
+
+def ring(k: int):
+    """k vertices of degree 2: one column each."""
+    a = np.arange(k, dtype=np.int32)
+    return both_ways(k, list(zip(a.tolist(), np.roll(a, -1).tolist())))
+
+
+#: name -> (graph, SMALL_MAX, KEY_BITS), None: as the module has it. A
+#: forced small row makes rows many and the wide class populous on a
+#: graph a test can count; a forced key width makes the bits run out
+FORMS = {
+    # the hub alone in the wide class, a thousand vertices a small row
+    "as_it_is": (lambda: random_graph(43, 9000, 12000, 8800), None, None),
+    # rows of 16 columns: most of them full, a wide class of 2,048
+    # columns with every vertex above 16 columns in it: 10 bits of owner
+    # in the small rows would be 4, in the wide 7
+    "rows_of_16": (lambda: random_graph(52, 3000, 40000, 2500), 16, None),
+    # a small row exactly full of one-column vertices: no owner number
+    # is free for a pad of its own, at either width
+    "full_of_units": (lambda: ring(2 * 1024), None, None),
+    "full_of_units_16": (lambda: ring(64), 16, None),
+    # 2^23 vertices: 24 bits of label leave 8 of owner, a small row of
+    # 256 columns; the hub's 2,048 columns are 8 small rows
+    "small_row_256": (lambda: random_graph(53, 1 << 23, 30000, 9000),
+                      None, None),
+    # the bits do not fit: a key of 14 bits holds the label alone, the
+    # pair (owner, label) is sorted, rows of SMALL_MAX
+    "two_keys": (lambda: random_graph(43, 9000, 12000, 8800), None, 14),
+    "two_keys_rows_of_16": (lambda: random_graph(52, 3000, 40000, 2500),
+                            16, 14),
+}
+
+
+@pytest.fixture(params=sorted(FORMS))
+def form(request, monkeypatch):
+    make, small_max, key_bits = FORMS[request.param]
+    if small_max is not None:
+        monkeypatch.setattr(C, "SMALL_MAX", small_max)
+    if key_bits is not None:
+        monkeypatch.setattr(C, "KEY_BITS", key_bits)
+    n, src, dst = make()
+    return request.param, n, src, dst, snap_mod.from_arrays(n, src, dst)
+
+
+def row_of(im, lane):
+    """(row counted over both classes, its first lane) of a lane."""
+    (rows, small), (_wrows, wide) = im["classes"]
+    edge = rows * 8 * small
+    row = np.where(lane < edge, lane // (8 * small),
+                   rows + (lane - edge) // (8 * wide))
+    return row, np.where(lane < edge, lane // (8 * small) * (8 * small),
+                         edge + (lane - edge) // (8 * wide) * (8 * wide))
+
+
+def lanes_by_vertex(im):
+    """(the vertices in lane order, each one's first lane): a vertex's
+    lanes end at its ``last`` and begin behind the vertex before it, or
+    where its row begins."""
+    last, has = np.asarray(im["last_lane"]), np.asarray(im["has"])
+    mine = np.flatnonzero(has)
+    mine = mine[np.argsort(last[mine])]
+    behind = np.concatenate([[0], last[mine][:-1] + 1])
+    return mine, np.maximum(behind, row_of(im, last[mine])[1])
+
+
+def test_the_row_images_invariants(form):
+    name, n, _src, _dst, snap = form
+    im = C.cdlp_image(snap)
+    (rows, small), (wrows, wide) = im["classes"]
+    assert im["keys"] == (2 if name.startswith("two_keys") else 1)
+    assert small == (256 if name == "small_row_256"
+                     else FORMS[name][1] or 1024)
+    assert wrows > 0 or name.startswith("full_of_units")
+    idx, own = np.asarray(im["idx"]), np.asarray(im["key_hi"])
+    last, has = np.asarray(im["last_lane"]), np.asarray(im["has"])
+    assert idx.shape == own.shape == (im["lanes"],) and own.dtype == np.uint32
+    assert np.array_equal(has, np.diff(snap.indptr_in) > 0)
+    mine, first = lanes_by_vertex(im)
+    ends = last[mine] + 1
+    assert (first < ends).all() and (first[1:] >= ends[:-1]).all()
+    # a vertex of every lane, -1 in the rows that hold none
+    vertex = np.full(im["lanes"], -1)
+    spans = ends - first
+    inside = np.repeat(first - np.cumsum(spans) + spans, spans) \
+        + np.arange(spans.sum())
+    vertex[inside] = np.repeat(mine, spans)
+    # every in-edge slot in exactly one lane, under its own vertex; the
+    # other lanes are pads
+    real = idx != n + 1
+    assert (vertex[real] >= 0).all()
+    got = np.stack([vertex[real], idx[real]])
+    want = np.stack([snap.dst, snap.src]).astype(got.dtype)
+    assert np.array_equal(got[:, np.lexsort(got[::-1])],
+                          want[:, np.lexsort(want[::-1])])
+    # every row holds whole vertices and nothing of any other: a vertex's
+    # lanes are whole columns of one row, a row is its vertices' lanes
+    assert np.array_equal(row_of(im, first)[0], row_of(im, last[mine])[0])
+    assert (first % 8 == 0).all() and (ends % 8 == 0).all()
+    starts_row = row_of(im, first)[1] == first
+    ends_row = np.append(starts_row[1:], True)
+    assert (first[~starts_row] == ends[:-1][~starts_row[1:]]).all()
+    assert (row_of(im, ends[ends_row] - 1)[1]
+            + 8 * np.where(first[ends_row] < rows * 8 * small, small, wide)
+            == ends[ends_row]).all()
+    # the owner inside the row: one number a vertex, counted up along
+    # its row from 0, under the row's columns, inside the key's bits
+    shift = im["label_bits"] if im["keys"] == 1 else 0
+    assert im["label_bits"] == (n + 1).bit_length()
+    local = own >> shift
+    assert (own == local << shift).all()
+    assert np.array_equal(local[inside], np.repeat(local[first], spans))
+    assert (local[first][starts_row] == 0).all()
+    assert (np.diff(local[first])[~starts_row[1:]] == 1).all()
+    width = np.where(first < rows * 8 * small, small, wide)
+    assert (local[first] < width).all()
+    if im["keys"] == 1:
+        assert int(local.max()) < 1 << (C.KEY_BITS - im["label_bits"])
+        assert wide // small <= 1 << (C.KEY_BITS - im["label_bits"])
+    if name.startswith("full_of_units"):
+        # full rows, a vertex a column: the last owner number is taken
+        assert len(mine) % small == 0 and len(mine) >= 2 * small
+        assert int(local.max()) == small - 1
+        assert (spans == 8).all()
+    # the sort only groups: behind it a vertex's labels stand sorted in
+    # the lanes that were its own, pads last, so ``last`` is its last
+    labels = np.random.default_rng(9).permutation(n).astype(np.int32)
+    gathered = C._gather()(labels, im["idx"], impl="xla", n_=n)
+    owner, by_label = (np.asarray(a) for a in C._sort()(
+        im["key_hi"], gathered, **C.sort_statics(im)))
+    assert owner.dtype == by_label.dtype == np.int32
+    assert np.array_equal(owner[inside], np.repeat(owner[first], spans))
+    assert len(np.unique(owner[first])) == len(first)
+    nth = np.full(im["lanes"], -1)
+    nth[inside] = np.repeat(np.arange(len(mine)), spans)
+    seen = np.stack([nth[real], labels[idx[real]]])
+    seen = seen[:, np.lexsort(seen[::-1])]
+    voting = by_label != n + 1
+    assert np.array_equal(np.stack([nth[voting], by_label[voting]]), seen)
+
+
+@pytest.mark.parametrize("rounds", [1, 3])
+def test_labels_equal_the_reference_under_every_form(reference, form,
+                                                     rounds):
+    _name, n, src, dst, snap = form
+    want = reference.propagate(*structure(n, src, dst), rounds)
+    got, its = C.cdlp(snap, rounds)
+    assert its == rounds
+    assert reference.mislabelled(got, want) == 0
 
 
 def test_a_directed_graph_votes_over_its_in_edges(reference):
@@ -230,7 +393,7 @@ def test_the_kernel_gathers_the_same_lanes(graph, monkeypatch):
     _name, n, _src, _dst, snap = graph
     monkeypatch.setattr(vg, "colsum_vmem", functools.partial(
         vg.colsum_vmem, interpret=True))
-    im = pp.pull_image(snap)
+    im = C.cdlp_image(snap)
     labels = np.random.default_rng(5).permutation(n).astype(np.int32)
     lanes = {impl: np.asarray(C._gather()(labels, im["idx"], impl=impl,
                                           n_=n))
@@ -426,12 +589,27 @@ def test_the_jobs_spans_and_counters():
     assert all(s.parent_id in round_ids for s in kernels)
     assert {s.attrs.get("impl") for s in kernels
             if s.attrs["key"] == "cdlp_gather"} == {"xla"}
-    q_in = pp.pull_columns(snap_mod.from_arrays(n, src, dst).indptr_in, n)
+    # the sort's span says what it sorted: one small row of 1,024
+    # columns here, no wide one, one word a lane, and how much is pad
+    im = C.cdlp_image(snap_mod.from_arrays(n, src, dst))
+    assert im["classes"] == ((1, 1024), (0, 1024)) and im["lanes"] == 8192
+    sorts = [s.attrs for s in kernels if s.attrs["key"] == "cdlp_sort"]
+    assert {(a["rows"], a["width"], a["keys"], a["label_bits"],
+             a["pad_share"]) for a in sorts} \
+        == {("1+0", "1024+1024", 1, 10, f"{1 - len(src) / 8192:.4f}")}
     assert m.counter_value("device.cdlp.rounds") == 4
     assert m.counter("device.cdlp.lanes",
-                     labels={"impl": "xla"}).count == 4 * 8 * q_in
+                     labels={"impl": "xla"}).count == 4 * 8192
     assert m.counter("device.cdlp.lanes",
                      labels={"impl": "vmem"}).count == 0
+    by_form = {(c, k): m.counter("device.cdlp.sort_lanes", labels={
+        "class": c, "keys": k}).count
+        for c in ("small", "wide") for k in ("1", "2")}
+    assert by_form == {("small", "1"): 4 * 8192, ("wide", "1"): 0,
+                       ("small", "2"): 0, ("wide", "2"): 0}
+    assert m.counter("device.xfer.h2d_bytes",
+                     labels={"site": "cdlp.image"}).count \
+        == C.image_bytes(n, 8192) == 8192 * 8 + 5 * n
     assert m.counter("device.xfer.d2h_bytes",
                      labels={"site": "cdlp.result"}).count == 4 * n
     for key in ("cdlp_gather", "cdlp_sort", "cdlp_vote"):
@@ -508,10 +686,14 @@ def test_a_crashed_job_resumes_from_its_checkpoint_bit_equal(tmp_path):
 def test_admission_reserves_the_working_set_and_lets_it_go():
     n, src, dst = random_graph(50, 500, 1500)
     snap = snap_mod.from_arrays(n, src, dst)
-    images = snapshot_csr_bytes(snap) + snapshot_pull_bytes(snap)
+    # the forward image and the job's own: it reads no pull image
+    images = snapshot_csr_bytes(snap) + snapshot_cdlp_image_bytes(snap)
     work = snapshot_cdlp_bytes(snap)
-    q_in = pp.pull_columns(snap.indptr_in, n)
-    assert work == C.work_bytes(n, q_in) > 7 * (8 * q_in * 4)
+    lanes = C.image_lanes(snap)
+    assert lanes == C.cdlp_image(snap)["lanes"] == 8192
+    assert snapshot_cdlp_image_bytes(snap) == C.image_bytes(n, lanes) \
+        == 2 * 4 * lanes + 5 * n
+    assert work == C.work_bytes(n, lanes) > 7 * (lanes * 4)
     served = Served(n, src, dst)
     try:
         env = served.job({"kind": "cdlp", "iterations": 2})
@@ -526,7 +708,8 @@ def test_admission_reserves_the_working_set_and_lets_it_go():
     assert ledger.pinned_bytes() == 0
     # a budget that holds both images but not the rounds' working set
     # beside them refuses the job and leaves nothing pinned, where a
-    # PageRank job over the same images is admitted
+    # PageRank job, whose two images are smaller, is admitted
+    assert snapshot_pull_bytes(snap) < snapshot_cdlp_image_bytes(snap)
     served = Served(n, src, dst, hbm_budget_bytes=images + work - 1)
     try:
         env = served.job({"kind": "cdlp", "iterations": 2})
@@ -544,7 +727,7 @@ def test_a_second_tenants_image_leaves_no_room():
     admission, not run into the device's memory."""
     n, src, dst = random_graph(51, 500, 1500)
     snap = snap_mod.from_arrays(n, src, dst)
-    need = snapshot_csr_bytes(snap) + snapshot_pull_bytes(snap) \
+    need = snapshot_csr_bytes(snap) + snapshot_cdlp_image_bytes(snap) \
         + snapshot_cdlp_bytes(snap)
     served = Served(n, src, dst, hbm_budget_bytes=need + 1000)
     try:

@@ -459,15 +459,24 @@ def count_pr_gather(impl: str, lanes: int) -> None:
                              labels={"impl": impl}).inc(int(lanes))
 
 
-def count_cdlp_round(impl: str, lanes: int) -> None:
+def count_cdlp_round(impl: str, classes: tuple, keys: int) -> None:
     """Count one round of ``models/cdlp.cdlp`` (its gather, sort and
-    vote dispatched) and the lanes it gathered (8 x the pull image's
-    columns, pad lanes included) by what served them, ``"vmem"`` or
-    ``"xla"`` (ops/vmem_gather.gather_impl)."""
+    vote dispatched), the lanes it gathered (every lane of the row
+    image, pad lanes included) by what served them, ``"vmem"`` or
+    ``"xla"`` (ops/vmem_gather.gather_impl), and the lanes it sorted by
+    the class of their rows (``classes``: ``(rows, width)`` small, then
+    wide) and by the sort's operands (``keys``: 1 word a lane, or the
+    pair)."""
+    by_class = {name: rows * 8 * width
+                for name, (rows, width) in zip(("small", "wide"), classes)}
     for prof in list(_PROFILERS):
         prof.metrics.counter("device.cdlp.rounds").inc()
-        prof.metrics.counter("device.cdlp.lanes",
-                             labels={"impl": impl}).inc(int(lanes))
+        prof.metrics.counter("device.cdlp.lanes", labels={"impl": impl}) \
+            .inc(sum(by_class.values()))
+        for name, lanes in by_class.items():
+            prof.metrics.counter(
+                "device.cdlp.sort_lanes",
+                labels={"class": name, "keys": str(keys)}).inc(lanes)
 
 
 def count_lcc(part: str, edges: int, wedges: Optional[int] = None

@@ -6,9 +6,11 @@ the forward chunked CSR every kind reads (``id(snap)``; byte model:
 the transposed 8-aligned ``dstT`` [8, q_total] int32 plus three [n+1]
 int32 side arrays (colstart/degc/deg) —
 models/bfs_hybrid.build_chunked_csr's exact footprint), the in-edge
-pull image a ``pagerank`` or ``cdlp`` job reads beside it
+pull image a ``pagerank`` or ``lcc`` job reads beside it
 (``("pagerank-pull", id(snap))``, models/pagerank_pull.pull_image), a
-``cdlp`` run's working set (``("cdlp-work", id(snap))``, reserved for
+``cdlp`` job's row image (``("cdlp-image", id(snap))``,
+models/cdlp.cdlp_image: resident and evictable like the others) and its
+run's working set (``("cdlp-work", id(snap))``, reserved for
 the run and released behind it, models/cdlp.work_bytes), an ``lcc``
 job's hub bit table with what is built beside it (``("lcc-image",
 id(snap))``, models/lcc.lcc_image: resident and evictable like the
@@ -19,11 +21,15 @@ the interactive lane's reversed layout for ``out()``
 unpinned entries; pinned entries (graphs under a running batch) are
 never evicted.
 
-Every size derives from ``n`` and two column counts, sum(ceil(deg/8))
-+ 1 over the out-degrees (``"out"``) and over the in-degrees
-(``"in"``). Each is one pass over a degree array, paid once a
-snapshot: ``_columns`` keeps it ON the snapshot (``_q_out``,
-``_q_in``), and ``GraphSnapshot._invalidate_layout_caches`` drops it
+Every size derives from ``n`` and three counts: two of columns,
+sum(ceil(deg/8)) + 1 over the out-degrees (``"out"``) and over the
+in-degrees (``"in"``), and the lanes of CDLP's row image (``"cdlp"``:
+its rows hold whole vertices, so how full they pack follows from the
+in-degrees one by one and from no sum over them:
+models/cdlp.row_plan). Each is one pass over a degree array, paid once
+a snapshot: ``_columns`` keeps it ON the snapshot (``_q_out``,
+``_q_in``, ``_cdlp_lanes``), and
+``GraphSnapshot._invalidate_layout_caches`` drops it
 with the layouts it sizes, so a refreshed or mutated snapshot is
 re-priced before its next reservation. ``price`` tells an admission how
 many passes it paid (0 on a priced snapshot).
@@ -41,36 +47,43 @@ import numpy as np
 DEFAULT_BUDGET_BYTES = 12.0e9
 
 #: counter of passes over a degree array that pricing ran, by
-#: ``{image="out"|"in"}``
+#: ``{image="out"|"in"|"cdlp"}``
 SIZING_PASSES = "serving.hbm.sizing_passes"
 
-#: image -> (the attribute its column count is kept under, the built
-#: layout that carries the same count as ``q_total``)
-_KEPT = {"out": ("_q_out", "_hybrid_csr"),
-         "in": ("_q_in", "_hybrid_csr_rev")}
+#: image -> (the attribute its count is kept under, the built layout
+#: that carries the same count, and under what name)
+_KEPT = {"out": ("_q_out", "_hybrid_csr", "q_total"),
+         "in": ("_q_in", "_hybrid_csr_rev", "q_total"),
+         "cdlp": ("_cdlp_lanes", "_cdlp_csr", "lanes")}
 
 
 def _columns(snap, image: str, metrics=None) -> tuple:
-    """``(columns, passes paid)`` of a snapshot's forward (``"out"``) or
-    reversed (``"in"``) chunked layout, computable BEFORE any build
-    (admission must not pay the upload to learn it doesn't fit):
-    sum(ceil(deg/8)) + 1 pad column. The one place that reads a degree
+    """``(count, passes paid)`` of a snapshot's forward (``"out"``) or
+    reversed (``"in"``) chunked layout, in columns (sum(ceil(deg/8)) +
+    1 pad column), or of its CDLP row image (``"cdlp"``), in lanes
+    (models/cdlp.image_lanes: the packing planned, nothing built),
+    computable BEFORE any build (admission must not pay the upload to
+    learn it doesn't fit). The one place that reads a degree
     array: once a snapshot, then the kept integer; a layout already
     built is asked for its own count instead. A pass is counted on
     ``metrics`` (the process-wide registry without one). Two threads
     that price one snapshot at once may each run the pass: both count
     it, and both keep the same integer."""
-    attr, built = _KEPT[image]
+    attr, built, name = _KEPT[image]
     q = getattr(snap, attr, None)
     if q is not None:
         return q, 0
     layout = getattr(snap, built, None)
     if layout is not None:
-        q, paid = int(layout["q_total"]), 0
+        q, paid = int(layout[name]), 0
     else:
-        deg = snap.out_degree if image == "out" \
-            else np.diff(snap.indptr_in[:snap.n + 1])
-        q = int((-(-deg.astype(np.int64) // 8)).sum()) + 1
+        if image == "cdlp":
+            from titan_tpu.models.cdlp import image_lanes
+            q = image_lanes(snap)
+        else:
+            deg = snap.out_degree if image == "out" \
+                else np.diff(snap.indptr_in[:snap.n + 1])
+            q = int((-(-deg.astype(np.int64) // 8)).sum()) + 1
         paid = 1
         if metrics is None:
             from titan_tpu.utils.metrics import MetricManager
@@ -81,9 +94,9 @@ def _columns(snap, image: str, metrics=None) -> tuple:
 
 
 def price(snap, images, metrics=None) -> int:
-    """Make sure the column counts of ``images`` (``"out"``, ``"in"``)
-    are kept on ``snap``, so that the byte functions below read two
-    integers: returns the passes over a degree array this admission
+    """Make sure the counts of ``images`` (``"out"``, ``"in"``,
+    ``"cdlp"``) are kept on ``snap``, so that the byte functions below
+    read integers: returns the passes over a degree array this admission
     paid for it, 0 on a priced snapshot."""
     return sum(_columns(snap, image, metrics)[1] for image in images)
 
@@ -125,13 +138,23 @@ def snapshot_pull_bytes(snap) -> int:
     return pull_image_bytes(snap.n, _pull_columns(snap))
 
 
+def snapshot_cdlp_image_bytes(snap) -> int:
+    """Predicted device bytes of a snapshot's CDLP row image
+    (models/cdlp.cdlp_image: a neighbour id and the key's owner part a
+    lane, a few bytes a vertex), from the kept ``"cdlp"`` lane count,
+    BEFORE the build. A ``cdlp`` job reserves it beside the forward
+    image; it reads no pull image."""
+    from titan_tpu.models.cdlp import image_bytes
+    return image_bytes(snap.n, _columns(snap, "cdlp")[0])
+
+
 def snapshot_cdlp_bytes(snap) -> int:
-    """Predicted device bytes a ``cdlp`` job's rounds work on beside the
-    pull image (models/cdlp.work_bytes: the gathered labels, the sort's
-    operands and the vote's temporaries, each as wide as the image's
-    lanes), sized from the in-degrees like the image itself."""
+    """Predicted device bytes a ``cdlp`` job's rounds work on beside its
+    image (models/cdlp.work_bytes: the gathered labels, the sort's
+    operand and what it is split into, the vote's temporaries, each as
+    wide as the image's lanes), from the same lane count."""
     from titan_tpu.models.cdlp import work_bytes
-    return work_bytes(snap.n, _pull_columns(snap))
+    return work_bytes(snap.n, _columns(snap, "cdlp")[0])
 
 
 def snapshot_lcc_bytes(snap) -> int:

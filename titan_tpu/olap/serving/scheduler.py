@@ -104,6 +104,7 @@ from titan_tpu.olap.serving.batcher import (Batcher, batch_key,
 from titan_tpu.olap.serving.hbm import (DEFAULT_BUDGET_BYTES,
                                         AdmissionError, HBMLedger, price,
                                         snapshot_cdlp_bytes,
+                                        snapshot_cdlp_image_bytes,
                                         snapshot_csr_bytes,
                                         snapshot_lcc_bytes,
                                         snapshot_lcc_work_bytes,
@@ -120,14 +121,20 @@ from titan_tpu.utils.metrics import MetricManager
 _SNAPSHOT_KINDS = ("bfs", "sssp", "pagerank", "wcc", "cdlp", "lcc",
                    "dense")
 
-#: kinds that read the in-edge pull image of models/pagerank_pull (no
-#: overlay seam: leased compacted)
-_PULL_KINDS = ("pagerank", "cdlp", "lcc")
+#: kinds that read the in-edge pull image of models/pagerank_pull
+_PULL_KINDS = ("pagerank", "lcc")
+
+#: kinds whose image has no overlay seam: leased compacted
+_COMPACTED_KINDS = _PULL_KINDS + ("cdlp", "dense")
 
 #: kind -> (ledger key, bytes from the snapshot, the snapshot attribute
-#: an eviction drops) of an image the kind keeps resident beside the
-#: pull image
-_KIND_IMAGE = {"lcc": ("lcc-image", snapshot_lcc_bytes, "_lcc_csr")}
+#: an eviction drops, the count hbm.price keeps for it) of an image of
+#: the kind's own, resident beside the forward image (and the pull
+#: image, where the kind pulls)
+_KIND_IMAGE = {
+    "lcc": ("lcc-image", snapshot_lcc_bytes, "_lcc_csr", "in"),
+    "cdlp": ("cdlp-image", snapshot_cdlp_image_bytes, "_cdlp_csr",
+             "cdlp")}
 
 #: kind -> (ledger key, bytes from the snapshot) of the working set a
 #: run of the kind holds and releases
@@ -430,8 +437,8 @@ class JobScheduler:
         key = id(snap)
         self._evictable.pop(key, None)
         self.ledger.release(key)
-        for rider in (("interactive-rev", key), ("pagerank-pull", key),
-                      ("lcc-image", key)):
+        for rider in [("interactive-rev", key), ("pagerank-pull", key)] \
+                + [(image[0], key) for image in _KIND_IMAGE.values()]:
             self._evictable.pop(rider, None)
             self.ledger.release(rider)
 
@@ -1026,8 +1033,9 @@ class JobScheduler:
         # the device's idle gap there a name (obs/tracing)
         with job_phase(head, "job.lease"):
             try:
-                # dense window sweeps (pagerank / DenseProgram) and the
-                # pull image (pagerank, cdlp, lcc) have no overlay seam: the
+                # dense window sweeps (pagerank / DenseProgram), the pull
+                # image (pagerank, lcc) and cdlp's row image have no
+                # overlay seam: the
                 # live pool folds the overlay into the base BEFORE
                 # leasing for these kinds (the documented
                 # compact-before-run fallback, models/frontier.py)
@@ -1035,7 +1043,7 @@ class JobScheduler:
                                           edge_keys=edge_keys,
                                           directed=spec.directed,
                                           compacted=spec.kind in
-                                          _PULL_KINDS + ("dense",))
+                                          _COMPACTED_KINDS)
             except Exception as e:
                 for job in group:
                     job.fail(f"snapshot: {type(e).__name__}: {e}")
@@ -1048,12 +1056,14 @@ class JobScheduler:
                 job.ran_epoch = epoch_info
             with job_phase(head, "job.admit") as admit:
                 ledger_key = id(snap)
-                # every size below reads the column counts kept on the
+                # every size below reads the counts kept on the
                 # snapshot; a snapshot's first admission pays the pass
                 # over a degree array that each count takes, here
                 pulls = spec.kind in _PULL_KINDS
-                passes = price(snap, ("out", "in") if pulls else ("out",),
-                               self._metrics)
+                own = _KIND_IMAGE.get(spec.kind)
+                passes = price(
+                    snap, ["out"] + ["in"] * pulls
+                    + ([own[3]] if own else []), self._metrics)
                 # mesh-placed cohorts charge the PER-DEVICE share (the
                 # edge image shards over the mesh — hbm.meshed_snapshot_
                 # csr_bytes); only batched BFS runs meshed (single-run
@@ -1072,14 +1082,15 @@ class JobScheduler:
                         snap, int(self.mesh.devices.size))
                 else:
                     nbytes = snapshot_csr_bytes(snap)
-                # a `pagerank`, `cdlp` or `lcc` job reads a second image:
+                # a `pagerank` or `lcc` job reads a second image:
                 # the in-edge pull image of models/pagerank_pull, under a
                 # key of its own so that a snapshot already resident for
                 # other kinds is not taken to hold it. An `lcc` job keeps a
-                # third beside it, its hub bit table (_KIND_IMAGE), resident
+                # third beside it, its hub bit table, and a `cdlp` job,
+                # which does not pull, its row image (_KIND_IMAGE), resident
                 # and evictable like the others. A `cdlp` or `lcc` job
                 # besides works on more than it keeps (_KIND_WORK: the
-                # sort's operands and the vote's temporaries; the tiles'
+                # sort's operand and the vote's temporaries; the tiles'
                 # gathered rows): reserved for the run under a key with
                 # nothing to evict, and released, not left resident,
                 # behind it
@@ -1088,8 +1099,8 @@ class JobScheduler:
                     images.append((("pagerank-pull", ledger_key),
                                    snapshot_pull_bytes(snap),
                                    (snap, "_pull_csr")))
-                if spec.kind in _KIND_IMAGE:
-                    name, sized, attr = _KIND_IMAGE[spec.kind]
+                if own:
+                    name, sized, attr, _count = own
                     images.append(((name, ledger_key), sized(snap),
                                    (snap, attr)))
                 work_key = None

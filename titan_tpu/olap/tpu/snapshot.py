@@ -339,13 +339,14 @@ class GraphSnapshot:
         """Drop every derived layout / device-array cache the model
         kernels lazily attach (they rebuild from the refreshed arrays),
         and with them the column counts admission prices those layouts
-        by (``_q_out``, ``_q_in``: olap/serving/hbm keeps them here).
+        by (``_q_out``, ``_q_in``, ``_cdlp_lanes``: olap/serving/hbm keeps
+        them here).
         The dense vertex-property columns are NOT cleared here — they
         stay aligned across edge-only merges; apply_changes clears them
         on property mutations (by key) and vertex-set changes (all)."""
         for attr in ("_out_csr", "_out_csr_order", "_hybrid_csr",
-                     "_hybrid_csr_rev", "_pull_csr", "_lcc_csr", "_q_out",
-                     "_q_in",
+                     "_hybrid_csr_rev", "_pull_csr", "_lcc_csr", "_cdlp_csr",
+                     "_cdlp_plan", "_q_out", "_q_in", "_cdlp_lanes",
                      "_frontier_shards",
                      "_dev_frontier_sh", "_tiled_shards", "_dev_outdeg",
                      "_dev_frontier"):
