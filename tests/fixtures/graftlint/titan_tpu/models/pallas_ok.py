@@ -2,7 +2,7 @@
 runs on keyword-only compile-time constants. ``pallas_call`` passes
 only the refs, positionally, so the seam must classify kwonly params
 (bound through ``functools.partial``) as static — the ``while d <
-block`` ladder idiom of ops/pallas_segment.py. Never imported."""
+block`` unroll ladder idiom. Never imported."""
 
 import functools
 

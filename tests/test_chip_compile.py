@@ -79,15 +79,6 @@ def test_sorted_segment_combine(spec, combine, dtype):
              spec((N,), jnp.int32), spec((N,), jnp.bool_))
 
 
-def test_pallas_seg_scan(spec):
-    from titan_tpu.ops.pallas_segment import pallas_seg_scan
-
-    text = _compile(functools.partial(pallas_seg_scan, combine="sum"),
-                    spec((E,), jnp.float32),
-                    spec((E,), jnp.bool_)).as_text()
-    assert "tpu_custom_call" in text      # the kernel is in the program
-
-
 # -- what phase 3 of the smoke dispatches (keys and shapes taken from a
 #    devprof'd CPU rehearsal at scale 20: the most-called jit_once keys) ----
 

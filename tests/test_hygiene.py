@@ -59,9 +59,8 @@ def test_no_pycache_only_directories():
         f"source")
 
 
-#: ROADMAP D3: TITAN_TPU_SEGMENT_KERNEL (scan | native | pallas) waits
-#: for a cell that runs SSSP / WCC (R3) to settle it
-_ENV_READ_DEBTS = {"titan_tpu/ops/segment.py"}
+#: none left: ROADMAP D3's TITAN_TPU_SEGMENT_KERNEL went with PR 45
+_ENV_READ_DEBTS: set = set()
 
 
 def test_no_kernel_reads_the_environment():

@@ -310,7 +310,7 @@ def test_batch_key_separates_incompatible_jobs():
     own). Since ISSUE 19 SSSP and WCC are batchable too — into
     PER-ALGORITHM cohorts whose keys can never collide with another
     kind's (the kind leads every key)."""
-    from titan_tpu.olap.serving.batcher import batch_key
+    from titan_tpu.olap.serving.kinds import batch_key
 
     base = batch_key(JobSpec(kind="bfs"))
     assert base is not None

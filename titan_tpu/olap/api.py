@@ -255,15 +255,9 @@ class JobSpec:
     gremlin-server requests feed FulgoraGraphComputer's executor, here
     as an admission-controlled queue over the TPU engine).
 
-    ``kind``: 'bfs' (batchable — same-snapshot BFS jobs fuse into ONE
-    [K, n] multi-source device run), 'sssp' | 'pagerank' | 'wcc'
-    (frontier kernels, executed singly), 'cdlp' (label propagation's
-    most-frequent-label vote, ``params['iterations']`` synchronous
-    rounds, executed singly), 'lcc' (the local clustering coefficient
-    of an undirected snapshot from exact triangle counts, no parameter,
-    executed singly), 'dense' (a DenseProgram
-    instance under ``params['program']``), or 'callable'
-    (``params['fn']`` — the host computer's async delegation hook).
+    ``kind`` names a row of ``olap/serving/kinds.KINDS`` (what the kind
+    reads, reserves, checkpoints and runs; docs/serving.md lists every
+    kind's ``params`` and result keys).
 
     ``deadline`` is an absolute ``time.time()`` by which the job must
     START — jobs still queued past it are EXPIRED by admission control.

@@ -12,6 +12,9 @@ admission-controlled asynchronous job plane over the TPU engine:
                   the epoch/refresh() freshness contract before hand-out.
 * ``hbm``       — device-memory accounting (the bench ``_DEV_GRAPHS``
                   budget/eviction logic as a library) backing admission.
+* ``kinds``     — one row a job kind: what it reads, reserves,
+                  checkpoints and runs; the scheduler, the batcher and
+                  the server read it.
 * ``batcher``   — multi-source fusion: compatible same-snapshot BFS jobs
                   execute as ONE batched [K, n] device run
                   (models/bfs_hybrid.frontier_bfs_batched), amortizing

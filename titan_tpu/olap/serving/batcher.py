@@ -9,9 +9,12 @@ admitted jobs leased onto ONE snapshot, it
   per-round plan floor K-fold (PERF_NOTES "K-way plan-amortization
   model"). Cancellation and timeout act through the kernel's per-job
   early-exit mask at level boundaries;
-* runs everything else singly (sssp / pagerank / wcc frontier kernels,
-  'dense' DensePrograms through the TPU engine, 'callable' host
-  delegations), honoring cancel-before-start.
+* fuses fresh SSSP / WCC jobs into per-member cohorts
+  (models/frontier._frontier_cohort);
+* runs everything else singly through ONE body (``run_single``) and the
+  kind's row (serving/kinds.py: the kernel call, the parameters'
+  defaults and the result keys are the row's ``run``), honoring
+  cancel-before-start.
 
 Results are plain dicts; the full distance arrays stay host-side under
 keys the wire form omits (Job.to_wire) — callers resolve per-target
@@ -21,96 +24,15 @@ distances via ``params['targets']``.
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager, nullcontext
-from typing import Optional
+from contextlib import contextmanager
 
 import numpy as np
 
-from titan_tpu.obs.tracing import phase, scope
+from titan_tpu.obs.tracing import phase
 from titan_tpu.olap.serving.jobs import Job
-
-#: jobs of these kinds fuse into one batched run when they share a
-#: snapshot — BFS through the [K, n] batched kernel, SSSP/WCC through
-#: the per-member cohort driver (models/frontier._frontier_cohort)
-BATCHABLE_KINDS = ("bfs", "sssp", "wcc")
-
-#: kinds the mesh placement path understands (parallel/partition
-#: places the BATCHED BFS layout only) — the would_mesh predicate and
-#: the scheduler's per-device ledger accounting key off this, NOT off
-#: BATCHABLE_KINDS, so adding cohort kinds cannot silently change what
-#: the admission guard charges per device
-_MESH_KINDS = ("bfs",)
-
-
-def batch_key(spec) -> Optional[tuple]:
-    """Grouping key: jobs with equal keys may fuse into one batch. The
-    kind is always in the key (a mixed stream fuses into PER-ALGORITHM
-    cohorts, never across kinds), plus every knob the fused run shares:
-    ``max_levels`` for BFS (one shared level loop), the scheduler-mode
-    knobs ``max_rounds``/``delta``/``quantile_mass`` for SSSP (the
-    cohort runs each member's trajectory under cohort-wide mode knobs,
-    so differing knobs must not fuse)."""
-    if spec.kind not in BATCHABLE_KINDS:
-        return None
-    base = (spec.kind,
-            tuple(spec.labels) if spec.labels is not None else None,
-            bool(spec.directed))
-    try:
-        if spec.kind == "bfs":
-            return base + (int(spec.params.get("max_levels", 1000)),)
-        if spec.kind == "sssp":
-            delta = spec.params.get("delta")
-            qm = spec.params.get("quantile_mass")
-            return base + (
-                int(spec.params.get("max_rounds", 10_000)),
-                float(delta) if delta is not None else None,
-                int(qm) if qm is not None else None)
-        return base          # wcc: no per-job kernel knobs
-    except (TypeError, ValueError):
-        return None      # junk knob values: run (and fail) alone
-
-
-def _dense_source(snap, params: dict) -> int:
-    """Resolve a job's source to a dense index: ``source_dense`` wins,
-    else ``source`` is an original vertex id mapped through the
-    snapshot. Raises ValueError for ANY malformed value (None, lists,
-    non-numeric strings) — callers catch it per job; it must never
-    escape as a TypeError that could take the worker thread down."""
-    try:
-        if "source_dense" in params:
-            return int(params["source_dense"])
-        if "source" in params:
-            return snap.dense_of(int(params["source"]))
-    except KeyError as e:                 # dense_of: unknown vertex
-        raise ValueError(str(e)) from e
-    except (TypeError, ValueError) as e:
-        raise ValueError(f"bad source value: {e}") from e
-    raise ValueError("job params need 'source' (vertex id) or "
-                     "'source_dense'")
-
-
-def _components(labels: np.ndarray) -> int:
-    """Components of a WCC answer. A label is its component's smallest
-    vertex id, so a component is counted at the one vertex that carries
-    its own id: one pass, where ``np.unique`` sorts all n labels inside
-    the job's ``exec_ms``."""
-    return int((labels == np.arange(labels.shape[0],
-                                    dtype=labels.dtype)).sum())
-
-
-def _communities(labels: np.ndarray) -> int:
-    """Distinct labels of a CDLP answer. A label is a vertex id, so each
-    is marked where it points: one pass, no sort."""
-    seen = np.zeros(labels.shape[0], bool)
-    seen[labels] = True
-    return int(seen.sum())
-
-
-def _under(handle, run_span):
-    """The scope in which a kernel's leaf phases journal as children of
-    the job's ``run`` span (nothing without a trace)."""
-    return nullcontext() if handle is None \
-        else scope(handle.tracer, handle.trace_id, run_span)
+from titan_tpu.olap.serving.kinds import (KINDS, ParamError, RunContext,
+                                          checkpointing, dense_source,
+                                          sssp_answer, under, wcc_answer)
 
 
 @contextmanager
@@ -120,7 +42,7 @@ def job_phase(job, name: str, **attrs):
     ``attempt`` and a profiler annotation, so a device idle gap between
     two runs carries its name."""
     h = job.trace
-    with _under(h, h.attempt if h is not None else None):
+    with under(h, h.attempt if h is not None else None):
         with phase(name, **attrs) as ph:
             yield ph
 
@@ -184,20 +106,18 @@ class Batcher:
         would over-commit real device HBM past the admission guard)."""
         return (self.mesh is not None
                 and int(self.mesh.devices.size) > 1
-                and kind in _MESH_KINDS
+                and KINDS[kind].meshes
                 and (overlay is None or overlay.empty))
 
     def run_batch(self, jobs: list[Job], snap, overlay=None) -> None:
         """Kind-generic batch entry (the scheduler's one dispatch
         point): BFS groups go through the [K, n] batched kernel,
-        SSSP/WCC groups through the frontier cohort driver. The
-        scheduler's grouping key always carries the kind, so a group
-        is single-kind by construction."""
-        kind = jobs[0].spec.kind
-        if kind == "bfs":
-            self.run_bfs_batch(jobs, snap, overlay=overlay)
-        elif kind in ("sssp", "wcc"):
-            self.run_frontier_batch(jobs, snap, overlay=overlay)
+        SSSP/WCC groups through the frontier cohort driver
+        (``_BATCHED``). The scheduler's grouping key always carries
+        the kind, so a group is single-kind by construction."""
+        road = self._BATCHED.get(jobs[0].spec.kind)
+        if road is not None:
+            road(self, jobs, snap, overlay=overlay)
         else:
             for job in jobs:
                 self.run_single(job, snap, overlay=overlay)
@@ -225,7 +145,7 @@ class Batcher:
         resumed: list[tuple[Job, int, object]] = []
         for job in jobs:
             try:
-                src = _dense_source(snap, job.spec.params)
+                src = dense_source(snap, job.spec.params)
                 # junk max_levels is a param error too — it must fail
                 # permanently HERE, not detonate retryably mid-group
                 int(job.spec.params.get("max_levels", 1000))
@@ -401,7 +321,7 @@ class Batcher:
             src = 0
             if kind == "sssp":
                 try:
-                    src = _dense_source(snap, job.spec.params)
+                    src = dense_source(snap, job.spec.params)
                 except (KeyError, ValueError, TypeError) as e:
                     job.fail(f"{type(e).__name__}: {e}", permanent=True)
                     continue
@@ -432,8 +352,7 @@ class Batcher:
 
     def _frontier_group(self, runnable: list[Job], sources: list[int],
                         snap, overlay=None) -> None:
-        from titan_tpu.models.frontier import (FINF,
-                                               frontier_sssp_batched,
+        from titan_tpu.models.frontier import (frontier_sssp_batched,
                                                frontier_wcc_batched)
 
         kind = runnable[0].spec.kind
@@ -475,28 +394,17 @@ class Batcher:
                 return False
             return True
 
+        # what a member saves, a solo retry resumes (run_single): one
+        # shape, the row's
         token = _epoch_token(snap, overlay)
+        savers = [checkpointing(KINDS[kind], job.recovery, token)[0]
+                  for job in runnable]
 
         def ckpt(k, rounds, state):
-            rec = runnable[k].recovery
-            if rec is None or rec.store is None or not rec.due(rounds):
-                return
-            arrays = {"val": np.asarray(state["val"]),
-                      "val_exp": np.asarray(state["val_exp"])}
-            if kind == "sssp":
-                rec.save(rounds, arrays, kind="sssp",
-                         meta={"epoch": token,
-                               "bucket_end": float(state["bucket_end"]),
-                               "quantile_mass":
-                                   int(state["quantile_mass"])})
-            else:
-                rec.save(rounds, arrays, kind="wcc",
-                         meta={"epoch": token,
-                               "levels": int(state["levels"])})
+            if savers[k] is not None:
+                savers[k](rounds, state)
 
-        wants_ckpt = any(j.recovery is not None
-                         and j.recovery.store is not None
-                         for j in runnable)
+        wants_ckpt = any(save is not None for save in savers)
         params0 = runnable[0].spec.params
         try:
             if kind == "sssp":
@@ -513,7 +421,7 @@ class Batcher:
                 # and wcc.seed, wcc.propagate, a wcc.result a member)
                 # journal under its FIRST member's `run` span: one
                 # thread drives the cohort, and at K = 1 that is the job
-                with _under(runnable[0].trace, runs[0]):
+                with under(runnable[0].trace, runs[0]):
                     outs, rounds_l, stopped = frontier_wcc_batched(
                         snap, K, on_round=on_round,
                         checkpoint=ckpt if wants_ckpt else None,
@@ -524,7 +432,6 @@ class Batcher:
                     job.trace.end(runs[i], error=f"{type(e).__name__}")
                 job.fail(f"{type(e).__name__}: {e}")
             return
-        from titan_tpu.obs import devprof
         for i, job in enumerate(runnable):
             if job.trace is not None:
                 job.trace.end(runs[i], rounds=int(rounds_l[i]))
@@ -534,51 +441,50 @@ class Batcher:
                 else:
                     job.mark_cancelled()
                 continue
-            arr = outs[i]
             if kind == "sssp":
-                devprof.count_d2h("frontier.result",
-                                  getattr(arr, "nbytes", 0))
-                job.complete({"rounds": int(rounds_l[i]),
-                              "reached":
-                                  int((arr < float(FINF)).sum()),
-                              "dist": arr})
+                answer = sssp_answer(outs[i], rounds_l[i])
             else:       # counted where it was read back: wcc.result
                 with job_phase(job, "wcc.count"):
-                    components = _components(arr)
-                job.complete({"rounds": int(rounds_l[i]),
-                              "components": components,
-                              "labels": arr})
+                    answer = wcc_answer(outs[i], rounds_l[i])
+            job.complete(answer)
 
     # -- single execution ---------------------------------------------------
 
     def run_single(self, job: Job, snap, overlay=None) -> None:
-        """One job alone (still async from the caller's view). The
-        frontier kinds honor cancellation/timeout at ROUND boundaries
-        through ``_frontier_run``'s on_round veto (models/frontier
-        RoundInterrupted) — the single-execution analog of the batched
-        kernel's level mask. The same boundaries drive the recovery
-        plane (job.recovery): fault injection, checkpoint capture at
-        the job's cadence, and — on a retry attempt — resume from the
-        newest valid checkpoint (epoch-matched; otherwise clean
-        restart). Param errors fail permanently (no retry)."""
-        job.batch_k = 1
+        """One job alone (still async from the caller's view), through
+        its kind's row (kinds.KINDS): ONE body for every kind, the few
+        lines that are a kind's own in the row's ``run``. Cancellation
+        and timeout act at ROUND boundaries through the kernel's
+        ``on_round`` veto (models/frontier RoundInterrupted) — the
+        single-execution analog of the batched kernel's level mask. The
+        same boundaries drive the recovery plane (job.recovery): fault
+        injection, checkpoint capture at the job's cadence, and — on a
+        retry attempt — resume from the newest valid checkpoint
+        (epoch-matched; otherwise clean restart). Param errors fail
+        permanently (no retry)."""
         kind = job.spec.kind
+        row = KINDS.get(kind)
+        if row is None:
+            job.fail(f"unknown job kind {kind!r}", permanent=True)
+            return
+        job.batch_k = 1
+        if row.run is None:
+            # the batched road owns its own resume bookkeeping (doing
+            # it here too would double-count serving.recovery.resumes /
+            # rounds_replayed)
+            self._BATCHED[kind](self, [job], snap, overlay=overlay)
+            return
         params = dict(job.spec.params)
         params.pop("faults", None)       # injector is not a kernel param
         rec = job.recovery
         started = time.time()
         interrupted = {}
 
-        if kind == "bfs":
-            # bfs delegates wholesale — run_bfs_batch owns its own
-            # resume bookkeeping (doing it here too would double-count
-            # serving.recovery.resumes / rounds_replayed)
-            self.run_bfs_batch([job], snap, overlay=overlay)
-            return
-
+        # a host job (no snapshot) has no device run: no `run` span, no
+        # checkpoint to adopt
         h = job.trace
         run_span = None
-        if h is not None and kind != "callable":
+        if h is not None and snap is not None:
             run_span = h.start(
                 "run", kind=kind,
                 **({"overlay_edges": overlay.count,
@@ -588,16 +494,16 @@ class Batcher:
         # round-window anchor: at/after the run span's start so round
         # children nest inside it
         prev_t = [time.time()]
-        # per-round timeline (obs): pagerank/dense rounds are stamped
-        # from the host callbacks below; sssp/wcc rounds come from
-        # _frontier_run's existing mass-accounting trace instead — it
-        # already carries frontier size / listed chunk mass / plan cost
-        # per round at zero extra syncs (the stats readback happens
+        # per-round timeline (obs): rounds are stamped from the host
+        # callback below; a kind whose row says `round_trace` takes them
+        # from _frontier_run's existing mass-accounting trace instead —
+        # it already carries frontier size / listed chunk mass / plan
+        # cost per round at zero extra syncs (the stats readback happens
         # regardless), so the span timeline gets the band/plan story
         # for free
         trace_rounds = None
         _csr_trace_prev = None
-        if h is not None and kind in ("sssp", "wcc"):
+        if h is not None and row.round_trace:
             from titan_tpu.models.bfs_hybrid import build_chunked_csr
             _csr = build_chunked_csr(snap)
             _csr_trace_prev = _csr.get("_trace_rounds")
@@ -628,202 +534,21 @@ class Batcher:
         # store is shared and keyed, so attempt 1 here resumes the
         # logical job's newest checkpoint instead of restarting; keyed
         # first runs with no checkpoint are fresh, never "restarted")
-        if rec is not None and kind != "callable" \
+        if rec is not None and snap is not None \
                 and (job.attempt > 1 or job.spec.idempotency_key):
             ck = rec.latest(kind=kind, epoch=epoch)
             if ck is not None:
                 rec.resumed(ck.round)
             elif job.attempt > 1:
                 rec.restarted()
-        wants_ckpt = rec is not None and rec.store is not None
+        checkpoint, resume = checkpointing(row, rec, epoch, ck)
 
         try:
-            if kind == "sssp":
-                from titan_tpu.models.frontier import FINF, frontier_sssp
-                try:
-                    src = _dense_source(snap, params)
-                except (KeyError, ValueError) as e:
-                    job.fail(f"{type(e).__name__}: {e}", permanent=True)
-                    return
-                ckpt = None
-                if wants_ckpt:
-                    def ckpt(rounds, state):
-                        if rec.due(rounds):
-                            rec.save(rounds,
-                                     {"val": np.asarray(state["val"]),
-                                      "val_exp":
-                                          np.asarray(state["val_exp"])},
-                                     kind="sssp",
-                                     meta={"epoch": epoch,
-                                           "bucket_end":
-                                               float(state["bucket_end"]),
-                                           "quantile_mass":
-                                               int(state["quantile_mass"])})
-                resume = None
-                if ck is not None:
-                    resume = {"val": ck.arrays["val"],
-                              "val_exp": ck.arrays["val_exp"],
-                              "rounds": ck.round,
-                              "bucket_end": ck.meta["bucket_end"],
-                              "quantile_mass": ck.meta["quantile_mass"]}
-                dist, rounds = frontier_sssp(
-                    snap, src,
-                    delta=params.get("delta"),
-                    quantile_mass=params.get("quantile_mass"),
-                    max_rounds=int(params.get("max_rounds", 10_000)),
-                    on_round=on_round, checkpoint=ckpt, resume=resume,
-                    overlay=overlay)
-                from titan_tpu.obs import devprof
-                devprof.count_d2h("frontier.result",
-                                  getattr(dist, "nbytes", 0))
-                dist = np.asarray(dist)
-                job.complete({"rounds": int(rounds),
-                              "reached": int((dist < float(FINF)).sum()),
-                              "dist": dist})
-            elif kind == "pagerank":
-                from titan_tpu.models.frontier import pagerank_dense
-                ckpt = None
-                if wants_ckpt:
-                    def ckpt(it, state):
-                        if rec.due(it):
-                            rec.save(it,
-                                     {"rank": np.asarray(state["rank"])},
-                                     kind="pagerank",
-                                     meta={"epoch": epoch})
-                resume = None
-                if ck is not None:
-                    resume = {"rank": ck.arrays["rank"], "it": ck.round}
-                # the sweep's leaf phases (pr.sweep, pr.finish,
-                # pr.result) journal under this job's `run` span; the
-                # readback is counted where it is made
-                # (device.xfer.d2h_bytes{site="pagerank.result"})
-                with _under(h, run_span):
-                    rank, iters = pagerank_dense(
-                        snap,
-                        iterations=int(params.get("iterations", 20)),
-                        damping=float(params.get("damping", 0.85)),
-                        tol=params.get("tol"), on_round=on_round,
-                        checkpoint=ckpt, resume=resume, overlay=overlay)
-                job.complete({"iterations": int(iters), "rank": rank})
-            elif kind == "wcc":
-                from titan_tpu.models.frontier import frontier_wcc
-                ckpt = None
-                if wants_ckpt:
-                    def ckpt(rounds, state):
-                        if rec.due(rounds):
-                            rec.save(rounds,
-                                     {"val": np.asarray(state["val"]),
-                                      "val_exp":
-                                          np.asarray(state["val_exp"])},
-                                     kind="wcc",
-                                     meta={"epoch": epoch,
-                                           "levels": int(state["levels"])})
-                resume = None
-                if ck is not None:
-                    resume = {"val": ck.arrays["val"],
-                              "val_exp": ck.arrays["val_exp"],
-                              "rounds": ck.round,
-                              "levels": ck.meta.get("levels", 0)}
-                # the peel's, the propagation's and the readback's leaf
-                # phases (bfs.level, wcc.seed, wcc.propagate, wcc.result)
-                # journal under this job's `run` span; the readback is
-                # counted where it is made
-                # (device.xfer.d2h_bytes{site="wcc.result"})
-                with _under(h, run_span):
-                    lab, rounds = frontier_wcc(
-                        snap, on_round=on_round, checkpoint=ckpt,
-                        resume=resume, overlay=overlay)
-                    with phase("wcc.count"):
-                        components = _components(lab)
-                job.complete({"rounds": int(rounds),
-                              "components": components,
-                              "labels": lab})
-            elif kind == "cdlp":
-                from titan_tpu.models.cdlp import cdlp
-                ckpt = None
-                if wants_ckpt:
-                    def ckpt(it, state):
-                        if rec.due(it):
-                            rec.save(it,
-                                     {"labels":
-                                          np.asarray(state["labels"])},
-                                     kind="cdlp",
-                                     meta={"epoch": epoch})
-                resume = None
-                if ck is not None:
-                    resume = {"labels": ck.arrays["labels"],
-                              "it": ck.round}
-                # the rounds' and the readback's leaf phases (cdlp.round,
-                # cdlp.result) journal under this job's `run` span; the
-                # readback is counted where it is made
-                # (device.xfer.d2h_bytes{site="cdlp.result"})
-                with _under(h, run_span):
-                    labels, iters = cdlp(
-                        snap,
-                        iterations=int(params.get("iterations", 10)),
-                        on_round=on_round, checkpoint=ckpt,
-                        resume=resume, overlay=overlay)
-                    with phase("cdlp.count"):
-                        communities = _communities(labels)
-                job.complete({"iterations": int(iters),
-                              "communities": communities,
-                              "labels": labels})
-            elif kind == "lcc":
-                from titan_tpu.models.lcc import lcc
-                # no checkpoint: a retried job starts over, the image
-                # still resident. The parts' leaf phases (lcc.image,
-                # lcc.hub, lcc.tail, lcc.result) journal under this
-                # job's `run` span; the readback is counted where it
-                # is made (device.xfer.d2h_bytes{site="lcc.result"})
-                with _under(h, run_span):
-                    counts, coeff = lcc(snap, on_round=on_round,
-                                        overlay=overlay)
-                    with phase("lcc.count"):
-                        # every triangle stands at its three vertices
-                        triangles = int(counts.sum(dtype=np.int64)) // 3
-                job.complete({"triangles": triangles,
-                              "lcc": coeff,
-                              "triangle_counts": counts})
-            elif kind == "dense":
-                from titan_tpu.olap.tpu.engine import run_single
-                program = params.pop("program")
-                ckpt = None
-                every = 0
-                if rec is not None and (wants_ckpt
-                                        or rec.faults is not None):
-                    # dense programs have no on_round veto; the chunk
-                    # boundary is the only host hook, so faults fire
-                    # here — and a fault plan WITHOUT a store still
-                    # needs the chunked loop (every=1) to get hooks
-                    every = rec.every if wants_ckpt else 1
-
-                    def ckpt(it, state):
-                        job.last_round = it
-                        if h is not None:
-                            now = time.time()
-                            h.event("round", parent=run_span,
-                                    t0=prev_t[0], t1=now, round=it)
-                            prev_t[0] = now
-                        if rec.faults is not None:
-                            rec.faults.check(it, job.attempt, snap)
-                        if wants_ckpt and rec.due(it):
-                            rec.save(it,
-                                     {k: np.asarray(v)
-                                      for k, v in state.items()},
-                                     kind="dense",
-                                     meta={"epoch": epoch})
-                resume = None
-                if ck is not None:
-                    resume = {"state": ck.arrays, "iteration": ck.round}
-                res = run_single(
-                    program, snap, params, resume=resume, checkpoint=ckpt,
-                    checkpoint_every=every)
-                job.complete({"iterations": res.iterations,
-                              **{k: np.asarray(v) for k, v in res.items()}})
-            elif kind == "callable":
-                job.complete({"value": params["fn"]()})
-            else:
-                job.fail(f"unknown job kind {kind!r}", permanent=True)
+            job.complete(row.run(RunContext(
+                job, snap, overlay, params, on_round, checkpoint, resume,
+                run_span)))
+        except ParamError as e:
+            job.fail(str(e), permanent=True)
         except Exception as e:
             from titan_tpu.models.frontier import RoundInterrupted
             if isinstance(e, RoundInterrupted):
@@ -857,3 +582,8 @@ class Batcher:
                         _csr["_trace_rounds"] = _csr_trace_prev
                 if run_span is not None:
                     h.end(run_span, rounds=int(job.last_round))
+
+    #: the batched kernels: kind -> the road a group of its jobs takes
+    #: (a kind whose row has no ``run`` takes it alone too)
+    _BATCHED = {"bfs": run_bfs_batch, "sssp": run_frontier_batch,
+                "wcc": run_frontier_batch}

@@ -1,25 +1,16 @@
 """Device-memory (HBM) accounting for graph images — admission's ledger.
 
 The serving scheduler admits jobs against it before building a
-snapshot's images on device. The ledger holds, each under its own key:
-the forward chunked CSR every kind reads (``id(snap)``; byte model:
-the transposed 8-aligned ``dstT`` [8, q_total] int32 plus three [n+1]
-int32 side arrays (colstart/degc/deg) —
-models/bfs_hybrid.build_chunked_csr's exact footprint), the in-edge
-pull image a ``pagerank`` or ``lcc`` job reads beside it
-(``("pagerank-pull", id(snap))``, models/pagerank_pull.pull_image), a
-``cdlp`` job's row image (``("cdlp-image", id(snap))``,
-models/cdlp.cdlp_image: resident and evictable like the others) and its
-run's working set (``("cdlp-work", id(snap))``, reserved for
-the run and released behind it, models/cdlp.work_bytes), an ``lcc``
-job's hub bit table with what is built beside it (``("lcc-image",
-id(snap))``, models/lcc.lcc_image: resident and evictable like the
-other images, eight times the pull image at graph500-22) and its
-working set (``("lcc-work", id(snap))``, released behind the run) and
-the interactive lane's reversed layout for ``out()``
-(``("interactive-rev", id(snap))``). Eviction is largest-first over
-unpinned entries; pinned entries (graphs under a running batch) are
-never evicted.
+snapshot's images on device. The ledger holds, each under its own key,
+the images a job kind's row lists (serving/kinds.py: the forward
+chunked CSR under ``id(snap)``, every other image and a run's working
+set under ``(name, id(snap))``) and the interactive lane's reversed
+layout for ``out()`` (``("interactive-rev", id(snap))``); the byte
+models are the functions below. The forward image's: the transposed
+8-aligned ``dstT`` [8, q_total] int32 plus three [n+1] int32 side
+arrays (colstart/degc/deg) — models/bfs_hybrid.build_chunked_csr's
+exact footprint. Eviction is largest-first over unpinned entries;
+pinned entries (graphs under a running batch) are never evicted.
 
 Every size derives from ``n`` and three counts: two of columns,
 sum(ceil(deg/8)) + 1 over the out-degrees (``"out"``) and over the

@@ -99,49 +99,17 @@ from titan_tpu.obs import devprof
 from titan_tpu.obs.flightrec import FlightRecorder
 from titan_tpu.obs.tracing import TraceHandle, Tracer
 from titan_tpu.olap.api import JobSpec
-from titan_tpu.olap.serving.batcher import (Batcher, batch_key,
-                                              job_phase)
+from titan_tpu.olap.serving.batcher import Batcher, job_phase
 from titan_tpu.olap.serving.hbm import (DEFAULT_BUDGET_BYTES,
-                                        AdmissionError, HBMLedger, price,
-                                        snapshot_cdlp_bytes,
-                                        snapshot_cdlp_image_bytes,
-                                        snapshot_csr_bytes,
-                                        snapshot_lcc_bytes,
-                                        snapshot_lcc_work_bytes,
-                                        snapshot_pull_bytes)
+                                        AdmissionError, HBMLedger,
+                                        meshed_snapshot_csr_bytes, price)
 from titan_tpu.olap.serving.jobs import Job, JobState
+from titan_tpu.olap.serving.kinds import KINDS, batch_key, image_keys
 from titan_tpu.olap.serving.pool import SnapshotPool
 from titan_tpu.olap.serving.tenants import (QuotaExceeded,
                                             TenantAccounting,
                                             effective_tenant)
 from titan_tpu.utils.metrics import MetricManager
-
-#: job kinds that execute against a pooled snapshot (everything except
-#: host 'callable' delegations)
-_SNAPSHOT_KINDS = ("bfs", "sssp", "pagerank", "wcc", "cdlp", "lcc",
-                   "dense")
-
-#: kinds that read the in-edge pull image of models/pagerank_pull
-_PULL_KINDS = ("pagerank", "lcc")
-
-#: kinds whose image has no overlay seam: leased compacted
-_COMPACTED_KINDS = _PULL_KINDS + ("cdlp", "dense")
-
-#: kind -> (ledger key, bytes from the snapshot, the snapshot attribute
-#: an eviction drops, the count hbm.price keeps for it) of an image of
-#: the kind's own, resident beside the forward image (and the pull
-#: image, where the kind pulls)
-_KIND_IMAGE = {
-    "lcc": ("lcc-image", snapshot_lcc_bytes, "_lcc_csr", "in"),
-    "cdlp": ("cdlp-image", snapshot_cdlp_image_bytes, "_cdlp_csr",
-             "cdlp")}
-
-#: kind -> (ledger key, bytes from the snapshot) of the working set a
-#: run of the kind holds and releases
-_KIND_WORK = {"cdlp": ("cdlp-work", snapshot_cdlp_bytes),
-              "lcc": ("lcc-work", snapshot_lcc_work_bytes)}
-
-_KNOWN_KINDS = _SNAPSHOT_KINDS + ("callable",)
 
 
 class JobScheduler:
@@ -432,15 +400,14 @@ class JobScheduler:
         """Pool close hook: a retired/rebuilt snapshot leaves the HBM
         ledger (and the evictable map) instead of counting as resident
         forever — including the layouts riding on the same snapshot
-        (the interactive lane's reversed orientation, PageRank's pull
-        image)."""
+        (the interactive lane's reversed orientation, every image of a
+        kind's own: kinds.image_keys)."""
         key = id(snap)
         self._evictable.pop(key, None)
         self.ledger.release(key)
-        for rider in [("interactive-rev", key), ("pagerank-pull", key)] \
-                + [(image[0], key) for image in _KIND_IMAGE.values()]:
-            self._evictable.pop(rider, None)
-            self.ledger.release(rider)
+        for name in {"interactive-rev"} | image_keys():
+            self._evictable.pop((name, key), None)
+            self.ledger.release((name, key))
 
     # -- submission surface --------------------------------------------------
 
@@ -455,21 +422,20 @@ class JobScheduler:
         # rejected submits must NOT count as submitted (the counter
         # moves only after admission): unknown kinds and closed-
         # scheduler refusals are serving.jobs.rejected instead
-        if spec.kind not in _KNOWN_KINDS:
+        # (a kind off the wire may be any JSON value)
+        row = KINDS.get(spec.kind) if isinstance(spec.kind, str) else None
+        if row is None:
             self._metrics.counter(
                 "serving.jobs.rejected",
                 labels={"kind": "unknown", "tenant": tenant}).inc()
             raise ValueError(f"unknown job kind {spec.kind!r} "
-                             f"(known: {', '.join(_KNOWN_KINDS)})")
-        if spec.kind == "lcc" and spec.directed:
+                             f"(known: {', '.join(KINDS)})")
+        why = row.refuse(spec)
+        if why is not None:
             self._metrics.counter(
                 "serving.jobs.rejected",
                 labels={"kind": spec.kind, "tenant": tenant}).inc()
-            raise ValueError(
-                "lcc on a directed snapshot: the specification's "
-                "directed form (in- and out-neighbours together, a pair "
-                "counted in each direction it is an edge) is not "
-                "implemented; submit with directed=false")
+            raise ValueError(why)
         faults = spec.params.get("faults") \
             if isinstance(spec.params, dict) else None
         if faults is not None:
@@ -1012,7 +978,11 @@ class JobScheduler:
                 for job in group:
                     if job.trace is not None:
                         job.trace.event("controller", decisions=brief)
-        if head.spec.kind == "callable":
+        spec = head.spec
+        row = KINDS[spec.kind]
+        if not row.images:
+            # a host job reads nothing on the device: no lease, no
+            # admission
             t0 = time.time()
             for job in group:
                 self.batcher.run_single(job, None)
@@ -1020,30 +990,20 @@ class JobScheduler:
             if self.recorder is not None:
                 self.recorder.metric_delta()
             return
-        spec = head.spec
-        edge_keys = tuple(spec.edge_keys or ())
-        if spec.kind == "dense" and not edge_keys:
-            # a DenseProgram that reads edge properties needs them
-            # extracted into the snapshot — derive from the program
-            program = spec.params.get("program")
-            if program is not None and hasattr(program, "edge_keys"):
-                edge_keys = tuple(program.edge_keys())
+        edge_keys = tuple(spec.edge_keys or ()) or row.edge_keys(spec)
         # `job.lease` and `job.admit`: leaf phases under the head job's
         # attempt, so the host's time between two runs has spans and
         # the device's idle gap there a name (obs/tracing)
         with job_phase(head, "job.lease"):
             try:
-                # dense window sweeps (pagerank / DenseProgram), the pull
-                # image (pagerank, lcc) and cdlp's row image have no
-                # overlay seam: the
-                # live pool folds the overlay into the base BEFORE
+                # an image with no overlay seam (the row's `compacted`):
+                # the live pool folds the overlay into the base BEFORE
                 # leasing for these kinds (the documented
                 # compact-before-run fallback, models/frontier.py)
                 lease = self.pool.acquire(labels=spec.labels,
                                           edge_keys=edge_keys,
                                           directed=spec.directed,
-                                          compacted=spec.kind in
-                                          _COMPACTED_KINDS)
+                                          compacted=row.compacted)
             except Exception as e:
                 for job in group:
                     job.fail(f"snapshot: {type(e).__name__}: {e}")
@@ -1059,55 +1019,43 @@ class JobScheduler:
                 # every size below reads the counts kept on the
                 # snapshot; a snapshot's first admission pays the pass
                 # over a degree array that each count takes, here
-                pulls = spec.kind in _PULL_KINDS
-                own = _KIND_IMAGE.get(spec.kind)
-                passes = price(
-                    snap, ["out"] + ["in"] * pulls
-                    + ([own[3]] if own else []), self._metrics)
-                # mesh-placed cohorts charge the PER-DEVICE share (the
-                # edge image shards over the mesh — hbm.meshed_snapshot_
-                # csr_bytes); only batched BFS runs meshed (single-run
-                # kinds and overlay leases keep the single-device layout).
-                # The predicate is the BATCHER's (Batcher.would_mesh) —
-                # the accounting here and the placement there must answer
+                passes = price(snap, [image.count for image in row.images],
+                               self._metrics)
+                # mesh-placed cohorts charge the PER-DEVICE share of the
+                # forward image (its edges shard over the mesh —
+                # hbm.meshed_snapshot_csr_bytes); single-run kinds and
+                # overlay leases keep the single-device layout. The
+                # predicate is the BATCHER's (Batcher.would_mesh) — the
+                # accounting here and the placement there must answer
                 # from one definition. A snapshot already resident under
                 # the other accounting keeps its first byte count
                 # (reserve() pins existing keys without re-pricing) —
                 # conservative either way.
                 meshed = self.batcher.would_mesh(spec.kind, overlay)
-                if meshed:
-                    from titan_tpu.olap.serving.hbm import \
-                        meshed_snapshot_csr_bytes
-                    nbytes = meshed_snapshot_csr_bytes(
-                        snap, int(self.mesh.devices.size))
-                else:
-                    nbytes = snapshot_csr_bytes(snap)
-                # a `pagerank` or `lcc` job reads a second image:
-                # the in-edge pull image of models/pagerank_pull, under a
-                # key of its own so that a snapshot already resident for
-                # other kinds is not taken to hold it. An `lcc` job keeps a
-                # third beside it, its hub bit table, and a `cdlp` job,
-                # which does not pull, its row image (_KIND_IMAGE), resident
-                # and evictable like the others. A `cdlp` or `lcc` job
-                # besides works on more than it keeps (_KIND_WORK: the
-                # sort's operand and the vote's temporaries; the tiles'
-                # gathered rows): reserved for the run under a key with
-                # nothing to evict, and released, not left resident,
-                # behind it
-                images = [(ledger_key, nbytes, snap)]
-                if pulls:
-                    images.append((("pagerank-pull", ledger_key),
-                                   snapshot_pull_bytes(snap),
-                                   (snap, "_pull_csr")))
-                if own:
-                    name, sized, attr, _count = own
-                    images.append(((name, ledger_key), sized(snap),
-                                   (snap, attr)))
+                # the row's images, each under a key of its own so that
+                # a snapshot already resident for other kinds is not
+                # taken to hold it, resident and evictable behind the
+                # run; then its working set, reserved for the run under
+                # a key with nothing to evict, and released, not left
+                # resident, behind it
+                images = []
+                for image in row.images:
+                    if image.key is not None:
+                        images.append(((image.key, ledger_key),
+                                       image.nbytes(snap),
+                                       (snap, image.attr)))
+                        continue
+                    # the forward image: the snapshot's own entry
+                    images.append((
+                        ledger_key,
+                        meshed_snapshot_csr_bytes(
+                            snap, int(self.mesh.devices.size))
+                        if meshed else image.nbytes(snap),
+                        snap))
                 work_key = None
-                if spec.kind in _KIND_WORK:
-                    name, sized = _KIND_WORK[spec.kind]
-                    work_key = (name, ledger_key)
-                    images.append((work_key, sized(snap), None))
+                if row.work is not None:
+                    work_key = (row.work.key, ledger_key)
+                    images.append((work_key, row.work.nbytes(snap), None))
                 nbytes = sum(image[1] for image in images)
                 held = []
                 try:

@@ -1,20 +1,17 @@
 """Segment-reduction kernels — the SpMV primitive of the OLAP engine.
 
-Three implementations of "combine per-edge messages by destination",
-selected by ``TITAN_TPU_SEGMENT_KERNEL`` (see PERF_NOTES.md for the full
-measurement story — beware XLA constant-folding jit-captured inputs;
-only argument-passed benchmarks are real):
+Two implementations of "combine per-edge messages by destination";
+``segment_combine`` chooses from what it can observe (see PERF_NOTES.md
+for the full measurement story — beware XLA constant-folding
+jit-captured inputs; only argument-passed benchmarks are real):
 
-* ``scan`` (DEFAULT on non-CPU backends when segment metadata is present):
-  sorted-segment Hillis-Steele scan + static last-index gather. At real
-  scale (268M edges, v5e, readback-synced): scan 330ms + last-gather 270ms
-  vs 3 275ms for the scatter path — ~5× faster.
-* ``native`` (and the CPU default): ``jax.ops.segment_*`` scatter — XLA's
-  TPU scatter lowering runs at a flat ~100M elem/s, but it is the right
-  path on CPU and for unsorted segments.
-* ``pallas`` (opt-in): one-pass streamed scan (ops/pallas_segment.py),
-  currently lane-shift-bound, ~par with the XLA scan; retained as the
-  kernel substrate for future tuning.
+* the sorted scan, where segment metadata is given and the backend is
+  not the CPU: sorted-segment Hillis-Steele scan + static last-index
+  gather. At real scale (268M edges, v5e, readback-synced): scan 330ms +
+  last-gather 270ms vs 3 275ms for the scatter path — ~5× faster.
+* ``jax.ops.segment_*`` scatter otherwise — XLA's TPU scatter lowering
+  runs at a flat ~100M elem/s, but it is the right path on CPU and for
+  unsorted segments.
 """
 
 from __future__ import annotations
@@ -194,18 +191,8 @@ def sorted_segment_combine(values, seg_ids, last_idx, seg_has, combine: str):
 def segment_combine(values, segment_ids, num_segments: int, combine: str,
                     indices_are_sorted: bool = True,
                     last_idx=None, seg_has=None):
-    import os
-    kernel = os.environ.get("TITAN_TPU_SEGMENT_KERNEL", "scan")
-    if kernel not in ("scan", "native", "pallas"):
-        raise ValueError(
-            f"TITAN_TPU_SEGMENT_KERNEL={kernel!r}: expected scan|native|pallas")
-    has_meta = last_idx is not None and seg_has is not None
-    if has_meta and kernel == "pallas" and jax.default_backend() == "tpu":
-        from titan_tpu.ops.pallas_segment import \
-            pallas_sorted_segment_combine
-        return pallas_sorted_segment_combine(
-            values, segment_ids, last_idx, seg_has, combine)
-    if has_meta and kernel == "scan" and jax.default_backend() != "cpu":
+    if last_idx is not None and seg_has is not None \
+            and jax.default_backend() != "cpu":
         return sorted_segment_combine(values, segment_ids, last_idx, seg_has,
                                       combine)
     try:

@@ -47,16 +47,9 @@ Endpoints:
                        "targets": [ids], "max_retries": 0,
                        "checkpoint_every": 0, "tenant": "team-a"}
                       → 202 {"job": id}.
-                      Kinds: bfs, sssp, wcc, pagerank, cdlp (LDBC
-                      Graphalytics' community detection by label
-                      propagation: {"kind": "cdlp", "iterations": 10},
-                      synchronous rounds; result ``iterations``,
-                      ``communities`` and the array ``labels`` int32
-                      [n]), lcc (LDBC Graphalytics' local clustering
-                      coefficient: {"kind": "lcc"}, no parameter, an
-                      undirected snapshot only; result ``triangles``
-                      and the arrays ``lcc`` float32 [n] and
-                      ``triangle_counts`` int32 [n]), dense.
+                      Kinds, their parameters and result keys:
+                      olap/serving/kinds.py (one row a kind) and
+                      docs/serving.md.
                       Same-snapshot BFS jobs fuse into one batched
                       [K, n] device run; max_retries/checkpoint_every
                       opt into the recovery plane (olap/recovery —

@@ -20,7 +20,7 @@ refs, positionally, so the kernel's POSITIONAL parameters are the
 traced refs while keyword-only parameters (bound through
 ``functools.partial`` at the call site) are compile-time constants —
 Python control flow on them is legal and expected
-(ops/pallas_segment.py's ``while d < block`` ladder).
+(a ``while d < block`` unroll ladder).
 Resolution is best-effort and PURELY lexical: a builder whose return
 can't be followed (e.g. mesh.py's own generic ``builder(mesh)``
 trampoline) contributes nothing rather than guessing.
