@@ -1,0 +1,28 @@
+"""Scheduler and batcher (``olap/serving``): median of the BC jobs'
+``exec_ms`` (started -> finished: lease, HBM admission of the two images
+and the working set, every root's forward and backward phase, the scores
+on the host), from the ``GET /jobs/<id>`` envelope. It prints first the
+medians of the host's leaf phases of a job, where the program writes
+them: ``job.lease``, ``job.admit`` (with the bytes admission reserved)
+and ``bc.result`` (the sum over the roots, the division and the one
+readback)."""
+
+import spans
+import stats
+
+HOST_PHASES = ("job.lease", "job.admit", "bc.result")
+
+
+def read(record: dict):
+    got = spans.in_window(record)
+    for name in HOST_PHASES if got is not None else ():
+        found = spans.named(got, name)
+        ms = [s["duration_ms"] for s in found
+              if s.get("duration_ms") is not None]
+        if ms:
+            reserved = {spans.attr(s, "bytes") for s in found} - {None}
+            print(f"host {name}: median {stats.median(ms):.1f}ms in "
+                  f"{len(ms)} jobs" + (f", bytes {sorted(reserved)}"
+                                       if reserved else ""), flush=True)
+    values = stats.field(record, "exec_ms")
+    return stats.median(values) if values else None

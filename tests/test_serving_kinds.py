@@ -32,8 +32,8 @@ from titan_tpu.utils.metrics import MetricManager
 
 #: the rows as they stood before the table (PR 44): the names a ledger
 #: key is made of, in the order admission reserves them (None: the
-#: forward image, under ``id(snap)`` alone; the last of cdlp's and lcc's
-#: is the working set), and whether the lease folds the overlay
+#: forward image, under ``id(snap)`` alone; the last of cdlp's, lcc's
+#: and bc's is the working set), and whether the lease folds the overlay
 LEDGER = {
     "bfs": [None],
     "sssp": [None],
@@ -41,18 +41,20 @@ LEDGER = {
     "wcc": [None],
     "cdlp": [None, "cdlp-image", "cdlp-work"],
     "lcc": [None, "pagerank-pull", "lcc-image", "lcc-work"],
+    "bc": [None, "pagerank-pull", "bc-work"],
     "dense": [None],
 }
-WORK = {"cdlp": "cdlp-work", "lcc": "lcc-work"}
+WORK = {"cdlp": "cdlp-work", "lcc": "lcc-work", "bc": "bc-work"}
 BYTES = {None: hbm.snapshot_csr_bytes,
          "pagerank-pull": hbm.snapshot_pull_bytes,
          "cdlp-image": hbm.snapshot_cdlp_image_bytes,
          "cdlp-work": hbm.snapshot_cdlp_bytes,
          "lcc-image": hbm.snapshot_lcc_bytes,
-         "lcc-work": hbm.snapshot_lcc_work_bytes}
+         "lcc-work": hbm.snapshot_lcc_work_bytes,
+         "bc-work": hbm.snapshot_bc_work_bytes}
 COMPACTED = {"bfs": False, "sssp": False, "pagerank": True, "wcc": False,
-             "cdlp": True, "lcc": True, "dense": True}
-ORDER = ["bfs", "sssp", "pagerank", "wcc", "cdlp", "lcc", "dense",
+             "cdlp": True, "lcc": True, "bc": True, "dense": True}
+ORDER = ["bfs", "sssp", "pagerank", "wcc", "cdlp", "lcc", "bc", "dense",
          "callable"]
 
 
@@ -101,6 +103,7 @@ def _params(kind: str, snap) -> dict:
             "sssp": {"source_dense": _source(snap)},
             "pagerank": {"iterations": 6},
             "cdlp": {"iterations": 6},
+            "bc": {"sources_dense": [_source(snap), 0]},
             "dense": {"program": BFS(max_iterations=100),
                       "source_dense": _source(snap)},
             }.get(kind, {})
@@ -451,7 +454,7 @@ def sched():
     s.close()
 
 
-def test_the_table_holds_eight_rows_in_order():
+def test_the_table_holds_nine_rows_in_order():
     assert list(KINDS) == ORDER
     assert all(row.name == name for name, row in KINDS.items())
     assert {k for k, row in KINDS.items() if row.images} == set(LEDGER)
@@ -464,7 +467,7 @@ def test_an_unknown_kind_is_refused_with_the_list(sched, kind):
         sched.submit(JobSpec(kind=kind))
     assert str(e.value) == (
         f"unknown job kind {kind!r} (known: bfs, sssp, pagerank, wcc, "
-        "cdlp, lcc, dense, callable)")
+        "cdlp, lcc, bc, dense, callable)")
     assert sched._metrics.counter(
         "serving.jobs.rejected",
         labels={"kind": "unknown", "tenant": "default"}).count == 1

@@ -27,6 +27,10 @@ under ``jit_once``):
   the small ranks) and one sorted gather reads each vertex's last
   column.
 
+The gather and the segment sum take any ``[n + 1]`` float32 table
+(:func:`pull_sum`): PageRank's ``rank / deg``, and the level-masked
+tables of ``models/bc.py``.
+
 What chooses the gather is what the code can observe — the backend and
 the table's size (``vmem_gather.gather_impl``) — never a flag, an
 argument or the environment.
@@ -110,6 +114,22 @@ def _colsum_xla(idx, table):
     return table.reshape(-1)[idx.reshape(8, -1)].sum(axis=0)
 
 
+def pull_sum(table, idx, first, last, has, impl: str, seg_max: int):
+    """``acc`` [n]: for every vertex the sum of ``table`` (float32
+    [n + 1], entry n the sink's 0.0) over its in-neighbours: the column
+    sums by the gather ``impl`` names, the segment scan, each vertex's
+    last column. Traced inside its caller's program: ``pagerank_pull``
+    hands it ``rank / deg``, ``models/bc.py`` a level's masked table."""
+    import jax.numpy as jnp
+
+    from titan_tpu.ops.segment import seg_scan
+
+    colsum = (vmem_gather.colsum_vmem if impl == "vmem"
+              else _colsum_xla)(idx, vmem_gather.as_table(table))
+    run = seg_scan(colsum, first, "sum", max_len=seg_max)
+    return jnp.where(has, run[last], 0.0)
+
+
 def pull_step():
     """``pagerank_pull``: one iteration's sums, ``acc`` [n] from
     ``rank`` [n + 1]. ``contrib`` is computed here, from ``rank`` and
@@ -118,17 +138,12 @@ def pull_step():
         import jax
         import jax.numpy as jnp
 
-        from titan_tpu.ops.segment import seg_scan
-
         @functools.partial(jax.jit,
                            static_argnames=("impl", "seg_max"))
         def step(rank, deg, idx, first, last, has, impl: str,
                  seg_max: int):
             contrib = jnp.where(deg > 0, rank / jnp.maximum(deg, 1), 0.0)
-            colsum = (vmem_gather.colsum_vmem if impl == "vmem"
-                      else _colsum_xla)(idx,
-                                        vmem_gather.as_table(contrib))
-            run = seg_scan(colsum, first, "sum", max_len=seg_max)
-            return jnp.where(has, run[last], 0.0)
+            return pull_sum(contrib, idx, first, last, has, impl,
+                            seg_max)
         return step
     return jit_once("pagerank_pull", build)

@@ -495,6 +495,18 @@ def count_lcc(part: str, edges: int, wedges: Optional[int] = None
                                  labels={"part": part}).inc(int(wedges))
 
 
+def count_bc(part: str, levels: int) -> None:
+    """Count one phase of one root of ``models/bc.bc`` run: its pulls
+    by ``part`` (``"forward"``: the levels that hold a vertex, the last
+    pull finds nobody; ``"backward"``: two fewer), and the root once,
+    with its forward phase."""
+    for prof in list(_PROFILERS):
+        prof.metrics.counter("device.bc.levels",
+                             labels={"part": part}).inc(int(levels))
+        if part == "forward":
+            prof.metrics.counter("device.bc.roots").inc()
+
+
 def current() -> Optional["DeviceCostProfiler"]:
     """The most recently installed profiler, or None."""
     return _PROFILERS[-1] if _PROFILERS else None

@@ -164,6 +164,16 @@ def snapshot_lcc_work_bytes(snap) -> int:
     return lcc.work_bytes(snap.n, _pull_columns(snap), lcc.HUBS)
 
 
+def snapshot_bc_work_bytes(snap) -> int:
+    """Predicted device bytes a ``bc`` job's levels work on beside the
+    forward and pull images (models/bc.work_bytes: a few n-vectors a
+    root and one kept a root, priced at the most roots a job may name,
+    and a level's column-wide temporaries), from ``n`` and the kept
+    ``"in"`` column count."""
+    from titan_tpu.models import bc
+    return bc.work_bytes(snap.n, _pull_columns(snap))
+
+
 def meshed_snapshot_csr_bytes(snap, num_devices: int) -> int:
     """PER-DEVICE bytes of a MESH-PLACED chunked CSR (ISSUE 13,
     ``parallel/partition.place_batched_csr``): the ``dstT`` edge image

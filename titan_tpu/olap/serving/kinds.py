@@ -308,6 +308,31 @@ def _lcc_refuses(spec) -> Optional[str]:
     return None
 
 
+def _run_bc(ctx: RunContext) -> dict:
+    from titan_tpu.models import bc
+    try:
+        roots = bc.dense_roots(ctx.snap, ctx.params)
+    except ValueError as e:
+        raise ParamError(f"{type(e).__name__}: {e}") from e
+    # no checkpoint: a retried job starts over. The phases (bc.forward
+    # and bc.backward a root, bc.result) journal under the job's `run`
+    # span; the readback is counted where it is made
+    # (device.xfer.d2h_bytes{site="bc.result"})
+    with ctx.under():
+        scores, levels, reached = bc.bc(
+            ctx.snap, roots, on_round=ctx.on_round, overlay=ctx.overlay)
+    return {"levels": levels, "reached": reached, "scores": scores}
+
+
+def _bc_refuses(spec) -> Optional[str]:
+    if spec.directed:
+        return ("bc on a directed snapshot: the backward phase walks "
+                "the out-edges, whose image a directed snapshot would "
+                "need beside the in-edges', is not implemented; submit "
+                "with directed=false")
+    return None
+
+
 def _run_dense(ctx: RunContext) -> dict:
     from titan_tpu.olap.tpu.engine import run_single
     program = ctx.params.pop("program")
@@ -376,6 +401,9 @@ KINDS: dict[str, Kind] = {row.name: row for row in (
                        "_lcc_csr")),
          work=Work("lcc-work", hbm.snapshot_lcc_work_bytes),
          compacted=True, refuse=_lcc_refuses),
+    Kind("bc", _run_bc, images=(FORWARD, PULL),
+         work=Work("bc-work", hbm.snapshot_bc_work_bytes),
+         compacted=True, refuse=_bc_refuses),
     Kind("dense", _run_dense, images=(FORWARD,), compacted=True,
          edge_keys=_dense_edge_keys, checkpoint=Checkpoint("iteration")),
     # the host computer's async delegation hook

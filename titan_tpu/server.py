@@ -346,7 +346,8 @@ class GraphServer:
         from titan_tpu.olap.api import JobSpec
         kind = body.get("kind", "bfs")
         params = dict(body.get("params") or {})
-        for key in ("source", "source_dense", "targets", "max_levels",
+        for key in ("source", "source_dense", "sources", "sources_dense",
+                    "targets", "max_levels",
                     "iterations", "damping", "delta", "quantile_mass"):
             if key in body:
                 params[key] = body[key]
