@@ -201,34 +201,45 @@ def test_pagerank_pull_at_graph500_22(spec):
     _compile(_pr_result(), spec((N22 + 1,), jnp.float32), n_=N22)
 
 
-def test_bc_levels_at_graph500_22(spec):
+@pytest.mark.parametrize("width", [4, 1])
+def test_bc_levels_at_graph500_22(spec, width):
     """The two level programs of the served BC job (ISSUE 46) at the
     shapes of the cell kron-s22.bc-c2, which are g500-22.pr-c2's: the
     same Pallas gather, its table a level's masked sigma or (1 + delta)
-    / sigma; a level's temporaries and outputs stay inside what
-    admission reserves for them (``models/bc.work_bytes``, no root's
-    delta kept)."""
+    / sigma. Since ISSUE 47 at the cell's width, four roots side by
+    side in one 38.3 MB table (and at width 1: a fifth root alone); a
+    level's temporaries and outputs stay inside what admission reserves
+    for them (``models/bc.level_bytes``, no root's delta kept)."""
     from titan_tpu.models import bc as B
-    from titan_tpu.ops.vmem_gather import padded_columns
+    from titan_tpu.ops.vmem_gather import (VMEM_TABLE_MAX, padded_columns,
+                                           shared_width, table_rows)
 
+    assert shared_width(N22, B.MAX_ROOTS) == 4
+    assert table_rows(N22, 4) * 512 <= VMEM_TABLE_MAX < \
+        table_rows(N22, 8) * 512
     q_in = padded_columns(Q22)
     image = (spec((8 * q_in,), jnp.int32), spec((q_in,), jnp.bool_),
              spec((N22,), jnp.int32), spec((N22,), jnp.bool_))
-    depth, vec = spec((N22,), jnp.int32), spec((N22,), jnp.float32)
+    depth = spec((width, N22), jnp.int32)
+    vec = spec((width, N22), jnp.float32)
     level = spec((), jnp.int32)
-    statics = {"impl": "vmem", "seg_max": 20_413}
+    statics = {"impl": "vmem", "seg_max": 20_413, "width": width}
     forward = _compile(B._level("bc_forward_level", B.forward_level),
                        depth, vec, level, *image, **statics)
     backward = _compile(B._level("bc_backward_level", B.backward_level),
                         depth, vec, vec, level, *image, **statics)
     for program in (forward, backward):
-        assert "tpu_custom_call" in program.as_text()
+        text = program.as_text()
+        assert "tpu_custom_call" in text
+        # no array with the roots minor: it would pad every value to a
+        # row of lanes (1.2 GB a table at this n)
+        assert f"f32[{N22 + 1},{width}]{{1,0" not in text
         m = program.memory_analysis()
         # the column sums and the scan's passes are q_in wide, not n
         assert m.temp_size_in_bytes + m.output_size_in_bytes \
-            <= B.work_bytes(N22, q_in, roots=0)
-    _compile(B._seed(), level, n_=N22)
-    _compile(B._result(), (vec,) * 4)
+            <= B.level_bytes(N22, q_in, width)
+    _compile(B._seed(), spec((width,), jnp.int32), n_=N22)
+    _compile(B._result(), (vec,))
 
 
 def test_cdlp_round_at_graph500_22(spec):

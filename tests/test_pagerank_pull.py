@@ -207,7 +207,9 @@ def test_what_chooses_the_gather(monkeypatch):
     edge = vg.VMEM_TABLE_MAX // 4 - 2
     assert vg.gather_impl(edge) == "vmem"
     assert vg.gather_impl(edge + 1) == "xla"
-    assert list(inspect.signature(vg.gather_impl).parameters) == ["n"]
+    # n and the values a vertex (tests/test_shared_pull.py): no flag
+    assert list(inspect.signature(vg.gather_impl).parameters) \
+        == ["n", "width"]
     for mod in (pp, vg):
         src = inspect.getsource(mod)
         assert "os.environ" not in src and "getenv" not in src

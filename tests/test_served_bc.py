@@ -10,7 +10,9 @@ in each). Then the served path: ``JobScheduler.submit`` and ``POST
 /jobs`` with ``sources`` -> result plane, spans and counters, a cancel
 between the phases, a timeout, what is refused and in what words, what
 admission reserves; and the shared pull-sum: ``pagerank_pull`` bit-equal
-to the program it was before ``pull_sum`` was cut out of it.
+to the program it was before ``pull_sum`` was cut out of it. Since ISSUE
+47 a job's roots run in groups that share every pull: every root's
+dependencies are what the root gives alone, whatever stands beside it.
 """
 
 import functools
@@ -193,7 +195,14 @@ def test_what_the_model_refuses():
         == [2]
 
 
-def test_a_veto_stops_at_a_level_boundary():
+@pytest.mark.parametrize("roots,limit", [
+    # one group of two, 8 forward pulls and 6 backward: the veto falls
+    # in its backward phase
+    ([0, 7], 10),
+    # 2 + 1: the pair's 14 pulls, then root 3 alone (5 forward pulls, 3
+    # backward): the veto falls in the second group's forward phase
+    ([0, 7, 3], 16)])
+def test_a_veto_stops_at_a_level_boundary(roots, limit):
     from titan_tpu.models.frontier import RoundInterrupted
 
     n, src, dst = a_path(8)
@@ -201,12 +210,80 @@ def test_a_veto_stops_at_a_level_boundary():
 
     def veto(i):
         seen.append(i)
-        return i < 10
+        return i < limit
 
-    # root 0: 8 forward pulls, 6 backward; the veto falls in the second
+    # a boundary a SHARED level: the pair's pulls are counted once
     with pytest.raises(RoundInterrupted):
-        B.bc(snap_mod.from_arrays(n, src, dst), [0, 7], on_round=veto)
-    assert seen == list(range(1, 11))
+        B.bc(snap_mod.from_arrays(n, src, dst), roots, on_round=veto)
+    assert seen == list(range(1, limit + 1))
+    asked = []
+    _, levels, _ = B.bc(snap_mod.from_arrays(n, src, dst), roots,
+                        on_round=lambda i: asked.append(i) or True)
+    assert levels == [8, 8, 5][:len(roots)]
+    assert asked == list(range(1, 14 + (8 if len(roots) == 3 else 0) + 1))
+
+
+# -- the roots of a group share every level ------------------------------------
+
+def deltas_of(snap, roots, monkeypatch):
+    """``bc`` of ``roots`` with the groups' dependencies caught where
+    ``bc_result`` takes them: ``(scores, levels, reached, deltas)``,
+    ``deltas`` one ``[w, n]`` array a group."""
+    real, caught = B._result, []
+
+    def catching():
+        result = real()
+
+        def catch(deltas):
+            caught.extend(np.asarray(d) for d in deltas)
+            return result(deltas)
+        return catch
+    with monkeypatch.context() as m:
+        m.setattr(B, "_result", catching)
+        return B.bc(snap, roots) + (caught,)
+
+
+#: name -> (graph, roots, the groups' widths)
+GROUPS = {
+    # four roots of 64, 64, 33 and 54 levels in one group
+    "depths-differ": ("a_path", [0, 63, 31, 10], [4]),
+    "a-root-alone": ("two_components", [11], [1]),
+    # the isolated vertex (one level) beside a root of four
+    "a-root-of-one-level": ("two_components", [11, 1], [2]),
+    "a-repeated-root": ("two_components", [5, 5], [2]),
+    "three-roots": ("two_components", [1, 5, 5], [2, 1]),
+    "five-roots": ("two_components", [1, 5, 10, 4, 11], [4, 1]),
+    "nine-roots": ("graph500_s10", None, [8, 1]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GROUPS))
+def test_every_root_of_a_group_is_the_root_alone(case, name, monkeypatch):
+    """Each root's delta is BIT-equal to the same root run alone (the
+    CPU: XLA's road, where a gathered ``[8, Q, w]`` is summed over its
+    leading axis in the order ``[8, Q]`` is; the same scan; a root that
+    is done pulls zeros), and the scores are inside the rule."""
+    graph, roots, widths = GROUPS[name]
+    n, src, dst, drawn, ref = case(graph)
+    if roots is None:
+        roots = drawn + [int(r) for r in np.random.default_rng(47)
+                         .choice(n, 5, replace=False)]
+    snap = snap_mod.from_arrays(n, src, dst)
+    scores, levels, reached, deltas = deltas_of(snap, roots, monkeypatch)
+    assert [d.shape for d in deltas] == [(w, n) for w in widths]
+    assert [vg.shared_width(n, left) for left in (1, 2, 3, 5, 9, 16)] \
+        == [1, 2, 2, 4, 8, 8]
+    rows = np.concatenate(deltas)
+    for i, root in enumerate(roots):
+        _, (lv,), (seen,), (alone,) = deltas_of(snap, [root], monkeypatch)
+        assert rows[i].tobytes() == alone[0].tobytes(), (name, root)
+        assert (levels[i], reached[i]) == (lv, seen)
+        exact = brandes(n, src, dst, root)
+        assert np.allclose(rows[i], exact, rtol=1e-5, atol=0)
+        assert np.array_equal(rows[i] == 0, exact == 0)
+    # the scores inside the rule against the plain reference, which
+    # works a root no pool holds when it meets it
+    assert ref.check({"sources": roots}, scores) == {"scores": 0}
 
 
 # -- the shared pull-sum ------------------------------------------------------
@@ -245,6 +322,15 @@ def test_pagerank_pull_is_bit_equal_to_the_parents(bench, impl,
     now = pp.pull_step()(*args, impl=impl, seg_max=im["seg_max"])
     then = _parents_pull_step()(*args, impl=impl, seg_max=im["seg_max"])
     assert np.asarray(now).tobytes() == np.asarray(then).tobytes()
+    # one table is the program it was: the same operations traced, not
+    # only the same bits (ISSUE 47: the width is the table's shape)
+    import inspect
+
+    import jax
+    traced = [str(jax.make_jaxpr(functools.partial(
+        inspect.unwrap(step), impl=impl, seg_max=im["seg_max"]))(*args))
+        for step in (pp.pull_step(), _parents_pull_step())]
+    assert traced[0] == traced[1]
     assert float(now.sum()) > 0
     # and the levels' sums are the same function of another table
     table = jnp.asarray(rank)
@@ -257,17 +343,19 @@ def test_pagerank_pull_is_bit_equal_to_the_parents(bench, impl,
 
 def test_the_kernel_and_xla_agree_on_a_job(case, monkeypatch):
     """The Pallas gather (in its interpreter) serves the levels' tables
-    as XLA's gather does: the same depths, the same scores."""
+    as XLA's gather does, four roots side by side in one table: the
+    same depths, the same scores."""
     monkeypatch.setattr(vg, "colsum_vmem", functools.partial(
         vg.colsum_vmem, interpret=True))
     n, src, dst, roots, ref = case("graph500_s10")
     snap = snap_mod.from_arrays(n, src, dst)
-    by_xla = B.bc(snap, roots[:2])
-    monkeypatch.setattr(vg, "gather_impl", lambda _n: "vmem")
-    by_kernel = B.bc(snap, roots[:2])
+    by_xla = B.bc(snap, roots)
+    monkeypatch.setattr(vg, "gather_impl", lambda _n, _width=1: "vmem")
+    by_kernel = B.bc(snap, roots)                 # one group of four
     assert by_kernel[1:] == by_xla[1:]
-    assert ref.check({"sources": roots[:2]}, by_kernel[0]) == {"scores": 0}
+    assert ref.check({"sources": roots}, by_kernel[0]) == {"scores": 0}
     assert np.allclose(by_kernel[0], by_xla[0], rtol=1e-5, atol=0)
+    assert np.array_equal(by_kernel[0] == 0, by_xla[0] == 0)
 
 
 # -- the served path ---------------------------------------------------------
@@ -334,7 +422,8 @@ def test_one_altered_score_reads_one_mismatch(case, monkeypatch):
         result = real()
 
         def one_more(deltas):
-            return result((deltas[0].at[at].multiply(1.01),) + deltas[1:])
+            return result((deltas[0].at[0, at].multiply(1.01),)
+                          + deltas[1:])
         return one_more
     monkeypatch.setattr(B, "_result", altered)
     served = Served(n, src, dst)
@@ -418,48 +507,59 @@ def test_the_jobs_spans_and_counters(bench):
         by_name.setdefault(s.name, []).append(s)
     (run,) = by_name["run"]
     (result,) = by_name["bc.result"]
-    forward, backward = by_name["bc.forward"], by_name["bc.backward"]
-    levels = env["result"]["levels"]
-    assert [s.attrs["root"] for s in forward] == roots[::-1] \
-        == [s.attrs["root"] for s in backward]
-    assert [s.attrs["levels"] for s in forward] == levels
-    assert [s.attrs["reached"] for s in forward] \
-        == env["result"]["reached"]
-    assert [s.attrs["levels"] for s in backward] \
-        == [lv - 2 for lv in levels]
-    leaves = forward + backward + [result]
+    # four roots, one group: a span a phase, its pulls shared
+    (forward,), (backward,) = by_name["bc.forward"], by_name["bc.backward"]
+    levels, reached = env["result"]["levels"], env["result"]["reached"]
+    deep = max(levels)
+    for s in (forward, backward):
+        assert s.attrs["roots"] == roots[::-1] and s.attrs["width"] == 4
+        assert s.attrs["impl"] == "xla" and s.attrs["sync_ms"] >= 0
+        assert "root" not in s.attrs
+    # the scalars the benchmark's readers put in a set stay scalars: the
+    # group's pulls of the phase, the vertices its roots reached, summed
+    assert forward.attrs["levels"] == deep
+    assert forward.attrs["reached"] == sum(reached)
+    assert backward.attrs["levels"] == deep - 2
+    assert forward.attrs["root_levels"] == levels
+    assert forward.attrs["root_reached"] == reached
+    assert backward.attrs["root_levels"] == [lv - 2 for lv in levels]
+    leaves = [forward, backward, result]
     assert all(s.parent_id == run.span_id for s in leaves)
-    ordered = sorted(leaves, key=lambda s: s.t_start)
-    assert [s.name for s in ordered] \
-        == ["bc.forward", "bc.backward"] * 4 + ["bc.result"]
-    assert all(a.t_end <= b.t_start for a, b in zip(ordered, ordered[1:]))
-    assert all(s.attrs["impl"] == "xla" and s.attrs["sync_ms"] >= 0
-               for s in forward + backward)
+    assert forward.t_end <= backward.t_start \
+        and backward.t_end <= result.t_start
     assert result.attrs["bytes"] == 4 * n and result.attrs["roots"] == 4
     assert len(by_name["job.lease"]) == len(by_name["job.admit"]) == 1
-    # every program a kernel span under the phase that dispatched it
+    # every program a kernel span under the phase that dispatched it,
+    # the level programs' with the width they served
     under: dict = {s.span_id: [] for s in leaves}
     for s in by_name["kernel"]:
         under[s.parent_id].append(s.attrs["key"])
-    for f, b, lv in zip(forward, backward, levels):
-        assert under[f.span_id] == ["bc_seed"] + ["bc_forward_level"] * lv
-        assert under[b.span_id] == ["bc_backward_level"] * (lv - 2)
+        if s.attrs["key"].endswith("_level"):
+            assert s.attrs["width"] == 4 and s.attrs["impl"] == "xla"
+    assert under[forward.span_id] == ["bc_seed"] + ["bc_forward_level"] * deep
+    assert under[backward.span_id] == ["bc_backward_level"] * (deep - 2)
     assert under[result.span_id] == ["bc_result"]
-    # a boundary a pull: the round the job stopped at, and its timeline
-    pulls = sum(levels) + sum(lv - 2 for lv in levels)
+    # a boundary a SHARED pull: the round the job stopped at, its timeline
+    pulls = deep + deep - 2
     assert run.attrs["rounds"] == pulls == len(by_name["round"])
     both = 2                                    # jobs counted
+    # a root's levels, as before; beside them the pulls that served them
     assert m.counter("device.bc.levels",
                      labels={"part": "forward"}).count == both * sum(levels)
     assert m.counter("device.bc.levels", labels={"part": "backward"}) \
         .count == both * sum(lv - 2 for lv in levels)
+    assert m.counter("device.bc.pulls", labels={
+        "part": "forward", "width": "4"}).count == both * deep
+    assert m.counter("device.bc.pulls", labels={
+        "part": "backward", "width": "4"}).count == both * (deep - 2)
     assert m.counter_value("device.bc.roots") == both * 4
     assert m.counter("device.xfer.d2h_bytes",
                      labels={"site": "bc.result"}).count == both * 4 * n
     for key in ("bc_forward_level", "bc_backward_level"):
         assert m.counter("device.exec.unstamped",
                          labels={"kernel": key}).count == 0
-    assert "device_bc_levels" in text.replace(".", "_")
+    text = text.replace(".", "_")
+    assert "device_bc_levels" in text and "device_bc_pulls" in text
 
 
 def test_cancel_between_the_phases_and_a_timeout(case, monkeypatch):
@@ -476,7 +576,7 @@ def test_cancel_between_the_phases_and_a_timeout(case, monkeypatch):
         done = sched.submit(JobSpec(kind="bc",
                                     params={"sources_dense": roots}))
         assert done.wait(120) and done.state.value == "done", done.error
-        first = done.result["levels"][0]
+        first = max(done.result["levels"])      # one group of four
         real = B.bc
 
         def cancelling(snap, roots_, **kw):
@@ -506,7 +606,11 @@ def test_admission_reserves_both_images_and_lets_the_work_go(case):
     q_in = pp.pull_columns(snap.indptr_in, n)
     images = snapshot_csr_bytes(snap) + snapshot_pull_bytes(snap)
     work = snapshot_bc_work_bytes(snap)
-    assert work == B.work_bytes(n, q_in) == 4 * n * 21 + 10 * q_in
+    # sixteen roots at this n are groups of eight: a level of eight
+    # roots (seven n-vectors and 16 B a column each) and sixteen deltas
+    assert vg.shared_width(n, B.MAX_ROOTS) == 8
+    assert work == B.work_bytes(n, q_in) \
+        == 8 * (4 * n * 7 + 16 * q_in) + 4 * n * 16
     served = Served(n, src, dst)
     try:
         first = served.job({"kind": "bc", "sources": roots})

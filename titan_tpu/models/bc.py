@@ -30,14 +30,35 @@ level d) until a pull finds nobody; the levels stay stored in ``depth``.
 The backward phase walks them from the deepest to the root's
 neighbours, a level a pull (the table is (1 + delta) / sigma at depth
 d; depth d - 1 takes sigma times its sum). A root of L levels is L
-forward and L - 2 backward pulls. Every array is n wide whatever the
-root and whatever the level, so the two level programs
-(``bc_forward_level``, ``bc_backward_level``) are built once a
-snapshot shape; ``bc_seed`` and ``bc_result`` beside them are a few
-elementwise passes. The level loop lives on the host: the forward phase
-needs one scalar a level (how many joined) and the veto a boundary a
-level, so the host waits for every level's output before it dispatches
-the next (a dispatch of idle device time against a pull of 128 M lanes).
+forward and L - 2 backward levels.
+
+**A job's roots run in groups that share every pull** (PERF.md 6, PR
+47): a pull's price is the index, not the table, so the masked tables
+of w roots stand side by side in one VMEM table
+(``vmem_gather.as_table`` of ``[w, n + 1]``) and one pass over the
+image serves them all. A group is the largest power of two that the
+roots left, the kernel's eight selector rows and the table's cap at
+this ``n`` allow (``vmem_gather.shared_width``: four at graph500-22,
+38.3 MB of 64 MiB; a job of five roots is 4 + 1): what the code
+observes, never a flag, a request field or the environment; a root
+alone is the group of width 1, not a second path. ``depth``, ``sigma`` and ``delta`` are ``[w, n]``;
+level d masks every root's own ``depth == d - 1``; the forward phase
+ends when NO root gained a vertex (w counts read back a level); a root
+that is done has nobody at the level asked for, pulls zeros and stays
+as it is, in both phases, so every root's ``delta`` is what the root
+gives alone (on XLA's road to the bit; under the kernel the MXU sums a
+column's lanes, which stand elsewhere at another width, in another
+order: a last-bit matter). A group whose deepest root has L levels is L
+forward and L - 2 backward PULLS.
+
+Every array is n wide whatever the roots and whatever the level, so the
+two level programs (``bc_forward_level``, ``bc_backward_level``) are
+built once a snapshot shape and group width; ``bc_seed`` and
+``bc_result`` beside them are a few elementwise passes. The level loop
+lives on the host: the forward phase needs the counts a level (who
+joined) and the veto a boundary a level, so the host waits for every
+level's output before it dispatches the next (a dispatch of idle device
+time against a pull of 128 M lanes).
 
 float32 throughout. Every term of both recurrences is non-negative, so
 rounding is all that separates the result from float64's; sigma is
@@ -57,15 +78,23 @@ from titan_tpu.utils.jitcache import dev_scalar, jit_once
 MAX_ROOTS = 16
 
 
+def level_bytes(n: int, q_in: int, width: int) -> int:
+    """Device bytes a level of a group of ``width`` roots works on: the
+    group's depth, sigma and delta, the level's outputs, the masked
+    table as the roots hold it and as the gather reads it (seven
+    n-vectors a root), and the temporaries as wide as the pull image's
+    ``q_in`` columns (the kernel's sums a root, their stack, the scan's
+    passes: 16 bytes a column and root; the chip's compiler counts 14
+    at width 4 and 9.1 at width 1, tests/test_chip_compile.py)."""
+    return width * (4 * n * (3 + 2 + 2) + 16 * q_in)
+
+
 def work_bytes(n: int, q_in: int, roots: int = MAX_ROOTS) -> int:
-    """Device bytes a job works on beside the images: a root's depth,
-    sigma and delta, the outputs of the level in flight, every root's
-    delta kept for the sum (``roots``: admission prices the most a job
-    may name), and a level's temporaries, which are as wide as the pull
-    image's ``q_in`` columns (the column sums and the scan's passes:
-    9.1 bytes a column in the chip's compiler's count,
-    tests/test_chip_compile.py)."""
-    return 4 * n * (3 + 2 + roots) + 10 * q_in
+    """Device bytes a job works on beside the images: a level of its
+    widest group (``level_bytes``) and every root's delta kept for the
+    sum (``roots``: admission prices the most a job may name)."""
+    return level_bytes(n, q_in, vmem_gather.shared_width(n, roots)) \
+        + 4 * n * roots
 
 
 def _seed():
@@ -74,42 +103,53 @@ def _seed():
         import jax.numpy as jnp
 
         @functools.partial(jax.jit, static_argnames=("n_",))
-        def seed(root, n_: int):
-            at = jnp.arange(n_, dtype=jnp.int32) == root
+        def seed(roots, n_: int):
+            at = jnp.arange(n_, dtype=jnp.int32)[None, :] == roots[:, None]
             return (jnp.where(at, 0, -1).astype(jnp.int32),
-                    at.astype(jnp.float32), jnp.zeros(n_, jnp.float32))
+                    at.astype(jnp.float32), jnp.zeros(at.shape, jnp.float32))
         return seed
     return jit_once("bc_seed", build)
 
 
 def _table(values):
-    """``values`` [n] as the pull's table [n + 1]: the sink reads 0."""
+    """``values`` [w, n] as the pull's table [w, n + 1]: the sink reads
+    0."""
     import jax.numpy as jnp
-    return jnp.concatenate([values, jnp.zeros(1, values.dtype)])
+    return jnp.pad(values, ((0, 0), (0, 1)))
 
 
 def forward_level(depth, sigma, d, idx, first, last, has, impl: str,
-                  seg_max: int):
-    """Level ``d`` of the forward phase: ``(depth, sigma, joined)``."""
+                  seg_max: int, width: int):
+    """Level ``d`` of a group's forward phase: ``(depth, sigma,
+    joined)``, [w, n], [w, n] and the vertices each root gained [w].
+    Every root's ``depth == d - 1`` masks its own sigma and ONE pull
+    serves them all; a root that gained nobody at an earlier level has
+    nobody at ``d - 1``, pulls zeros and stays as it is. ``width`` is
+    the arrays' leading axis (static, so that the ``kernel`` span
+    carries it)."""
     import jax.numpy as jnp
 
     from titan_tpu.models.pagerank_pull import pull_sum
 
+    assert depth.shape[0] == width, (depth.shape, width)
     table = _table(jnp.where(depth == d - 1, sigma, 0.0))
     paths = pull_sum(table, idx, first, last, has, impl, seg_max)
     new = (depth < 0) & (paths > 0)
     return (jnp.where(new, d, depth), jnp.where(new, paths, sigma),
-            new.sum(dtype=jnp.int32))
+            new.sum(axis=1, dtype=jnp.int32))
 
 
 def backward_level(depth, sigma, delta, d, idx, first, last, has,
-                   impl: str, seg_max: int):
-    """Level ``d`` of the backward phase: ``delta`` with depth ``d - 1``
-    filled in from depth ``d``."""
+                   impl: str, seg_max: int, width: int):
+    """Level ``d`` of a group's backward phase: ``delta`` [w, n] with
+    each root's depth ``d - 1`` filled in from its depth ``d``. A root
+    whose levels end before ``d`` pulls zeros: its deepest level takes
+    sigma times 0, the 0.0 it was seeded with."""
     import jax.numpy as jnp
 
     from titan_tpu.models.pagerank_pull import pull_sum
 
+    assert depth.shape[0] == width, (depth.shape, width)
     table = _table(jnp.where(depth == d,
                              (1.0 + delta) / jnp.maximum(sigma, 1.0), 0.0))
     share = pull_sum(table, idx, first, last, has, impl, seg_max)
@@ -119,7 +159,7 @@ def backward_level(depth, sigma, delta, d, idx, first, last, has,
 def _level(key: str, body):
     def build():
         import jax
-        return jax.jit(body, static_argnames=("impl", "seg_max"))
+        return jax.jit(body, static_argnames=("impl", "seg_max", "width"))
     return jit_once(key, build)
 
 
@@ -130,7 +170,10 @@ def _result():
 
         @jax.jit
         def result(deltas):
-            total = functools.reduce(jnp.add, deltas)
+            # a row a root, summed in the order the job named them
+            total = functools.reduce(
+                jnp.add, [rows[r] for rows in deltas
+                          for r in range(rows.shape[0])])
             top = total.max()
             return total / jnp.where(top > 0, top, 1.0)
         return result
@@ -167,14 +210,14 @@ def bc(snap, roots, on_round=None, overlay=None):
     """``(scores float32 [n] on the host, levels, reached)``: the sum
     of the roots' dependencies over its largest entry, and for each
     root the BFS levels that hold a vertex and the vertices it reached.
-    ``roots``: dense indices, one after another.
+    ``roots``: dense indices; they run in groups of ``shared_width``, in
+    the order named, and a group's roots share every pull.
 
     ``on_round(i)``: veto at the i-th level boundary, counted over both
-    phases and all roots (RoundInterrupted): the serving layer's cancel
+    phases and all groups (RoundInterrupted): the serving layer's cancel
     and timeout hook, within one pull of its cause. No checkpoint: a
     retried job starts over, the image still resident."""
     import jax
-    import jax.numpy as jnp
 
     from titan_tpu.models.frontier import RoundInterrupted
     from titan_tpu.models.pagerank_pull import pull_image
@@ -195,9 +238,7 @@ def bc(snap, roots, on_round=None, overlay=None):
                          "is not an undirected graph held in both "
                          "directions")
     im = pull_image(snap)
-    impl = vmem_gather.gather_impl(n)
     image = (im["idx"], im["first"], im["last"], im["has"])
-    statics = {"impl": impl, "seg_max": im["seg_max"]}
     seed = _seed()
     forward = _level("bc_forward_level", forward_level)
     backward = _level("bc_backward_level", backward_level)
@@ -209,39 +250,49 @@ def bc(snap, roots, on_round=None, overlay=None):
         if on_round is not None and not on_round(done):
             raise RoundInterrupted(done)
 
+    roots = [int(r) for r in roots]
     deltas, levels, reached = [], [], []
-    for root in roots:
-        with phase("bc.forward", root=int(root), impl=impl) as ph:
-            depth, sigma, delta = seed(jnp.asarray(root, jnp.int32), n_=n)
-            d, seen = 0, 1
-            while True:
+    while len(levels) < len(roots):
+        width = vmem_gather.shared_width(n, len(roots) - len(levels))
+        group = roots[len(levels):len(levels) + width]
+        impl = vmem_gather.gather_impl(n, width)
+        statics = {"impl": impl, "seg_max": im["seg_max"], "width": width}
+        said = {"roots": group, "width": width, "impl": impl}
+        with phase("bc.forward", **said) as ph:
+            depth, sigma, delta = seed(np.asarray(group, np.int32), n_=n)
+            d, seen, deep = 0, [1] * width, [0] * width
+            while not all(deep):
                 d += 1
                 depth, sigma, joined = forward(depth, sigma, dev_scalar(d),
                                                *image, **statics)
                 with ph.sync():
-                    joined = int(joined)
+                    joined = np.asarray(joined).tolist()
                 boundary()
-                if not joined:
-                    break
-                seen += joined
-            ph.set(levels=d, reached=seen)
-        devprof.count_bc("forward", d)
+                for r, gained in enumerate(joined):
+                    # a root's levels end at its first pull that finds
+                    # nobody; it gains nobody at any later one
+                    seen[r] += gained
+                    if not gained and not deep[r]:
+                        deep[r] = d
+            ph.set(levels=d, reached=sum(seen), root_levels=deep,
+                   root_reached=seen)
+        devprof.count_bc("forward", deep, width)
         # levels 0 .. d - 1 hold a vertex; the deepest has no dependency
         # and the root takes none
-        back = max(d - 2, 0)
-        with phase("bc.backward", root=int(root), impl=impl,
-                   levels=back) as ph:
+        back = [max(lv - 2, 0) for lv in deep]
+        with phase("bc.backward", levels=max(back), root_levels=back,
+                   **said) as ph:
             for k in range(d - 1, 1, -1):
                 delta = backward(depth, sigma, delta, dev_scalar(k),
                                  *image, **statics)
                 with ph.sync():
                     jax.block_until_ready(delta)
                 boundary()
-        devprof.count_bc("backward", back)
+        devprof.count_bc("backward", back, width)
         deltas.append(delta)
-        levels.append(d)
-        reached.append(seen)
-    with phase("bc.result", bytes=4 * n, roots=len(deltas)) as ph:
+        levels += deep
+        reached += seen
+    with phase("bc.result", bytes=4 * n, roots=len(roots)) as ph:
         scores = _result()(tuple(deltas))
         devprof.count_d2h("bc.result", 4 * n)
         with ph.sync():

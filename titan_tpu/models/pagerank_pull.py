@@ -29,7 +29,10 @@ under ``jit_once``):
 
 The gather and the segment sum take any ``[n + 1]`` float32 table
 (:func:`pull_sum`): PageRank's ``rank / deg``, and the level-masked
-tables of ``models/bc.py``.
+tables of ``models/bc.py``, which hands the tables of a group of roots
+at once, ``[w, n + 1]``: ONE pass over the image gathers all w values
+at each index (``vmem_gather.colsum_vmem`` at ``width`` w), the scan
+and the last-column read run over ``[w, q_in]``.
 
 What chooses the gather is what the code can observe — the backend and
 the table's size (``vmem_gather.gather_impl``) — never a flag, an
@@ -109,9 +112,14 @@ def pull_image(snap) -> dict:
     return out
 
 
-def _colsum_xla(idx, table):
-    """XLA's gather over the same image: ``contrib[srcT].sum(0)``."""
-    return table.reshape(-1)[idx.reshape(8, -1)].sum(axis=0)
+def _colsum_xla(idx, table, width: int = 1):
+    """XLA's gather over the same image: ``contrib[srcT].sum(0)``; at
+    ``width`` values an entry, the entries' rows ``[width]`` gathered
+    by the one index and summed, value r's sums in row r."""
+    lanes = idx.reshape(8, -1)
+    if width == 1:
+        return table.reshape(-1)[lanes].sum(axis=0)
+    return table.reshape(-1, width)[lanes].sum(axis=0).T
 
 
 def pull_sum(table, idx, first, last, has, impl: str, seg_max: int):
@@ -119,15 +127,29 @@ def pull_sum(table, idx, first, last, has, impl: str, seg_max: int):
     [n + 1], entry n the sink's 0.0) over its in-neighbours: the column
     sums by the gather ``impl`` names, the segment scan, each vertex's
     last column. Traced inside its caller's program: ``pagerank_pull``
-    hands it ``rank / deg``, ``models/bc.py`` a level's masked table."""
+    hands it ``rank / deg``, ``models/bc.py`` a level's masked tables.
+
+    ``table`` [w, n + 1] (w a power of two up to
+    ``vmem_gather.MAX_WIDTH``) is w tables pulled in ONE pass over the
+    image: ``acc`` [w, n], row r what ``table[r]`` gives alone. The
+    tables stay major throughout (the column sums and the scan are
+    [w, q_in]): w minor would pad every value to a row of lanes."""
     import jax.numpy as jnp
 
     from titan_tpu.ops.segment import seg_scan
 
+    lead = table.shape[:-1]
+    if lead == (1,):
+        # one table is PageRank's program: a [1, q_in] array would fill
+        # one sublane of eight in every pass of the scan
+        return pull_sum(table[0], idx, first, last, has, impl,
+                        seg_max)[None]
     colsum = (vmem_gather.colsum_vmem if impl == "vmem"
-              else _colsum_xla)(idx, vmem_gather.as_table(table))
-    run = seg_scan(colsum, first, "sum", max_len=seg_max)
-    return jnp.where(has, run[last], 0.0)
+              else _colsum_xla)(idx, vmem_gather.as_table(table),
+                                width=lead[0] if lead else 1)
+    run = seg_scan(colsum.reshape(lead + (-1,)), first, "sum",
+                   max_len=seg_max)
+    return jnp.where(has, run[..., last], 0.0)
 
 
 def pull_step():

@@ -495,16 +495,21 @@ def count_lcc(part: str, edges: int, wedges: Optional[int] = None
                                  labels={"part": part}).inc(int(wedges))
 
 
-def count_bc(part: str, levels: int) -> None:
-    """Count one phase of one root of ``models/bc.bc`` run: its pulls
-    by ``part`` (``"forward"``: the levels that hold a vertex, the last
-    pull finds nobody; ``"backward"``: two fewer), and the root once,
-    with its forward phase."""
+def count_bc(part: str, levels, width: int) -> None:
+    """Count one phase of one group of ``models/bc.bc`` run: each
+    root's levels by ``part`` (``levels``, one a root of the group;
+    ``"forward"``: the levels that hold a vertex, the last pull finds
+    nobody; ``"backward"``: two fewer), the pulls the group paid for
+    them, its deepest root's count, by ``part`` and ``width`` (the
+    roots that shared each: levels less pulls are the passes over the
+    image saved), and each root once, with its forward phase."""
     for prof in list(_PROFILERS):
         prof.metrics.counter("device.bc.levels",
-                             labels={"part": part}).inc(int(levels))
+                             labels={"part": part}).inc(int(sum(levels)))
+        prof.metrics.counter("device.bc.pulls", labels={
+            "part": part, "width": str(width)}).inc(int(max(levels)))
         if part == "forward":
-            prof.metrics.counter("device.bc.roots").inc()
+            prof.metrics.counter("device.bc.roots").inc(len(levels))
 
 
 def current() -> Optional["DeviceCostProfiler"]:

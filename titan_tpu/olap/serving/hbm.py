@@ -166,10 +166,11 @@ def snapshot_lcc_work_bytes(snap) -> int:
 
 def snapshot_bc_work_bytes(snap) -> int:
     """Predicted device bytes a ``bc`` job's levels work on beside the
-    forward and pull images (models/bc.work_bytes: a few n-vectors a
-    root and one kept a root, priced at the most roots a job may name,
-    and a level's column-wide temporaries), from ``n`` and the kept
-    ``"in"`` column count."""
+    forward and pull images (models/bc.work_bytes: a level of the
+    widest group of roots that shares a pull at this ``n``, a few
+    n-vectors and the column-wide temporaries a root of it, and a delta
+    kept a root, priced at the most roots a job may name), from ``n``
+    and the kept ``"in"`` column count."""
     from titan_tpu.models import bc
     return bc.work_bytes(snap.n, _pull_columns(snap))
 

@@ -315,8 +315,8 @@ def _run_bc(ctx: RunContext) -> dict:
     except ValueError as e:
         raise ParamError(f"{type(e).__name__}: {e}") from e
     # no checkpoint: a retried job starts over. The phases (bc.forward
-    # and bc.backward a root, bc.result) journal under the job's `run`
-    # span; the readback is counted where it is made
+    # and bc.backward a group of roots, bc.result) journal under the
+    # job's `run` span; the readback is counted where it is made
     # (device.xfer.d2h_bytes{site="bc.result"})
     with ctx.under():
         scores, levels, reached = bc.bc(

@@ -58,19 +58,22 @@ def segment_metadata(indptr: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
 
 def seg_scan(values, flags, combine: str, max_len: Optional[int] = None):
-    """Inclusive segmented scan (Hillis-Steele): ``flags[i]`` marks the first
-    element of a segment; returns per-position running combine within the
-    segment. log₂(E) vectorized passes; everything static-shaped.
-    ``max_len``: the longest segment, where the caller knows it — the
-    passes stop at log₂ of it."""
+    """Inclusive segmented scan (Hillis-Steele) along the LAST axis:
+    ``flags[i]`` marks the first element of a segment; returns
+    per-position running combine within the segment. ``values`` [e], or
+    [..., e] for several arrays over the one ``flags`` [e] (each leading
+    index is scanned as it would be alone). log₂(E) vectorized passes;
+    everything static-shaped. ``max_len``: the longest segment, where
+    the caller knows it — the passes stop at log₂ of it."""
     op = _COMBINE_FN[combine]
     ident = combine_identity(combine, values.dtype)
-    e = values.shape[0]
+    lead, e = values.shape[:-1], values.shape[-1]
     if max_len is not None:
         e = min(e, max_len)
     d = 1
     while d < e:
-        pv = jnp.concatenate([jnp.full((d,), ident, values.dtype), values[:-d]])
+        pv = jnp.concatenate([jnp.full(lead + (d,), ident, values.dtype),
+                              values[..., :-d]], axis=-1)
         pf = jnp.concatenate([jnp.ones((d,), bool), flags[:-d]])
         values = jnp.where(flags, values, op(values, pv))
         flags = flags | pf
