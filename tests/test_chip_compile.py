@@ -286,73 +286,104 @@ def test_cdlp_round_at_graph500_22(spec):
 
 
 def test_lcc_programs_at_graph500_22(spec):
-    """The programs of the served LCC job (ISSUE 42) at graph500-22's
-    shapes (n 2,396,390; 17,447,936 columns; 16,384 hubs: a table of 512
-    words a row; 22.1 M low-low edges padded to 22 chunks; the tail's
-    rows of 128 and its widest and most populous classes: CPU counts,
-    PR 42): new to this compiler are ``lax.population_count``, a gather
-    of 512-word rows and the AND-reduce over them. The gathers stay
-    gathers (a row a lane, not a dynamic-slice a word), a dispatch's
-    temporaries stay inside what admission reserves for them, and none
-    builds for over a minute (build times printed)."""
+    """The programs of the served LCC job (ISSUE 42; the pass's own
+    lanes and the sorted credits ISSUE 48) at graph500-22's shapes (n
+    2,396,390; 16,384 asked hubs: a table of 512 words a row; the pass's
+    5,252,096 columns, every edge with a hub at an end once; 22.2 M
+    low-low edges padded to 22 chunks; the finish's sort of 88.2 M
+    counts by the vertex each names; the tail's rows of 128 and its
+    widest and most populous classes: CPU counts, PR 48, the same under
+    two relabellings): ``lax.population_count``, a gather of 512-word
+    rows and the AND-reduce over them. The gathers stay gathers (a row
+    a lane, not a dynamic-slice a word), a dispatch's temporaries stay
+    inside what admission reserves for them, and none builds for over a
+    minute (build times printed)."""
     import time
 
     from titan_tpu.models import lcc as L
     from titan_tpu.ops.vmem_gather import padded_columns
 
     q = padded_columns(Q22)
+    columns, low_low, hubs = 5_252_096, 22 * L.COL_CHUNK, 16_273
+    chunk = L.pass_chunk(columns)
+    passes = len(L._starts(columns, chunk))
+    assert (chunk, passes) == (875_520, 6)
     words = L.hub_words(L.HUBS)
     table = spec((N22 + 2, words), jnp.uint32)
-    lanes = spec((8, q), jnp.int32)
     at = spec((), jnp.int32)
-    rows = spec((2_028_000, L.TAIL_ROW), jnp.int32)
-    blocks = [((858_112, 8), 1024), ((63_488, 128), 64), ((1_008, 192), 42)]
+    rows = spec((2_031_616, L.TAIL_ROW), jnp.int32)
+    blocks = [((856_064, 8), 1024), ((59_840, 96), 85), ((1_344, 192), 42)]
     credits = tuple(
         (spec(shape, jnp.int32), spec(shape, jnp.int32))
         for (b, d), _per in blocks for shape in ((b, d), (b,)))
+    image = {"first": spec((columns,), jnp.bool_),
+             "owners": spec((hubs,), jnp.int32),
+             "last": spec((hubs,), jnp.int32),
+             "idx8": spec((8, columns), jnp.int32),
+             "ll": spec((2, low_low), jnp.int32),
+             "credit_last": spec((N22,), jnp.int32),
+             "hub_ids": spec((hubs,), jnp.int32),
+             "deg": spec((N22,), jnp.int32)}
     programs = {
         "lcc_pass": lambda: _compile(
-            L._pass(), table, lanes, spec((q,), jnp.int32),
-            spec((8, q), jnp.bool_), at, chunk=L.PASS_CHUNK,
+            L._pass(), table, image["idx8"], spec((columns,), jnp.int32),
+            spec((8, columns), jnp.bool_), at, chunk=chunk,
             tile=L.PASS_TILE),
         "lcc_colsum": lambda: _compile(
-            L._colsum(), table, spec((2, 22 * L.COL_CHUNK), jnp.int32),
-            at, chunk=L.COL_CHUNK, tile=L.COL_TILE),
+            L._colsum(), table, image["ll"], at, chunk=L.COL_CHUNK,
+            tile=L.COL_TILE),
         "lcc_finish": lambda: _compile(
             L._finish(),
-            (spec((L.PASS_CHUNK,), jnp.int32),) * 17, spec((q,), jnp.bool_),
-            spec((N22,), jnp.int32), spec((N22,), jnp.bool_),
-            spec((L.HUBS,), jnp.int32),
-            (spec((32 * words,), jnp.int32),) * 22,
-            spec((N22,), jnp.int32), credits, seg_max=20_413,
-            trim=17 * L.PASS_CHUNK - q),
+            (spec((chunk,), jnp.int32),) * passes,
+            (spec((8, chunk), jnp.int32),) * passes,
+            (spec((L.COL_CHUNK,), jnp.int32),) * 22,
+            (spec((32 * words,), jnp.int32),) * 22, credits, image,
+            seg_max=20_413, credit_max=1_048,
+            trim=passes * chunk - columns),
     }
     for (b, d), per in blocks:
         programs[f"lcc_tail d={d}"] = functools.partial(
             _compile, L._tail(), rows, spec((b, d), jnp.int32),
             spec((b, d), jnp.int32), per=per)
     work = L.work_bytes(N22, q, L.HUBS)
+    texts, temps = {}, {}
     for name, build in programs.items():
         t0 = time.time()
         program = build()
         took = time.time() - t0
         m = program.memory_analysis()
+        temps[name] = m.temp_size_in_bytes
         print(f"{name}: built in {took:.1f} s, temporaries "
-              f"{m.temp_size_in_bytes >> 20} MiB")
+              f"{temps[name] >> 20} MiB")
         assert took < 60, (name, took)
-        assert m.temp_size_in_bytes + m.output_size_in_bytes < work, name
-        text = program.as_text()
-        if name != "lcc_finish":
-            assert " gather(" in text, name
-    text = programs["lcc_pass"]().as_text()
+        assert temps[name] + m.output_size_in_bytes < work, name
+        texts[name] = program.as_text()
+        assert name == "lcc_finish" or " gather(" in texts[name], name
+    text = texts["lcc_pass"]
     assert "u32[8192,512]" in text          # a tile's rows, whole
     assert "popcnt" in text or "population" in text
     assert "cummax" not in text
-    # what admission prices holds what the build read on the chip (its
-    # `lcc.image` span's bytes, PR 42), with under a fifth to spare
+    assert "popcnt" in texts["lcc_colsum"] \
+        or "population" in texts["lcc_colsum"]
+    # the finish sorts the counts once, both operands in one sort
+    entries = 8 * columns + 2 * low_low
+    assert f"s32[{entries}]" in texts["lcc_finish"] \
+        and " sort(" in texts["lcc_finish"]
+    # what the finish holds (its arguments from the programs before it
+    # and its temporaries) stays inside the 20 bytes a named count of
+    # ``work_bytes``, priced at the pull image's columns
+    handed = 4 * (9 * passes * chunk + low_low)
+    assert temps["lcc_finish"] + handed < 20 * entries < 20 * 8 * q
+    # what admission prices holds what the build reads (CPU count, PR
+    # 48: the image's arrays at graph500-22), with under a fifth to spare
     assert L.table_bytes(N22, L.HUBS) == 4_907_810_816
-    assert 6_707_200_208 < L.image_bytes(N22, q, L.HUBS) \
-        < 1.2 * 6_707_200_208
+    assert 6_748_937_780 < L.image_bytes(N22, q, L.HUBS) \
+        < 1.2 * 6_748_937_780
+    # forward image, LCC image and working set inside the ledger's budget
+    from titan_tpu.olap.serving.hbm import (DEFAULT_BUDGET_BYTES,
+                                            chunked_csr_bytes)
+    assert chunked_csr_bytes(N22, Q22) + L.image_bytes(N22, q, L.HUBS) \
+        + work < DEFAULT_BUDGET_BYTES
 
 
 def test_wcc_propagation_on_the_remainder_at_graph500_24(spec):

@@ -1,7 +1,8 @@
 """LCC (ISSUE 42): LDBC Graphalytics' local clustering coefficient as a
 served job, on the CPU. The program (``models/lcc.py``: the hub bit
-table's pass over PageRank's pull image, the hubs' column sums, the
-compare tail) against the benchmark's plain reference
+table's pass over the job's own lanes, every edge with a hub at an end
+once (ISSUE 48), the hubs' column sums with the low-low edges' counts
+beside them, the compare tail) against the benchmark's plain reference
 (``benchmark/reference/lcc.py``: wedges listed in numpy, nothing of
 ``titan_tpu`` and no table in it) AND against ``set`` intersections a
 vertex at a time, so that the reference is itself checked:
@@ -15,6 +16,7 @@ counters, timeout and cancel between dispatches, what is refused,
 admission of the table and the working set, eviction and rebuild.
 """
 
+import functools
 import importlib.util
 import json
 import os
@@ -29,8 +31,7 @@ from titan_tpu.models import pagerank_pull as pp
 from titan_tpu.olap.api import JobSpec
 from titan_tpu.olap.serving.hbm import (snapshot_csr_bytes,
                                         snapshot_lcc_bytes,
-                                        snapshot_lcc_work_bytes,
-                                        snapshot_pull_bytes)
+                                        snapshot_lcc_work_bytes)
 from titan_tpu.olap.serving.scheduler import JobScheduler
 from titan_tpu.olap.tpu import snapshot as snap_mod
 from titan_tpu.server import GraphServer
@@ -182,28 +183,184 @@ def test_program_reference_and_sets_agree(reference, name, hubs):
         assert len(im["blocks"]) >= 2       # more than one class of d+
 
 
+def image_edges(im, n):
+    """(owner, neighbour) of every lane of the pass's image that holds
+    an edge, in the image's order."""
+    idx8, own = np.asarray(im["idx8"]), np.asarray(im["own"])
+    held = idx8 != n + 1
+    return np.broadcast_to(own, idx8.shape)[held], idx8[held]
+
+
+@pytest.mark.parametrize("hubs", [8, 64, 256])
+@pytest.mark.parametrize("name", sorted(GRAPHS))
+def test_the_image_holds_every_hub_edge_once_at_its_hub(name, hubs):
+    """ISSUE 48: the pass's lanes are the edges with a hub at an end,
+    each ONCE, in the columns of the hub that owns it (of two hubs the
+    higher in (degree, id) order); the low-low edges stand in ``ll``
+    and nowhere else; between them every edge of the graph, once."""
+    n, src, dst = GRAPHS[name]
+    snap = snap_mod.from_arrays(n, src, dst)
+    im = L.lcc_image(snap, hubs)
+    deg = np.bincount(dst, minlength=n)
+    hub = np.zeros(n + 2, bool)
+    hub[np.asarray(im["hub_ids"])] = True
+    ow, nb = image_edges(im, n)
+    assert hub[ow].all()
+    both = hub[nb]
+    # of two hubs the owner is the higher in (degree, id)
+    assert ((deg[ow[both]] > deg[nb[both]])
+            | ((deg[ow[both]] == deg[nb[both]])
+               & (ow[both] > nb[both]))).all()
+    lo, hi = np.minimum(ow, nb).astype(np.int64), np.maximum(ow, nb)
+    lanes = lo * n + hi
+    assert len(np.unique(lanes)) == len(lanes) == im["hub_edges"]
+    ll = np.asarray(im["ll"])[:, :im["ll_edges"]].astype(np.int64)
+    assert not hub[ll].any() and (ll[0] < ll[1]).all()
+    low_low = ll[0] * n + ll[1]
+    assert len(np.unique(low_low)) == len(low_low)
+    once = src < dst
+    want = src[once].astype(np.int64) * n + dst[once]
+    assert np.array_equal(np.sort(np.concatenate([lanes, low_low])),
+                          np.sort(want))
+    with_hub = hub[src[once]] | hub[dst[once]]
+    assert np.array_equal(np.sort(lanes), np.sort(want[with_hub]))
+    # an owner's columns are adjacent, ``first`` at each one's start,
+    # ``last`` at its end; the pads (columns and lanes) read row n + 1
+    own = np.asarray(im["own"])
+    held = int((own != n + 1).sum())
+    assert (own[held:] == n + 1).all() and (np.diff(own[:held]) >= 0).all()
+    first = np.asarray(im["first"])
+    assert np.array_equal(
+        np.flatnonzero(first[:held]),
+        np.flatnonzero(np.diff(own[:held], prepend=-1)))
+    assert first[held:].all()
+    assert np.array_equal(own[np.asarray(im["last"])],
+                          np.asarray(im["owners"]))
+    assert im["idx8"].shape[1] % L.PASS_TILE == 0
+    assert np.array_equal(np.asarray(im["hubl"]),
+                          hub[np.asarray(im["idx8"])])
+
+
+def test_the_images_shapes_follow_from_the_degrees_alone():
+    """A relabelling (the benchmark's seed) moves which of two hubs of
+    one degree owns their edge; it moves no shape of the image, and with
+    it no program's: a hub's columns keep room for every such edge."""
+    n, src, dst = GRAPHS["kron10"]
+    shapes = set()
+    for seed in range(4):
+        perm = np.random.default_rng(seed).permutation(n).astype(np.int32)
+        snap = snap_mod.from_arrays(n, perm[src], perm[dst])
+        p = L.plan(snap, 64)
+        lanes = p["lanes"]
+        deg = p["deg"]
+        hub = p["is_hub"]
+        ties = int((hub[snap.src] & hub[snap.dst]
+                    & (deg[snap.src] == deg[snap.dst])).sum()) // 2
+        assert ties > 0             # the rule has something to decide
+        shapes.add((lanes["idx8"].shape, len(lanes["owners"]),
+                    lanes["seg_max"], lanes["edges"], p["credit_max"],
+                    p["ll"].shape, p["rows"].shape,
+                    tuple(b["nbr"].shape for b in p["blocks"])))
+    assert len(shapes) == 1, shapes
+
+
+# hubs 0, 1, 2 (degrees 6, 5, 5: a triangle), the others low; by hand
+BY_EDGE = {
+    "n": 7,
+    # (x, y): c(x, y), the hubs adjacent to both
+    "hub_hub": {(0, 1): 1, (0, 2): 1, (2, 1): 1},    # owner first
+    "hub_low": {(0, 3): 1, (1, 3): 1, (0, 4): 2, (1, 4): 2, (2, 4): 2,
+                (0, 5): 2, (1, 5): 2, (2, 5): 2, (0, 6): 1, (2, 6): 1},
+    "low_low": {(3, 4): 2},
+    "triangles": [8, 7, 6, 3, 5, 3, 1],
+}
+
+
+def test_the_case_table_by_edge_by_hand():
+    """ISSUE 48's table on seven vertices: a low-low edge gives 2 c to
+    both ends, a hub-low edge 2 c to the hub and c to the other, a
+    hub-hub edge c to both; each class's share carried separately."""
+    import jax.numpy as jnp
+
+    e = BY_EDGE
+    pairs = [p for k in ("hub_hub", "hub_low", "low_low") for p in e[k]]
+    n, src, dst = both_ways(e["n"], pairs)
+    snap = snap_mod.from_arrays(n, src, dst)
+    counts, _ = L.lcc(snap, hubs=3)
+    assert counts.tolist() == by_sets(n, src, dst)[0].tolist() \
+        == e["triangles"]
+    im = snap._lcc_csr
+    assert sorted(np.asarray(im["hub_ids"]).tolist()) == [0, 1, 2]
+    columns = im["idx8"].shape[1]
+    cols, lanes = L._pass()(im["table"], im["idx8"], im["own"], im["hubl"],
+                            jnp.int32(0), chunk=columns, tile=L.PASS_TILE)
+    ow, nb = image_edges(im, n)
+    c = np.asarray(lanes)[np.asarray(im["idx8"]) != n + 1]
+    made = dict(zip(zip(ow.tolist(), nb.tolist()), c.tolist()))
+    # every edge with a hub once, at its owner: 2 owns (2, 1), the tie
+    assert made == {**e["hub_hub"], **e["hub_low"]}
+    own_sum = {}
+    for col, x in zip(np.asarray(cols).tolist(),
+                      np.asarray(im["own"]).tolist()):
+        own_sum[x] = own_sum.get(x, 0) + col
+    assert own_sum.pop(n + 1) == 0
+    want = dict.fromkeys((0, 1, 2), 0)
+    for (x, _y), cc in e["hub_hub"].items():
+        want[x] += cc                               # c to the owner
+    for (x, _y), cc in e["hub_low"].items():
+        want[x] += 2 * cc                           # 2 c to the hub
+    assert own_sum == want == {0: 14, 1: 10, 2: 11}
+    sums, ll_counts = L._colsum()(im["table"], im["ll"], jnp.int32(0),
+                                  chunk=im["col_chunk"], tile=L.COL_TILE)
+    assert np.asarray(im["ll"])[:, 0].tolist() == [3, 4]
+    assert np.asarray(ll_counts)[:2].tolist() == [2, 0]
+    # the low-low edge (3, 4) stands inside N(0) and N(1): Q
+    by_hub = dict(zip(np.asarray(im["hub_ids"]).tolist(),
+                      np.asarray(sums)[:3].tolist()))
+    assert by_hub == {0: 1, 1: 1, 2: 0}
+    # 2 A by the table, from the three classes' shares
+    twice_a = [0] * n
+    for (x, y), cc in e["hub_hub"].items():
+        twice_a[x] += cc
+        twice_a[y] += cc
+    for (x, y), cc in e["hub_low"].items():
+        twice_a[x] += 2 * cc
+        twice_a[y] += cc
+    for (x, y), cc in e["low_low"].items():
+        twice_a[x] += 2 * cc
+        twice_a[y] += 2 * cc
+    assert twice_a == [14, 12, 12, 6, 10, 6, 2]
+    assert [a // 2 + by_hub.get(v, 0) for v, a in enumerate(twice_a)] \
+        == e["triangles"]                           # no low triangle
+
+
 def test_each_part_carries_its_class(reference):
     """The parts one at a time, each against the triangles of its class
-    counted by sets: the pass (twice A, summed), the hubs' column sums,
-    the tail's centres."""
+    counted by sets: an edge's c is the triangles on it whose third
+    vertex is a hub, so the pass's hub-hub lanes sum to 3 x the
+    triangles of three hubs, its hub-low lanes to 2 x those of two, the
+    low-low edges' counts (and the hubs' column sums) to those of one;
+    the tail's centres carry the rest."""
     import jax.numpy as jnp
 
     n, src, dst = GRAPHS["kron10"]
     snap = snap_mod.from_arrays(n, src, dst)
     L.lcc(snap, hubs=64)
-    im, pim = snap._lcc_csr, pp.pull_image(snap)
+    im = snap._lcc_csr
     held = classes(n, src, dst, np.asarray(im["hub_ids"]))
-    q = pim["q_in"]
-    cols2 = L._pass()(im["table"], pim["idx"].reshape(8, q), im["own"],
-                      im["hubl"], jnp.int32(0), chunk=q, tile=1024)
-    # A summed over the vertices: a triangle stands once at each vertex
-    # that sees a hub among the other two: 3, 3, 2 for 3, 2, 1 hubs
-    assert int(cols2.sum()) == 2 * (3 * held[3] + 3 * held[2]
-                                    + 2 * held[1])
-    sums = L._colsum()(im["table"], im["ll"], jnp.int32(0),
-                       chunk=im["col_chunk"], tile=L.COL_TILE)
+    columns = im["idx8"].shape[1]
+    cols, lanes = L._pass()(im["table"], im["idx8"], im["own"], im["hubl"],
+                            jnp.int32(0), chunk=columns, tile=1024)
+    lanes, to_hub = np.asarray(lanes), np.asarray(im["hubl"])
+    assert int(lanes[to_hub].sum()) == 3 * held[3]
+    assert int(lanes[~to_hub].sum()) == 2 * held[2]
+    # the owners' share: c at a hub neighbour, 2 c at a low one
+    assert int(cols.sum()) == 3 * held[3] + 2 * 2 * held[2]
+    sums, ll_counts = L._colsum()(im["table"], im["ll"], jnp.int32(0),
+                                  chunk=im["col_chunk"], tile=L.COL_TILE)
     assert im["ll"].shape[1] == im["col_chunk"]
     assert int(sums.sum()) == held[1]       # one hub, once at the hub
+    assert int(ll_counts.sum()) == held[1]  # and once at its low-low edge
     centres = 0
     for blk in im["blocks"]:
         place, centre = L._tail()(im["rows"], blk["nbr"], blk["rows"],
@@ -254,14 +411,15 @@ def test_every_vertex_a_hub_and_no_low_low_edge(reference):
 def test_chunks_that_do_not_divide_the_image(monkeypatch):
     """The last dispatch of the pass and of the table's build is moved
     back to end with the image: its neighbour's columns are neither
-    added to the table twice nor summed twice."""
+    added to the table twice nor summed or credited twice."""
     n, src, dst = GRAPHS["kron11"]
-    q = pp.pull_columns(snap_mod.from_arrays(n, src, dst).indptr_in, n)
-    assert q % 2048 == 1024, q
-    monkeypatch.setattr(L, "PASS_CHUNK", 2048)
+    monkeypatch.setattr(L, "PASS_TILE", 128)
+    monkeypatch.setattr(L, "PASS_CHUNK", 1024)
     monkeypatch.setattr(L, "COL_CHUNK", 2048)
     snap = snap_mod.from_arrays(n, src, dst)
     counts, _ = L.lcc(snap, hubs=64)
+    columns = snap._lcc_csr["idx8"].shape[1]
+    assert columns > 1024 and columns % 1024 == 896, columns
     assert snap._lcc_csr["ll"].shape[1] > 2 * 2048
     assert (counts == by_sets(n, src, dst)[0]).all()
 
@@ -272,16 +430,25 @@ def test_counts_pass_two_to_the_24_exactly():
     import jax.numpy as jnp
 
     big = (1 << 24) + 2
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    pad = 3                                         # n + 1
+    idx8 = np.full((8, 4), pad)
+    idx8[5, 3] = 1                                  # one lane names 1
+    lanes = np.zeros((8, 3), np.int32)
+    lanes[5, 2] = 3                                 # column 3, trimmed by 2
     counts, coeff = L._finish()(
-        (jnp.asarray([big, big, 2], jnp.int32),
-         jnp.asarray([7, 7, 4], jnp.int32)),        # two of it its neighbour's
-        jnp.asarray([True, False, False, True]),
-        jnp.asarray([2, 3], jnp.int32), jnp.asarray([True, True]),
-        jnp.asarray([1], jnp.int32),
-        (jnp.asarray([big], jnp.int32), jnp.asarray([1], jnp.int32)),
-        jnp.asarray([3, 1 << 14], jnp.int32), (), seg_max=3, trim=2)
+        (i32([big, big, 2]), i32([7, 7, 4])),       # two of it its neighbour's
+        (i32(np.zeros((8, 3))), i32(lanes)),
+        (i32([1, 0]),), (i32([big]),), (),
+        {"first": jnp.asarray([True, False, False, True]),
+         "owners": i32([0, 1]), "last": i32([2, 3]), "idx8": i32(idx8),
+         # a low-low edge's count goes twice to either end
+         "ll": i32([[1, pad, pad], [1, pad, pad]]),
+         "credit_last": i32([-1, 2]), "hub_ids": i32([1]),
+         "deg": i32([3, 1 << 14])},
+        seg_max=3, credit_max=3, trim=2)
     # (2^25 + 6) / 2 = 2^24 + 3, odd and above 2^24: no float32 holds it
-    assert counts.tolist() == [(1 << 24) + 3, 2 + big + 1]
+    assert counts.tolist() == [(1 << 24) + 3, (3 + 2 + 2 + 4) // 2 + big]
     assert int(np.float32(int(counts[0]))) != int(counts[0])
     assert coeff.dtype == np.float32 and coeff[0] > 0
     words = jnp.full((256, 3), 0x80000001, jnp.uint32)
@@ -417,10 +584,10 @@ def test_one_altered_count_reads_one_mismatch(reference, few_hubs,
     def altered():
         finish = real()
 
-        def one_more(cols, *rest, **kw):
-            at = int(np.asarray(rest[1])[5])    # vertex 5's last column
-            assert len(cols) == 1
-            return finish((cols[0].at[at].add(2),), *rest, **kw)
+        def one_more(cols, lanes, ll_counts, hub_sums, *rest, **kw):
+            assert len(hub_sums) == 1           # the first hub's sum
+            return finish(cols, lanes, ll_counts,
+                          (hub_sums[0].at[0].add(1),), *rest, **kw)
         return one_more
     monkeypatch.setattr(L, "_finish", altered)
     served = Served(n, src, dst)
@@ -429,13 +596,14 @@ def test_one_altered_count_reads_one_mismatch(reference, few_hubs,
         assert env["status"] == "done", env
         coeff = served.array(env["job"], "lcc")
         counts = served.array(env["job"], "triangle_counts")
+        at = int(np.asarray(served.snap._lcc_csr["hub_ids"])[0])
     finally:
         served.close()
     indptr, indices = _reference("csr").structure(n, src, dst)
     ref = reference.prepare(n, indptr, indices, {}, {})
     assert ref.check({"kind": "lcc"}, coeff) == {"lcc": 1}
     assert (counts != ref.triangles).sum() == 1
-    assert counts[5] == ref.triangles[5] + 1
+    assert counts[at] == ref.triangles[at] + 1
     assert env["result"]["triangles"] == int(ref.triangles.sum() + 1) // 3
 
 
@@ -488,14 +656,15 @@ def test_the_jobs_spans_and_counters(few_hubs):
     assert [s.name for s in ordered] == [
         "lcc.image", "lcc.hub", "lcc.tail", "lcc.result", "lcc.count"]
     assert all(a.t_end <= b.t_start for a, b in zip(ordered, ordered[1:]))
-    q_in = pp.pull_columns(served.snap.indptr_in, n)
+    edges = len(src) // 2                   # undirected, each ANDed once
+    assert im["hub_edges"] + im["ll_edges"] == edges
     assert image.attrs["cache"] == "hit" and image.attrs["hubs"] == 64
     assert image.attrs["bytes"] == im["bytes"]
     (built,) = [s for s in cold if s.name == "lcc.image"]
     assert built.attrs["cache"] == "miss" \
         and built.attrs["bytes"] == im["bytes"]
     assert hub.attrs["level"] == 1 and hub.attrs["hubs"] == 64
-    assert hub.attrs["edges"] == 8 * q_in and hub.attrs["tiles"] == 2
+    assert hub.attrs["edges"] == edges and hub.attrs["tiles"] == 2
     assert tail.attrs["wedges"] == im["wedges"] > 0
     assert tail.attrs["edges"] == im["ll_edges"] == im["tail_edges"]
     assert tail.attrs["tiles"] == len(im["blocks"])
@@ -514,7 +683,7 @@ def test_the_jobs_spans_and_counters(few_hubs):
         == ["lcc_colsum", "lcc_finish", "lcc_flags", "lcc_pass",
             "lcc_place", "lcc_tail"]
     assert m.counter("device.lcc.edges",
-                     labels={"part": "hub"}).count == 2 * 8 * q_in
+                     labels={"part": "hub"}).count == 2 * edges
     assert m.counter("device.lcc.edges",
                      labels={"part": "tail"}).count == 2 * im["ll_edges"]
     assert m.counter("device.lcc.wedges",
@@ -524,7 +693,7 @@ def test_the_jobs_spans_and_counters(few_hubs):
                      labels={"site": "lcc.result"}).count == 2 * 8 * n
     assert m.counter("device.xfer.h2d_bytes",
                      labels={"site": "lcc.image"}).count \
-        == im["bytes"] - 8 * q_in
+        == im["bytes"] - im["idx8"].size
     for key in ("lcc_pass", "lcc_colsum", "lcc_tail", "lcc_finish"):
         assert m.counter("device.exec.unstamped",
                          labels={"kernel": key}).count == 0
@@ -596,7 +765,7 @@ def test_admission_reserves_the_table_and_lets_the_working_set_go(
     n, src, dst = GRAPHS["kron10"]
     snap = snap_mod.from_arrays(n, src, dst)
     q_in = pp.pull_columns(snap.indptr_in, n)
-    images = snapshot_csr_bytes(snap) + snapshot_pull_bytes(snap)
+    images = snapshot_csr_bytes(snap)       # the job reads no pull image
     table = snapshot_lcc_bytes(snap)
     work = snapshot_lcc_work_bytes(snap)
     assert table == L.image_bytes(n, q_in, 64) > L.table_bytes(n, 64) \
@@ -640,8 +809,8 @@ def test_the_table_is_evicted_under_pressure_and_rebuilt(few_hubs):
     next job builds it again."""
     n, src, dst = GRAPHS["kron10"]
     snap = snap_mod.from_arrays(n, src, dst)
-    need = snapshot_csr_bytes(snap) + snapshot_pull_bytes(snap) \
-        + snapshot_lcc_bytes(snap) + snapshot_lcc_work_bytes(snap)
+    need = snapshot_csr_bytes(snap) + snapshot_lcc_bytes(snap) \
+        + snapshot_lcc_work_bytes(snap)
     served = Served(n, src, dst, hbm_budget_bytes=need + 1000)
     try:
         ledger = served.sched.ledger

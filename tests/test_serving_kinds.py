@@ -30,7 +30,8 @@ from titan_tpu.olap.tpu import snapshot as snap_mod
 from titan_tpu.server import GraphServer
 from titan_tpu.utils.metrics import MetricManager
 
-#: the rows as they stood before the table (PR 44): the names a ledger
+#: the rows as they stood before the table (PR 44; lcc reads no pull
+#: image since PR 48): the names a ledger
 #: key is made of, in the order admission reserves them (None: the
 #: forward image, under ``id(snap)`` alone; the last of cdlp's, lcc's
 #: and bc's is the working set), and whether the lease folds the overlay
@@ -40,7 +41,7 @@ LEDGER = {
     "pagerank": [None, "pagerank-pull"],
     "wcc": [None],
     "cdlp": [None, "cdlp-image", "cdlp-work"],
-    "lcc": [None, "pagerank-pull", "lcc-image", "lcc-work"],
+    "lcc": [None, "lcc-image", "lcc-work"],
     "bc": [None, "pagerank-pull", "bc-work"],
     "dense": [None],
 }

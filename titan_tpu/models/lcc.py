@@ -1,6 +1,7 @@
 """Local clustering coefficient (LDBC Graphalytics' LCC, specification
 v1.0) of an undirected snapshot: exact triangle counts from a hub bit
-table, the first job here whose work is wedges and not edge slots.
+table, the first job here whose work is wedges and not edge slots. It
+reads no other kind's image.
 
     N(v)   = the neighbours of v, v itself never
     LCC(v) = 2 T(v) / (d(v) (d(v) - 1))   if d(v) >= 2, else 0
@@ -11,13 +12,15 @@ rest are low. ``R`` uint32
 ``[n + 2, HUBS / 32]``: bit h of row x set where x is adjacent to hub h
 (rows n and n + 1, the sink's and the pad's, are zero). For an edge
 (x, y), ``c(x, y) = popcount(R[x] & R[y])`` is the triangles on that
-edge whose third vertex is a hub. Three parts, every count int32:
+edge whose third vertex is a hub. Three programs and a finish, every
+count int32:
 
-* **the pass** (``lcc_pass``), over the lanes (owner x, neighbour y) of
-  PageRank's pull image, a row where a rank was: per vertex
-  ``A(x) = 1/2 * sum of c over x's hub neighbours + sum of c over x's
-  low neighbours``, the triangles through x that hold a hub among their
-  OTHER two vertices. The case table (x any vertex; y, z the others):
+* **the pass** (``lcc_pass``) and **the column sums** (``lcc_colsum``)
+  make every edge's ``c`` ONCE between them, and the finish credits it
+  to both ends. Per vertex ``A(x) = 1/2 * sum of c over x's hub
+  neighbours + sum of c over x's low neighbours``, the triangles
+  through x that hold a hub among their OTHER two vertices. The case
+  table (x any vertex; y, z the others):
 
       y, z          seen from x as                         counted
       hub, hub      c(x, y) holds z AND c(x, z) holds y    2 x 1/2 = 1
@@ -25,11 +28,26 @@ edge whose third vertex is a hub. Three parts, every count int32:
                     c(x, y) holds only hubs, so not z
       low, low      in no c                                0 (below)
 
-  The program carries 2 A: each lane's count times 2 - hub(y), summed a
-  column, then ``ops/segment.seg_scan`` a vertex.
-* **the column sums** (``lcc_colsum``): a hub x's low-low triangles
-  ``Q(x)`` = the low-low edges (y, z) inside N(x) = the column sums,
-  over every low-low edge once, of the bits of ``R[y] & R[z]``.
+  so an edge's c goes into 2 A by its ends' classes:
+
+      edge (x, y)   made by                    to x    to y
+      low, low      the column sums            2 c     2 c
+      hub, low      the pass, at x's lane      2 c     c
+      hub, hub      the pass, at the owner's   c       c
+
+  The pass runs over an image of its own (:func:`_hub_lanes`): every
+  edge with a hub at an end once, a lane of its OWNER's columns (the
+  hub; of two hubs the higher in (degree, id) order), 8 neighbours a
+  column, a 2 KB row of ``R`` gathered a lane: out come the column's
+  sum weighted for the owner (2 c at a low neighbour, c at a hub) and
+  the lanes' c themselves for the neighbours.
+  The column sums: a hub x's low-low triangles ``Q(x)`` = the low-low
+  edges (y, z) inside N(x) = the column sums, over every low-low edge
+  once, of the bits of ``R[y] & R[z]``, whose popcount is that edge's
+  c. The finish (``lcc_finish``) sorts the counts by the vertex each
+  names (names the image knows, so it also knows where a vertex's run
+  ends), sums along the runs (``ops/segment.seg_scan``) and the owners'
+  columns along theirs, and halves.
 * **the tail** (``lcc_tail``): ``T_ll``, the triangles of the graph the
   low vertices induce, whose degrees the hubs' leaving has cut (under
   1,005 at graph500-22, 154 higher neighbours at most). Oriented by its
@@ -48,13 +66,13 @@ Exact: no sampling, no cut-off. int32 end to end: a count is bounded by
 the edges (64.15 M at graph500-22) and passes 2^24, so nothing sums in
 float32 but the coefficient itself, made last.
 
-The table, the lanes' hub flags, the low-low edges and the tail's blocks
-are made once a snapshot epoch, on the host, and sent (:func:`lcc_image`,
-kept on the snapshot as ``_lcc_csr``, dropped with the other layouts;
-the table a slab at a time: ``_send_table``). The snapshot has to
-be a simple symmetric graph, as an undirected data set loads: a
-neighbour counted twice is a triangle counted twice, so a self-loop or
-a doubled edge is refused at the build.
+The table, the pass's lanes and their hub flags, the low-low edges and
+the tail's blocks are made once a snapshot epoch, on the host, and sent
+(:func:`lcc_image`, kept on the snapshot as ``_lcc_csr``, dropped with
+the other layouts; the table a slab at a time: ``_send_table``). The
+snapshot has to be a simple symmetric graph, as an undirected data set
+loads: a neighbour counted twice is a triangle counted twice, so a
+self-loop or a doubled edge is refused at the build.
 """
 
 from __future__ import annotations
@@ -69,7 +87,7 @@ from titan_tpu.utils.jitcache import dev_scalar, jit_once
 #: (PERF.md 5, PR 42: the readings at 8,192 and 32,768 beside it)
 HUBS = 16384
 #: columns a tile of the pass (8 x 1,024 rows gathered: 16 MB at 512
-#: words a row) and a dispatch of it
+#: words a row) and the most a dispatch of it (:func:`pass_chunk`)
 PASS_TILE = 1024
 PASS_CHUNK = 1 << 20
 #: rows of the table the host makes and sends at a time (256 MB at 512
@@ -105,27 +123,37 @@ def table_bytes(n: int, hubs: int) -> int:
 def image_bytes(n: int, q_in: int, hubs: int) -> int:
     """Device bytes admission holds for what :func:`lcc_image` keeps
     resident, from ``n``, the pull image's columns and the hub count
-    alone: the table, a column's owner and a lane's hub flag (12 bytes
-    a column), the tail's rows (a piece a vertex and one more a
-    ``TAIL_ROW`` of the low graph's edges, which are under 4 a column),
-    the low-low edges (under 32 bytes a column) and the tail's blocks,
-    which the degree sequence pads and no admission can know before the
-    build: priced at 32 bytes a column (20 at graph500-22: PERF.md 4,
-    PR 42). 7.76 GB at graph500-22, where the build reads 6.71. The
-    build is held to it."""
+    alone: the table; the pass's lanes and the low-low edges, which hold
+    every edge once between them (a low-low pair is 8 bytes; a lane, its
+    flag and its eighth of a column's owner and start under 6, twice
+    where two hubs of one degree both keep room for it: 12 bytes an
+    edge, and a column of the pull image holds 4 edges) and a part-filled
+    column an owner; where a vertex's credits end (4 bytes); the tail's
+    rows (a piece a vertex and one more a ``TAIL_ROW`` of the low graph's
+    edges, which are under 4 a column) and its blocks, which the degree
+    sequence pads and no admission can know before the build: priced at
+    32 bytes a column (20 at graph500-22: PERF.md 4, PR 42). 7.84 GB at
+    graph500-22, where the build reads 6.75. The build is held to it."""
+    hubs = min(hubs, max(n, 1))
     rows = 4 * TAIL_ROW * (n + 1) + 16 * q_in
-    return (table_bytes(n, hubs) + 12 * q_in + rows + 32 * q_in
-            + 32 * q_in + (16 << 20))
+    lanes = 48 * q_in + 45 * (hubs + PASS_TILE)
+    return (table_bytes(n, hubs) + lanes + 4 * n + rows + 32 * q_in
+            + (16 << 20))
 
 
 def work_bytes(n: int, q_in: int, hubs: int) -> int:
-    """Device bytes a job works on beside the images: a tile's gathered
+    """Device bytes a job works on beside the image: a tile's gathered
     rows and their ANDs (the pass's eight a column, the column sums' two
-    an edge), the tail's rows and compares, the column sums of the pass
-    and the finish's scans over them, and the vertex-wide results."""
+    an edge), the tail's rows and compares, and what the finish sorts: a
+    count and the vertex it names, a lane's once and a low-low edge's
+    twice (2 an edge at most, so 8 a column of the pull image), 20 bytes
+    each: 4 as the programs leave them, 16 for the sort's two operands
+    and what it returns, which the scan's passes then reuse (the
+    compiler's count at graph500-22 is 17.7 bytes each of the 88.2 M
+    that are there: tests/test_chip_compile.py)."""
     w = hub_words(min(hubs, max(n, 1)))
     tiles = 4 * (8 * PASS_TILE + 2 * COL_TILE) * w * 4 + (256 << 20)
-    return tiles + 8 * 4 * q_in + 6 * 4 * (n + 2)
+    return tiles + 20 * 8 * q_in + 6 * 4 * (n + 2)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -140,6 +168,17 @@ def _starts(total: int, chunk: int) -> list:
     if starts[-1] + chunk < total:
         starts.append(total - chunk)
     return starts
+
+
+def pass_chunk(columns: int) -> int:
+    """Columns a dispatch of the pass over ``columns`` (whole tiles):
+    the fewest dispatches of at most ``PASS_CHUNK``, all of one size, so
+    that the last, moved back to end with the image (:func:`_starts`),
+    repeats under a tile a dispatch of its neighbour's columns and not
+    most of a chunk (graph500-22's 5,252,096 columns are 5.009 chunks
+    of 2^20: six dispatches of 875,520)."""
+    dispatches = -(-columns // PASS_CHUNK)
+    return _round_up(-(-columns // dispatches), PASS_TILE)
 
 
 def _assert_simple(snap) -> None:
@@ -174,7 +213,7 @@ def _assert_simple(snap) -> None:
 
 
 def _flags():
-    """``lcc_flags``: which lanes of the pull image read a hub."""
+    """``lcc_flags``: which lanes of the pass's image read a hub."""
     def build():
         import jax
 
@@ -223,11 +262,53 @@ def _send_table(n: int, words: int, x, h):
     return r
 
 
-def plan(snap, hubs: int, q: int) -> dict:
+def _hub_lanes(n: int, src, dst, deg, hs, hd) -> dict:
+    """The pass's image: every edge with a hub at an end ONCE, at the end
+    that owns it: the hub, of two hubs the higher in the (degree, id)
+    order (its place among the hubs, ``hs`` / ``hd`` of an edge's ends,
+    -1 at a low one, rises with it). Columns of 8 neighbours an owner,
+    the owners rising (``dst`` rises, so an owner's neighbours are
+    adjacent). Which of two hubs of one degree owns their edge follows
+    from the ids, so a hub's columns have ROOM for every such edge: the
+    shapes, and the bounds on a segment's length, follow from the
+    degrees alone. ``got`` [n]: the lanes that name each vertex as the
+    neighbour; ``most`` [n]: the most it could be named in."""
+    mine = hd > hs
+    nbr, ow = src[mine], dst[mine]
+    tie = np.flatnonzero((hs >= 0) & (hd >= 0))
+    tie = tie[deg[src[tie]] == deg[dst[tie]]]
+    won = hd[tie] > hs[tie]
+    owned = np.bincount(ow, minlength=n)
+    room = owned + np.bincount(dst[tie[~won]], minlength=n)
+    owners = np.flatnonzero(room).astype(np.int32)
+    cols = -(-room[owners] // 8)
+    colstart = np.cumsum(cols) - cols
+    held = int(cols.sum())
+    columns = _round_up(max(held, 1), PASS_TILE)
+    idx = np.full(8 * columns, n + 1, np.int32)
+    start = np.cumsum(owned) - owned
+    for v, c0 in zip(owners.tolist(), (8 * colstart).tolist()):
+        idx[c0:c0 + owned[v]] = nbr[start[v]:start[v] + owned[v]]
+    own = np.full(columns, n + 1, np.int32)
+    own[:held] = np.repeat(owners, cols)
+    first = np.ones(columns, bool)              # the pad columns too
+    first[:held] = False
+    first[colstart] = True
+    got = np.bincount(nbr, minlength=n)
+    return {"idx8": np.ascontiguousarray(idx.reshape(columns, 8).T),
+            "own": own, "first": first, "owners": owners,
+            "last": (colstart + cols - 1).astype(np.int32),
+            "seg_max": int(cols.max()) if len(cols) else 1,
+            "edges": int(len(ow)), "got": got,
+            "most": got + np.bincount(dst[tie[won]], minlength=n)}
+
+
+def plan(snap, hubs: int) -> dict:
     """Host arrays of everything :func:`lcc_image` puts on the device:
     the hubs and the (vertex, hub) adjacencies their bit table is made
-    of, each column's owner in the pull image's ``q`` columns, the
-    low-low edges once each, and the tail's rows and blocks."""
+    of, the pass's lanes (:func:`_hub_lanes`), the low-low edges once
+    each, where each vertex's credits end once the finish has sorted
+    them, and the tail's rows and blocks."""
     n = snap.n
     src, dst = snap.src, snap.dst
     deg = np.diff(snap.indptr_in[:n + 1]).astype(np.int64)
@@ -245,23 +326,29 @@ def plan(snap, hubs: int, q: int) -> dict:
     hubs = len(hub_ids)
     hub_of = np.full(n + 2, -1, np.int32)
     hub_of[hub_ids] = np.arange(hubs, dtype=np.int32)
-    to_hub = hub_of[src] >= 0
-    at = np.flatnonzero(to_hub)
-    hub_pairs = dst[at], hub_of[src[at]]    # (row, hub), the rows rising
+    hs, hd = hub_of[src], hub_of[dst]       # an edge's ends among the hubs
+    at = np.flatnonzero(hs >= 0)
+    hub_pairs = dst[at], hs[at]             # (row, hub), the rows rising
     del at
-    # the pull image holds the vertices' columns one vertex after another
-    degc = -(-deg // 8)
-    own = np.full(q, n + 1, np.int32)
-    own[:int(degc.sum())] = np.repeat(np.arange(n, dtype=np.int32), degc)
-    low = ~to_hub & (hub_of[dst] < 0)
+    lanes = _hub_lanes(n, src, dst, deg, hs, hd)
+    low = (hs < 0) & (hd < 0)
+    del hs, hd
     s2, d2 = src[low], dst[low]         # the low graph, both directions
     once = s2 < d2
     ll = np.stack([s2[once], d2[once]])
+    # the finish sorts an edge's credits by the vertex they name (a
+    # lane's neighbour, a low-low edge's two ends; the pads' n + 1 goes
+    # last): where each vertex's run ends, and the longest run's bound
+    low_deg = np.bincount(d2, minlength=n)
+    named = lanes.pop("got") + low_deg
+    credit_last = np.where(named > 0, np.cumsum(named) - 1,
+                           -1).astype(np.int32)
+    credit_max = max(int((lanes.pop("most") + low_deg).max()), 1) \
+        if n else 1
     # the tail: the low graph oriented by its own (degree, id) rank,
     # centre -> the neighbours that rank higher
     rank = np.empty(n, np.int32)
-    rank[np.argsort(np.bincount(d2, minlength=n), kind="stable")] = \
-        np.arange(n, dtype=np.int32)
+    rank[np.argsort(low_deg, kind="stable")] = np.arange(n, dtype=np.int32)
     up = rank[s2] > rank[d2]
     nb, ce = s2[up], d2[up]             # ce rising: a centre's are adjacent
     dplus = np.bincount(ce, minlength=n).astype(np.int64)
@@ -321,7 +408,8 @@ def plan(snap, hubs: int, q: int) -> dict:
                        "rows": block(empty, first[mine],
                                      more_row[theirs])})
     return {"n": n, "hubs": hubs, "words": words, "hub_ids": hub_ids,
-            "hub_pairs": hub_pairs, "own": own,
+            "hub_pairs": hub_pairs, "lanes": lanes,
+            "credit_last": credit_last, "credit_max": credit_max,
             "is_hub": hub_of >= 0,
             "deg": deg.astype(np.int32), "ll": ll, "rows": rows,
             "blocks": blocks, "wedges": wedges,
@@ -346,13 +434,14 @@ def _pass():
                 # a row's popcount is at most its 32 x words bits
                 c = jax.lax.population_count(both).astype(
                     jnp.int32).sum(-1)
-                return (c * jnp.where(h, 1, 2)).sum(0)
+                return (c * jnp.where(h, 1, 2)).sum(0), c
 
-            out = jax.lax.map(one, (
+            out, lanes = jax.lax.map(one, (
                 ix.reshape(8, t, tile).transpose(1, 0, 2),
                 ow.reshape(t, tile),
                 hb.reshape(8, t, tile).transpose(1, 0, 2)))
-            return out.reshape(chunk)
+            return out.reshape(chunk), \
+                lanes.transpose(1, 0, 2).reshape(8, chunk)
         return hub_pass
     return jit_once("lcc_pass", build)
 
@@ -381,6 +470,7 @@ def bit_column_sums(words):
 def _colsum():
     def build():
         import jax
+        import jax.numpy as jnp
 
         @functools.partial(jax.jit, static_argnames=("chunk", "tile"))
         def colsum(r, ll, e0, chunk: int, tile: int):
@@ -388,12 +478,14 @@ def _colsum():
             ab = ab.reshape(2, chunk // tile, tile).transpose(1, 0, 2)
 
             def one(acc, yz):
-                return acc + bit_column_sums(r[yz[0]] & r[yz[1]]), None
+                both = r[yz[0]] & r[yz[1]]
+                c = jax.lax.population_count(both).astype(
+                    jnp.int32).sum(-1)
+                return acc + bit_column_sums(both), c
 
-            import jax.numpy as jnp
-            acc, _ = jax.lax.scan(
+            acc, counts = jax.lax.scan(
                 one, jnp.zeros((r.shape[1], 32), jnp.int32), ab)
-            return acc.reshape(-1)
+            return acc.reshape(-1), counts.reshape(chunk)
         return colsum
     return jit_once("lcc_colsum", build)
 
@@ -435,25 +527,49 @@ def _finish():
 
         from titan_tpu.ops.segment import seg_scan
 
-        @functools.partial(jax.jit, static_argnames=("seg_max", "trim"))
-        def finish(cols, first, last, has, hub_ids, hub_sums, deg,
-                   credits, seg_max: int, trim: int = 0):
-            """``cols``: the pass's dispatches, the last one less its
-            first ``trim`` columns (its neighbour's); ``hub_sums``: the
-            column sums' dispatches; ``credits``: (vertex ids, counts)
-            of the tail."""
-            n = deg.shape[0]
+        @functools.partial(jax.jit, static_argnames=(
+            "seg_max", "credit_max", "trim"))
+        def finish(cols, lanes, ll_counts, hub_sums, credits, im,
+                   seg_max: int, credit_max: int, trim: int = 0):
+            """``cols``, ``lanes``: the pass's dispatches, the last one
+            less its first ``trim`` columns (its neighbour's);
+            ``ll_counts``, ``hub_sums``: the column sums' dispatches;
+            ``credits``: (vertex ids, counts) of the tail; ``im``: the
+            image's ``first``, ``owners``, ``last``, ``idx8``, ``ll``,
+            ``credit_last``, ``hub_ids``, ``deg``."""
+            n = im["deg"].shape[0]
             cols2 = jnp.concatenate(cols[:-1] + (cols[-1][trim:],))
-            run = seg_scan(cols2, first, "sum", max_len=seg_max)
+            lanes2 = jnp.concatenate(
+                lanes[:-1] + (lanes[-1][:, trim:],), axis=1)
+            # a low-low edge counts twice at either end
+            twice = 2 * jnp.concatenate(
+                ll_counts + (jnp.zeros(0, jnp.int32),))
+            ll = im["ll"][:, :twice.shape[0]]
+            # 2 A: a lane's count to its neighbour, a low-low edge's to
+            # both ends, sorted by the vertex named (the names are the
+            # image's, so where a vertex's run ends is known), summed
+            # along the runs; the owners' columns along theirs
+            named, counts = jax.lax.sort(
+                (jnp.concatenate([im["idx8"].reshape(-1), ll[0], ll[1]]),
+                 jnp.concatenate([lanes2.reshape(-1), twice, twice])),
+                num_keys=1, is_stable=False)
+            starts = jnp.concatenate(
+                [jnp.ones(1, bool), named[1:] != named[:-1]])
+            run = seg_scan(counts, starts, "sum", max_len=credit_max)
+            last = im["credit_last"]
+            t = jnp.where(last >= 0, run[jnp.maximum(last, 0)], 0)
+            own = seg_scan(cols2, im["first"], "sum", max_len=seg_max)
             # 2 A is even: the hub neighbours' share counts ordered pairs
-            t = jnp.where(has, run[last], 0) // 2
-            hubs = hub_ids.shape[0]
-            t = t.at[hub_ids].add(sum((part[:hubs] for part in hub_sums),
-                                      jnp.zeros(hubs, jnp.int32)))
+            t = t.at[im["owners"]].add(own[im["last"]]) // 2
+            hubs = im["hub_ids"].shape[0]
+            t = t.at[im["hub_ids"]].add(
+                sum((part[:hubs] for part in hub_sums),
+                    jnp.zeros(hubs, jnp.int32)))
             t = jnp.concatenate([t, jnp.zeros(2, jnp.int32)])
-            for ids, counts in credits:
-                t = t.at[ids.reshape(-1)].add(counts.reshape(-1))
+            for ids, tail_counts in credits:
+                t = t.at[ids.reshape(-1)].add(tail_counts.reshape(-1))
             t = t[:n]
+            deg = im["deg"]
             d = deg.astype(jnp.float32)
             # float32 from here on: 2 T passes 2^24 and rounds to 6e-8,
             # the rule (1e-4) is three orders above
@@ -464,6 +580,11 @@ def _finish():
     return jit_once("lcc_finish", build)
 
 
+#: what of the image ``lcc_finish`` reads
+_FINISH_READS = ("first", "owners", "last", "idx8", "ll", "credit_last",
+                 "hub_ids", "deg")
+
+
 def lcc_image(snap, hubs: int | None = None) -> dict:
     """(cached on the snapshot as ``_lcc_csr``, dropped with the other
     layouts): everything of the module docstring a job reads and no job
@@ -472,7 +593,7 @@ def lcc_image(snap, hubs: int | None = None) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from titan_tpu.models.pagerank_pull import pull_image
+    from titan_tpu.models.pagerank_pull import pull_columns
     from titan_tpu.obs import devprof
     from titan_tpu.obs.tracing import phase
 
@@ -483,45 +604,48 @@ def lcc_image(snap, hubs: int | None = None) -> dict:
                    bytes=cached["bytes"], cache="hit"):
             return cached
     with phase("lcc.image", hubs=min(hubs, snap.n), cache="miss") as ph:
-        im = pull_image(snap)
-        n, q = im["n"], im["q_in"]
-        p = plan(snap, hubs, q)
+        n = snap.n
+        p = plan(snap, hubs)
+        lanes = p["lanes"]
         pad = _round_up(max(p["ll"].shape[1], 1), COL_TILE)
         col_chunk = min(COL_CHUNK, pad)
         ll = np.full((2, _round_up(pad, col_chunk)), n + 1, np.int32)
         ll[:, :p["ll"].shape[1]] = p["ll"]
-        host = [p["own"], p["is_hub"], ll, p["rows"], p["hub_ids"],
-                p["deg"]]
+        sent = {"idx8": lanes["idx8"], "own": lanes["own"],
+                "first": lanes["first"], "owners": lanes["owners"],
+                "last": lanes["last"], "ll": ll,
+                "credit_last": p["credit_last"], "rows": p["rows"],
+                "hub_ids": p["hub_ids"], "deg": p["deg"]}
+        host = list(sent.values()) + [p["is_hub"]]
         for blk in p["blocks"]:
             host += [blk["centres"], blk["nbr"], blk["mid"], blk["rows"]]
+        flags = lanes["idx8"].size              # a byte a lane, made there
         nbytes = sum(int(a.nbytes) for a in host) \
-            + 4 * (n + 2) * p["words"] + 8 * q
-        priced = image_bytes(n, q, hubs)
+            + 4 * (n + 2) * p["words"] + flags
+        priced = image_bytes(n, pull_columns(snap.indptr_in, n), hubs)
         if nbytes > priced:
             raise RuntimeError(
                 f"lcc: the image is {nbytes} bytes, admission priced "
-                f"{priced} (models/lcc.image_bytes): the low graph "
-                f"holds {p['tail_edges']} edges in "
+                f"{priced} (models/lcc.image_bytes): the pass holds "
+                f"{lanes['idx8'].shape[1]} columns, the low graph "
+                f"{p['tail_edges']} edges in "
                 f"{sum(b['nbr'].size for b in p['blocks'])} slots")
-        devprof.count_h2d("lcc.image", nbytes - 8 * q)  # flags: made there
-        out = {
+        devprof.count_h2d("lcc.image", nbytes - flags)
+        out = {k: jnp.asarray(v) for k, v in sent.items()}
+        out.update({
             "asked": hubs, "hubs": p["hubs"], "n": n, "bytes": nbytes,
             "table": _send_table(n, p["words"], *p["hub_pairs"]),
-            "own": jnp.asarray(p["own"]),
-            "hubl": _flags()(jnp.asarray(p["is_hub"]),
-                             im["idx"].reshape(8, q)),
-            "ll": jnp.asarray(ll), "ll_edges": int(p["ll"].shape[1]),
-            "col_chunk": col_chunk,
-            "rows": jnp.asarray(p["rows"]),
-            "hub_ids": jnp.asarray(p["hub_ids"]),
-            "deg": jnp.asarray(p["deg"]),
+            "hubl": _flags()(jnp.asarray(p["is_hub"]), out["idx8"]),
+            "seg_max": lanes["seg_max"], "credit_max": p["credit_max"],
+            "hub_edges": lanes["edges"],
+            "ll_edges": int(p["ll"].shape[1]), "col_chunk": col_chunk,
             "blocks": [{"centres": jnp.asarray(b["centres"]),
                         "nbr": jnp.asarray(b["nbr"]),
                         "mid": jnp.asarray(b["mid"]),
                         "rows": jnp.asarray(b["rows"]),
                         "per": b["per"]} for b in p["blocks"]],
             "wedges": p["wedges"], "tail_edges": p["tail_edges"],
-        }
+        })
         jax.block_until_ready(out["table"])
         ph.set(bytes=nbytes, hubs=p["hubs"])
     snap._lcc_csr = out
@@ -542,7 +666,6 @@ def lcc(snap, on_round=None, overlay=None, hubs: int | None = None):
     import jax
 
     from titan_tpu.models.frontier import RoundInterrupted
-    from titan_tpu.models.pagerank_pull import pull_image
     from titan_tpu.obs import devprof
     from titan_tpu.obs.tracing import phase
 
@@ -551,13 +674,11 @@ def lcc(snap, on_round=None, overlay=None, hubs: int | None = None):
     if ov is not None and not ov.empty:
         raise RuntimeError(
             "lcc on a live overlay: compact the overlay first "
-            "(LiveGraphPlane.compact_if_dirty); the pull image has no "
+            "(LiveGraphPlane.compact_if_dirty); the image has no "
             "overlay seam")
-    pim = pull_image(snap)
     im = lcc_image(snap, hubs)
-    n, q = pim["n"], pim["q_in"]
-    idx8 = pim["idx"].reshape(8, q)
-    r = im["table"]
+    n, r = im["n"], im["table"]
+    columns = im["idx8"].shape[1]
     done = 0
     behind = None
 
@@ -572,25 +693,31 @@ def lcc(snap, on_round=None, overlay=None, hubs: int | None = None):
         if on_round is not None and not on_round(done):
             raise RoundInterrupted(done)
 
-    chunk = min(PASS_CHUNK, q)
-    starts = _starts(q, chunk)
+    chunk = pass_chunk(columns)
+    starts = _starts(columns, chunk)
     col_chunk = im["col_chunk"]
     col_starts = range(0, im["ll"].shape[1], col_chunk) \
         if im["ll_edges"] else ()
-    with phase("lcc.hub", level=1, hubs=im["hubs"],
-               edges=8 * q, tiles=len(starts) + len(col_starts)):
+    # an edge's AND is made once: at its hub's lane, or as a low-low edge
+    edges = im["hub_edges"] + im["ll_edges"]
+    with phase("lcc.hub", level=1, hubs=im["hubs"], edges=edges,
+               tiles=len(starts) + len(col_starts)):
         hub_pass, colsum = _pass(), _colsum()
-        cols, hub_sums = [], []
+        cols, lanes, hub_sums, ll_counts = [], [], [], []
         for c0 in starts:
-            cols.append(hub_pass(r, idx8, im["own"], im["hubl"],
+            col, lane = hub_pass(r, im["idx8"], im["own"], im["hubl"],
                                  dev_scalar(c0), chunk=chunk,
-                                 tile=min(PASS_TILE, chunk)))
-            step(cols[-1])
+                                 tile=PASS_TILE)
+            cols.append(col)
+            lanes.append(lane)
+            step(col)
         for e0 in col_starts:
-            hub_sums.append(colsum(r, im["ll"], dev_scalar(e0),
-                                   chunk=col_chunk, tile=COL_TILE))
-            step(hub_sums[-1])
-    devprof.count_lcc("hub", 8 * q)
+            sums, counts = colsum(r, im["ll"], dev_scalar(e0),
+                                  chunk=col_chunk, tile=COL_TILE)
+            hub_sums.append(sums)
+            ll_counts.append(counts)
+            step(sums)
+    devprof.count_lcc("hub", edges)
     credits = []
     with phase("lcc.tail", wedges=im["wedges"], edges=im["tail_edges"],
                tiles=len(im["blocks"])):
@@ -604,9 +731,9 @@ def lcc(snap, on_round=None, overlay=None, hubs: int | None = None):
     devprof.count_lcc("tail", im["tail_edges"], wedges=im["wedges"])
     with phase("lcc.result", bytes=8 * n) as ph:
         counts, coeff = _finish()(
-            tuple(cols), pim["first"], pim["last"], pim["has"],
-            im["hub_ids"], tuple(hub_sums), im["deg"], tuple(credits),
-            seg_max=pim["seg_max"],
+            tuple(cols), tuple(lanes), tuple(ll_counts), tuple(hub_sums),
+            tuple(credits), {k: im[k] for k in _FINISH_READS},
+            seg_max=im["seg_max"], credit_max=im["credit_max"],
             trim=starts[-2] + chunk - starts[-1] if len(starts) > 1
             else 0)
         devprof.count_d2h("lcc.result", 8 * n)

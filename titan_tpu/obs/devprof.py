@@ -482,9 +482,10 @@ def count_cdlp_round(impl: str, classes: tuple, keys: int) -> None:
 def count_lcc(part: str, edges: int, wedges: Optional[int] = None
               ) -> None:
     """Count one part of ``models/lcc.lcc`` dispatched: ``"hub"`` (a
-    level's pass and column sums; ``edges``: the lanes it read, pad
-    lanes included) or ``"tail"`` (``edges``: the low graph's, once
-    each; ``wedges``: the oriented wedges its compares decide)."""
+    level's pass and column sums; ``edges``: the ANDs of two rows they
+    made, one an undirected edge) or ``"tail"`` (``edges``: the low
+    graph's, once each; ``wedges``: the oriented wedges its compares
+    decide)."""
     for prof in list(_PROFILERS):
         prof.metrics.counter("device.lcc.edges",
                              labels={"part": part}).inc(int(edges))

@@ -150,9 +150,10 @@ def snapshot_cdlp_bytes(snap) -> int:
 
 def snapshot_lcc_bytes(snap) -> int:
     """Predicted device bytes of what an ``lcc`` job keeps resident
-    beside the pull image (models/lcc.image_bytes: the hub bit table and
-    what is built with it), from ``n``, the kept ``"in"`` column count
-    and the module's hub count: no pass over a degree array."""
+    beside the forward image (models/lcc.image_bytes: the hub bit table
+    and what is built with it; the job reads no pull image, whose
+    columns only bound its edges), from ``n``, the kept ``"in"`` column
+    count and the module's hub count: no pass over a degree array."""
     from titan_tpu.models import lcc
     return lcc.image_bytes(snap.n, _pull_columns(snap), lcc.HUBS)
 

@@ -396,7 +396,7 @@ KINDS: dict[str, Kind] = {row.name: row for row in (
          work=Work("cdlp-work", hbm.snapshot_cdlp_bytes),
          compacted=True, checkpoint=Checkpoint("it", ("labels",))),
     Kind("lcc", _run_lcc,
-         images=(FORWARD, PULL,
+         images=(FORWARD,
                  Image("lcc-image", "in", hbm.snapshot_lcc_bytes,
                        "_lcc_csr")),
          work=Work("lcc-work", hbm.snapshot_lcc_work_bytes),
