@@ -103,6 +103,43 @@ def ref_bfs(indptr, indices, source: int) -> np.ndarray:
     return dist
 
 
+def edge_keys(indptr, indices) -> np.ndarray:
+    """Every edge u -> v of the structure as the key ``u * n + v``,
+    sorted: what ``bfs_tree_faults`` searches."""
+    n = len(indptr) - 1
+    keys = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr)) * n \
+        + indices
+    keys.sort()
+    return keys
+
+
+def bfs_tree_faults(keys, ref: np.ndarray, source: int, parent) -> str:
+    """GAP's rule for a BFS tree (``BFSVerifier``) against the serial
+    depths ``ref`` (-1 = unreached): the source its own parent, a parent
+    exactly where the serial BFS reaches, every other reached vertex's
+    parent a neighbour one level nearer. '' where ``parent`` (dense ids,
+    -1 = none) keeps it, else what it breaks."""
+    n = len(ref)
+    parent = np.asarray(parent, np.int64)
+    if parent.shape != (n,):
+        return f"shape {parent.shape}"
+    if parent[source] != source:
+        return f"parent[source] = {parent[source]}"
+    there = ref >= 0
+    if ((parent >= 0) != there).any():
+        return f"{int(((parent >= 0) != there).sum())} parents off the tree"
+    there[source] = False
+    v = np.flatnonzero(there)
+    p = parent[v]
+    if (p >= n).any() or (ref[np.minimum(p, n - 1)] != ref[v] - 1).any():
+        return "a parent that is not one level nearer the source"
+    want = p * n + v
+    at = np.minimum(np.searchsorted(keys, want), len(keys) - 1)
+    if (keys[at] != want).any():
+        return f"{int((keys[at] != want).sum())} parents that are no neighbour"
+    return ""
+
+
 def ref_hops_out_count(indptr, indices, start: int, hops: int) -> int:
     """|out^hops(start)| as a SET (the lane's dedup'd count)."""
     cur = np.array([start], np.int64)
@@ -388,6 +425,9 @@ def phase_served(ctx: dict, seed: int) -> None:
                           replace=False)
     targets = rng.choice(n, min(N_TARGETS, n), replace=False)
     bfs_ref = [ref_bfs(sym_ptr, sym_idx, int(s)) for s in bfs_src]
+    # what a served BFS tree is held to (ISSUE 50): the edges, and each
+    # job's source beside its serial depths
+    tree = (edge_keys(sym_ptr, sym_idx), [int(s) for s in bfs_src])
     trav_ref = [ref_hops_out_count(out_ptr, out_idx, int(s), 2)
                 for s in trav_src]
     wcc_ref = ref_components(sym_ptr, sym_idx, n)
@@ -406,7 +446,8 @@ def phase_served(ctx: dict, seed: int) -> None:
         client = Client(srv.host, srv.port)
         tgt_ids = [int(vids[t]) for t in targets]
         bfs_jobs = [{"kind": "bfs", "source": int(vids[s]),
-                     "targets": tgt_ids} for s in bfs_src]
+                     "targets": tgt_ids, "parents": True}
+                    for s in bfs_src]
 
         # -- pass 1: 8 BFS queued on the paused scheduler, then wcc,
         #    pagerank and sssp; started together (serve_smoke.sh shape)
@@ -427,10 +468,10 @@ def phase_served(ctx: dict, seed: int) -> None:
         sched.start()
         finals = [client.wait_done(j) for j in ids]
         pass1 = w1.close()
-        _check_bfs(finals, sched, bfs_ref, targets, tgt_ids, inf)
+        _check_bfs(finals, sched, bfs_ref, targets, tgt_ids, inf, tree)
         log(f"phase 3 bfs: {N_BFS} jobs fused batch_k={finals[0]['batch_k']}"
             f", reached/levels/{len(tgt_ids)} target distances/full dist "
-            f"exact; reached={[f['result']['reached'] for f in finals]} "
+            f"exact, all {n} parents of each a valid BFS tree; reached={[f['result']['reached'] for f in finals]} "
             f"levels={[f['result']['levels'] for f in finals]}")
 
         body = client.wait_done(others["wcc"])
@@ -521,7 +562,7 @@ def phase_served(ctx: dict, seed: int) -> None:
         w2 = prof.window()
         finals2 = _bfs_cohort(client, sched, bfs_jobs)
         pass2 = w2.close()
-        _check_bfs(finals2, sched, bfs_ref, targets, tgt_ids, inf)
+        _check_bfs(finals2, sched, bfs_ref, targets, tgt_ids, inf, tree)
         log(f"phase 4 second pass: pass1 compiles={pass1['compiles']} "
             f"wall_s={pass1['wall_s']:.2f} (cohort + wcc/pagerank/sssp "
             f"queued behind it); pass2 compiles={pass2['compiles']} "
@@ -530,6 +571,28 @@ def phase_served(ctx: dict, seed: int) -> None:
         check(pass2["compiles"] == 0,
               f"second BFS pass compiled {pass2['compiles']} kernels: "
               f"{prof.compile_log()[-pass2['compiles']:]}")
+        # -- a lone BFS job from a drawn source (ISSUE 49): K = 1, so
+        #    the job builds every program a source can meet ahead of its
+        #    run (bfs.build); a second one, of another source, builds
+        #    nothing. Depths, targets, reached and levels held against the
+        #    reference as the cohort's are
+        lone = []
+        for i, (job, ref) in enumerate(zip(bfs_jobs[:2], bfs_ref[:2])):
+            w = prof.window()
+            body = client.wait_done(client.req("/jobs", job)["job"])
+            lone.append((body, w.close()))
+            _check_bfs([body], sched, [ref], targets, tgt_ids, inf,
+                       (tree[0], tree[1][i:]), batch_k=1)
+        check(lone[1][1]["compiles"] == 0,
+              f"the second lone BFS job compiled "
+              f"{lone[1][1]['compiles']} kernels: "
+              f"{prof.compile_log()[-lone[1][1]['compiles']:]}")
+        log(f"phase 4 lone bfs: 2 jobs of drawn sources, batch_k=1, all "
+            f"{n} depths exact and parents valid; lone1 compiles="
+            f"{lone[0][1]['compiles']} "
+            f"exec_ms={lone[0][0].get('exec_ms')} (the build-ahead), "
+            f"lone2 compiles={lone[1][1]['compiles']} "
+            f"exec_ms={lone[1][0].get('exec_ms')}")
         tot = prof.stats()
         log(f"phase 4 device cost: calls={tot['calls']} compiles="
             f"{tot['compiles']} compile_s={tot['compile_s']:.1f} "
@@ -544,12 +607,14 @@ def phase_served(ctx: dict, seed: int) -> None:
         prof.uninstall()
 
 
-def _check_bfs(finals, sched, bfs_ref, targets, tgt_ids, inf) -> None:
-    for body, ref in zip(finals, bfs_ref):
+def _check_bfs(finals, sched, bfs_ref, targets, tgt_ids, inf, tree,
+               batch_k: int = N_BFS) -> None:
+    keys, sources = tree
+    for body, ref, source in zip(finals, bfs_ref, sources):
         res = body["result"]
-        check(body["batch_k"] == N_BFS,
+        check(body["batch_k"] == batch_k,
               f"bfs job {body['job']} ran in a batch of "
-              f"{body['batch_k']}, not {N_BFS}")
+              f"{body['batch_k']}, not {batch_k}")
         check(res["reached"] == int((ref >= 0).sum()),
               f"bfs reached {res['reached']} != {int((ref >= 0).sum())}")
         # the served level count includes the source's own level 0
@@ -562,6 +627,9 @@ def _check_bfs(finals, sched, bfs_ref, targets, tgt_ids, inf) -> None:
         dist = np.asarray(sched.get(body["job"]).result["dist"])
         full = np.where(dist < inf, dist, -1)
         check(np.array_equal(full, ref), "bfs full distance array differs")
+        faults = bfs_tree_faults(
+            keys, ref, source, sched.get(body["job"]).result["parent"])
+        check(not faults, f"bfs job {body['job']}: parent array: {faults}")
 
 
 def phase_sharded(scale: int, seed: int) -> None:

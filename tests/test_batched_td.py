@@ -190,6 +190,10 @@ def test_rungs_below_n_columns_list_the_same_pairs(graph, mode,
     want = bh.frontier_bfs_batched(dict(g, directed=True), srcs, **kw)
     top = bh._next_pow2(len(srcs) * g["q_total"])
     monkeypatch.setattr(bh, "_td_caps", lambda _g: (n // 8, n // 2, top))
+    # every level pushes, the heavy ones too (a BFS level's rule weighs
+    # its pull at one chunk round since ISSUE 50 and would pull them):
+    # the listing's two forms are what is under test
+    monkeypatch.setattr(bh, "TD_BU_COST", 0)
     got, attrs = sweep_attrs(
         lambda: bh.frontier_bfs_batched(g, srcs, **kw))
     caps = {a["p_cap"] for a in attrs if a["dir"] == "td"}
@@ -229,6 +233,14 @@ def test_the_rule():
     edge = bh.BU_CHUNK_ROUNDS * few // bh.TD_BU_COST
     assert bh._td_cap(g, 16, edge, few, False) is not None
     assert bh._td_cap(g, 16, edge + 1, few, False) is None
+    # a BFS level's pull runs its chunk rounds a dispatch each, and the
+    # first decides nearly every candidate: one round to weigh, not
+    # eight (ISSUE 50); a hop's level fuses all eight as ever
+    assert bh._bu_fuse(False, 0) == 1
+    assert bh._bu_fuse(True, 0) == bh.BU_CHUNK_ROUNDS
+    edge1 = few // bh.TD_BU_COST
+    assert bh._td_cap(g, 16, edge1, few, False, 1) is not None
+    assert bh._td_cap(g, 16, edge1 + 1, few, False, 1) is None
     # the ladder follows the layout: the top rung is the largest power
     # of two at or below half its chunk columns
     assert bh._td_caps({"n": 64, "q_total": 300}) == (2, 8, 16, 32, 64, 128)

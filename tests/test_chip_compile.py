@@ -182,6 +182,65 @@ def test_pagerank_job_at_graph500_22(spec):
     assert win.memory_analysis().temp_size_in_bytes < 4 << 30
 
 
+@pytest.mark.parametrize("parents", [False, True],
+                         ids=["depths", "tree"])
+@pytest.mark.parametrize("prog, caps", [
+    ("bstep", (1 << 22,)), ("bex", (1 << 12, 1 << 16)),
+    ("bex", (1 << 22, 1 << 25))],
+    ids=["bstep-top", "bex-lowest", "bex-top"])
+def test_batched_pull_ladder_at_graph500_22(spec, prog, caps, parents):
+    """The cell kron-s22.bfs-tree-c2's pulled level at K = 1 (ISSUE 49,
+    ISSUE 50): the ladder's top ``bstep`` rung, and the lowest and the
+    top ``bex`` pair (the sweep over 2^25 chunk columns is the largest
+    program of the set: 8 x 2^25 parents gathered at once), each as a
+    depth-only job takes it and with the parent plane beside ``dist``
+    (the lane that hit reduced to an id, a second scatter). The lists
+    come in at the ladder's top width and the programs read their first
+    ``c_cap``."""
+    from titan_tpu.models.bfs_hybrid import (_batched_bu, _batched_exhaust,
+                                             _bu_caps, _fbits_bytes)
+
+    c_caps, ex_pairs = _bu_caps({"n": N22, "q_total": Q22})
+    assert caps in ex_pairs or caps[0] in c_caps
+    top = c_caps[-1]
+    state = spec((1, N22 + 1), jnp.int32)
+    args = ((state, state) if parents else state,
+            spec((1, _fbits_bytes(N22)), jnp.uint8),
+            spec((top,), jnp.int32), spec((top,), jnp.int32),
+            spec((2,), jnp.int32), spec((), jnp.int32),
+            spec((8, Q22), jnp.int32), spec((N22 + 1,), jnp.int32),
+            spec((N22 + 1,), jnp.int32), spec((1,), jnp.uint8))
+    if prog == "bstep":
+        got = _compile(_batched_bu(), *args, c_cap=caps[0], n_=N22,
+                       fuse=8, masked=False, expand=False)
+    else:
+        got = _compile(_batched_exhaust(), *args, c_cap=caps[0],
+                       p_cap=caps[1], n_=N22, masked=False, expand=False)
+    # beside the 0.59 GB image and a few n-vectors: well inside 16 GB
+    assert got.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_batched_push_with_parents_at_graph500_22(spec):
+    """The cell's pushed levels with the parent plane: the lowest rung
+    (the one that dedups its targets and hands the next level its list)
+    and the top one, and the seed that makes both planes."""
+    from titan_tpu.models.bfs_hybrid import (_batched_seed, _batched_td,
+                                             _td_caps, _td_lists)
+
+    caps = _td_caps({"q_total": Q22})
+    state = spec((1, N22 + 1), jnp.int32)
+    for p_cap in (caps[0], caps[-1]):
+        _compile(_batched_td(), (state, state),
+                 spec((caps[-1],), jnp.int32), spec((caps[-1],), jnp.int32),
+                 spec((), jnp.int32), spec((1,), jnp.bool_),
+                 spec((), jnp.int32), spec((), jnp.int32),
+                 spec((8, Q22), jnp.int32), spec((N22 + 1,), jnp.int32),
+                 spec((N22 + 1,), jnp.int32), p_cap=p_cap, n_=N22,
+                 expand=False, lists=_td_lists(p_cap, N22))
+    _compile(_batched_seed(), spec((1,), jnp.int32), spec((), jnp.int32),
+             n_=N22, cap=caps[-1], expand=False, parents=True)
+
+
 def test_pagerank_pull_at_graph500_22(spec):
     """The served job's iteration since ISSUE 35: the Pallas gather
     with the 9.6 MB table in VMEM and 8,192 indices a grid step in

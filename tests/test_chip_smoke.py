@@ -34,7 +34,25 @@ def cache_config():
         jax.config.update(k, v)
 
 
-def test_smoke_passes_under_cpu_rehearsal(monkeypatch, capsys, cache_config):
+@pytest.fixture(params=["alone", "behind a leaked profiler"])
+def leaked_profiler(request):
+    """An earlier test of the worker (a scheduler it never closed) left
+    its profiler installed over the process-wide default registry, the
+    one the smoke's own profiler counts on: an event still counts once
+    (``devprof._installed``; the smoke read its WCC job's endgame twice
+    behind such a leak until ISSUE 49)."""
+    from titan_tpu.obs import devprof
+
+    if request.param == "alone":
+        yield
+        return
+    leaked = devprof.DeviceCostProfiler().install()
+    yield
+    leaked.uninstall()
+
+
+def test_smoke_passes_under_cpu_rehearsal(monkeypatch, capsys, cache_config,
+                                          leaked_profiler):
     monkeypatch.setattr(chip_smoke, "require_tpu", lambda dev: None)
     assert chip_smoke.main(["--scale", "10"]) == 0
     lines = capsys.readouterr().out.strip().splitlines()
@@ -48,6 +66,8 @@ def test_smoke_passes_under_cpu_rehearsal(monkeypatch, capsys, cache_config):
                   "phase 3 traverse", "phase 4 second pass"):
         assert phase in body
     assert "pass2 compiles=0" in body and "batch_k=8" in body
+    # a lone BFS job builds ahead; the one behind it builds nothing
+    assert "phase 4 lone bfs: 2 jobs" in body and "lone2 compiles=0" in body
     # the WCC job's peel says what served its endgame's frontier test
     assert "end's frontier test impl=xla x1" in body
 

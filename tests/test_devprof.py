@@ -284,3 +284,40 @@ def test_two_profilers_fan_out(snap, clean_profilers):
         a.uninstall()
         b.uninstall()
     assert a.stats()["calls"] == b.stats()["calls"] > 0
+
+
+def test_two_profilers_over_one_registry_count_an_event_once(
+        snap, clean_profilers):
+    """A scheduler that was never closed leaves its profiler installed,
+    and the next one's shares the process-wide default registry with it:
+    every event still counts ONCE on that registry (``chip_smoke.py``
+    read its WCC job's endgame twice behind such a leak: ISSUE 49),
+    while each profiler's own totals take every call."""
+    shared = MetricManager()
+    leaked = devprof.DeviceCostProfiler(metrics=shared).install()
+    mine = devprof.DeviceCostProfiler(metrics=shared).install()
+    apart = devprof.DeviceCostProfiler(metrics=MetricManager()).install()
+    try:
+        nz = np.flatnonzero(snap.out_degree > 0)
+        frontier_bfs_batched(snap, [int(nz[0])])
+        devprof.count_pull_rung(4096)
+        devprof.count_d2h("bfs.result", 100)
+        devprof.count_frontier_test("end", "xla")
+        devprof.drain()
+    finally:
+        for prof in (leaked, mine, apart):
+            prof.uninstall()
+    calls = mine.stats()["calls"]
+    assert calls == leaked.stats()["calls"] == apart.stats()["calls"] > 0
+    for metrics in (shared, apart.metrics):
+        assert sum(c.count for c in (
+            metrics.counter("device.exec.calls", labels={"kernel": k})
+            for k in mine.kernel_stats())) == calls
+        assert metrics.counter("device.bfs.pull_rung",
+                               labels={"c_cap": "4096"}).count >= 1
+        assert metrics.counter("device.xfer.d2h_bytes",
+                               labels={"site": "bfs.result"}).count == 100
+        assert metrics.counter_value(
+            "device.bfs.frontier_test",
+            {"prog": "end", "impl": "xla"}) == 1
+    assert mine.stats()["d2h_bytes"] == leaked.stats()["d2h_bytes"] > 100

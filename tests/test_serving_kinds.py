@@ -163,12 +163,16 @@ def test_a_job_reserves_its_rows_keys_in_order(admitted, kind):
              BYTES[name](snap)) for name in LEDGER[kind]]
     assert calls["reserve"] == want
     # the row says the same, from its own functions
+    # (a working set is priced from the snapshot and the specs that
+    # share the run; one that reads 0 reserves nothing: a ``bfs`` job
+    # that asked for no parents)
     row = KINDS[kind]
+    work = row.work.price(snap, [JobSpec(kind=kind)], 1) \
+        if row.work else 0
     assert [image.key for image in row.images] \
-        + ([row.work.key] if row.work else []) == LEDGER[kind]
+        + ([row.work.key] if work else []) == LEDGER[kind]
     assert [image.nbytes(snap) for image in row.images] \
-        + ([row.work.nbytes(snap)] if row.work else []) \
-        == [nbytes for _key, nbytes in want]
+        + ([work] if work else []) == [nbytes for _key, nbytes in want]
     # `job.admit` reads the sum; every count was priced in this job
     assert calls["admit"]["bytes"] == sum(b for _k, b in want)
     assert calls["admit"]["sizing_passes"] \

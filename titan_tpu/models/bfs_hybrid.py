@@ -115,10 +115,40 @@ END_P_CAP = 1 << 22
 # one (892,911), which all paid for 2^21 before; 2^19 none; 2^21 the
 # heaviest 16-query Kron batch (1.47 M chunks).
 TD_RUNG_SHIFTS = (9, 4, 3, 2, 1, 0)
+# batched bottom-up (pull) step: the ladders of its caps, as right
+# shifts of a top rung the layout states (``_bu_caps``), for the reason
+# the push has one: a cap that is the power of two of a count read back
+# from the device is an executable a source, and a new source then
+# builds inside a served window. A count takes the lowest rung that
+# holds it; the lanes above it are dead ones the programs mask already,
+# so ``dist`` is bit-equal whatever the rung. ``bstep``'s ``c_cap``
+# (the level's candidates): BU_RUNG_SHIFTS of the power of two at or
+# above n, which holds every list. A pulled level costs its RUNG, not
+# its candidates, and linearly (one v5e, K = 1, graph500-22, PERF.md 6,
+# PR 49: 0.16 us a lane of ``c_cap`` a chunk round: 1.3 s for the eight
+# rounds on 2^20, 2.7 on 2^21, 5.5 on 2^22), so from the middle up the
+# rungs stand a factor of two apart; a source's tail levels hold 6-150
+# thousand candidates, so below it a factor of four (a first ladder that
+# stood 2^12 and 2^18 with nothing between cost every job 300 ms).
+# Under the lowest rung a sweep is the dispatch's cost. ``bex`` (the
+# stragglers' sweep: survivors of the eight chunk rounds; on an
+# undirected graph a source of this size met none) is rare, so its
+# ladders are coarse: its ``c_cap`` the lowest and the top candidate
+# rung, its ``p_cap`` (their remaining chunk columns) EX_RUNG_SHIFTS of
+# the power of two at or above the layout's chunk columns.
+BU_RUNG_SHIFTS = (10, 8, 6, 4, 3, 2, 1, 0)
+EX_RUNG_SHIFTS = (9, 4, 0)
 # direction rule (e): a level goes top-down while
-#   mass * TD_BU_COST <= BU_CHUNK_ROUNDS * c_count
+#   mass * TD_BU_COST <= rounds * c_count
 # — one pushed chunk column against one candidate-round of the
-# bottom-up sweep. Chip measurement that set it: PERF.md 6, PR 26.
+# bottom-up sweep, ``rounds`` the chunk rounds the pull's first
+# dispatch runs over ALL its candidates (``_bu_fuse``): the eight of a
+# hop's level, ONE of a BFS level since its rounds run a dispatch each
+# and the first decides nearly every candidate (the rule had weighed a
+# BFS pull at eight, so a frontier of up to eight times the candidates'
+# chunk mass was pushed on the top rungs: 0.5-1 s a level where the
+# pull is 0.3-0.6, PERF.md 6, PR 50). Chip measurement that set the
+# constant: PERF.md 6, PR 26.
 TD_BU_COST = 1
 # hand-on rule of a pushed level (``_td_lists``): the push dedups its
 # scatter targets into the next level's pair list only while
@@ -899,6 +929,28 @@ def _frontier_of():
 # pulls at every level.
 
 
+def _with_parents(dist, par):
+    """The state the batched programs take and hand back in their first
+    position: ``dist`` alone, or the pair ``(dist, par)`` where the run
+    keeps the BFS tree (``par`` [K, n+1] int32: the vertex a job's search
+    reached a vertex FROM, the source its own, -1 where none yet). The
+    form is part of a program's signature as a static flag would be, so
+    a run without parents traces, builds and donates exactly what it did
+    before the plane existed."""
+    return dist if par is None else (dist, par)
+
+
+def _split_state(state) -> tuple:
+    """``(dist, par)`` of a program's state, ``par`` None where the run
+    keeps no parents."""
+    return state if isinstance(state, tuple) else (state, None)
+
+
+def _fbits_bytes(n_: int) -> int:
+    """Bytes of one job's frontier bitmap (``_pack_bits_batched``)."""
+    return (n_ + 2 + 7) // 8
+
+
 def _pack_bits_batched(dist, active, level, n_: int):
     """[K, nbytes] frontier bitmaps: bit v of row k = (dist[k, v] ==
     level and job k is active). Inactive jobs get an all-zero row, so
@@ -907,7 +959,7 @@ def _pack_bits_batched(dist, active, level, n_: int):
     import jax.numpy as jnp
 
     K = dist.shape[0]
-    nbytes = (n_ + 2 + 7) // 8
+    nbytes = _fbits_bytes(n_)
     mask = (dist == level) & active[:, None]
     mask = jnp.concatenate([mask, jnp.zeros((K, 8), bool)], axis=1)
     return jnp.packbits(mask[:, :nbytes * 8], axis=1, bitorder="little")
@@ -974,11 +1026,14 @@ def _batched_bu():
                            static_argnames=("c_cap", "n_", "fuse",
                                             "masked", "expand"),
                            donate_argnums=(0,))
-        def bstep(dist, fbits, cand, off, prog, level, dstT, colstart,
+        def bstep(state, fbits, cand, off, prog, level, dstT, colstart,
                   degc, tbits, c_cap: int, n_: int, fuse: int,
                   masked: bool = False, expand: bool = False):
             """``fuse`` chunk-check rounds over the shared candidate
-            list: chunk ``off`` of each candidate is gathered ONCE and
+            list (``cand``, ``off``: any width from ``c_cap`` up, the
+            first ``c_cap`` read here and the lists handed back at the
+            width they came in, so the host slices and pads nothing):
+            chunk ``off`` of each candidate is gathered ONCE and
             tested against all K bitmaps; per-job finds scatter into
             dist rows; a candidate survives while it has chunks left
             AND some job still has it undecided. With ``masked``,
@@ -995,13 +1050,23 @@ def _batched_bu():
             candidate retires once every LIVE job (nonzero frontier
             bitmap — deactivated/pad rows never hit and must not pin
             candidates through all their chunks) has stamped it this
-            level."""
+            level.
+
+            ``state`` (``_with_parents``): with a parent plane, the lane
+            that hit names the parent: of a chunk's lanes in a job's
+            frontier the largest id (a max over the eight the test
+            already holds: any of them is a valid parent, and the rounds
+            still stop at the first chunk that hits), scattered where
+            the depth is."""
+            dist, par = _split_state(state)
             c_count = prog[0]
             q_pad = dstT.shape[1] - 1
             live = (fbits != 0).any(axis=1) if expand else None  # [K]
+            room = (0, cand.shape[0] - c_cap)
+            cand, off = cand[:c_cap], off[:c_cap]
 
             def round_(state, _):
-                dist, cand, off, c_count = state
+                dist, par, cand, off, c_count = state
                 alive = jnp.arange(c_cap) < c_count
                 v = jnp.minimum(cand, n_)
                 cols = jnp.where(alive & (off < degc[v]),
@@ -1022,22 +1087,31 @@ def _batched_bu():
                 else:
                     undec = dist[:, v] >= INF
                     found = undec & hit & alive[None, :]
-                    dist = dist.at[:, jnp.where(alive, v, n_ + 1)].min(
+                    to = jnp.where(alive, v, n_ + 1)
+                    dist = dist.at[:, to].min(
                         jnp.where(found, level + 1, INF), mode="drop")
+                    if par is not None:
+                        via = jnp.where(hitl, parents[None], -1) \
+                            .max(axis=1)                   # [K, c_cap]
+                        par = par.at[:, to].max(
+                            jnp.where(found, via, -1), mode="drop")
                 rem = (undec & ~hit).any(axis=0)
                 surv = alive & rem & (off + 1 < degc[v])
                 nc = surv.sum().astype(jnp.int32)
                 _, (cand2, off2) = scatter_compact(
                     surv, (cand, off + 1), c_cap, (n_ + 1, 0))
-                return (dist, cand2, off2, nc), None
+                return (dist, par, cand2, off2, nc), None
 
-            (dist, cand, off, c_count), _ = jax.lax.scan(
-                round_, (dist, cand, off, c_count), None, length=fuse)
+            (dist, par, cand, off, c_count), _ = jax.lax.scan(
+                round_, (dist, par, cand, off, c_count), None,
+                length=fuse)
             alive = jnp.arange(c_cap) < c_count
             v = jnp.minimum(cand, n_)
             rem8 = jnp.where(alive, jnp.maximum(degc[v] - off, 0), 0) \
                 .sum(dtype=jnp.int32)
-            return dist, cand, off, jnp.stack([c_count, rem8])
+            return (_with_parents(dist, par),
+                    jnp.pad(cand, room, constant_values=n_ + 1),
+                    jnp.pad(off, room), jnp.stack([c_count, rem8]))
         return bstep
     return _get("batched_bu", build)
 
@@ -1048,8 +1122,10 @@ def _batched_seed():
         import jax.numpy as jnp
 
         @functools.partial(jax.jit,
-                           static_argnames=("n_", "cap", "expand"))
-        def bseed(src, start, n_: int, cap: int, expand: bool = False):
+                           static_argnames=("n_", "cap", "expand",
+                                            "parents"))
+        def bseed(src, start, n_: int, cap: int, expand: bool = False,
+                  parents: bool = False):
             """The whole start of a single-start batch in ONE program:
             the ``[K, n+1]`` state (bfs: INF with 0 at each job's
             source; hops: 0 with ``start`` at it, the pad slot INF),
@@ -1057,7 +1133,9 @@ def _batched_seed():
             the pair list the push reads — the sources ARE the list, so
             nothing is compacted. Returns ``(dist, active, pj, pv,
             count)``; the list's capacity is ``cap`` (the caller hands
-            it forward only where ``K <= cap``)."""
+            it forward only where ``K <= cap``). With ``parents`` (bfs
+            mode) ``dist`` is the pair ``(dist, par)``: the parent plane
+            -1 with each job's source its own parent."""
             K = src.shape[0]
             k = jnp.arange(K, dtype=jnp.int32)
             if expand:
@@ -1069,6 +1147,9 @@ def _batched_seed():
             room = max(cap, K)
             pj = jnp.zeros((room,), jnp.int32).at[:K].set(k)[:cap]
             pv = jnp.full((room,), n_, jnp.int32).at[:K].set(src)[:cap]
+            if parents:
+                dist = (dist, jnp.full((K, n_ + 1), -1, jnp.int32)
+                        .at[k, src].set(src))
             return dist, jnp.ones((K,), bool), pj, pv, jnp.int32(K)
         return bseed
     return _get("batched_seed", build)
@@ -1144,7 +1225,7 @@ def _batched_td():
                            static_argnames=("p_cap", "n_", "expand",
                                             "lists"),
                            donate_argnums=(0,))
-        def btd(dist, pj, pv, count, active, level, want, dstT, colstart,
+        def btd(state, pj, pv, count, active, level, want, dstT, colstart,
                 degc, p_cap: int, n_: int, expand: bool = False,
                 lists: bool = False):
             """One top-down level for all K jobs, from the level's
@@ -1179,7 +1260,14 @@ def _batched_td():
             Returns ``(dist, nj, nv, ncount, stats)``; ``stats`` =
             ``[pairs, columns, ncount, c_count, nf[K], mass[K]]`` with
             ``ncount`` = -1 where no list was made (it may exceed the
-            capacity: the list is then cut and the caller scans)."""
+            capacity: the list is then cut and the caller scans).
+
+            ``state`` (``_with_parents``; bfs mode): with a parent plane
+            the pushing vertex of a column is the parent of every lane
+            of it that read INF before the scatter; of the pushers that
+            reach one vertex in one level the largest id stays (a max:
+            any is a valid parent, the max is the same on every run)."""
+            dist, par = _split_state(state)
             K = dist.shape[0]
             row = n_ + 1
             cap = pj.shape[0]
@@ -1194,21 +1282,27 @@ def _batched_td():
             nbr = jnp.take(dstT, cols, axis=1)           # [8, p_cap]
             rows = jnp.broadcast_to(job[owner][None, :], nbr.shape)
             pushed = jnp.stack([valid.sum().astype(jnp.int32), p_total])
-            if lists:
+            if lists or par is not None:
                 # the dist gather reads PRE-scatter state: duplicates
                 # of one new vertex all see INF and race on the claim
                 found = nbr < n_
                 if not expand:
                     found = found & (
                         dist[rows, jnp.minimum(nbr, n_)] >= INF)
+            if par is not None:
+                par = par.at[rows, jnp.where(found, nbr, n_ + 1)].max(
+                    jnp.broadcast_to(v[owner][None, :], nbr.shape),
+                    mode="drop")
             if expand:
                 dist = dist.at[rows, nbr].max(level + 1, mode="drop")
             else:
                 dist = dist.at[rows, nbr].min(level + 1, mode="drop")
             if not lists:
                 none = jnp.zeros((0,), jnp.int32)
-                return dist, none, none, jnp.int32(-1), jnp.concatenate(
-                    [pushed, jnp.full((2 + 2 * K,), -1, jnp.int32)])
+                return (_with_parents(dist, par), none, none,
+                        jnp.int32(-1), jnp.concatenate(
+                            [pushed,
+                             jnp.full((2 + 2 * K,), -1, jnp.int32)]))
 
             def hand_on(_):
                 key = jnp.where(found, rows * row + nbr, K * row)
@@ -1243,7 +1337,8 @@ def _batched_td():
 
             nj, nv, ncount, nxt = jax.lax.cond(want != 0, hand_on,
                                                leave, None)
-            return dist, nj, nv, ncount, jnp.concatenate([pushed, nxt])
+            return (_with_parents(dist, par), nj, nv, ncount,
+                    jnp.concatenate([pushed, nxt]))
         return btd
     return _get("batched_td", build)
 
@@ -1272,6 +1367,53 @@ def _td_caps(g) -> tuple:
     return tuple(sorted({max(top >> s, 2) for s in TD_RUNG_SHIFTS}))
 
 
+def _bu_caps(g) -> tuple:
+    """The pull's ladders of one layout, each lowest rung first:
+    ``(c_caps, ex_pairs)``. ``c_caps``: ``bstep``'s candidate caps,
+    BU_RUNG_SHIFTS of the power of two at or above n. ``ex_pairs``:
+    every ``(c_cap, p_cap)`` a ``bex`` can take: its ``c_cap`` the
+    lowest or the top of ``c_caps``, its ``p_cap`` EX_RUNG_SHIFTS of
+    the power of two at or above the chunk columns; a survivor has a
+    chunk left, so a pair whose ``p_cap`` lies under the rung below its
+    ``c_cap`` is never taken and not in the set."""
+    top = _next_pow2(max(g["n"], 2))
+    c_caps = tuple(sorted({max(top >> s, 2) for s in BU_RUNG_SHIFTS}))
+    ptop = _next_pow2(max(int(g["q_total"]), 2))
+    p_caps = sorted({max(ptop >> s, 2) for s in EX_RUNG_SHIFTS})
+    ex_c = sorted({c_caps[0], c_caps[-1]})
+    return c_caps, tuple(
+        (c, p) for i, c in enumerate(ex_c) for p in p_caps
+        if i == 0 or ex_c[i - 1] < p)
+
+
+def _rung(caps, count: int) -> int:
+    """The lowest rung of an ascending ladder that holds ``count``."""
+    return next(cap for cap in caps if count <= cap)
+
+
+def _bu_fuse(expand: bool, rounds: int) -> int:
+    """How many of a pulled level's BU_CHUNK_ROUNDS chunk rounds the next
+    ``bstep`` runs in one dispatch, ``rounds`` of them behind it. A BFS
+    level: ONE, and the host looks at what it left: a round costs its
+    rung's lanes whoever is still alive in them, and on an undirected
+    graph the first round decides nearly every candidate (graph500-22,
+    six drawn sources: 0 to 8 survivors of 0.7-2.2 M candidates; CPU
+    count, PR 49), so seven rounds fused behind it swept dead lanes for
+    seven eighths of a job's time. Each later round runs on the rung its
+    survivors take. A hop's level (``expand``): all that are left, as
+    ever: a candidate there retires only once EVERY live job has
+    stamped it, so the rounds rarely thin out and a readback between
+    them buys nothing."""
+    return BU_CHUNK_ROUNDS - rounds if expand else 1
+
+
+def _ex_rung(pairs, c_count: int, rem8: int) -> tuple:
+    """The ``(c_cap, p_cap)`` a ``bex`` over ``c_count`` survivors with
+    ``rem8`` chunk columns left takes."""
+    c_cap = _rung(sorted({c for c, _ in pairs}), c_count)
+    return c_cap, _rung([p for c, p in pairs if c == c_cap], rem8)
+
+
 def _td_lists(p_cap: int, n: int) -> bool:
     """Whether a push on rung ``p_cap`` hands the next level its list
     (and statistics): while deduping its 8 x p_cap lanes is cheaper
@@ -1279,7 +1421,8 @@ def _td_lists(p_cap: int, n: int) -> bool:
     return 8 * p_cap * TD_DEDUP_COST < n
 
 
-def _td_cap(g, K: int, mass: int, c_count: int, masked: bool):
+def _td_cap(g, K: int, mass: int, c_count: int, masked: bool,
+            rounds: int = BU_CHUNK_ROUNDS):
     """The direction rule of a batched level, from what the plan read
     back and what the layout and masks say: the rung (``p_cap``) a
     top-down step takes, or None for bottom-up. Top-down when (a) the
@@ -1288,12 +1431,13 @@ def _td_cap(g, K: int, mass: int, c_count: int, masked: bool):
     orientation of a directed graph, whose columns hold parents, not
     children, (c) no slot bitmap (tombstones, a hop's label mask) is in
     force this level, (d) the cohort is not mesh-placed, and (e) the
-    push is the cheaper side."""
+    push is the cheaper side: against a pull whose first dispatch runs
+    ``rounds`` chunk rounds over every candidate (``_bu_fuse``)."""
     if masked or g.get("directed") or "_mesh" in g:
         return None
     if K * (g["n"] + 1) >= (1 << 31):
         return None
-    if mass * TD_BU_COST > BU_CHUNK_ROUNDS * c_count:
+    if mass * TD_BU_COST > rounds * c_count:
         return None
     return next((cap for cap in _td_caps(g) if mass <= cap), None)
 
@@ -1341,6 +1485,126 @@ def warm_batched_td(g, K: int, expand: bool) -> None:
     dist.block_until_ready()
 
 
+#: the (n, chunk columns, K, expand, parents) ``warm_batched`` has run
+#: at: the executables are keyed by a layout's shape, not by the layout,
+#: and live as long as the process
+_WARMED: set = set()
+#: threads ``warm_batched`` builds on
+WARM_THREADS = 4
+
+
+def batched_is_warm(g, K: int, expand: bool = False,
+                    parents: bool = False) -> bool:
+    return (g["n"], int(g["q_total"]), K, expand, parents) in _WARMED
+
+
+def warm_batched(g, K: int, expand: bool = False,
+                 parents: bool = False) -> None:
+    """Build (or load) every executable a batch of size ``K`` can meet
+    on this layout, unmasked and off a mesh, before its first level: the
+    seed, the plan, the scan road's listing, every rung of the push
+    (what ``warm_batched_td`` runs for the lane) and the pull's finite
+    set (``_bu_caps``): ``bstep`` on every rung of its ladder and
+    ``bex`` on every pair of its, each once over dead lanes. So the
+    programs a source meets follow from the layout alone, whatever its
+    levels weigh. With ``parents`` the state every program takes is the
+    pair (``_with_parents``), which is a set of executables of its own
+    (the plan and the listing read ``dist`` alone and are shared).
+
+    The programs are built SIDE BY SIDE on a few threads: each call
+    below is independent of every other (its own state from the seed,
+    empty lists made here), and a build is the compiler's time, outside
+    the interpreter's lock: graph500-22's twenty-three programs are some
+    260 s of builds one after another (PERF.md 6, PR 49), which
+    matters to the job that waits for them. Once a shape of layout, K,
+    mode and state in a process (``batched_is_warm``)."""
+    import contextlib
+    import os
+    from concurrent.futures import ThreadPoolExecutor
+
+    import jax
+    import jax.numpy as jnp
+
+    from titan_tpu.obs import tracing
+    from titan_tpu.utils.jitcache import dev_scalar
+
+    if batched_is_warm(g, K, expand, parents):
+        return
+    n, degc, dstT, colstart = g["n"], g["degc"], g["dstT"], g["colstart"]
+    caps = _td_caps(g)
+    c_caps, ex_pairs = _bu_caps(g)
+    # fetched here: a program's first ``jit_once`` must not race
+    bseed, bplan, blist = _batched_seed(), _batched_plan(), _batched_list()
+    btd, bstep, bex = _batched_td(), _batched_bu(), _batched_exhaust()
+    level, zero = dev_scalar(2), dev_scalar(0)  # nothing is stamped 2
+    src = np.zeros(K, np.int32)
+
+    def seed():
+        return bseed(src, dev_scalar(1), n_=n, cap=caps[-1], expand=expand,
+                     **({"parents": True} if parents else {}))
+
+    jax.block_until_ready(seed()[0])    # the one every other call needs
+    # what a pull reads where no plan ran: no frontier bit, no candidate
+    fbits = jnp.zeros((K, _fbits_bytes(n)), jnp.uint8)
+    cand = jnp.full((c_caps[-1],), n + 1, jnp.int32)
+    off = jnp.zeros((c_caps[-1],), jnp.int32)
+    prog = jnp.asarray([0, 0], jnp.int32)
+    tbits = jnp.zeros((1,), jnp.uint8)
+
+    def plan():
+        state, active, *_ = seed()
+        return bplan(_split_state(state)[0], active, level, degc,
+                     c_cap=c_caps[-1], n_=n, expand=expand)
+
+    def listing():
+        state, active, *_ = seed()
+        return blist(_split_state(state)[0], active, level, zero, degc,
+                     caps=caps, n_=n)
+
+    def push(p_cap):
+        def go():
+            dist, active, pj, pv, count = seed()
+            return btd(dist, pj, pv, count, active, level, dev_scalar(1),
+                       dstT, colstart, degc, p_cap=p_cap, n_=n,
+                       expand=expand, lists=_td_lists(p_cap, n))
+        return go
+
+    def pull(c_cap):
+        def go():
+            return bstep(seed()[0], fbits, cand, off, prog, level, dstT,
+                         colstart, degc, tbits, c_cap=c_cap, n_=n,
+                         fuse=_bu_fuse(expand, 0), masked=False,
+                         expand=expand)
+        return go
+
+    def exhaust(c_cap, p_cap):
+        def go():
+            return bex(seed()[0], fbits, cand, off, prog, level, dstT,
+                       colstart, degc, tbits, c_cap=c_cap, p_cap=p_cap,
+                       n_=n, masked=False, expand=expand)
+        return go
+
+    # the longest builds first (the listing holds every rung's branch)
+    calls = [listing, plan] + [push(c) for c in reversed(caps)] \
+        + [pull(c) for c in reversed(c_caps)] \
+        + [exhaust(c, p) for c, p in reversed(ex_pairs)]
+    where = tracing.current_span()
+
+    def build(call):
+        # a build's ``compile`` span and the call's ``kernel`` span
+        # journal where the caller stands, not in a trace of their own;
+        # the call is awaited here and its outputs dropped, so no more
+        # than the pool's width of them are ever pending or alive
+        with tracing.scope(*where) if where is not None \
+                else contextlib.nullcontext():
+            jax.block_until_ready(call())
+
+    with ThreadPoolExecutor(min(WARM_THREADS, os.cpu_count() or 1),
+                            thread_name_prefix="bfs-build") as pool:
+        list(pool.map(build, calls))
+    _WARMED.add((g["n"], int(g["q_total"]), K, expand, parents))
+
+
 def _batched_exhaust():
     def build():
         import jax
@@ -1350,14 +1614,20 @@ def _batched_exhaust():
                            static_argnames=("c_cap", "p_cap", "n_",
                                             "masked", "expand"),
                            donate_argnums=(0,))
-        def bex(dist, fbits, cand, off, prog, level, dstT, colstart,
+        def bex(state, fbits, cand, off, prog, level, dstT, colstart,
                 degc, tbits, c_cap: int, p_cap: int, n_: int,
                 masked: bool = False, expand: bool = False):
             """One masked sweep over ALL remaining chunks of the
-            surviving candidates (hub stragglers), per-job any-hit via
-            a shared owner scatter. ``masked``/``tbits``: tombstoned
-            slots never hit (see _batched_bu)."""
+            surviving candidates (hub stragglers; the first ``c_cap``
+            of ``cand`` / ``off``), per-job any-hit via a shared owner
+            scatter. ``masked``/``tbits``: tombstoned slots never hit
+            (see _batched_bu). With a parent plane (``state``:
+            ``_with_parents``) the owner scatter carries the largest
+            frontier id of a survivor's remaining lanes, which says both
+            that one hit and who."""
+            dist, par = _split_state(state)
             c_count = prog[0]
+            cand, off = cand[:c_cap], off[:c_cap]
             valid = jnp.arange(c_cap) < c_count
             v = jnp.minimum(cand, n_)
             rem = jnp.maximum(degc[v] - off, 0)
@@ -1381,9 +1651,17 @@ def _batched_exhaust():
                     jnp.where(found, level + 1, 0), mode="drop")
             undec = dist[:, v] >= INF
             found = undec & (found_per > 0) & valid[None, :]
-            dist = dist.at[:, jnp.where(valid, v, n_ + 1)].min(
+            to = jnp.where(valid, v, n_ + 1)
+            dist = dist.at[:, to].min(
                 jnp.where(found, level + 1, INF), mode="drop")
-            return dist
+            if par is not None:
+                via = jnp.full((dist.shape[0], c_cap), -1, jnp.int32) \
+                    .at[:, own].max(
+                        jnp.where(hitl, parents[None], -1).max(axis=1),
+                        mode="drop")
+                par = par.at[:, to].max(jnp.where(found, via, -1),
+                                        mode="drop")
+            return _with_parents(dist, par)
         return bex
     return _get("batched_ex", build)
 
@@ -1396,7 +1674,7 @@ def _overlay_scatter_batched():
         @functools.partial(jax.jit,
                            static_argnames=("cap", "n_", "expand"),
                            donate_argnums=(0,))
-        def oscat(dist, fbits, ov_src, ov_dst, level, cap: int,
+        def oscat(state, fbits, ov_src, ov_dst, level, cap: int,
                   n_: int, expand: bool = False):
             """Delta-COO expansion pass: for every live overlay edge
             (u, v), jobs whose frontier bitmap holds u scatter
@@ -1406,13 +1684,22 @@ def _overlay_scatter_batched():
             scatter; min keeps earlier levels, so the pass composes
             with the base sweep in any order. ``expand`` (hops mode):
             max-scatter of the hop stamp instead — same monotone
-            re-stamp contract as the base sweep."""
+            re-stamp contract as the base sweep. With a parent plane
+            (``state``: ``_with_parents``; bfs mode) an overlay edge
+            that reaches a vertex whose depth read INF before this pass
+            is its parent (of several, the largest source)."""
+            dist, par = _split_state(state)
             hit = _bit_of_batched(fbits, ov_src)          # [K, cap]
             if expand:
                 return dist.at[:, ov_dst].max(
                     jnp.where(hit, level + 1, 0), mode="drop")
+            if par is not None:
+                new = hit & (dist[:, jnp.minimum(ov_dst, n_)] >= INF)
+                par = par.at[:, ov_dst].max(
+                    jnp.where(new, ov_src[None, :], -1), mode="drop")
             msg = jnp.where(hit, level + 1, INF)
-            return dist.at[:, ov_dst].min(msg, mode="drop")
+            return _with_parents(
+                dist.at[:, ov_dst].min(msg, mode="drop"), par)
         return oscat
     return _get("batched_overlay_scatter", build)
 
@@ -1421,7 +1708,8 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
                          on_level=None, return_device: bool = False,
                          init_dist=None, start_level: int = 0,
                          checkpoint=None, overlay=None,
-                         mode: str = "bfs", level_masks=None):
+                         mode: str = "bfs", level_masks=None,
+                         parents: bool = False, init_parent=None):
     """Batched multi-source BFS: run K BFS jobs over the SAME graph as
     one device run with [K, n] state. Each job's ``dist`` row is
     bit-equal to ``frontier_bfs_hybrid`` from that source (BFS distances
@@ -1485,33 +1773,51 @@ def frontier_bfs_batched(snap_or_graph, sources, max_levels: int = 1000,
     replicated); the kernels are unchanged — GSPMD partitions them
     from the committed input placements.
 
+    The BFS tree (``parents``, bfs mode: GAP's and Graph500's answer):
+    a second ``[K, n+1]`` int32 plane beside ``dist``, carried through
+    the loop by the programs that already hold the id (the push's
+    scatter, the pull's hit, the overlay's edge): ``parent[k, v]`` is
+    the vertex job k's search reached v from, a neighbour one level
+    nearer the source; the source is its own parent; -1 where the
+    source reaches nobody. ANY valid tree is an answer (which of a
+    vertex's possible parents it names follows the direction a level
+    took; on one road the largest id, so a run repeats itself). The
+    state is then the pair: ``checkpoint`` is handed ``(dist, par)`` and
+    a resume needs ``init_parent`` ([K, n]) beside ``init_dist``. A run
+    without ``parents`` builds and runs what it did before the plane.
+
     Returns ``(dist, levels, completed)``: dist [K, n] (device array
     when ``return_device``, else numpy; INF = unreachable — partial for
-    non-completed jobs), levels np int32 [K] (the level at which each
-    job's frontier emptied), completed np bool [K] (False = deactivated
-    early via on_level)."""
-    dist, levels, completed = batched_bfs_state(
+    non-completed jobs; with ``parents`` the pair ``(dist, parent)``,
+    each [K, n]), levels np int32 [K] (the level at which each job's
+    frontier emptied), completed np bool [K] (False = deactivated early
+    via on_level)."""
+    state, levels, completed = batched_bfs_state(
         snap_or_graph, sources, max_levels=max_levels, on_level=on_level,
         init_dist=init_dist, start_level=start_level,
         checkpoint=checkpoint, overlay=overlay, mode=mode,
-        level_masks=level_masks)
-    out = dist[:, :dist.shape[1] - 1]
+        level_masks=level_masks, parents=parents, init_parent=init_parent)
+    out = [a[:, :a.shape[1] - 1] for a in _split_state(state)
+           if a is not None]
     if not return_device:
         from titan_tpu.obs import devprof
-        devprof.count_d2h("bfs.dist", out.nbytes)
-        out = np.asarray(out)
-    return out, levels, completed
+        for i, a in enumerate(out):
+            devprof.count_d2h("bfs.dist", a.nbytes)
+            out[i] = np.asarray(a)
+    return (tuple(out) if parents else out[0]), levels, completed
 
 
 def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
                       on_level=None, init_dist=None, start_level: int = 0,
                       checkpoint=None, overlay=None, mode: str = "bfs",
-                      level_masks=None):
+                      level_masks=None, parents: bool = False,
+                      init_parent=None):
     """``frontier_bfs_batched``'s level loop, returning the state as
     the loop leaves it: ``dist`` [K, n+1] on the device, pad slot
-    included, for callers that read it with a program of their own (the
-    interactive lane's ``hop_extract``) and want no slice dispatched
-    in between.
+    included (with ``parents`` the pair ``(dist, par)``:
+    ``_with_parents``), for callers that read it with a program of
+    their own (the interactive lane's ``hop_extract``) and want no slice
+    dispatched in between.
 
     The loop's state is ``(dist, level)`` and two caches of it that one
     program hands the next: the level's frontier as a list of (job,
@@ -1548,6 +1854,13 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
     if expand and start_level < 1:
         raise ValueError("hops mode needs start_level >= 1 (0 is the "
                          "never-reached background value)")
+    if parents and expand:
+        raise ValueError("parents needs mode='bfs': a hop set re-stamps "
+                         "a vertex it reaches again, and has no tree")
+    if parents and (init_dist is None) != (init_parent is None):
+        raise ValueError("a run with parents resumes from init_dist AND "
+                         "init_parent: depths alone do not say by which "
+                         "edge a vertex was reached")
     K = len(sources)
     if K == 0:
         raise ValueError("frontier_bfs_batched needs >= 1 source")
@@ -1562,14 +1875,10 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
     from titan_tpu.obs.tracing import phase
     from titan_tpu.utils.jitcache import dev_scalar
 
-    cap_n = _next_pow2(max(n, 2))
-
-    def pad(a):
-        if a.shape[0] < cap_n:
-            a = jnp.concatenate(
-                [a, jnp.full((cap_n - a.shape[0],), n + 1, a.dtype)])
-        return a
-
+    # the plan lists the candidates at the pull ladder's top rung, and
+    # the lists keep that width from program to program
+    c_caps, ex_pairs = _bu_caps(g)
+    cap_n = c_caps[-1]
     caps = _td_caps(g)
     # what one program may hand the next (the list, the statistics):
     # only where a level can push at all, and not under a live overlay,
@@ -1577,11 +1886,16 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
     # and whose scatter reads the plan's bitmaps at every level
     hand_on = ov is None and _td_cap(g, K, 0, 1, False) is not None
     lst, held, st = None, 0, None
-    with phase("bfs.seed", K=K, n=n, mode=mode):
+    par = None
+    with phase("bfs.seed", K=K, n=n, mode=mode, parents=parents):
         if init_dist is None:
-            dist, active, pj, pv, count = _batched_seed()(
+            # (a run without parents passes no flag: the call it made
+            # before the plane, so the executable it built before)
+            state, active, pj, pv, count = _batched_seed()(
                 src_arr.astype(np.int32), dev_scalar(int(start_level)),
-                n_=n, cap=caps[-1], expand=expand)
+                n_=n, cap=caps[-1], expand=expand,
+                **({"parents": True} if parents else {}))
+            dist, par = _split_state(state)
             # the start level's frontier is the sources (bfs: stamped
             # 0, so only a run from level 0 has one)
             if hand_on and "_host" in g and K <= caps[-1] \
@@ -1598,6 +1912,13 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
             # host: uploads, and no program to build for a rare road
             dist = jnp.asarray(np.concatenate(
                 [d, np.full((K, 1), INF, np.int32)], axis=1))
+            if parents:
+                p = np.asarray(init_parent, np.int32)
+                if p.shape != (K, n):
+                    raise ValueError(f"init_parent must be [K={K}, "
+                                     f"n={n}], got {p.shape}")
+                par = jnp.asarray(np.concatenate(
+                    [p, np.full((K, 1), -1, np.int32)], axis=1))
             active = jnp.asarray(np.ones(K, bool))
         if "_state_sharding" in g:
             # mesh-placed cohort (parallel/partition.place_batched_csr):
@@ -1606,6 +1927,8 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
             # reshard
             import jax
             dist = jax.device_put(dist, g["_state_sharding"])
+            if par is not None:
+                par = jax.device_put(par, g["_state_sharding"])
         act_h = np.ones(K, bool)
     levels = np.zeros(K, np.int32)
     completed = np.zeros(K, bool)
@@ -1658,7 +1981,7 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
         if checkpoint is not None:
             # consistent boundary: every level < ``level`` is final in
             # dist, this level's frontier (dist == level) is unswept
-            checkpoint(level, dist, act_h.copy())
+            checkpoint(level, _with_parents(dist, par), act_h.copy())
         if mask_changed:
             active = jnp.asarray(act_h)
             if planned or not expand:
@@ -1691,7 +2014,8 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
                 if 0 <= i_lm < len(level_masks) else None
             if lm is not None:
                 tb_l, masked_l = lm, True
-        p_cap = _td_cap(g, K, mass, c_count, masked_l)
+        p_cap = _td_cap(g, K, mass, c_count, masked_l,
+                        _bu_fuse(expand, 0))
         if p_cap is None and not planned:
             # a pull reads the plan's bitmaps and candidate list: made
             # only now that one will (the overlay's scatter reads them
@@ -1719,11 +2043,13 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
                         dist, active, dev_scalar(level),
                         dev_scalar(caps.index(p_cap)), degc, caps=caps,
                         n_=n)
-                dist, nj, nv, ncount, pushed = btd(
-                    dist, *lst, active, dev_scalar(level),
+                state, nj, nv, ncount, pushed = btd(
+                    _with_parents(dist, par), *lst, active,
+                    dev_scalar(level),
                     dev_scalar(int(want)), dstT, colstart, degc,
                     p_cap=p_cap, n_=n, expand=expand,
                     lists=_td_lists(p_cap, n))
+                dist, par = _split_state(state)
                 with ph.sync():
                     got = np.asarray(pushed)
                 devprof.count_d2h("bfs.stats", got.nbytes)
@@ -1742,29 +2068,31 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
             # so order is immaterial), and it must run even when the
             # base candidate list is empty (vertices reachable only
             # through overlay edges)
-            dist = oscat(dist, fbits, ov.src_dev, ov.dst_dev,
-                         dev_scalar(level), cap=ov.cap, n_=n,
-                         expand=expand)
+            dist, par = _split_state(oscat(
+                _with_parents(dist, par), fbits, ov.src_dev, ov.dst_dev,
+                dev_scalar(level), cap=ov.cap, n_=n, expand=expand))
         # bottom-up: chunk rounds over the shared candidate list
         # (bu_more shape)
         off = None
         rounds = 0
         prog = None
         while c_count > 0 and rounds < BU_CHUNK_ROUNDS:
-            c_cap2 = min(_next_pow2(max(c_count, 2)), cap_n)
-            fuse = BU_CHUNK_ROUNDS - rounds
+            # the rung that holds the candidates: the lanes above them
+            # are dead (``arange < c_count``), so the rung only pads
+            c_cap2 = _rung(c_caps, c_count)
+            devprof.count_pull_rung(c_cap2)
+            fuse = _bu_fuse(expand, rounds)
             with phase("bfs.sweep", level=level, dir="bu", c_cap=c_cap2,
-                       fuse=fuse) as ph:
+                       fuse=fuse, candidates=c_count) as ph:
                 if off is None:
-                    cand = pad(cand)
                     off = jnp.zeros((cap_n,), jnp.int32)
                     prog = jnp.asarray([c_count, 0], jnp.int32)
-                dist, cand, off, prog = bstep(
-                    dist, fbits, cand[:c_cap2], off[:c_cap2], prog,
+                state, cand, off, prog = bstep(
+                    _with_parents(dist, par), fbits, cand, off, prog,
                     dev_scalar(level), dstT, colstart, degc, tb_l,
                     c_cap=c_cap2, n_=n, fuse=fuse, masked=masked_l,
                     expand=expand)
-                cand, off = pad(cand), pad(off)
+                dist, par = _split_state(state)
                 with ph.sync():
                     left = np.asarray(prog)
                 devprof.count_d2h("bfs.stats", left.nbytes)
@@ -1772,22 +2100,23 @@ def batched_bfs_state(snap_or_graph, sources, max_levels: int = 1000,
                 ph.set(c_count=c_count, rem8=rem8)
             rounds += fuse
         if c_count > 0:
-            c_cap2 = min(_next_pow2(max(c_count, 2)), cap_n)
-            rem_cap = _next_pow2(max(rem8, 2))
+            c_cap2, rem_cap = _ex_rung(ex_pairs, c_count, rem8)
             # no sync here: bex's device time falls into the next phase
             # that reads back (the next level's plan, or the caller's)
             with phase("bfs.exhaust", level=level, c_cap=c_cap2,
-                       p_cap=rem_cap, **{"async": True}):
-                dist = bex(dist, fbits, cand[:c_cap2], off[:c_cap2],
-                           prog, dev_scalar(level), dstT, colstart, degc,
-                           tb_l, c_cap=c_cap2, p_cap=rem_cap, n_=n,
-                           masked=masked_l, expand=expand)
+                       p_cap=rem_cap, survivors=c_count, rem8=rem8,
+                       **{"async": True}):
+                dist, par = _split_state(bex(
+                    _with_parents(dist, par), fbits, cand, off, prog,
+                    dev_scalar(level), dstT, colstart, degc, tb_l,
+                    c_cap=c_cap2, p_cap=rem_cap, n_=n, masked=masked_l,
+                    expand=expand))
         level += 1
     # jobs still active at max_levels count as completed-at-cap
     if act_h.any():
         completed[act_h] = True
         levels[act_h] = level
-    return dist, levels, completed
+    return _with_parents(dist, par), levels, completed
 
 
 def frontier_bfs_hybrid(snap, source_dense: int, max_levels: int = 1000,

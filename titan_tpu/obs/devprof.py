@@ -219,13 +219,13 @@ class _Watcher:
         attrs = dict(_statics(kwargs), key=key,
                      fn=getattr(fn, "__name__", key),
                      dispatch_ms=round(wall_s * 1e3, 3))
-        profs = list(_PROFILERS)
+        profs = _installed()
         try:
             self._q.put_nowait((_smallest_array(out),
                                 (attrs, where, clock, clock(), profs)))
         except queue.Full:
-            for prof in profs:
-                prof.on_stamp(key, 0.0, False)
+            for prof, counts in profs:
+                prof.on_stamp(key, 0.0, False, counts)
             return
         if not self.alive:
             self._start()
@@ -276,8 +276,8 @@ class _Watcher:
             if self._carry is None:
                 self._carry = dispatched
         device_s = ready - start
-        for prof in profs:
-            prof.on_stamp(attrs["key"], device_s, stamped)
+        for prof, counts in profs:
+            prof.on_stamp(attrs["key"], device_s, stamped, counts)
         if where is not None:
             tracer, trace_id, parent = where
             tracer.event(trace_id, "kernel", parent=parent, t0=start,
@@ -356,9 +356,9 @@ def _dispatch(key: str, fn, args, kwargs):
         wall = time.perf_counter() - t0
         after = cache_size() if cache_size is not None else -1
         compiled = after > before >= 0
-        for prof in list(_PROFILERS):
+        for prof, counts in _installed():
             prof.on_call(key, wall, compiled, ctx["compile_s"],
-                         ctx["compile_events"])
+                         ctx["compile_events"], counts)
     _WATCHER.submit(key, fn, kwargs, out, wall)
     return out
 
@@ -372,18 +372,38 @@ def profiled(key: str, fn, *args, **kwargs):
     return _dispatch(key, fn, args, kwargs)
 
 
+def _installed() -> list:
+    """``[(profiler, counts)]`` of the installed profilers: ``counts``
+    is False for one whose metric registry an earlier one of the list
+    shares. An event counts once a REGISTRY, however many profilers are
+    installed over it: a scheduler that was never closed leaves its
+    profiler installed, and the next scheduler's shares the process-wide
+    default registry with it (every counter then read double)."""
+    seen: set = set()
+    out = []
+    for prof in list(_PROFILERS):
+        out.append((prof, id(prof.metrics) not in seen))
+        seen.add(id(prof.metrics))
+    return out
+
+
+def _registries() -> list:
+    """The installed profilers' metric registries, each once."""
+    return [prof.metrics for prof, counts in _installed() if counts]
+
+
 def count_h2d(site: str, nbytes: int) -> None:
     """Attribute ``nbytes`` of host→device transfer to ``site``."""
     if _PROFILERS and nbytes:
-        for prof in list(_PROFILERS):
-            prof.on_xfer("h2d", site, int(nbytes))
+        for prof, counts in _installed():
+            prof.on_xfer("h2d", site, int(nbytes), counts)
 
 
 def count_d2h(site: str, nbytes: int) -> None:
     """Attribute ``nbytes`` of device→host readback to ``site``."""
     if _PROFILERS and nbytes:
-        for prof in list(_PROFILERS):
-            prof.on_xfer("d2h", site, int(nbytes))
+        for prof, counts in _installed():
+            prof.on_xfer("d2h", site, int(nbytes), counts)
 
 
 def count_level(direction: str, road: str, levels: int = 1) -> None:
@@ -396,10 +416,19 @@ def count_level(direction: str, road: str, levels: int = 1) -> None:
     direction ``"head"`` | ``"td"`` | ``"bu"`` | ``"end"``, the fused
     head and endgame counting every level their one dispatch ran."""
     if levels > 0:
-        for prof in list(_PROFILERS):
-            prof.metrics.counter("device.bfs.levels",
-                                 labels={"dir": direction,
-                                         "list": road}).inc(int(levels))
+        for metrics in _registries():
+            metrics.counter("device.bfs.levels",
+                            labels={"dir": direction,
+                                    "list": road}).inc(int(levels))
+
+
+def count_pull_rung(c_cap: int) -> None:
+    """Count one pulled level of the batched loop by the rung of the
+    pull's ladder its candidates took (``bfs_hybrid._bu_caps``: the
+    ``c_cap`` its ``bstep`` ran at)."""
+    for metrics in _registries():
+        metrics.counter("device.bfs.pull_rung",
+                        labels={"c_cap": str(int(c_cap))}).inc()
 
 
 def count_opener(impl: str) -> None:
@@ -408,9 +437,9 @@ def count_opener(impl: str) -> None:
     the first lanes n-wide over the leading-lane image), ``"plain"``
     the opener that tests every lane at once over the candidates' list
     (under ``bfs_hybrid.SPLIT_LANE_MIN`` candidates)."""
-    for prof in list(_PROFILERS):
-        prof.metrics.counter("device.bfs.opener",
-                             labels={"impl": impl}).inc()
+    for metrics in _registries():
+        metrics.counter("device.bfs.opener",
+                        labels={"impl": impl}).inc()
 
 
 def count_frontier_test(prog: str, impl: str) -> None:
@@ -419,17 +448,17 @@ def count_frontier_test(prog: str, impl: str) -> None:
     ``bu0b``, ``bu``, ``ex``, ``bu0``, ``end``) by what served the
     test's random reads (``bfs_hybrid._frontier_road``): ``"vmem"`` the
     frontier as a table in VMEM, ``"xla"`` the bitmap's byte gather."""
-    for prof in list(_PROFILERS):
-        prof.metrics.counter("device.bfs.frontier_test",
-                             labels={"prog": prog, "impl": impl}).inc()
+    for metrics in _registries():
+        metrics.counter("device.bfs.frontier_test",
+                        labels={"prog": prog, "impl": impl}).inc()
 
 
 def count_wcc_rounds(rounds: int) -> None:
     """Count the min-label propagation rounds of one WCC run (the
     rounds ``_frontier_run`` planned after the peel)."""
     if rounds > 0:
-        for prof in list(_PROFILERS):
-            prof.metrics.counter("device.wcc.rounds").inc(int(rounds))
+        for metrics in _registries():
+            metrics.counter("device.wcc.rounds").inc(int(rounds))
 
 
 def count_wcc_plan(domain: str) -> None:
@@ -437,16 +466,16 @@ def count_wcc_plan(domain: str) -> None:
     ``"list"`` over the peel's remainder (``frontier._list_plan``),
     ``"n"`` over every vertex (``_band_plan``: no peel, or a remainder
     past the list's cap)."""
-    for prof in list(_PROFILERS):
-        prof.metrics.counter("device.wcc.plans",
-                             labels={"domain": domain}).inc()
+    for metrics in _registries():
+        metrics.counter("device.wcc.plans",
+                        labels={"domain": domain}).inc()
 
 
 def count_pr_iteration() -> None:
     """Count one iteration of ``frontier.pagerank_dense`` (its sweep
     and its finish dispatched)."""
-    for prof in list(_PROFILERS):
-        prof.metrics.counter("device.pr.iterations").inc()
+    for metrics in _registries():
+        metrics.counter("device.pr.iterations").inc()
 
 
 def count_pr_gather(impl: str, lanes: int) -> None:
@@ -454,9 +483,9 @@ def count_pr_gather(impl: str, lanes: int) -> None:
     gathered (8 x the pull image's columns, pad lanes included) by what
     served them: ``"vmem"`` the Pallas kernel's table, ``"xla"`` XLA's
     gather (ops/vmem_gather.gather_impl)."""
-    for prof in list(_PROFILERS):
-        prof.metrics.counter("device.pr.gather_lanes",
-                             labels={"impl": impl}).inc(int(lanes))
+    for metrics in _registries():
+        metrics.counter("device.pr.gather_lanes",
+                        labels={"impl": impl}).inc(int(lanes))
 
 
 def count_cdlp_round(impl: str, classes: tuple, keys: int) -> None:
@@ -469,12 +498,12 @@ def count_cdlp_round(impl: str, classes: tuple, keys: int) -> None:
     pair)."""
     by_class = {name: rows * 8 * width
                 for name, (rows, width) in zip(("small", "wide"), classes)}
-    for prof in list(_PROFILERS):
-        prof.metrics.counter("device.cdlp.rounds").inc()
-        prof.metrics.counter("device.cdlp.lanes", labels={"impl": impl}) \
+    for metrics in _registries():
+        metrics.counter("device.cdlp.rounds").inc()
+        metrics.counter("device.cdlp.lanes", labels={"impl": impl}) \
             .inc(sum(by_class.values()))
         for name, lanes in by_class.items():
-            prof.metrics.counter(
+            metrics.counter(
                 "device.cdlp.sort_lanes",
                 labels={"class": name, "keys": str(keys)}).inc(lanes)
 
@@ -486,14 +515,14 @@ def count_lcc(part: str, edges: int, wedges: Optional[int] = None
     made, one an undirected edge) or ``"tail"`` (``edges``: the low
     graph's, once each; ``wedges``: the oriented wedges its compares
     decide)."""
-    for prof in list(_PROFILERS):
-        prof.metrics.counter("device.lcc.edges",
-                             labels={"part": part}).inc(int(edges))
+    for metrics in _registries():
+        metrics.counter("device.lcc.edges",
+                        labels={"part": part}).inc(int(edges))
         if part == "hub":
-            prof.metrics.counter("device.lcc.levels").inc()
+            metrics.counter("device.lcc.levels").inc()
         if wedges is not None:
-            prof.metrics.counter("device.lcc.wedges",
-                                 labels={"part": part}).inc(int(wedges))
+            metrics.counter("device.lcc.wedges",
+                            labels={"part": part}).inc(int(wedges))
 
 
 def count_bc(part: str, levels, width: int) -> None:
@@ -504,13 +533,13 @@ def count_bc(part: str, levels, width: int) -> None:
     them, its deepest root's count, by ``part`` and ``width`` (the
     roots that shared each: levels less pulls are the passes over the
     image saved), and each root once, with its forward phase."""
-    for prof in list(_PROFILERS):
-        prof.metrics.counter("device.bc.levels",
-                             labels={"part": part}).inc(int(sum(levels)))
-        prof.metrics.counter("device.bc.pulls", labels={
+    for metrics in _registries():
+        metrics.counter("device.bc.levels",
+                        labels={"part": part}).inc(int(sum(levels)))
+        metrics.counter("device.bc.pulls", labels={
             "part": part, "width": str(width)}).inc(int(max(levels)))
         if part == "forward":
-            prof.metrics.counter("device.bc.roots").inc(len(levels))
+            metrics.counter("device.bc.roots").inc(len(levels))
 
 
 def current() -> Optional["DeviceCostProfiler"]:
@@ -581,17 +610,21 @@ class DeviceCostProfiler:
     # -- record side ---------------------------------------------------------
 
     def on_call(self, key: str, wall_s: float, compiled: bool,
-                compile_s: float, compile_events: int) -> None:
+                compile_s: float, compile_events: int,
+                counts: bool = True) -> None:
+        """``counts``: this profiler is the one that counts the call on
+        its registry (``_installed``); its own totals take every call."""
         m = self.metrics
-        m.counter("device.exec.calls", labels={"kernel": key}).inc()
-        if compiled:
-            m.counter("device.compile.count",
-                      labels={"kernel": key}).inc()
-            m.histogram("device.compile.ms",
-                        labels={"kernel": key}).update(compile_s * 1e3)
-        else:
-            m.counter("device.compile.cache_hits",
-                      labels={"kernel": key}).inc()
+        if counts:
+            m.counter("device.exec.calls", labels={"kernel": key}).inc()
+            if compiled:
+                m.counter("device.compile.count",
+                          labels={"kernel": key}).inc()
+                m.histogram("device.compile.ms", labels={"kernel": key}) \
+                    .update(compile_s * 1e3)
+            else:
+                m.counter("device.compile.cache_hits",
+                          labels={"kernel": key}).inc()
         with self._lock:
             k = self._kernels.setdefault(
                 key, {"calls": 0, "compiles": 0, "cache_hits": 0,
@@ -623,26 +656,31 @@ class DeviceCostProfiler:
                        **({"compile_ms": round(compile_s * 1e3, 3)}
                           if compiled else {}))
 
-    def on_stamp(self, key: str, device_s: float, stamped: bool) -> None:
+    def on_stamp(self, key: str, device_s: float, stamped: bool,
+                 counts: bool = True) -> None:
         """Watcher thread: one profiled call's time on the device, or
         that it could not be stamped."""
         if not stamped:
-            self.metrics.counter("device.exec.unstamped",
-                                 labels={"kernel": key}).inc()
+            if counts:
+                self.metrics.counter("device.exec.unstamped",
+                                     labels={"kernel": key}).inc()
             return
-        self.metrics.histogram("device.exec.ms",
-                               labels={"kernel": key}).update(
-                                   device_s * 1e3)
+        if counts:
+            self.metrics.histogram("device.exec.ms",
+                                   labels={"kernel": key}).update(
+                                       device_s * 1e3)
         with self._lock:
             k = self._kernels.get(key)
             if k is not None:
                 k["exec_s"] += device_s
             self._totals["exec_s"] += device_s
 
-    def on_xfer(self, direction: str, site: str, nbytes: int) -> None:
+    def on_xfer(self, direction: str, site: str, nbytes: int,
+                counts: bool = True) -> None:
         name = "device.xfer.h2d_bytes" if direction == "h2d" \
             else "device.xfer.d2h_bytes"
-        self.metrics.counter(name, labels={"site": site}).inc(nbytes)
+        if counts:
+            self.metrics.counter(name, labels={"site": site}).inc(nbytes)
         with self._lock:
             self._totals[f"{direction}_bytes"] += nbytes
         rec = self.recorder

@@ -32,7 +32,8 @@ from titan_tpu.obs.tracing import phase
 from titan_tpu.olap.serving.jobs import Job
 from titan_tpu.olap.serving.kinds import (KINDS, ParamError, RunContext,
                                           checkpointing, dense_source,
-                                          sssp_answer, under, wcc_answer)
+                                          sssp_answer, under, wants_parents,
+                                          wcc_answer)
 
 
 @contextmanager
@@ -61,10 +62,14 @@ def _epoch_token(snap, overlay):
 
 
 def _bfs_result(snap, dist_row: np.ndarray, levels: int, inf: int,
-                params: dict) -> dict:
+                params: dict, parent_row=None) -> dict:
     reached = int((dist_row < inf).sum())
     out = {"levels": int(levels), "reached": reached, "n": int(dist_row.shape[0]),
            "dist": dist_row}
+    if parent_row is not None:
+        # the BFS tree, dense ids as ``dist`` is indexed: the source its
+        # own parent, -1 where the source reaches nobody
+        out["parent"] = parent_row
     targets = params.get("targets")
     if targets:
         td = {}
@@ -138,7 +143,10 @@ class Batcher:
         each active job's dist row at its cadence; an injected fault
         raising out of a level boundary fails the WHOLE batch (that is
         what a real worker death does), and each member then retries
-        under its own policy."""
+        under its own policy. A job that asked for ``parents`` saves the
+        parent plane beside ``dist`` and resumes from both (a group is
+        all of one mind: ``kinds._bfs_knobs``); a checkpoint that lacks
+        the plane its job needs is not resumed from."""
         t_fuse0 = time.time()
         fresh: list[Job] = []
         fresh_src: list[int] = []
@@ -165,6 +173,9 @@ class Batcher:
                                     or job.spec.idempotency_key):
                 ck = rec.latest(kind="bfs",
                                 epoch=_epoch_token(snap, overlay))
+                if ck is not None and wants_parents(job.spec.params) \
+                        and "parent" not in ck.arrays:
+                    ck = None
                 if ck is not None:
                     rec.resumed(ck.round)
                 elif job.attempt > 1:
@@ -191,19 +202,28 @@ class Batcher:
             self._bfs_group(fresh, fresh_src, snap, None, 0,
                             overlay=overlay)
         for job, src, ck in resumed:
-            self._bfs_group([job], [src], snap,
-                            np.asarray(ck.arrays["dist"])[None, :],
-                            ck.round, overlay=overlay)
+            self._bfs_group(
+                [job], [src], snap, np.asarray(ck.arrays["dist"])[None, :],
+                ck.round, overlay=overlay,
+                init_parent=np.asarray(ck.arrays["parent"])[None, :]
+                if wants_parents(job.spec.params) else None)
 
     def _bfs_group(self, runnable: list[Job], sources: list[int], snap,
-                   init_dist, start_level: int, overlay=None) -> None:
+                   init_dist, start_level: int, overlay=None,
+                   init_parent=None) -> None:
         from titan_tpu.models.bfs import INF
-        from titan_tpu.models.bfs_hybrid import frontier_bfs_batched
+        from titan_tpu.models.bfs_hybrid import (batched_is_warm,
+                                                 build_chunked_csr,
+                                                 frontier_bfs_batched,
+                                                 warm_batched)
+        from titan_tpu.obs import devprof
 
         K = len(runnable)
         for job in runnable:
             job.batch_k = K
-        started = time.time()
+        # the group's one answer to "with the BFS tree?": its members
+        # agree (the knob is in their batch key)
+        parents = wants_parents(runnable[0].spec.params)
         dropped = [None] * K    # terminal state decided at a boundary
         n = snap.n if hasattr(snap, "n") else snap["n"]
         # mesh placement: overlay leases stay single-device (the
@@ -227,6 +247,18 @@ class Batcher:
                                    if meshed else {}))
                 if job.trace is not None else None
                 for job in runnable]
+        if K == 1 and not meshed and (overlay is None or overlay.empty):
+            # the first lone job on a layout of a new shape builds every
+            # program a source can meet (the push's rungs, the pull's
+            # ladder: bfs_hybrid.warm_batched), so that no later source
+            # builds one inside a served window; the job's own clock
+            # starts behind it
+            g = build_chunked_csr(snap)
+            if not batched_is_warm(g, K, parents=parents):
+                with under(runnable[0].trace, runs[0]), \
+                        phase("bfs.build", K=K, n=g["n"], parents=parents):
+                    warm_batched(g, K, parents=parents)
+        started = time.time()
         # anchor AFTER the run spans open so the first round's window
         # nests inside them (children must not start before parents)
         prev_t = [time.time()]
@@ -260,12 +292,15 @@ class Batcher:
 
         token = _epoch_token(snap, overlay)
 
-        def checkpoint(level, dist, act):
+        def checkpoint(level, state, act):
+            planes = dict(zip(("dist", "parent"),
+                              state if parents else (state,)))
             for i, job in enumerate(runnable):
                 rec = job.recovery
                 if rec is not None and act[i] and rec.due(level):
                     rec.save(level,
-                             {"dist": np.asarray(dist[i, :n])},
+                             {name: np.asarray(a[i, :n])
+                              for name, a in planes.items()},
                              kind="bfs",
                              meta={"epoch": token})
 
@@ -273,13 +308,31 @@ class Batcher:
                          and j.recovery.store is not None
                          for j in runnable)
         try:
-            dist, levels, completed = frontier_bfs_batched(
-                target, sources, max_levels=int(
-                    runnable[0].spec.params.get("max_levels", 1000)),
-                on_level=on_level,
-                init_dist=init_dist, start_level=start_level,
-                checkpoint=checkpoint if wants_ckpt else None,
-                overlay=overlay)
+            # the level loop's leaf phases (bfs.seed, bfs.plan,
+            # bfs.sweep, bfs.exhaust), the readback's and the programs'
+            # kernel spans journal under the FIRST member's `run` span,
+            # as a WCC cohort's do: one thread drives the batch, and at
+            # K = 1 that is the job
+            with under(runnable[0].trace, runs[0]):
+                out, levels, completed = frontier_bfs_batched(
+                    target, sources, max_levels=int(
+                        runnable[0].spec.params.get("max_levels", 1000)),
+                    on_level=on_level, return_device=True,
+                    init_dist=init_dist, start_level=start_level,
+                    checkpoint=checkpoint if wants_ckpt else None,
+                    overlay=overlay, parents=parents,
+                    init_parent=init_parent)
+                # the one readback of the answer: [K, n] depths, and
+                # the [K, n] parents where the group asked for them
+                planes = out if parents else (out,)
+                nbytes = sum(int(a.nbytes) for a in planes)
+                with phase("bfs.result", bytes=nbytes,
+                           parents=parents) as ph:
+                    with ph.sync():
+                        planes = [np.asarray(a) for a in planes]
+                    devprof.count_d2h("bfs.result", nbytes)
+                dist = planes[0]
+                parent = planes[1] if parents else None
         except Exception as e:
             for i, job in enumerate(runnable):
                 if job.trace is not None:
@@ -292,8 +345,9 @@ class Batcher:
                 job.trace.end(runs[i], levels=int(levels[i]))
         for i, job in enumerate(runnable):
             if completed[i]:
-                job.complete(_bfs_result(snap, dist[i], levels[i], inf,
-                                         job.spec.params))
+                job.complete(_bfs_result(
+                    snap, dist[i], levels[i], inf, job.spec.params,
+                    parent[i] if parents else None))
             elif dropped[i] == "timeout":
                 job.time_out()
             else:

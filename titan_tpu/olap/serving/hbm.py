@@ -176,6 +176,14 @@ def snapshot_bc_work_bytes(snap) -> int:
     return bc.work_bytes(snap.n, _pull_columns(snap))
 
 
+def bfs_plane_bytes(n: int, k: int, num_devices: int = 1) -> int:
+    """Device bytes of ONE ``[K, n+1]`` int32 plane of the batched BFS's
+    state (models/bfs_hybrid: ``dist``; ``par``, the BFS tree, for a
+    group that asked for parents), a device's share where the cohort is
+    mesh-placed (the vertex axis shards over the mesh)."""
+    return -(-4 * int(k) * (int(n) + 1) // max(int(num_devices), 1))
+
+
 def meshed_snapshot_csr_bytes(snap, num_devices: int) -> int:
     """PER-DEVICE bytes of a MESH-PLACED chunked CSR (ISSUE 13,
     ``parallel/partition.place_batched_csr``): the ``dstT`` edge image

@@ -42,6 +42,15 @@ class Work(NamedTuple):
     """The working set reserved for a run and released behind it."""
     key: str
     nbytes: Callable                    # bytes(snap)
+    #: the working set follows from who shares the run: ``nbytes`` is
+    #: then bytes(snap, specs, devices), ``specs`` the group's and
+    #: ``devices`` the mesh's size where the run places over one (else
+    #: 1); 0 reserves nothing
+    grouped: bool = False
+
+    def price(self, snap, specs, devices: int) -> int:
+        return self.nbytes(snap, specs, devices) if self.grouped \
+            else self.nbytes(snap)
 
 
 #: marks a meta field a resume cannot do without
@@ -207,9 +216,37 @@ def batch_key(spec) -> Optional[tuple]:
 
 # -- the rows' own lines ------------------------------------------------------
 
+def wants_parents(params: dict) -> bool:
+    """Whether a ``bfs`` job asked for its BFS tree (``"parents":
+    true``: the result array ``parent`` beside ``dist``)."""
+    return params.get("parents") is True
+
+
 def _bfs_knobs(spec) -> tuple:
-    # one shared level loop
-    return (int(spec.params.get("max_levels", 1000)),)
+    # one shared level loop, and one state: a job that wants the tree
+    # does not fuse with one that does not (the group would carry the
+    # second [K, n] plane, and pay its scatters, for all)
+    return (int(spec.params.get("max_levels", 1000)),
+            wants_parents(spec.params))
+
+
+def _bfs_refuses(spec) -> Optional[str]:
+    got = spec.params.get("parents", False) \
+        if isinstance(spec.params, dict) else False
+    if not isinstance(got, bool):
+        return (f"bfs: 'parents' must be true or false, got {got!r}: "
+                "with true the job answers the array 'parent' (the BFS "
+                "tree, GAP's answer) beside 'dist'")
+    return None
+
+
+def _bfs_work(snap, specs, devices: int) -> int:
+    # the parent plane of the jobs that asked for it; the depth plane
+    # every BFS has carried since before the ledger priced working sets
+    # stays unpriced
+    if not wants_parents(specs[0].params):
+        return 0
+    return hbm.bfs_plane_bytes(snap.n, len(specs), devices)
 
 
 def _sssp_knobs(spec) -> tuple:
@@ -375,7 +412,9 @@ _FRONTIER_STATE = ("val", "val_exp")
 KINDS: dict[str, Kind] = {row.name: row for row in (
     # same-snapshot jobs fuse into ONE [K, n] run, which keeps its own
     # checkpoint bookkeeping (Batcher.run_bfs_batch)
-    Kind("bfs", images=(FORWARD,), batch_key=_bfs_knobs, meshes=True),
+    Kind("bfs", images=(FORWARD,),
+         work=Work("bfs-parents", _bfs_work, grouped=True),
+         batch_key=_bfs_knobs, meshes=True, refuse=_bfs_refuses),
     Kind("sssp", _run_sssp, images=(FORWARD,), batch_key=_sssp_knobs,
          round_trace=True,
          checkpoint=Checkpoint(

@@ -638,6 +638,12 @@ class JobScheduler:
         snapshot when None); never raises into the worker path."""
         if self.recorder is None:
             return None
+        if job is not None and self.profiler is not None:
+            # the job's programs journal `kernel` spans under its `run`
+            # span from the watcher's thread (obs/devprof), the last of
+            # them after the job has ended: in hand before the tree is
+            # read, so that the bundle's tree is GET /trace's
+            devprof.drain()
         try:
             path = self.recorder.dump(
                 reason=reason,
@@ -1053,9 +1059,12 @@ class JobScheduler:
                         if meshed else image.nbytes(snap),
                         snap))
                 work_key = None
-                if row.work is not None:
+                work_bytes = 0 if row.work is None else row.work.price(
+                    snap, [job.spec for job in group],
+                    int(self.mesh.devices.size) if meshed else 1)
+                if work_bytes:
                     work_key = (row.work.key, ledger_key)
-                    images.append((work_key, row.work.nbytes(snap), None))
+                    images.append((work_key, work_bytes, None))
                 nbytes = sum(image[1] for image in images)
                 held = []
                 try:

@@ -347,7 +347,7 @@ class GraphServer:
         kind = body.get("kind", "bfs")
         params = dict(body.get("params") or {})
         for key in ("source", "source_dense", "sources", "sources_dense",
-                    "targets", "max_levels",
+                    "targets", "max_levels", "parents",
                     "iterations", "damping", "delta", "quantile_mass"):
             if key in body:
                 params[key] = body[key]
